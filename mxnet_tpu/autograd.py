@@ -35,9 +35,6 @@ __all__ = [
 _STATE = threading.local()
 
 
-from ._jax_compat import typeof as _typeof
-
-
 def _st():
     if not hasattr(_STATE, "recording"):
         _STATE.recording = False
@@ -183,7 +180,7 @@ def _record_invoke(opref, primals, kwargs, array_args):
     multi = isinstance(results, (tuple, list))
     outs = list(results) if multi else [results]
     node = TapeNode(opref.name, vjp_fn, parents,
-                    [_typeof(o) for o in outs], multi=multi)
+                    [jax.typeof(o) for o in outs], multi=multi)
     return results, node
 
 
@@ -248,7 +245,7 @@ def _backward_walk(heads, head_grads, targets=None, retain_graph=False):
     for h, hg in zip(heads, head_grads):
         g = hg._data if isinstance(hg, NDArray) else hg
         if g is None:
-            aval = _typeof(h._data)
+            aval = jax.typeof(h._data)
             g = jnp.ones(aval.shape, aval.dtype) if jnp.issubdtype(
                 aval.dtype, jnp.floating) else _zero_cotangent(aval)
         if h._autograd_node is FREED:
@@ -517,7 +514,7 @@ class Function:
             else:
                 parents.append(None)
         node = TapeNode(type(self).__name__, vjp_fn, parents,
-                        [_typeof(o._data) for o in outs], multi=multi)
+                        [jax.typeof(o._data) for o in outs], multi=multi)
         for i, o in enumerate(outs):
             o._autograd_node = node
             o._autograd_idx = i

@@ -40,6 +40,19 @@ _jax.config.update(
     "jax_default_matmul_precision",
     os.environ.get("MXNET_TPU_MATMUL_PRECISION", "highest"))
 
+# Persistent compile cache.  JAX reads JAX_COMPILATION_CACHE_DIR itself;
+# when it is unset the cache lives at a FIXED path inside the checkout — a
+# path that moves (tempfile, pid, timestamp) is never found again by the
+# next process.  A process pinned to the CPU gets none: XLA:CPU logs two
+# multi-KB "machine type doesn't match" errors per cache hit, and the CPU
+# runs are the tests, whose compiles are small.
+if not os.environ.get("JAX_COMPILATION_CACHE_DIR") and \
+        os.environ.get("JAX_PLATFORMS", "").strip().lower() != "cpu":
+    _jax.config.update(
+        "jax_compilation_cache_dir",
+        os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), ".jax_cache"))
+
 
 string_types = (str,)
 numeric_types = (float, int, onp.generic)

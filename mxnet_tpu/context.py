@@ -69,9 +69,10 @@ class Context:
             return devs[min(self.device_id, len(devs) - 1)]
         accel = _accel_devices()
         if not accel:
-            # graceful degrade: no accelerator present, run on host
-            devs = _local_devices()
-            return devs[min(self.device_id, len(devs) - 1)]
+            raise MXNetError(
+                f"context {self}: no accelerator visible to jax (platform "
+                f"found: {jax.default_backend()}); use mx.cpu() to run on "
+                "the host")
         if self.device_id >= len(accel):
             raise MXNetError(
                 f"context {self} out of range: {len(accel)} device(s) visible")
@@ -106,20 +107,8 @@ _ACCEL_CACHE = None
 
 
 def _local_devices(platform: str = None):
-    """This process's addressable devices, optionally of one backend.
-    Falls back to filtering the global list by process_index on backends
-    without the local/global distinction."""
-    try:
-        return jax.local_devices(backend=platform) if platform \
-            else jax.local_devices()
-    except Exception:
-        devs = jax.devices(platform) if platform else jax.devices()
-        try:
-            me = jax.process_index()
-        except Exception:
-            me = 0
-        local = [d for d in devs if getattr(d, "process_index", me) == me]
-        return local or devs
+    """This process's addressable devices, optionally of one backend."""
+    return jax.local_devices(backend=platform)
 
 
 def _accel_devices():
@@ -169,38 +158,14 @@ def current_context() -> Context:
     return Context.default_ctx()
 
 
-# HBM per chip by device-kind substring — the fallback gauge total when
-# the backend exposes no allocator stats (e.g. tunneled devices)
-_HBM_BYTES = (("v5 lite", 16 << 30), ("v5e", 16 << 30),
-              ("v5p", 95 << 30), ("v4", 32 << 30), ("v6", 32 << 30),
-              ("v3", 16 << 30), ("v2", 8 << 30))
-
-
 def gpu_memory_info(device_id: int = 0):
-    """(free, total) bytes of device HBM (reference:
-    ``mx.context.gpu_memory_info``).
-
-    Primary source: the backend allocator (``device.memory_stats``).
-    Fallback (backends that return no stats, e.g. tunneled devices):
-    live-buffer accounting over ``jax.live_arrays`` against the known
-    per-chip HBM size — an upper bound on free memory, still a real
-    gauge instead of the old silent ``(0, 0)``."""
+    """(free, total) bytes of device HBM from the backend allocator
+    (``device.memory_stats``; reference: ``mx.context.gpu_memory_info``).
+    Raises ``MXNetError`` without an accelerator, like any accelerator
+    context."""
     dev = Context("tpu", device_id).jax_device()
-    stats = None
-    try:
-        stats = dev.memory_stats()
-    except Exception:
-        pass
-    if stats and stats.get("bytes_limit"):
-        total = stats["bytes_limit"]
-        used = stats.get("bytes_in_use", 0)
-        return (max(total - used, 0), total)
-    # per-device shard bytes over jax.live_arrays() — the same walk the
-    # telemetry memory accountant reconciles against (charging full
-    # global nbytes would overcount sharded arrays mesh-wide)
-    from .telemetry.memory import _devstr, live_device_bytes
-
-    used = live_device_bytes().get(_devstr(dev), 0)
-    kind = getattr(dev, "device_kind", "").lower()
-    total = next((b for k, b in _HBM_BYTES if k in kind), 0)
-    return (max(total - used, 0), total)
+    stats = dev.memory_stats()
+    if not stats or not stats.get("bytes_limit"):
+        raise MXNetError(f"{dev} reports no allocator stats")
+    total = stats["bytes_limit"]
+    return (max(total - stats.get("bytes_in_use", 0), 0), total)

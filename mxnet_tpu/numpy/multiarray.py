@@ -320,7 +320,7 @@ _UNARY = {
     "sinh": jnp.sinh, "cosh": jnp.cosh, "tanh": jnp.tanh,
     "arcsinh": jnp.arcsinh, "arccosh": jnp.arccosh, "arctanh": jnp.arctanh,
     "degrees": jnp.degrees, "radians": jnp.radians,
-    "rint": jnp.rint, "fix": jnp.fix, "floor": jnp.floor,
+    "rint": jnp.rint, "fix": jnp.trunc, "floor": jnp.floor,
     "ceil": jnp.ceil, "trunc": jnp.trunc, "round": jnp.round,
     "around": jnp.round,
     "logical_not": jnp.logical_not, "invert": jnp.invert,

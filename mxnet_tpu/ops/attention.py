@@ -51,18 +51,12 @@ def _interpret() -> bool:
     return os.environ.get("MXNET_FLASH_INTERPRET", "") == "1"
 
 
-from .._jax_compat import compiler_params as _compiler_params
-
-
 def _pallas_backend_ok() -> bool:
     """Shared Pallas backend gate (flash, q8_matvec): interpret mode or a
     real TPU backend."""
     if _interpret():
         return True
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def _use_pallas() -> bool:
@@ -352,7 +346,7 @@ def _pallas_fwd(q, k, v, scale, causal, kmask=None, seed=None, dropout=0.0,
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
-        compiler_params=_compiler_params(pltpu,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
     )(*args)
@@ -469,7 +463,7 @@ def _pallas_bwd_dq(q, k, v, g, lse_rep, dlt_rep, scale, causal, kmask=None,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((B * H, L, D), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        compiler_params=_compiler_params(pltpu,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
     )(*args)
@@ -621,7 +615,7 @@ def _pallas_bwd_dkv(q, k, v, g, lse_rep, dlt_rep, scale, causal, kmask=None,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch,
-        compiler_params=_compiler_params(pltpu,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
     )(*args)
@@ -778,14 +772,14 @@ _PLAIN_ATTN_MAX_SCORES = 512 * 512
 # --------------------------------------------------------------------------- #
 # measured dispatch (VERDICT r2 item 4: "chosen path == fastest measured
 # path").  Constants are the crossover sequence lengths from
-# ``benchmark/attention_bench.py`` on v5e (causal, B4 H8 D64, bf16) — see
-# the sweep table in BASELINE.md.  Entries are (max_seq, impl); the first
-# row whose bound covers max(Lq, Lk) wins.  "plain" materializes O(L²)
+# ``benchmark/attention_bench.py`` on v5e (causal, B4 H8 D64, bf16).
+# Entries are (max_seq, impl); the first row whose bound covers
+# max(Lq, Lk) wins.  "plain" materializes O(L²)
 # scores (fused-softmax), "xla" is the blockwise lax.scan path, "pallas"
 # the Pallas kernels (fwd + bwd).
 # --------------------------------------------------------------------------- #
 _PATH_TABLE = {
-    # measured 2026-07-30 on v5e (see BASELINE.md sweep):
+    # measured 2026-07-30 on v5e (builders' round-3 sweep):
     #   fwd:   512 plain 0.80ms | 1k-4k xla (1.17/2.02/5.92ms, pallas
     #          1.58/3.43/10.63) | 8k pallas 38.8ms (xla 39.0)
     #   train: 512 plain 0.79ms | 1k xla 1.74ms (plain 2.12, pallas 2.27)
@@ -995,7 +989,6 @@ def ring_attention(q, k, v, *, scale: Optional[float] = None,
     """Sequence-parallel attention: inputs sharded over ``axis`` on the seq
     dim; communication is ``ppermute`` around the ring (ICI-neighbor
     traffic only, the canonical long-context pattern)."""
-    from .._jax_compat import NO_CHECK, shard_map
     from ..parallel.mesh import default_mesh, local_mesh_axes, P
     from jax.sharding import NamedSharding
 
@@ -1007,11 +1000,11 @@ def ring_attention(q, k, v, *, scale: Optional[float] = None,
     q = jax.device_put(q, seq_sharding)
     k = jax.device_put(k, seq_sharding)
     v = jax.device_put(v, seq_sharding)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_ring_attn_local, scale=float(scale),
                           causal=bool(causal), axis=axis, n_shards=n),
         mesh=mesh,
         in_specs=(P(None, None, axis, None),) * 3,
         out_specs=P(None, None, axis, None),
-        **NO_CHECK)
+        check_vma=False)
     return fn(q, k, v)

@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding
 
-from .._jax_compat import NO_CHECK as _NO_CHECK, shard_map
 from .mesh import Mesh, P, default_mesh, local_mesh_axes
 
 __all__ = ["all_reduce", "all_gather", "reduce_scatter", "broadcast",
@@ -76,8 +75,8 @@ def all_reduce(x, mesh: Optional[Mesh] = None, axis: str = "dp",
     red = _OPS[op]
     data = _unwrap(x)
 
-    fn = shard_map(lambda v: red(v, axis), mesh=mesh,
-                   in_specs=P(axis), out_specs=P())
+    fn = jax.shard_map(lambda v: red(v, axis), mesh=mesh,
+                       in_specs=P(axis), out_specs=P())
     # input must be laid out sharded over axis; put it there if it isn't
     data = jax.device_put(data, NamedSharding(mesh, P(axis)))
     return _wrap_like(fn(data), x)
@@ -89,9 +88,9 @@ def all_gather(x, mesh: Optional[Mesh] = None, axis: str = "dp",
     (s*n, ...) on every device."""
     mesh = mesh or default_mesh()
     data = jax.device_put(_unwrap(x), NamedSharding(mesh, P(axis)))
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda v: jax.lax.all_gather(v, axis, tiled=tiled),
-        mesh=mesh, in_specs=P(axis), out_specs=P(), **_NO_CHECK)
+        mesh=mesh, in_specs=P(axis), out_specs=P(), check_vma=False)
     return _wrap_like(fn(data), x)
 
 
@@ -109,7 +108,7 @@ def reduce_scatter(x, mesh: Optional[Mesh] = None, axis: str = "dp",
             f"leading dim {data.shape[0]} not divisible by axis size {n}")
     # replicate input, psum_scatter inside shard_map
     data = jax.device_put(data, NamedSharding(mesh, P()))
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda v: jax.lax.psum_scatter(v, axis, scatter_dimension=0,
                                        tiled=True),
         mesh=mesh, in_specs=P(), out_specs=P(axis))
@@ -134,7 +133,7 @@ def broadcast(x, mesh: Optional[Mesh] = None, axis: str = "dp",
         contrib = jnp.where(idx == root, v, jnp.zeros_like(v))
         return jax.lax.psum(contrib, axis)
 
-    fn = shard_map(_bcast, mesh=mesh, in_specs=P(axis), out_specs=P())
+    fn = jax.shard_map(_bcast, mesh=mesh, in_specs=P(axis), out_specs=P())
     return _wrap_like(fn(data), x)
 
 
@@ -147,7 +146,7 @@ def ring_pass(x, mesh: Optional[Mesh] = None, axis: str = "sp",
     n = local_mesh_axes(mesh)[axis]
     perm = [(i, (i + shift) % n) for i in range(n)]
     data = jax.device_put(_unwrap(x), NamedSharding(mesh, P(axis)))
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(jax.lax.ppermute, axis_name=axis, perm=perm),
         mesh=mesh, in_specs=P(axis), out_specs=P(axis))
     return _wrap_like(fn(data), x)

@@ -10,7 +10,6 @@ and XLA emits the collectives; multi-host membership comes from
 """
 from __future__ import annotations
 
-import inspect
 import math
 import os
 import threading
@@ -160,13 +159,7 @@ def _configure_cpu_collectives():
     impl = os.environ.get("MXNET_CPU_COLLECTIVES", "gloo")
     if impl.lower() in ("", "0", "none"):
         return
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", impl)
-    except Exception:
-        # older jaxlib without pluggable CPU collectives: leave the
-        # default in place; the rendezvous still works, collectives
-        # surface their own (loud) backend error
-        pass
+    jax.config.update("jax_cpu_collectives_implementation", impl)
 
 
 def _init_timeout_from_env():
@@ -240,10 +233,7 @@ def init_distributed(coordinator_address: Optional[str] = None,
                   num_processes=num_processes,
                   process_id=process_id,
                   local_device_ids=local_device_ids)
-    # older jax has no bounded init — degrade to unbounded rather than
-    # TypeError (the retry loop still bounds total attempts)
-    if initialization_timeout is not None and "initialization_timeout" \
-            in inspect.signature(jax.distributed.initialize).parameters:
+    if initialization_timeout is not None:
         # jax takes whole seconds: round UP so a sub-second budget
         # becomes 1s, never a truncated 0 (= immediate deadline)
         kwargs["initialization_timeout"] = max(

@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding
 
-from .._jax_compat import NO_CHECK, shard_map
-
 from .mesh import Mesh, P, default_mesh, local_mesh_axes
 
 __all__ = ["gpipe_apply", "stack_stage_params"]
@@ -110,12 +108,7 @@ def gpipe_apply(stage_fn: Callable, stage_params, x, mesh: Mesh = None,
 
         state0 = jnp.zeros(xs_local.shape[1:], out_dtype)
         # the carry varies per pp shard; mark the init accordingly
-        # (jax 0.4.x predates pcast/pvary — there the rep checker is
-        # simply disabled below and no mark is needed)
-        if hasattr(lax, "pcast"):
-            state0 = lax.pcast(state0, (axis,), to="varying")
-        elif hasattr(lax, "pvary"):
-            state0 = lax.pvary(state0, (axis,))
+        state0 = lax.pcast(state0, (axis,), to="varying")
         _, hist = lax.scan(tick, state0, jnp.arange(M + S - 1))
         # the final stage emits microbatch m at tick m + S - 1
         outs = lax.dynamic_slice_in_dim(hist, S - 1, M, axis=0)
@@ -127,16 +120,10 @@ def gpipe_apply(stage_fn: Callable, stage_params, x, mesh: Mesh = None,
     params = jax.device_put(params, jax.tree.map(
         lambda s: NamedSharding(mesh, s), pspec))
     x_spec = P(None, batch_axis) if batch_axis else P()
-    kwargs = {}
-    if "check_rep" in NO_CHECK:
-        # jax 0.4.x: the old replication checker has no pvary marks to
-        # see through the ppermute ring — disable it outright
-        kwargs.update(NO_CHECK)
-    elif param_specs is not None or batch_axis:
-        # in-stage collectives (tp) defeat the static replication checker
-        kwargs.update(NO_CHECK)
-    fn = shard_map(shard_fn, mesh=mesh, in_specs=(pspec, x_spec),
-                   out_specs=x_spec, **kwargs)
+    # in-stage collectives (tp) defeat the static replication checker
+    check_vma = param_specs is None and not batch_axis
+    fn = jax.shard_map(shard_fn, mesh=mesh, in_specs=(pspec, x_spec),
+                       out_specs=x_spec, check_vma=check_vma)
     out = fn(params, xs)
     result = out.reshape((B,) + out.shape[2:])
     return NDArray(result) if isinstance(x, NDArray) else result

@@ -390,18 +390,17 @@ def test_ring_attention_grads_match_full():
     import jax.numpy as jnp
     from mxnet_tpu import parallel
     from mxnet_tpu.ops.attention import _ring_attn_local
-    from mxnet_tpu._jax_compat import NO_CHECK, shard_map
     from mxnet_tpu.parallel.mesh import P
     import functools
 
     mesh = parallel.make_mesh({"sp": 8})
     q, k, v = (_rand(1, 2, 64, 8) for _ in range(3))
 
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_ring_attn_local, scale=0.125, causal=True,
                           axis="sp", n_shards=8),
         mesh=mesh, in_specs=(P(None, None, "sp", None),) * 3,
-        out_specs=P(None, None, "sp", None), **NO_CHECK)
+        out_specs=P(None, None, "sp", None), check_vma=False)
 
     def ring_loss(q, k, v):
         return jnp.sum(fn(q, k, v).astype(jnp.float32) ** 2)

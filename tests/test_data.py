@@ -476,13 +476,16 @@ class TestDevicePrefetch:
         bs = list(p)
         assert len(bs) == 3 and all(_dev_id(b.data[0]) == 4 for b in bs)
 
-    def test_estimator_wraps_epoch_iterator(self):
-        from mxnet_tpu import gluon
+    def test_estimator_wraps_epoch_iterator(self, monkeypatch):
+        import jax
+        from mxnet_tpu import context, gluon
         from mxnet_tpu.gluon.contrib.estimator import Estimator
         from mxnet_tpu.gluon.data.dataloader import DevicePrefetchIter
         net = gluon.nn.Dense(2, in_units=3)
         data = [(onp.ones((2, 3), onp.float32), onp.zeros((2, 2), onp.float32))]
-        # accelerator context (degrades to host device here): ring engaged
+        # accelerator context: ring engaged (no accelerator here, so the
+        # host devices stand in for it)
+        monkeypatch.setattr(context, "_ACCEL_CACHE", jax.local_devices())
         est = Estimator(net, gluon.loss.L2Loss(),
                         context=mx.Context("tpu", 0))
         it = est._prefetched(data)

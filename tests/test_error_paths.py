@@ -32,8 +32,11 @@ class TestErrorPaths:
             with pytest.raises(MXNetError):
                 mx.tpu(len(accel) + 5).jax_device()
         else:
-            # documented graceful degrade: no accelerator -> host device
-            assert mx.tpu(99).jax_device().platform == "cpu"
+            # no accelerator: an accelerator context is an error naming
+            # the platform found, never a silent host device
+            for ctx in (mx.tpu(0), mx.gpu(0), mx.tpu(99)):
+                with pytest.raises(MXNetError, match="cpu"):
+                    ctx.jax_device()
 
     def test_uninitialized_parameter_data(self):
         from mxnet_tpu.gluon import Parameter
@@ -81,10 +84,15 @@ class TestErrorPaths:
 
 def test_gpu_memory_info_gauge():
     """HBM gauge (reference mx.context.gpu_memory_info): returns a
-    (free, total) pair; free <= total; on accelerator-less backends the
-    total degrades to 0 rather than raising (no HBM to gauge)."""
+    (free, total) pair with free <= total; without an accelerator there
+    is no HBM to gauge and the call raises like any accelerator context
+    (the reference raises without CUDA)."""
+    import jax
     import mxnet_tpu as mx
+    if all(d.platform == "cpu" for d in jax.devices()):
+        with pytest.raises(MXNetError):
+            mx.context.gpu_memory_info(0)
+        return
     free, total = mx.context.gpu_memory_info(0)
     assert isinstance(free, int) and isinstance(total, int)
-    assert free >= 0 and total >= 0
-    assert free <= total or total == 0
+    assert 0 <= free <= total
