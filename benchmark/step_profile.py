@@ -644,9 +644,10 @@ def main():
     onp.asarray(out.asnumpy())  # the traced region ends in a readback
     jax.profiler.stop_trace()
 
-    records = profiler_xla.parse_trace(td)
+    records = (profiler_xla.parse_xplane(td) or {"ops": []})["ops"]
     for r in records:
         r["dur_us"] /= args.iters
+        r["self_us"] /= args.iters
         r["flops"] //= args.iters
         r["bytes"] //= args.iters
     rows = profiler_xla.aggregate(records, by=args.by)

@@ -324,7 +324,7 @@ class FusedStep:
         if tr._window_pos < N:
             fn = self._get_fn("micro", sig)
             t0 = time.perf_counter()
-            with telemetry.annotation("mx:fused_step:micro"):
+            with telemetry.span("mx:fused_step:micro"):
                 outs, self._accum, new_frozen = fn(
                     train_vals, frozen_vals, self._accum, key, *args)
             tele["lat_micro"].observe(time.perf_counter() - t0)
@@ -349,7 +349,7 @@ class FusedStep:
         states = [tr._states[i] for i in self._train_idx]
         fn = self._get_fn("apply", sig)
         t0 = time.perf_counter()
-        with telemetry.annotation("mx:fused_step:apply"):
+        with telemetry.span("mx:fused_step:apply"):
             outs, new_ws, new_ss, new_frozen, new_accum = fn(
                 train_vals, states, frozen_vals,
                 self._accum if N > 1 else [], key,

@@ -754,6 +754,9 @@ class _DecodeEngine:
         return self._scan_token(x_tok, pos, kp, vp, sw, q8,
                                 per_slot=True, pages=(pt, page))
 
+    # what no inner scope names is ``mx.dense`` (an operation's region is
+    # its INNERMOST ``mx.*`` scope): the embeddings here
+    @jax.named_scope("mx.dense")
     def _scan_token(self, x_tok, pos, ck, cv, sw, q8, per_slot,
                     pages=None):
         from ..ops.attention import rope as _rope
@@ -792,6 +795,7 @@ class _DecodeEngine:
             # and costs the f32 path nothing
             quant = isinstance(ck, tuple)
 
+            @jax.named_scope("mx.paged_view")
             def _paged_view(pool_l):
                 # (NPAGES, KV, page, D) pool layer -> (B, KV, T, D)
                 # per-slot dense views through the page table; sentinel
@@ -809,6 +813,7 @@ class _DecodeEngine:
                 return jnp.moveaxis(g, 2, 1).reshape(B, KV, self.total,
                                                      D)
 
+        @jax.named_scope("mx.dense")
         def body(x, xs):
             w, kc, vc = xs                    # per-layer slices
             if pages is not None:
@@ -835,18 +840,23 @@ class _DecodeEngine:
                     _fc(h, w["qkv_w"], w["qkv_b"], flatten=False)
                 q, k, v = (qkv[:, j * U:(j + 1) * U].reshape(B, H, 1, D)
                            for j in range(3))
-            if per_slot:
-                kc = kc.at[iB, :, pos, :].set(k[:, :, 0, :])
-                vc = vc.at[iB, :, pos, :].set(v[:, :, 0, :])
-            else:
-                kc = lax.dynamic_update_slice(kc, k, (0, 0, pos, 0))
-                vc = lax.dynamic_update_slice(vc, v, (0, 0, pos, 0))
-            qg = q.reshape(B, KV, H // KV, D)
-            s = jnp.einsum("bkgd,bktd->bkgt", qg, kc,
-                           preferred_element_type=jnp.float32) * scale
-            s = jnp.where(idx[:, :, None] <= pos_b, s, -1e30)
-            p = jax.nn.softmax(s, axis=-1).astype(cdtype)
-            o = jnp.einsum("bkgt,bktd->bkgd", p, vc).reshape(B, U)
+            with jax.named_scope("mx.kv_write"):
+                if per_slot:
+                    kc = kc.at[iB, :, pos, :].set(k[:, :, 0, :])
+                    vc = vc.at[iB, :, pos, :].set(v[:, :, 0, :])
+                else:
+                    kc = lax.dynamic_update_slice(kc, k,
+                                                  (0, 0, pos, 0))
+                    vc = lax.dynamic_update_slice(vc, v,
+                                                  (0, 0, pos, 0))
+            with jax.named_scope("mx.attn"):
+                qg = q.reshape(B, KV, H // KV, D)
+                s = jnp.einsum(
+                    "bkgd,bktd->bkgt", qg, kc,
+                    preferred_element_type=jnp.float32) * scale
+                s = jnp.where(idx[:, :, None] <= pos_b, s, -1e30)
+                p = jax.nn.softmax(s, axis=-1).astype(cdtype)
+                o = jnp.einsum("bkgt,bktd->bkgd", p, vc).reshape(B, U)
             if llama:
                 x = x + (_q8l(o, w["o"]) if int8 else
                          _fc(o, w["o_w"], None, no_bias=True,
@@ -876,39 +886,51 @@ class _DecodeEngine:
                 x = x + _fc(hh, w["fc2_w"], w["fc2_b"], flatten=False)
             return x, (k, v)
 
-        x, (knew, vnew) = lax.scan(body, x, (sw, ck, cv))
+        # the scan's own slicing of the stacked pools (its xs: a copy of
+        # each layer's K and V pool on the v5e, PERF.md section 5) is the
+        # first leg of pool -> view, so the scan is ``mx.paged_view`` and
+        # its body ``mx.dense`` but for the regions named inside
+        with jax.named_scope("mx.paged_view"):
+            x, (knew, vnew) = lax.scan(body, x, (sw, ck, cv))
         # knew/vnew: (NL, B, KV, 1, D) — all layers' new columns land in
         # the carried caches as ONE update (slice, or per-slot scatter)
-        if pages is not None:
-            # slot b's position pos[b] lives at (page pt[b, pos//page],
-            # offset pos % page).  Retired slots carry the sentinel in
-            # their table rows so the scatter DROPS their zombie writes;
-            # the clip keeps a stale pos == T from indexing past the
-            # table (it would otherwise clamp onto a live entry).
-            pg = pt[iB, jnp.minimum(pos // page, maxp - 1)]
-            newk = jnp.moveaxis(knew[:, :, :, 0, :], 0, 1)
-            newv = jnp.moveaxis(vnew[:, :, :, 0, :], 0, 1)
-            if quant:
-                # requantizing page RMW: dequantize the frontier page,
-                # land the column, re-quantize (old scale as floor)
-                ck = _kv_step_rmw(ck, pg, iB, pos % page, newk)
-                cv = _kv_step_rmw(cv, pg, iB, pos % page, newv)
+        with jax.named_scope("mx.page_write"):
+            if pages is not None:
+                # slot b's position pos[b] lives at (page
+                # pt[b, pos//page], offset pos % page).  Retired slots
+                # carry the sentinel in their table rows so the scatter
+                # DROPS their zombie writes; the clip keeps a stale
+                # pos == T from indexing past the table (it would
+                # otherwise clamp onto a live entry).
+                pg = pt[iB, jnp.minimum(pos // page, maxp - 1)]
+                newk = jnp.moveaxis(knew[:, :, :, 0, :], 0, 1)
+                newv = jnp.moveaxis(vnew[:, :, :, 0, :], 0, 1)
+                if quant:
+                    # requantizing page RMW: dequantize the frontier
+                    # page, land the column, re-quantize (old scale as
+                    # floor)
+                    ck = _kv_step_rmw(ck, pg, iB, pos % page, newk)
+                    cv = _kv_step_rmw(cv, pg, iB, pos % page, newv)
+                else:
+                    ck = ck.at[:, pg, :, pos % page, :].set(newk,
+                                                            mode="drop")
+                    cv = cv.at[:, pg, :, pos % page, :].set(newv,
+                                                            mode="drop")
+            elif per_slot:
+                ck = ck.at[:, iB, :, pos, :].set(
+                    jnp.moveaxis(knew[:, :, :, 0, :], 0, 1))
+                cv = cv.at[:, iB, :, pos, :].set(
+                    jnp.moveaxis(vnew[:, :, :, 0, :], 0, 1))
             else:
-                ck = ck.at[:, pg, :, pos % page, :].set(newk,
-                                                        mode="drop")
-                cv = cv.at[:, pg, :, pos % page, :].set(newv,
-                                                        mode="drop")
-        elif per_slot:
-            ck = ck.at[:, iB, :, pos, :].set(
-                jnp.moveaxis(knew[:, :, :, 0, :], 0, 1))
-            cv = cv.at[:, iB, :, pos, :].set(
-                jnp.moveaxis(vnew[:, :, :, 0, :], 0, 1))
-        else:
-            ck = lax.dynamic_update_slice(ck, knew, (0, 0, 0, pos, 0))
-            cv = lax.dynamic_update_slice(cv, vnew, (0, 0, 0, pos, 0))
-        xl = _call(self.model.ln_f, x)
-        return self._head_logits(xl, q8), ck, cv
+                ck = lax.dynamic_update_slice(ck, knew,
+                                              (0, 0, 0, pos, 0))
+                cv = lax.dynamic_update_slice(cv, vnew,
+                                              (0, 0, 0, pos, 0))
+        with jax.named_scope("mx.head"):
+            xl = _call(self.model.ln_f, x)
+            return self._head_logits(xl, q8), ck, cv
 
+    @jax.named_scope("mx.dense")   # but for the regions named inside
     def chunk_tokens(self, toks, off, nlast, ptrow, page, kp, vp, sw,
                      q8=None):
         """ONE CHUNK of a single sequence's prefill against the PAGED
@@ -954,25 +976,27 @@ class _DecodeEngine:
         # cached tokens 0..off+j (its own column included post-update)
         mask = jnp.arange(T, dtype=jnp.int32)[None, :] <= cpos[:, None]
 
+        @jax.named_scope("mx.dense")
         def body(x, xs):
             w, kpl, vpl = xs
             # dense (1, KV, T, D) views of this slot's cached prefix,
             # gathered through its page-table row (sentinel -> zeros;
             # int8 pools dequantize in the same gather)
-            if quant:
-                kpl = _kv_dequant(
-                    kpl[0].at[ptrow].get(mode="fill", fill_value=0),
-                    kpl[1].at[ptrow].get(mode="fill", fill_value=0),
-                    cdtype)
-                vpl = _kv_dequant(
-                    vpl[0].at[ptrow].get(mode="fill", fill_value=0),
-                    vpl[1].at[ptrow].get(mode="fill", fill_value=0),
-                    cdtype)
-            else:
-                kpl = kpl.at[ptrow].get(mode="fill", fill_value=0)
-                vpl = vpl.at[ptrow].get(mode="fill", fill_value=0)
-            kc = jnp.moveaxis(kpl, 1, 0).reshape(KV, T, D)[None]
-            vc = jnp.moveaxis(vpl, 1, 0).reshape(KV, T, D)[None]
+            with jax.named_scope("mx.paged_view"):
+                if quant:
+                    kpl = _kv_dequant(
+                        kpl[0].at[ptrow].get(mode="fill", fill_value=0),
+                        kpl[1].at[ptrow].get(mode="fill", fill_value=0),
+                        cdtype)
+                    vpl = _kv_dequant(
+                        vpl[0].at[ptrow].get(mode="fill", fill_value=0),
+                        vpl[1].at[ptrow].get(mode="fill", fill_value=0),
+                        cdtype)
+                else:
+                    kpl = kpl.at[ptrow].get(mode="fill", fill_value=0)
+                    vpl = vpl.at[ptrow].get(mode="fill", fill_value=0)
+                kc = jnp.moveaxis(kpl, 1, 0).reshape(KV, T, D)[None]
+                vc = jnp.moveaxis(vpl, 1, 0).reshape(KV, T, D)[None]
             if llama:
                 h = _rms(x, w["rms1_g"], eps=eps1)
                 if int8:
@@ -1005,14 +1029,17 @@ class _DecodeEngine:
             v = v.astype(cdtype)
             # chunk K/V lands in the dense view BEFORE attention, so
             # one mask covers prefix and intra-chunk causality together
-            kc = lax.dynamic_update_slice(kc, k, (0, 0, off, 0))
-            vc = lax.dynamic_update_slice(vc, v, (0, 0, off, 0))
-            qg = q.reshape(1, KV, G, C, D)
-            s = jnp.einsum("bkgcd,bktd->bkgct", qg, kc,
-                           preferred_element_type=jnp.float32) * scale
-            s = jnp.where(mask[None, None, None], s, -1e30)
-            p = jax.nn.softmax(s, axis=-1).astype(cdtype)
-            o = jnp.einsum("bkgct,bktd->bkgcd", p, vc)
+            with jax.named_scope("mx.kv_write"):
+                kc = lax.dynamic_update_slice(kc, k, (0, 0, off, 0))
+                vc = lax.dynamic_update_slice(vc, v, (0, 0, off, 0))
+            with jax.named_scope("mx.attn"):
+                qg = q.reshape(1, KV, G, C, D)
+                s = jnp.einsum(
+                    "bkgcd,bktd->bkgct", qg, kc,
+                    preferred_element_type=jnp.float32) * scale
+                s = jnp.where(mask[None, None, None], s, -1e30)
+                p = jax.nn.softmax(s, axis=-1).astype(cdtype)
+                o = jnp.einsum("bkgct,bktd->bkgcd", p, vc)
             o = o.transpose(0, 3, 1, 2, 4).reshape(1, C, U)
             if llama:
                 x = x + (_q8l(o[0], w["o"])[None] if int8 else
@@ -1045,45 +1072,50 @@ class _DecodeEngine:
                 x = x + _fc(hh, w["fc2_w"], w["fc2_b"], flatten=False)
             return x, (k, v)
 
-        x, (knew, vnew) = lax.scan(body, x, (sw, kp, vp))
+        with jax.named_scope("mx.paged_view"):   # see _scan_token
+            x, (knew, vnew) = lax.scan(body, x, (sw, kp, vp))
         # knew/vnew: (NL, 1, KV, C, D) — scatter every chunk column
         # through the page-table row.  Positions past the reserved
         # pages (bucket-padded tails) resolve to the sentinel and DROP;
         # the explicit cpos < T guard covers tails that would otherwise
         # CLIP onto the row's own last page and corrupt earlier tokens.
-        if quant:
-            # requantizing page-WINDOW RMW: the C consecutive columns
-            # touch at most ntp consecutive pages of this row (static
-            # in C and page, so the program shape is unchanged).  Pad
-            # columns past ``nlast`` are masked OUT here — unlike the
-            # f32 path's harmless garbage-but-unreachable writes, a pad
-            # column would poison its page's shared SCALE.
-            ntp = (C + page - 2) // page + 1
-            p0 = off // page
-            widx = p0 + jnp.arange(ntp, dtype=jnp.int32)
-            wpgs = jnp.where(widx < maxp,
-                             ptrow[jnp.minimum(widx, maxp - 1)],
-                             npages)                       # (NTP,)
-            keepc = (jnp.arange(C, dtype=jnp.int32) <= nlast) & \
-                (cpos < T)
-            loc = jnp.where(keepc, cpos - p0 * page, ntp * page)
-            kp = _kv_chunk_rmw(kp, wpgs, loc, knew[:, 0], page, ntp)
-            vp = _kv_chunk_rmw(vp, wpgs, loc, vnew[:, 0], page, ntp)
-        else:
-            pgs = jnp.where(cpos < T,
-                            ptrow[jnp.minimum(cpos // page, maxp - 1)],
-                            npages)                        # (C,)
-            offs = cpos % page
-            kp = kp.at[:, pgs, :, offs, :].set(
-                jnp.moveaxis(knew[:, 0], 2, 0), mode="drop")
-            vp = vp.at[:, pgs, :, offs, :].set(
-                jnp.moveaxis(vnew[:, 0], 2, 0), mode="drop")
-        x_last = lax.dynamic_slice(x, (0, nlast, 0), (1, 1, U))[:, 0]
-        xl = _call(self.model.ln_f, x_last)
-        # the chunk head is native, matching prefill_batch (q8 covers
-        # the per-token decode matvecs; each chunk runs once)
-        return self._head_logits(xl, None), kp, vp
+        with jax.named_scope("mx.page_write"):
+            if quant:
+                # requantizing page-WINDOW RMW: the C consecutive columns
+                # touch at most ntp consecutive pages of this row (static
+                # in C and page, so the program shape is unchanged).  Pad
+                # columns past ``nlast`` are masked OUT here — unlike the
+                # f32 path's harmless garbage-but-unreachable writes, a pad
+                # column would poison its page's shared SCALE.
+                ntp = (C + page - 2) // page + 1
+                p0 = off // page
+                widx = p0 + jnp.arange(ntp, dtype=jnp.int32)
+                wpgs = jnp.where(widx < maxp,
+                                 ptrow[jnp.minimum(widx, maxp - 1)],
+                                 npages)                       # (NTP,)
+                keepc = (jnp.arange(C, dtype=jnp.int32) <= nlast) & \
+                    (cpos < T)
+                loc = jnp.where(keepc, cpos - p0 * page, ntp * page)
+                kp = _kv_chunk_rmw(kp, wpgs, loc, knew[:, 0], page, ntp)
+                vp = _kv_chunk_rmw(vp, wpgs, loc, vnew[:, 0], page, ntp)
+            else:
+                pgs = jnp.where(cpos < T,
+                                ptrow[jnp.minimum(cpos // page, maxp - 1)],
+                                npages)                        # (C,)
+                offs = cpos % page
+                kp = kp.at[:, pgs, :, offs, :].set(
+                    jnp.moveaxis(knew[:, 0], 2, 0), mode="drop")
+                vp = vp.at[:, pgs, :, offs, :].set(
+                    jnp.moveaxis(vnew[:, 0], 2, 0), mode="drop")
+        with jax.named_scope("mx.head"):
+            x_last = lax.dynamic_slice(x, (0, nlast, 0),
+                                       (1, 1, U))[:, 0]
+            xl = _call(self.model.ln_f, x_last)
+            # the chunk head is native, matching prefill_batch (q8
+            # covers the per-token decode matvecs; each chunk runs once)
+            return self._head_logits(xl, None), kp, vp
 
+    @jax.named_scope("mx.dense")   # but for the regions named inside
     def pool_verify_paged(self, toks, pos, pt, page, kp, vp, sw,
                           q8=None):
         """Draft-and-verify scoring against the PAGED pool
@@ -1150,25 +1182,27 @@ class _DecodeEngine:
         mask = jnp.arange(T, dtype=jnp.int32)[None, None, :] <= \
             cpos[:, :, None]
 
+        @jax.named_scope("mx.dense")
         def body(x, xs):
             w, kpl, vpl = xs
             # per-slot dense (B, KV, T, D) views through the page
             # table; sentinel rows (retired slots) gather zeros (int8
             # pools dequantize in the same gather)
-            if quant:
-                kpl = _kv_dequant(
-                    kpl[0].at[pt].get(mode="fill", fill_value=0),
-                    kpl[1].at[pt].get(mode="fill", fill_value=0),
-                    cdtype)
-                vpl = _kv_dequant(
-                    vpl[0].at[pt].get(mode="fill", fill_value=0),
-                    vpl[1].at[pt].get(mode="fill", fill_value=0),
-                    cdtype)
-            else:
-                kpl = kpl.at[pt].get(mode="fill", fill_value=0)
-                vpl = vpl.at[pt].get(mode="fill", fill_value=0)
-            kc = jnp.moveaxis(kpl, 2, 1).reshape(B, KV, T, D)
-            vc = jnp.moveaxis(vpl, 2, 1).reshape(B, KV, T, D)
+            with jax.named_scope("mx.paged_view"):
+                if quant:
+                    kpl = _kv_dequant(
+                        kpl[0].at[pt].get(mode="fill", fill_value=0),
+                        kpl[1].at[pt].get(mode="fill", fill_value=0),
+                        cdtype)
+                    vpl = _kv_dequant(
+                        vpl[0].at[pt].get(mode="fill", fill_value=0),
+                        vpl[1].at[pt].get(mode="fill", fill_value=0),
+                        cdtype)
+                else:
+                    kpl = kpl.at[pt].get(mode="fill", fill_value=0)
+                    vpl = vpl.at[pt].get(mode="fill", fill_value=0)
+                kc = jnp.moveaxis(kpl, 2, 1).reshape(B, KV, T, D)
+                vc = jnp.moveaxis(vpl, 2, 1).reshape(B, KV, T, D)
             if llama:
                 h = _rms(x, w["rms1_g"], eps=eps1)
                 if int8:
@@ -1206,16 +1240,19 @@ class _DecodeEngine:
             # block K/V lands in the dense views BEFORE attention
             # (per-slot scatter — offsets vary per row), so one mask
             # covers cached prefix and intra-block causality together
-            kc = kc.at[iB[:, None], :, wpos].set(
-                k.transpose(0, 2, 1, 3), mode="drop")
-            vc = vc.at[iB[:, None], :, wpos].set(
-                v.transpose(0, 2, 1, 3), mode="drop")
-            qg = q.reshape(B, KV, G, C, D)
-            s = jnp.einsum("bkgcd,bktd->bkgct", qg, kc,
-                           preferred_element_type=jnp.float32) * scale
-            s = jnp.where(mask[:, None, None], s, -1e30)
-            p = jax.nn.softmax(s, axis=-1).astype(cdtype)
-            o = jnp.einsum("bkgct,bktd->bkgcd", p, vc)
+            with jax.named_scope("mx.kv_write"):
+                kc = kc.at[iB[:, None], :, wpos].set(
+                    k.transpose(0, 2, 1, 3), mode="drop")
+                vc = vc.at[iB[:, None], :, wpos].set(
+                    v.transpose(0, 2, 1, 3), mode="drop")
+            with jax.named_scope("mx.attn"):
+                qg = q.reshape(B, KV, G, C, D)
+                s = jnp.einsum(
+                    "bkgcd,bktd->bkgct", qg, kc,
+                    preferred_element_type=jnp.float32) * scale
+                s = jnp.where(mask[:, None, None], s, -1e30)
+                p = jax.nn.softmax(s, axis=-1).astype(cdtype)
+                o = jnp.einsum("bkgct,bktd->bkgcd", p, vc)
             o = o.transpose(0, 3, 1, 2, 4).reshape(B, C, U)
             if llama:
                 x = x + (_q8l(o.reshape(B * C, U),
@@ -1251,47 +1288,50 @@ class _DecodeEngine:
                 x = x + _fc(hh, w["fc2_w"], w["fc2_b"], flatten=False)
             return x, (k, v)
 
-        x, (knew, vnew) = lax.scan(body, x, (sw, kp, vp))
+        with jax.named_scope("mx.paged_view"):   # see _scan_token
+            x, (knew, vnew) = lax.scan(body, x, (sw, kp, vp))
         # knew/vnew: (NL, B, KV, C, D) — scatter every block column of
         # every slot through its page-table row.  Out-of-range columns
         # (zombie lanes past T) resolve to the sentinel and DROP; the
         # cpos < T guard keeps them from CLIPPING onto a live page.
-        if quant:
-            # per-slot requantizing page-window RMW (the chunk write
-            # batched over slots): slot b's C columns touch at most ntp
-            # consecutive pages from its frontier page pos[b] // page
-            ntp = (C + page - 2) // page + 1
-            p0 = pos // page                               # (B,)
-            widx = p0[:, None] + jnp.arange(ntp, dtype=jnp.int32)
-            wpgs = jnp.where(widx < maxp,
-                             pt[iB[:, None],
-                                jnp.minimum(widx, maxp - 1)],
-                             npages)                       # (B, NTP)
-            loc = jnp.where(cpos < T, cpos - p0[:, None] * page,
-                            ntp * page)                    # (B, C)
-            kp = _kv_verify_rmw(kp, wpgs, iB, loc,
-                                jnp.transpose(knew, (1, 3, 0, 2, 4)),
-                                page, ntp)
-            vp = _kv_verify_rmw(vp, wpgs, iB, loc,
-                                jnp.transpose(vnew, (1, 3, 0, 2, 4)),
-                                page, ntp)
-        else:
-            pgs = jnp.where(cpos < T,
-                            pt[iB[:, None], jnp.minimum(cpos // page,
-                                                        maxp - 1)],
-                            npages)                        # (B, C)
-            offs = cpos % page
-            # result dims of the non-adjacent advanced indices go
-            # FIRST: value shape (B, C, NL, KV, D)
-            kp = kp.at[:, pgs, :, offs, :].set(
-                jnp.transpose(knew, (1, 3, 0, 2, 4)), mode="drop")
-            vp = vp.at[:, pgs, :, offs, :].set(
-                jnp.transpose(vnew, (1, 3, 0, 2, 4)), mode="drop")
-        xl = _call(self.model.ln_f, x)
-        # same head as the plain step (q8 when int8) — the greedy
-        # parity contract: out[b, 0]'s logits == the step path's
-        logits = self._head_logits(xl.reshape(B * C, U), q8)
-        return logits.reshape(B, C, -1), kp, vp
+        with jax.named_scope("mx.page_write"):
+            if quant:
+                # per-slot requantizing page-window RMW (the chunk write
+                # batched over slots): slot b's C columns touch at most ntp
+                # consecutive pages from its frontier page pos[b] // page
+                ntp = (C + page - 2) // page + 1
+                p0 = pos // page                               # (B,)
+                widx = p0[:, None] + jnp.arange(ntp, dtype=jnp.int32)
+                wpgs = jnp.where(widx < maxp,
+                                 pt[iB[:, None],
+                                    jnp.minimum(widx, maxp - 1)],
+                                 npages)                       # (B, NTP)
+                loc = jnp.where(cpos < T, cpos - p0[:, None] * page,
+                                ntp * page)                    # (B, C)
+                kp = _kv_verify_rmw(kp, wpgs, iB, loc,
+                                    jnp.transpose(knew, (1, 3, 0, 2, 4)),
+                                    page, ntp)
+                vp = _kv_verify_rmw(vp, wpgs, iB, loc,
+                                    jnp.transpose(vnew, (1, 3, 0, 2, 4)),
+                                    page, ntp)
+            else:
+                pgs = jnp.where(cpos < T,
+                                pt[iB[:, None], jnp.minimum(cpos // page,
+                                                            maxp - 1)],
+                                npages)                        # (B, C)
+                offs = cpos % page
+                # result dims of the non-adjacent advanced indices go
+                # FIRST: value shape (B, C, NL, KV, D)
+                kp = kp.at[:, pgs, :, offs, :].set(
+                    jnp.transpose(knew, (1, 3, 0, 2, 4)), mode="drop")
+                vp = vp.at[:, pgs, :, offs, :].set(
+                    jnp.transpose(vnew, (1, 3, 0, 2, 4)), mode="drop")
+        with jax.named_scope("mx.head"):
+            xl = _call(self.model.ln_f, x)
+            # same head as the plain step (q8 when int8) — the greedy
+            # parity contract: out[b, 0]'s logits == the step path's
+            logits = self._head_logits(xl.reshape(B * C, U), q8)
+            return logits.reshape(B, C, -1), kp, vp
 
     def fused_token(self, x_tok, pos, ck, cv, packed_t, q8=None):
         """one_token's Pallas twin: embeddings and head stay XLA ops;
@@ -1315,6 +1355,7 @@ class _DecodeEngine:
             return self.stacked_token(tok, t, ck, cv, sw, q8)
         return self.one_token(tok, t, ck, cv, q8)
 
+    @jax.named_scope("mx.dense")   # but for the regions named inside
     def prefill_batch(self, prompt_dev, ck, cv, last_index=None):
         """One causal forward over the whole (B, P) prompt: fills cache
         positions [0, P) and returns the position-P-1 logits (or the
@@ -1368,10 +1409,11 @@ class _DecodeEngine:
                 q, k, v = (qkv[..., j * U:(j + 1) * U]
                            .reshape(B, P, H, D).transpose(0, 2, 1, 3)
                            for j in range(3))
-            ck = lax.dynamic_update_slice(
-                ck, k.astype(cdtype)[None], (i, 0, 0, 0, 0))
-            cv = lax.dynamic_update_slice(
-                cv, v.astype(cdtype)[None], (i, 0, 0, 0, 0))
+            with jax.named_scope("mx.kv_write"):
+                ck = lax.dynamic_update_slice(
+                    ck, k.astype(cdtype)[None], (i, 0, 0, 0, 0))
+                cv = lax.dynamic_update_slice(
+                    cv, v.astype(cdtype)[None], (i, 0, 0, 0, 0))
             # causal attention over the prompt via the flash kernel —
             # O(P) memory (no (P, P) score tensor), so long prompts
             # prefill without OOM; GQA repeats k/v across head groups
@@ -1400,10 +1442,11 @@ class _DecodeEngine:
                 x_last = jnp.take_along_axis(
                     x, li.astype(jnp.int32)[:, None, None],
                     axis=1)[:, 0]
-        xl = _call(model.ln_f, x_last)
-        # the prefill head is always native (q8 covers decode-step
-        # matvecs; the prefill runs once)
-        return self._head_logits(xl, None), ck, cv
+        with jax.named_scope("mx.head"):
+            xl = _call(model.ln_f, x_last)
+            # the prefill head is always native (q8 covers decode-step
+            # matvecs; the prefill runs once)
+            return self._head_logits(xl, None), ck, cv
 
     def zero_caches(self):
         shape = (self.NL, self.B, self.KV, self.total, self.D)

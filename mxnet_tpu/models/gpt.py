@@ -10,6 +10,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
+import jax
 
 from ..gluon.block import HybridBlock
 from ..gluon.nn.basic_layers import Dense, Dropout, Embedding, LayerNorm
@@ -75,20 +76,25 @@ class GPT(HybridBlock):
     def forward(self, tokens, *args, **kwargs):
         from .. import ndarray as F
         B, L = tokens.shape
-        x = self.wte(tokens)
-        pos_ids = F.broadcast_to(
-            F.reshape(F.arange(L, dtype="int32"), shape=(1, L)),
-            shape=(B, L))
-        x = x + self.wpe(pos_ids)
-        if self.drop is not None:
-            x = self.drop(x)
+        # device-timeline regions (docs/TELEMETRY.md): the embeddings
+        # count with the layers, ``ln_f`` with the head
+        with jax.named_scope("mx.dense"):
+            x = self.wte(tokens)
+            pos_ids = F.broadcast_to(
+                F.reshape(F.arange(L, dtype="int32"), shape=(1, L)),
+                shape=(B, L))
+            x = x + self.wpe(pos_ids)
+            if self.drop is not None:
+                x = self.drop(x)
         for blk in self.blocks:
             x = blk(x)
-        x = self.ln_f(x)
-        w = self.wte.weight.data()                       # (vocab, units)
-        logits = F.dot(F.reshape(x, shape=(B * L, self._cfg.units)), w,
-                       transpose_b=True)
-        return F.reshape(logits, shape=(B, L, self._cfg.vocab_size))
+        with jax.named_scope("mx.head"):
+            x = self.ln_f(x)
+            w = self.wte.weight.data()                   # (vocab, units)
+            logits = F.dot(F.reshape(x, shape=(B * L, self._cfg.units)),
+                           w, transpose_b=True)
+            return F.reshape(logits,
+                             shape=(B, L, self._cfg.vocab_size))
 
     def stacked_decode_weights(self):
         """Every layer's decode weights stacked into (num_layers, ...)

@@ -12,6 +12,8 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
+import jax
+
 from ..gluon.block import HybridBlock
 from ..gluon.nn.basic_layers import Dense, Embedding, RMSNorm
 
@@ -122,6 +124,7 @@ class LlamaCell(HybridBlock):
             self.mlp = LlamaMLP(cfg.units, cfg.hidden_size,
                                 dtype=cfg.dtype, prefix="mlp_")
 
+    @jax.named_scope("mx.dense")    # region: see _TransformerCell
     def hybrid_forward(self, F, x):
         x = x + self.attn(self.rms1(x))
         return x + self.mlp(self.rms2(x))
@@ -167,10 +170,12 @@ class Llama(HybridBlock):
                               prefix="head_")
 
     def forward(self, tokens, *args, **kwargs):
-        x = self.wte(tokens)
+        with jax.named_scope("mx.dense"):
+            x = self.wte(tokens)
         for blk in self.blocks:
             x = blk(x)
-        return self.head(self.ln_f(x))
+        with jax.named_scope("mx.head"):
+            return self.head(self.ln_f(x))
 
     def stacked_decode_weights(self):
         """Every layer's decode weights stacked into (num_layers, ...)

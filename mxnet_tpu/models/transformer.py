@@ -10,6 +10,8 @@ SURVEY.md §5.7).
 """
 from __future__ import annotations
 
+import jax
+
 from ..base import MXNetError
 from ..gluon.block import HybridBlock
 from ..gluon.nn.basic_layers import Dense, Dropout, LayerNorm
@@ -97,6 +99,9 @@ class _TransformerCell(HybridBlock):
             self.ffn = PositionwiseFFN(units, hidden_size, dropout,
                                        dtype=dtype, prefix="ffn_")
 
+    # the device-timeline region of the layer (docs/TELEMETRY.md) but
+    # for the attention core, which ``ops.attention`` names ``mx.attn``
+    @jax.named_scope("mx.dense")
     def hybrid_forward(self, F, x, mask=None):
         x = x + self.attn(self.ln1(x), mask) if mask is not None else \
             x + self.attn(self.ln1(x))

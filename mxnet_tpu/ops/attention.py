@@ -860,6 +860,7 @@ def _plain_attn(q, k, v, bias, scale, causal, dropout=0.0, seed=None):
 
 
 @op("flash_attention")
+@jax.named_scope("mx.attn")
 def flash_attention(q, k, v, bias=None, *, scale: Optional[float] = None,
                     causal: bool = False, dropout: float = 0.0,
                     training: Optional[bool] = None):

@@ -219,9 +219,10 @@ class SPMDTrainer:
             with params_swapped(all_params, all_vals):
                 out = self._block(NDArray(data))
                 out0 = out[0] if isinstance(out, (list, tuple)) else out
-                loss = self._loss_fn(out0, NDArray(label))
-                loss_val = jnp.mean(loss._data if isinstance(loss, NDArray)
-                                    else loss)
+                with jax.named_scope("mx.head"):
+                    loss = self._loss_fn(out0, NDArray(label))
+                    loss_val = jnp.mean(
+                        loss._data if isinstance(loss, NDArray) else loss)
         aux_out.append([(p, jax.lax.stop_gradient(v))
                         for (p, v) in aux.values()])
         return loss_val
@@ -252,36 +253,37 @@ class SPMDTrainer:
             aux_pairs = aux_box[-1] if aux_box else []
 
             new_vals, new_states = [], []
-            for i, (w, g, s, mp) in enumerate(
-                    zip(train_vals, grads, opt_states, mp_flags)):
-                lr_i = lr * lr_mults[i]
-                wd_i = opt.wd * wd_mults[i]
-                if mp:
-                    master, inner = s
-                    g32 = g.astype(jnp.float32) * rescale
-                    if opt.clip_gradient is not None:
-                        g32 = jnp.clip(g32, -opt.clip_gradient,
-                                       opt.clip_gradient)
-                    nm, ni = opt._update_rule(master, g32, inner, lr_i,
-                                              wd_i, t)
-                    new_vals.append(nm.astype(w.dtype))
-                    new_states.append((nm, jax.tree.map(
-                        lambda a, b: b.astype(a.dtype) if hasattr(
-                            a, "dtype") else b, inner, ni)))
-                else:
-                    # CRITICAL dtype discipline: the traced f32 scalars
-                    # (rescale/lr) promote bf16 math to f32; without the
-                    # casts below one step() silently turns the whole
-                    # model f32 and the MXU runs at 1/2-1/4 rate
-                    g = (g * rescale).astype(w.dtype)
-                    if opt.clip_gradient is not None:
-                        g = jnp.clip(g, -opt.clip_gradient,
-                                     opt.clip_gradient)
-                    nw, ns = opt._update_rule(w, g, s, lr_i, wd_i, t)
-                    new_vals.append(nw.astype(w.dtype))
-                    new_states.append(jax.tree.map(
-                        lambda a, b: b.astype(a.dtype) if hasattr(
-                            a, "dtype") else b, s, ns))
+            with jax.named_scope("mx.optimizer"):
+                for i, (w, g, s, mp) in enumerate(
+                        zip(train_vals, grads, opt_states, mp_flags)):
+                    lr_i = lr * lr_mults[i]
+                    wd_i = opt.wd * wd_mults[i]
+                    if mp:
+                        master, inner = s
+                        g32 = g.astype(jnp.float32) * rescale
+                        if opt.clip_gradient is not None:
+                            g32 = jnp.clip(g32, -opt.clip_gradient,
+                                           opt.clip_gradient)
+                        nm, ni = opt._update_rule(master, g32, inner, lr_i,
+                                                  wd_i, t)
+                        new_vals.append(nm.astype(w.dtype))
+                        new_states.append((nm, jax.tree.map(
+                            lambda a, b: b.astype(a.dtype) if hasattr(
+                                a, "dtype") else b, inner, ni)))
+                    else:
+                        # CRITICAL dtype discipline: the traced f32 scalars
+                        # (rescale/lr) promote bf16 math to f32; without the
+                        # casts below one step() silently turns the whole
+                        # model f32 and the MXU runs at 1/2-1/4 rate
+                        g = (g * rescale).astype(w.dtype)
+                        if opt.clip_gradient is not None:
+                            g = jnp.clip(g, -opt.clip_gradient,
+                                         opt.clip_gradient)
+                        nw, ns = opt._update_rule(w, g, s, lr_i, wd_i, t)
+                        new_vals.append(nw.astype(w.dtype))
+                        new_states.append(jax.tree.map(
+                            lambda a, b: b.astype(a.dtype) if hasattr(
+                                a, "dtype") else b, s, ns))
 
             # map aux updates back to frozen-param slots
             aux_by_id = {id(p): v for p, v in aux_pairs}
@@ -385,22 +387,25 @@ class SPMDTrainer:
         self._ensure_built(NDArray(d[0]), NDArray(l[0]))
         if self._multi_step_fn is None:
             self._multi_step_fn = self._compile_multi()
-        keys = jax.random.split(mxrandom.next_key(), n)
-        lr = jnp.asarray(self._opt.learning_rate, jnp.float32)
-        rescale = jnp.asarray(
-            self._rescale / (batch_size if batch_size else 1.0), jnp.float32)
-        t0 = jnp.asarray(self._t + 1, jnp.int32)
-        sh = NamedSharding(self._mesh, P(None, self._dp_axis))
-        if jax.process_count() > 1:
-            repl = NamedSharding(self._mesh, P())
-            keys, lr, rescale, t0 = (global_put(a, repl) for a in
-                                     (keys, lr, rescale, t0))
-        d = global_put(d, sh)
-        l = global_put(l, sh)
-        losses, self._train_vals, self._opt_states, self._frozen_vals = \
-            self._multi_step_fn(self._train_vals, self._opt_states,
-                                self._frozen_vals, keys, lr, rescale, t0,
-                                d, l)
+        with telemetry.span("mx:train:feed", seq=self._t + 1, steps=n):
+            keys = jax.random.split(mxrandom.next_key(), n)
+            lr = jnp.asarray(self._opt.learning_rate, jnp.float32)
+            rescale = jnp.asarray(
+                self._rescale / (batch_size if batch_size else 1.0),
+                jnp.float32)
+            t0 = jnp.asarray(self._t + 1, jnp.int32)
+            sh = NamedSharding(self._mesh, P(None, self._dp_axis))
+            if jax.process_count() > 1:
+                repl = NamedSharding(self._mesh, P())
+                keys, lr, rescale, t0 = (global_put(a, repl) for a in
+                                         (keys, lr, rescale, t0))
+            d = global_put(d, sh)
+            l = global_put(l, sh)
+        with telemetry.span("mx:train:step", seq=self._t + 1, steps=n):
+            losses, self._train_vals, self._opt_states, \
+                self._frozen_vals = self._multi_step_fn(
+                    self._train_vals, self._opt_states,
+                    self._frozen_vals, keys, lr, rescale, t0, d, l)
         self._t += n
         self._opt.num_update = self._t
         for p, v in zip(self._train_params, self._train_vals):
@@ -448,21 +453,26 @@ class SPMDTrainer:
         self._ensure_built(NDArray(d), NDArray(l))
         self._t += 1
         self._opt.num_update = self._t
-        lr = jnp.asarray(self._opt.learning_rate, jnp.float32)
-        rescale = jnp.asarray(
-            self._rescale / (batch_size if batch_size else 1.0), jnp.float32)
-        t = jnp.asarray(self._t, jnp.int32)
-        key = mxrandom.next_key()
-        if jax.process_count() > 1:
-            repl = NamedSharding(self._mesh, P())
-            key, lr, rescale, t = (global_put(a, repl) for a in
-                                   (key, lr, rescale, t))
-        d = global_put(d, NamedSharding(self._mesh, P(self._dp_axis)))
-        l = global_put(l, NamedSharding(self._mesh, P(self._dp_axis)))
-        loss, self._train_vals, self._opt_states, self._frozen_vals = \
-            self._step_fn(self._train_vals, self._opt_states,
-                          self._frozen_vals, key, lr,
-                          rescale, t, d, l)
+        # the host's two phases of a step, on the device timeline while
+        # a trace runs (docs/TELEMETRY.md): feed, then the dispatch
+        with telemetry.span("mx:train:feed", seq=self._t):
+            lr = jnp.asarray(self._opt.learning_rate, jnp.float32)
+            rescale = jnp.asarray(
+                self._rescale / (batch_size if batch_size else 1.0),
+                jnp.float32)
+            t = jnp.asarray(self._t, jnp.int32)
+            key = mxrandom.next_key()
+            if jax.process_count() > 1:
+                repl = NamedSharding(self._mesh, P())
+                key, lr, rescale, t = (global_put(a, repl) for a in
+                                       (key, lr, rescale, t))
+            d = global_put(d, NamedSharding(self._mesh, P(self._dp_axis)))
+            l = global_put(l, NamedSharding(self._mesh, P(self._dp_axis)))
+        with telemetry.span("mx:train:step", seq=self._t):
+            loss, self._train_vals, self._opt_states, \
+                self._frozen_vals = self._step_fn(
+                    self._train_vals, self._opt_states,
+                    self._frozen_vals, key, lr, rescale, t, d, l)
         # sync new values back into the block's Parameters (rebind is
         # async — no host transfer)
         for p, v in zip(self._train_params, self._train_vals):

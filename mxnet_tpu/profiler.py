@@ -25,7 +25,8 @@ from collections import defaultdict
 import jax
 
 __all__ = ["set_config", "profiler_set_config", "start", "stop", "pause",
-           "resume", "dump", "dumps", "device_dumps", "set_state", "state",
+           "resume", "dump", "dumps", "device_dumps", "device_regions",
+           "set_state", "state",
            "Task", "Frame", "Counter", "Marker", "Scope", "TraceAnnotation"]
 
 _lock = threading.Lock()
@@ -38,9 +39,16 @@ _config = {
     "profile_api": False,
     "aggregate_stats": True,
     "continuous_dump": False,
+    # ``jax.profiler.ProfileOptions`` of the trace the facade starts
+    # itself (None = JAX's default).  The Python tracer slows host code
+    # severalfold: a live server is profiled with python_tracer_level=0
+    "python_tracer_level": None,
+    "host_tracer_level": None,
 }
+# ``xplane``: the bytes of the trace the last stop() ended (replaced, never
+# accumulated); ``parsed``: profiler_xla.parse_xplane of them, on first use
 _state = {"running": False, "trace_dir": None, "op_stats": None,
-          "paused": False}
+          "paused": False, "xplane": None, "parsed": None}
 
 
 def set_config(**kwargs):
@@ -96,39 +104,86 @@ def _hook(name, dt):
             st.record(name, dt)
 
 
+def _running_trace_dir():
+    """The log directory of a ``jax.profiler`` trace that already runs,
+    else ``None``.  JAX 0.9.0 keeps it in the private
+    ``jax._src.profiler._profile_state`` (``profile_session``,
+    ``log_dir``); ``tests/test_tracing_regions.py`` pins both names, and a
+    JAX without them reads as "no trace runs"."""
+    try:
+        from jax._src import profiler as _jax_profiler
+        st = _jax_profiler._profile_state
+        return st.log_dir if st.profile_session is not None else None
+    except Exception:
+        return None
+
+
+def _profile_options():
+    levels = {k: _config[k] for k in ("python_tracer_level",
+                                      "host_tracer_level")
+              if _config[k] is not None}
+    if not levels:
+        return None
+    options = jax.profiler.ProfileOptions()
+    for k, v in levels.items():
+        setattr(options, k, int(v))
+    return options
+
+
 def start():
-    """Start profiling: device trace + host op stats."""
+    """Start profiling: device trace + host op stats + the phase spans of
+    ``telemetry.span``.  Inside a ``jax.profiler`` trace that already runs
+    ("nested") the facade keeps to that trace: it records the running
+    trace's directory, and its ``stop()`` is what ends the trace."""
     # wire the per-op hook into the dispatch path (ops/registry.invoke)
     import sys
+    from . import telemetry
     from .ops import registry as _registry
     _registry._profiler = sys.modules[__name__]
     with _lock:
         if _state["running"]:
             return
-        trace_dir = _config["filename"]
-        if trace_dir.endswith(".json"):
-            trace_dir = trace_dir[:-5] + "_trace"
-        os.makedirs(trace_dir, exist_ok=True)
-        try:
-            jax.profiler.start_trace(trace_dir)
-        except Exception:
-            pass  # nested/unsupported backends: keep host stats only
+        trace_dir = _running_trace_dir()
+        if trace_dir is None:
+            trace_dir = _config["filename"]
+            if trace_dir.endswith(".json"):
+                trace_dir = trace_dir[:-5] + "_trace"
+            os.makedirs(trace_dir, exist_ok=True)
+            try:
+                jax.profiler.start_trace(
+                    trace_dir, profiler_options=_profile_options())
+            except Exception:
+                pass  # unsupported backends: keep host stats only
+        telemetry.clear_spans()
         _state["running"] = True
         _state["trace_dir"] = trace_dir
+        _state["xplane"] = _state["parsed"] = None
         if _state["op_stats"] is None or not _state["paused"]:
             _state["op_stats"] = _OpStats()
         _state["paused"] = False
 
 
 def stop():
+    """End the trace and keep its ``.xplane.pb`` as bytes (a file read, no
+    parse: a caller may stop the trace in the middle of serving, and a
+    parse under the GIL would slow the scheduler thread;
+    ``device_regions()`` / ``device_dumps()`` parse on first use)."""
+    from . import profiler_xla
     with _lock:
         if not _state["running"]:
             return
+        # spans stop with the trace, not with its export (seconds for a
+        # trace of tens of MB, during which a server keeps stepping)
+        _state["running"] = False
         try:
             jax.profiler.stop_trace()
         except Exception:
             pass
-        _state["running"] = False
+        try:
+            _state["xplane"] = profiler_xla.read_xplane(_state["trace_dir"])
+        except OSError:
+            _state["xplane"] = None
+        _state["parsed"] = None
 
 
 def pause(profile_process="worker"):
@@ -173,26 +228,54 @@ def dumps(reset=False):
     return s
 
 
+def _parsed_trace():
+    """``profiler_xla.parse_xplane`` of the trace the last ``stop()``
+    ended (parsed once), or ``None``: no trace, no device plane."""
+    from . import profiler_xla
+    with _lock:
+        raw, parsed = _state["xplane"], _state["parsed"]
+    if parsed is not None or not raw:
+        return parsed
+    try:        # outside the lock: the dispatch path's _hook takes it
+        parsed = profiler_xla.parse_xplane(raw)
+    except Exception:
+        parsed = None                   # truncated trace: best effort
+    with _lock:
+        if _state["xplane"] is raw:     # no newer trace since
+            _state["parsed"] = parsed
+    return parsed
+
+
 def device_dumps(by="tf_op", peak_tflops=None, limit=30):
     """Per-XLA-op device-time table for the last ``start()``/``stop()``
     window — the reference's per-op aggregate, recovered *inside* fused
     jit steps by parsing the device trace (see ``profiler_xla``).
 
-    ``by``: "tf_op" (jaxpr-level provenance), "name" (HLO op),
-    "category" (convolution/fusion/copy/all-reduce...), or "source"."""
+    ``by``: "tf_op" (jaxpr-level provenance), "region" (its innermost
+    ``mx.*`` scope), "name" (HLO op), "category"
+    (convolution/fusion/copy/all-reduce...), or "source"."""
     from . import profiler_xla
-    if by not in ("tf_op", "name", "category", "source"):
-        raise ValueError(f"by={by!r}: expected one of "
-                         "'tf_op', 'name', 'category', 'source'")
-    td = _state["trace_dir"]
-    if not td:
+    if by not in ("tf_op", "region", "name", "category", "source"):
+        raise ValueError(f"by={by!r}: expected one of 'tf_op', "
+                         "'region', 'name', 'category', 'source'")
+    parsed = _parsed_trace()
+    if parsed is None:
         return ""
-    try:
-        rows = profiler_xla.aggregate(profiler_xla.parse_trace(td), by=by)
-    except Exception:
-        return ""  # missing/truncated/in-flight trace: best-effort dump
+    rows = profiler_xla.aggregate(parsed["ops"], by=by)
     return profiler_xla.format_table(rows, peak_tflops=peak_tflops,
                                      limit=limit)
+
+
+def device_regions():
+    """Where the device time of the last ``start()``/``stop()`` window went
+    inside each executable: ``{"jit_step": {"runs": n, "run_seconds": s,
+    "regions": {"mx.attn": s, ..., "unscoped": s}}}`` over the runs that
+    lie whole in the trace (``profiler_xla.device_regions``; the region
+    vocabulary is docs/TELEMETRY.md's).  ``None`` where the trace has no
+    device plane (a CPU run) or no trace was taken."""
+    from . import profiler_xla
+    parsed = _parsed_trace()
+    return None if parsed is None else profiler_xla.device_regions(parsed)
 
 
 def set_state(state="stop", profile_process="worker"):

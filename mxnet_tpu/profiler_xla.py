@@ -6,100 +6,359 @@ per-op totals table.  Under XLA the entire train step is ONE fused program
 and host-side hooks see nothing — this module recovers the reference's
 visibility by parsing the ``jax.profiler`` device trace: every executed
 HLO op's device duration, bytes accessed, and model FLOPs, grouped by op
-name / HLO category / source tf_op.
+name / HLO category / provenance (``tf_op``) / ``mx.*`` region, read from
+the ``.xplane.pb`` the profiler writes (``parse_xplane``).
 
 Usage::
 
-    rows = profile_fn(step_fn, args)        # trace + parse in one call
-    print(format_table(rows))
+    ops = profile_fn(step_fn, args)         # trace + parse in one call
+    print(format_table(aggregate(ops, by="tf_op")))
 
 or through the ``mx.profiler`` facade: ``start()``/``stop()`` around any
 device work, then ``device_dumps()`` renders this table.
 """
 from __future__ import annotations
 
+import bisect
 import glob
-import gzip
-import json
 import os
 import re
+import struct
 import tempfile
 from collections import defaultdict
 
-__all__ = ["parse_trace", "aggregate", "format_table", "profile_fn",
-           "latest_session", "count_hlo_ops", "hlo_op_count"]
+__all__ = ["parse_xplane", "read_xplane", "device_regions", "region_of",
+           "aggregate", "format_table", "profile_fn", "count_hlo_ops",
+           "hlo_op_count"]
+
+# lane names of a v5e trace (PERF.md section 3); chipbench/reduce.py reads
+# the same two
+DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
+OPS_LINE, MODULES_LINE = "XLA Ops", "XLA Modules"
+UNSCOPED = "unscoped"
+_EDGE_PS = 1_000_000     # 1 us: a run this close to the trace's first or
+                         # last device event is the trace's first or last
+_REGION = re.compile(r"mx\.[a-z_]+")
 
 
-def latest_session(trace_dir):
-    """Return the newest profile-session directory under *trace_dir*."""
-    sessions = sorted(glob.glob(os.path.join(
-        trace_dir, "plugins", "profile", "*")))
-    if not sessions:
-        raise FileNotFoundError(f"no profile sessions under {trace_dir}")
-    return sessions[-1]
+def read_xplane(trace_dir):
+    """The bytes of the newest ``*.xplane.pb`` under *trace_dir* (what
+    ``jax.profiler.stop_trace`` leaves in ``plugins/profile/<time>/``),
+    or ``None`` when there is none."""
+    files = sorted(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                             recursive=True), key=os.path.getmtime)
+    if not files:
+        return None
+    with open(files[-1], "rb") as f:
+        return f.read()
 
 
-def parse_trace(trace_dir):
-    """Parse a ``jax.profiler`` trace directory into device-op records.
+# ----------------------------------------------------------------------- #
+# the xspace wire format — tsl/profiler/protobuf/xplane.proto
+# ----------------------------------------------------------------------- #
+# ``jax.profiler.ProfileData`` hands out an event's name, start, duration
+# and its OWN stats; the provenance of a device operation (``tf_op``, the
+# jaxpr name stack a ``jax.named_scope`` writes into), its category, FLOPs
+# and bytes are stats of its XEventMetadata, which ProfileData does not
+# expose (read off a v5e trace, PERF.md section 3).  So the few messages
+# below are decoded here, from the bytes, with the standard library only:
+#   XSpace{planes=1}  XPlane{name=2, lines=3, event_metadata=4 (map),
+#   stat_metadata=5 (map), stats=6}  XLine{name=2, timestamp_ns=3,
+#   events=4}  XEvent{metadata_id=1, offset_ps=2, duration_ps=3, stats=4}
+#   XEventMetadata{id=1, name=2, display_name=4, stats=5}
+#   XStatMetadata{id=1, name=2}  XStat{metadata_id=1, double=2, uint64=3,
+#   int64=4, str=5, bytes=6, ref=7}
 
-    Returns a list of dicts with keys: ``name``, ``category``, ``tf_op``,
-    ``dur_us`` (device duration), ``flops``, ``bytes``, ``occurrences`` =1.
-    Only events on the device "XLA Ops" lanes are returned (host python /
-    runtime events are skipped) — these are the per-HLO-op executions.
+def _varint(buf, pos):
+    b = buf[pos]
+    pos += 1
+    if b < 0x80:
+        return b, pos
+    out, shift = b & 0x7F, 7
+    while True:
+        b = buf[pos]
+        pos += 1
+        out |= (b & 0x7F) << shift
+        if b < 0x80:
+            return out, pos
+        shift += 7
+
+
+def _fields(buf, pos, end):
+    """``(field number, value)`` of one message: an int for a varint, a
+    ``(start, end)`` slice for a length-delimited field, raw bytes for a
+    fixed one."""
+    while pos < end:
+        key, pos = _varint(buf, pos)
+        kind = key & 7
+        if kind == 0:
+            val, pos = _varint(buf, pos)
+        elif kind == 2:
+            n, pos = _varint(buf, pos)
+            val = (pos, pos + n)
+            pos += n
+        elif kind in (1, 5):
+            n = 8 if kind == 1 else 4
+            val = buf[pos:pos + n]
+            pos += n
+        else:
+            raise ValueError(f"xspace: wire type {kind} at byte {pos}")
+        yield key >> 3, val
+
+
+def _text(buf, span):
+    return bytes(buf[span[0]:span[1]]).decode("utf-8", "replace")
+
+
+def _stat(buf, span, stat_names):
+    """``(name, value)`` of one XStat; a ``ref`` names its string."""
+    name = value = None
+    for f, v in _fields(buf, *span):
+        if f == 1:
+            name = stat_names.get(v, str(v))
+        elif f == 2:
+            value = struct.unpack("<d", v)[0]
+        elif f == 3:
+            value = v
+        elif f == 4:
+            value = v - (1 << 64) if v >> 63 else v
+        elif f == 5:
+            value = _text(buf, v)
+        elif f == 6:
+            value = bytes(buf[v[0]:v[1]])
+        elif f == 7:
+            value = stat_names.get(v, "")
+    return name, value
+
+
+def _map_entries(buf, spans):
+    """``{key: value span}`` of a ``map<int64, Message>`` field."""
+    out = {}
+    for span in spans:
+        key = val = None
+        for f, v in _fields(buf, *span):
+            if f == 1:
+                key = v
+            elif f == 2:
+                val = v
+        if key is not None and val is not None:
+            out[key] = val
+    return out
+
+
+def _device_planes(data):
+    """The device planes of a serialized xspace, decoded as far as
+    ``parse_xplane`` reads them: ``[{"name", "lines": {lane: [(metadata
+    id, start_ps, dur_ps), ...]}, "meta": {id: {"name", "display",
+    stats...}}}]``.  Host planes are skipped unread."""
+    buf = memoryview(data)
+    planes = []
+    for f, span in _fields(buf, 0, len(buf)):
+        if f != 1:
+            continue
+        name, lines, metas, stat_meta = "", [], [], []
+        for pf, v in _fields(buf, *span):
+            if pf == 2:
+                name = _text(buf, v)
+            elif pf == 3:
+                lines.append(v)
+            elif pf == 4:
+                metas.append(v)
+            elif pf == 5:
+                stat_meta.append(v)
+        if not DEVICE_PLANE.match(name):
+            continue
+        stat_names = {}
+        for key, v in _map_entries(buf, stat_meta).items():
+            stat_names[key] = next(
+                (_text(buf, x) for sf, x in _fields(buf, *v) if sf == 2),
+                "")
+        meta = {}
+        for key, v in _map_entries(buf, metas).items():
+            row = {"name": "", "display": ""}
+            for mf, x in _fields(buf, *v):
+                if mf == 2:
+                    row["name"] = _text(buf, x)
+                elif mf == 4:
+                    row["display"] = _text(buf, x)
+                elif mf == 5:
+                    k, val = _stat(buf, x, stat_names)
+                    row[k] = val
+            meta[key] = row
+        lanes = {}
+        for v in lines:
+            lane, base_ps, events = "", 0, []
+            for lf, x in _fields(buf, *v):
+                if lf == 2:
+                    lane = _text(buf, x)
+                elif lf == 3:
+                    base_ps = x * 1000
+                elif lf == 4:
+                    events.append(x)
+            if lane not in (OPS_LINE, MODULES_LINE):
+                continue
+            rows = []
+            for ev in events:
+                mid = off = dur = 0
+                for ef, x in _fields(buf, *ev):
+                    if ef == 1:
+                        mid = x
+                    elif ef == 2:
+                        off = x
+                    elif ef == 3:
+                        dur = x
+                rows.append((mid, base_ps + off, dur))
+            lanes[lane] = rows
+        planes.append({"name": name, "lines": lanes, "meta": meta})
+    return planes
+
+
+def region_of(provenance):
+    """The region of a device operation: the INNERMOST ``mx.*`` component
+    of its provenance path (``jit(step)/mx.dense/while/body/mx.attn/mul``
+    and ``transpose(jvp(mx.attn))/dot_general`` are both ``mx.attn``),
+    ``"unscoped"`` where the path has none.  The vocabulary is
+    docs/TELEMETRY.md's."""
+    found = _REGION.findall(provenance or "")
+    return found[-1] if found else UNSCOPED
+
+
+def _self_ps(events):
+    """``[(index, self_ps)]`` of ``[(start, dur), ...]``: an event's time
+    less that of the events it encloses (a ``while`` and its body)."""
+    order = sorted(range(len(events)),
+                   key=lambda i: (events[i][0], -events[i][1]))
+    out, stack = [], []          # stack of [index, end, self]
+    for i in order:
+        start, dur = events[i]
+        while stack and start >= stack[-1][1]:
+            top = stack.pop()
+            out.append((top[0], top[2]))
+        if stack:
+            stack[-1][2] -= dur
+        stack.append([i, start + dur, dur])
+    out.extend((top[0], top[2]) for top in stack)
+    return out
+
+
+def parse_xplane(source):
+    """Device operations and executable runs of a profiler trace.
+
+    *source* is a serialized xspace (``bytes``, what the ``mx.profiler``
+    facade keeps of the trace it stops) or a trace directory, whose newest
+    ``*.xplane.pb`` is read.  Returns ``None`` when there is no trace or
+    it has no ``/device:TPU:n`` plane (a CPU run), else ``{"ops": [...],
+    "runs": [...]}``:
+
+    - one ``runs`` row per event of a device's "XLA Modules" lane —
+      ``module`` (``jit_step(123)`` is keyed ``jit_step``), ``device``,
+      ``start_us``, ``dur_us`` and ``whole``: True when the device plane
+      also holds work before the run's start and after its end.  A run
+      the trace's start or end cut is recorded from where the trace began,
+      or up to where it ended (read off a v5e trace), so a run at either
+      end of the trace cannot be told from a cut one and is not counted;
+    - one ``ops`` row per event of the "XLA Ops" lane — ``name``
+      (``fusion.9``), ``long_name`` (the HLO text), ``category``,
+      ``tf_op`` (the provenance: the jaxpr name stack, ``jax.named_scope``
+      included), ``source``, ``start_us``, ``dur_us``, ``self_us`` (the
+      duration less the enclosed operations' — a ``while`` does not count
+      its body twice), ``flops``, ``bytes``, ``device``, and ``module`` /
+      ``run`` (index into ``runs``) of the run it started in, ``None``
+      outside any.
     """
-    session = latest_session(trace_dir)
-    records = []
-    for tj in sorted(glob.glob(os.path.join(session, "*.trace.json.gz"))):
-        with gzip.open(tj, "rt") as f:
-            trace = json.load(f)
-        events = trace.get("traceEvents", [])
-        # identify device pids and their "XLA Ops" / "Async XLA Ops" lanes
-        device_pids = set()
-        op_lanes = set()
-        for e in events:
-            if e.get("ph") != "M":
-                continue
-            if e.get("name") == "process_name" and \
-                    "/device:" in e["args"].get("name", ""):
-                device_pids.add(e["pid"])
-            if e.get("name") == "thread_name" and \
-                    "XLA Ops" in e["args"].get("name", ""):
-                op_lanes.add((e["pid"], e["tid"]))
-        for e in events:
-            if e.get("ph") != "X" or e.get("pid") not in device_pids:
-                continue
-            if (e["pid"], e.get("tid")) not in op_lanes:
-                continue
-            args = e.get("args", {})
-            dur_us = float(args.get("device_duration_ps", 0)) / 1e6 \
-                or float(e.get("dur", 0.0))
-            records.append({
-                "name": e.get("name", "?"),
-                "category": args.get("hlo_category", "?"),
-                "tf_op": args.get("tf_op", ""),
-                "source": args.get("source", ""),
-                "long_name": args.get("long_name", ""),
-                "dur_us": dur_us,
-                "flops": int(args.get("model_flops", 0)),
-                "bytes": int(args.get("raw_bytes_accessed",
-                                      args.get("bytes_accessed", 0))),
+    data = read_xplane(source) if isinstance(source, (str, os.PathLike)) \
+        else source
+    planes = _device_planes(data) if data else []
+    if not planes:
+        return None
+    ops, runs = [], []
+    for plane in planes:
+        meta = plane["meta"]
+        op_events = plane["lines"].get(OPS_LINE, [])
+        mod_events = sorted(plane["lines"].get(MODULES_LINE, []),
+                            key=lambda e: e[1])
+        every = op_events + mod_events
+        if not every:
+            continue
+        t_first = min(e[1] for e in every)
+        t_last = max(e[1] + e[2] for e in every)
+        base = len(runs)
+        for mid, start, dur in mod_events:
+            runs.append({
+                "module": meta.get(mid, {}).get("name", "?")
+                .split("(", 1)[0],
+                "device": plane["name"], "start_us": start / 1e6,
+                "dur_us": dur / 1e6,
+                "whole": start - t_first > _EDGE_PS
+                and t_last - (start + dur) > _EDGE_PS})
+        starts = [e[1] for e in mod_events]
+        selfs = dict(_self_ps([(s, d) for _, s, d in op_events]))
+        for i, (mid, start, dur) in enumerate(op_events):
+            m = meta.get(mid, {})
+            k = bisect.bisect_right(starts, start) - 1
+            inside = k >= 0 and start < starts[k] + mod_events[k][2]
+            ops.append({
+                "name": m.get("display") or _short_op(m.get("name", "?")),
+                "long_name": m.get("name", ""),
+                "category": m.get("hlo_category", "?"),
+                "tf_op": m.get("tf_op", ""),
+                "source": m.get("source", ""),
+                "start_us": start / 1e6, "dur_us": dur / 1e6,
+                "self_us": selfs[i] / 1e6,
+                "flops": int(m.get("model_flops", 0) or 0),
+                "bytes": int(m.get("raw_bytes_accessed",
+                                   m.get("bytes_accessed", 0)) or 0),
+                "device": plane["name"],
+                "module": runs[base + k]["module"] if inside else None,
+                "run": base + k if inside else None,
             })
-    return records
+    return {"ops": ops, "runs": runs}
+
+
+def _short_op(name):
+    """``%fusion.13 = bf16[..] fusion(..)`` -> ``fusion.13``."""
+    return name.split(" = ", 1)[0].lstrip("%").strip()
+
+
+def device_regions(parsed):
+    """Per executable of ``parse_xplane``'s result: ``{"jit_step":
+    {"runs": whole runs, "run_seconds": their device seconds,
+    "regions": {region: self seconds over those runs}}}``.  Operations of
+    a run the trace cut are left out, so the regions of an executable sum
+    to the busy time of its whole runs."""
+    out = {}
+    for run in parsed["runs"]:
+        if run["whole"]:
+            row = out.setdefault(run["module"], {
+                "runs": 0, "run_seconds": 0.0, "regions": {}})
+            row["runs"] += 1
+            row["run_seconds"] += run["dur_us"] / 1e6
+    for op in parsed["ops"]:
+        if op["run"] is None or not parsed["runs"][op["run"]]["whole"]:
+            continue
+        regions = out[op["module"]]["regions"]
+        key = region_of(op["tf_op"])
+        regions[key] = regions.get(key, 0.0) + op["self_us"] / 1e6
+    return out
 
 
 def aggregate(records, by="category"):
-    """Group records by ``category`` | ``name`` | ``tf_op`` | ``source``.
+    """Group ``parse_xplane``'s ``ops`` by ``category`` | ``name`` |
+    ``tf_op`` | ``source`` | ``region`` (``region_of`` of ``tf_op``).
 
     Returns rows sorted by total time desc: dicts with ``key``, ``calls``,
-    ``dur_us``, ``flops``, ``bytes``, ``tflops`` (achieved), ``gbps``
-    (achieved HBM bandwidth), ``pct`` of total device time.
+    ``dur_us`` (SELF time, so an enclosing ``while`` does not count its
+    body twice and the rows sum to the busy time), ``flops``, ``bytes``,
+    ``tflops`` (achieved), ``gbps`` (achieved HBM bandwidth), ``pct`` of
+    total device time.
     """
     groups = defaultdict(lambda: [0, 0.0, 0, 0])
     for r in records:
-        k = r[by] or "<none>"
+        k = region_of(r["tf_op"]) if by == "region" else \
+            r[by] or "<none>"
         g = groups[k]
         g[0] += 1
-        g[1] += r["dur_us"]
+        g[1] += r["self_us"]
         g[2] += r["flops"]
         g[3] += r["bytes"]
     total = sum(g[1] for g in groups.values()) or 1.0
@@ -134,7 +393,8 @@ def format_table(rows, peak_tflops=None, limit=30):
 
 
 def profile_fn(fn, *args, trace_dir=None, iters=2, warmup=True):
-    """Trace ``fn(*args)`` on device and return per-op records.
+    """Trace ``fn(*args)`` on device and return per-op records
+    (``parse_xplane``'s ``ops``; empty without a device plane).
 
     ``fn`` should be jit-compiled; it is run once for warmup (compile),
     then ``iters`` times inside the trace window with a device->host
@@ -160,9 +420,10 @@ def profile_fn(fn, *args, trace_dir=None, iters=2, warmup=True):
             onp.asarray(jax.device_get(leaves[0]))  # readback sync
     finally:
         jax.profiler.stop_trace()
-    records = parse_trace(trace_dir)
+    records = (parse_xplane(trace_dir) or {"ops": []})["ops"]
     for r in records:
         r["dur_us"] /= iters
+        r["self_us"] /= iters
     return records
 
 

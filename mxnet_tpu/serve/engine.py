@@ -339,6 +339,7 @@ class PoolPrograms:
         return -(-int(total_len) // self.page)
 
     # -- sampling ------------------------------------------------------- #
+    @jax.named_scope("mx.head")
     def _sample_slots(self, keys, logits, pos):
         """Per-slot next token: slot ``i`` draws with
         ``fold_in(keys[i], pos[i])`` over its own logits row — the exact
@@ -474,54 +475,55 @@ class PoolPrograms:
             done = stop_pos <= true_len
             if self.eos_id is not None:
                 done = done | (first == self.eos_id)
-            # page scatter: the dense (A, Ppad) scratch splits into A*NPB
-            # page-shaped rows that land at their reserved pool pages in
-            # one masked scatter per array (sentinel rows DROP)
-            tgt_pg = pages.reshape(A * npb)
-            if self.quant_kv:
-                # the padded tail's garbage columns are unreachable in
-                # the f32 pool but would poison the per-page SCALES
-                # here — zero them before the per-page quantization
-                colmask = jnp.arange(ppad, dtype=jnp.int32)[None] \
-                    < true_len[:, None]                     # (A, ppad)
-                ck1 = jnp.where(colmask[None, :, None, :, None],
-                                ck1, 0)
-                cv1 = jnp.where(colmask[None, :, None, :, None],
-                                cv1, 0)
-            c1 = ck1.reshape(NL, A, KV, npb, page, D) \
-                    .transpose(0, 1, 3, 2, 4, 5) \
-                    .reshape(NL, A * npb, KV, page, D)
-            v1 = cv1.reshape(NL, A, KV, npb, page, D) \
-                    .transpose(0, 1, 3, 2, 4, 5) \
-                    .reshape(NL, A * npb, KV, page, D)
-            if self.quant_kv:
-                # fresh whole pages: plain per-page quantization (no
-                # floor — nothing lived in these pages), then ONE
-                # masked scatter each for codes and scales
-                qc1, sc1 = _kv_requant(c1, 0.0)
-                qv1, sv1 = _kv_requant(v1, 0.0)
-                (kpc, kps), (vpc, vps) = kp, vp
-                # recycled-page reset: the pool free list is host-only
-                # bookkeeping, so a reallocated page still carries its
-                # previous tenant's codes AND scale.  A zero SCALE is a
-                # full reset — stale codes dequantize to exact zeros
-                # and the first RMW requantizes from floor 0.0, so the
-                # old tenant's dynamic range can never ratchet the new
-                # tenant's scale.  ``zpages`` holds every page the wave
-                # reserved (decode-frontier pages included — those are
-                # first WRITTEN by the step/verify RMWs); the prompt
-                # pages' scales are immediately overwritten by the
-                # scatter below.  Sentinel entries DROP.
-                zf = zpages.reshape(A * zpages.shape[1])
-                kps = kps.at[:, zf].set(0.0, mode="drop")
-                vps = vps.at[:, zf].set(0.0, mode="drop")
-                kp = (kpc.at[:, tgt_pg].set(qc1, mode="drop"),
-                      kps.at[:, tgt_pg].set(sc1, mode="drop"))
-                vp = (vpc.at[:, tgt_pg].set(qv1, mode="drop"),
-                      vps.at[:, tgt_pg].set(sv1, mode="drop"))
-            else:
-                kp = kp.at[:, tgt_pg].set(c1, mode="drop")
-                vp = vp.at[:, tgt_pg].set(v1, mode="drop")
+            with jax.named_scope("mx.page_write"):
+                # page scatter: the dense (A, Ppad) scratch splits into A*NPB
+                # page-shaped rows that land at their reserved pool pages in
+                # one masked scatter per array (sentinel rows DROP)
+                tgt_pg = pages.reshape(A * npb)
+                if self.quant_kv:
+                    # the padded tail's garbage columns are unreachable in
+                    # the f32 pool but would poison the per-page SCALES
+                    # here — zero them before the per-page quantization
+                    colmask = jnp.arange(ppad, dtype=jnp.int32)[None] \
+                        < true_len[:, None]                     # (A, ppad)
+                    ck1 = jnp.where(colmask[None, :, None, :, None],
+                                    ck1, 0)
+                    cv1 = jnp.where(colmask[None, :, None, :, None],
+                                    cv1, 0)
+                c1 = ck1.reshape(NL, A, KV, npb, page, D) \
+                        .transpose(0, 1, 3, 2, 4, 5) \
+                        .reshape(NL, A * npb, KV, page, D)
+                v1 = cv1.reshape(NL, A, KV, npb, page, D) \
+                        .transpose(0, 1, 3, 2, 4, 5) \
+                        .reshape(NL, A * npb, KV, page, D)
+                if self.quant_kv:
+                    # fresh whole pages: plain per-page quantization (no
+                    # floor — nothing lived in these pages), then ONE
+                    # masked scatter each for codes and scales
+                    qc1, sc1 = _kv_requant(c1, 0.0)
+                    qv1, sv1 = _kv_requant(v1, 0.0)
+                    (kpc, kps), (vpc, vps) = kp, vp
+                    # recycled-page reset: the pool free list is host-only
+                    # bookkeeping, so a reallocated page still carries its
+                    # previous tenant's codes AND scale.  A zero SCALE is a
+                    # full reset — stale codes dequantize to exact zeros
+                    # and the first RMW requantizes from floor 0.0, so the
+                    # old tenant's dynamic range can never ratchet the new
+                    # tenant's scale.  ``zpages`` holds every page the wave
+                    # reserved (decode-frontier pages included — those are
+                    # first WRITTEN by the step/verify RMWs); the prompt
+                    # pages' scales are immediately overwritten by the
+                    # scatter below.  Sentinel entries DROP.
+                    zf = zpages.reshape(A * zpages.shape[1])
+                    kps = kps.at[:, zf].set(0.0, mode="drop")
+                    vps = vps.at[:, zf].set(0.0, mode="drop")
+                    kp = (kpc.at[:, tgt_pg].set(qc1, mode="drop"),
+                          kps.at[:, tgt_pg].set(sc1, mode="drop"))
+                    vp = (vpc.at[:, tgt_pg].set(qv1, mode="drop"),
+                          vps.at[:, tgt_pg].set(sv1, mode="drop"))
+                else:
+                    kp = kp.at[:, tgt_pg].set(c1, mode="drop")
+                    vp = vp.at[:, tgt_pg].set(v1, mode="drop")
             # masked slot-state scatter: invalid rows target slot S
             # (out of bounds) and drop; valid rows carry distinct
             # host-assigned slots
@@ -594,31 +596,32 @@ class PoolPrograms:
             # scatter covers the whole wave's copies.  An int8 pool
             # copies codes AND scales together — a page's quantization
             # grid is part of its identity, refcounted as one unit.
-            if self.quant_kv:
-                (kpc, kps), (vpc, vps) = kp, vp
-                kcb = kpc.at[:, src].get(mode="fill", fill_value=0)
-                ksb = kps.at[:, src].get(mode="fill", fill_value=0)
-                vcb = vpc.at[:, src].get(mode="fill", fill_value=0)
-                vsb = vps.at[:, src].get(mode="fill", fill_value=0)
-                # recycled-page reset (see admit_fn): zero the SCALES
-                # of every freshly-owned page in the wave — including
-                # each row's decode-frontier pages and the COW dst —
-                # AFTER the src gathers above (a src page can double as
-                # another row's fresh page when an eviction inside this
-                # same wave recycled it) and BEFORE the dst scatter
-                # below re-lands the copied scale.
-                zf = zpages.reshape(-1)
-                kps = kps.at[:, zf].set(0.0, mode="drop")
-                vps = vps.at[:, zf].set(0.0, mode="drop")
-                kp = (kpc.at[:, dst].set(kcb, mode="drop"),
-                      kps.at[:, dst].set(ksb, mode="drop"))
-                vp = (vpc.at[:, dst].set(vcb, mode="drop"),
-                      vps.at[:, dst].set(vsb, mode="drop"))
-            else:
-                kblk = kp.at[:, src].get(mode="fill", fill_value=0)
-                vblk = vp.at[:, src].get(mode="fill", fill_value=0)
-                kp = kp.at[:, dst].set(kblk, mode="drop")
-                vp = vp.at[:, dst].set(vblk, mode="drop")
+            with jax.named_scope("mx.page_write"):
+                if self.quant_kv:
+                    (kpc, kps), (vpc, vps) = kp, vp
+                    kcb = kpc.at[:, src].get(mode="fill", fill_value=0)
+                    ksb = kps.at[:, src].get(mode="fill", fill_value=0)
+                    vcb = vpc.at[:, src].get(mode="fill", fill_value=0)
+                    vsb = vps.at[:, src].get(mode="fill", fill_value=0)
+                    # recycled-page reset (see admit_fn): zero the SCALES
+                    # of every freshly-owned page in the wave — including
+                    # each row's decode-frontier pages and the COW dst —
+                    # AFTER the src gathers above (a src page can double as
+                    # another row's fresh page when an eviction inside this
+                    # same wave recycled it) and BEFORE the dst scatter
+                    # below re-lands the copied scale.
+                    zf = zpages.reshape(-1)
+                    kps = kps.at[:, zf].set(0.0, mode="drop")
+                    vps = vps.at[:, zf].set(0.0, mode="drop")
+                    kp = (kpc.at[:, dst].set(kcb, mode="drop"),
+                          kps.at[:, dst].set(ksb, mode="drop"))
+                    vp = (vpc.at[:, dst].set(vcb, mode="drop"),
+                          vps.at[:, dst].set(vsb, mode="drop"))
+                else:
+                    kblk = kp.at[:, src].get(mode="fill", fill_value=0)
+                    vblk = vp.at[:, src].get(mode="fill", fill_value=0)
+                    kp = kp.at[:, dst].set(kblk, mode="drop")
+                    vp = vp.at[:, dst].set(vblk, mode="drop")
             tgt = jnp.where(valid, slot, self.S)
             pos = pos.at[tgt].set(true_len - 1, mode="drop")
             tok = tok.at[tgt].set(last_tok, mode="drop")
@@ -693,9 +696,10 @@ class PoolPrograms:
                 # the freshly-allocated rows in ``zrow`` on the first
                 # chunk only (all-sentinel afterward — later chunks
                 # must keep the ratchet of earlier ones).
-                (kpc, kps), (vpc, vps) = kp, vp
-                kp = (kpc, kps.at[:, zrow].set(0.0, mode="drop"))
-                vp = (vpc, vps.at[:, zrow].set(0.0, mode="drop"))
+                with jax.named_scope("mx.page_write"):
+                    (kpc, kps), (vpc, vps) = kp, vp
+                    kp = (kpc, kps.at[:, zrow].set(0.0, mode="drop"))
+                    vp = (vpc, vps.at[:, zrow].set(0.0, mode="drop"))
             with _TRACE_LOCK, params_swapped(deng.params, param_vals):
                 logits, kp, vp = deng.chunk_tokens(
                     toks, off, nlast, ptrow, page, kp, vp, sw, q8)
@@ -782,7 +786,9 @@ class PoolPrograms:
             with _TRACE_LOCK, params_swapped(deng.params, param_vals):
                 logits, kp, vp = deng.pool_verify_paged(
                     toks, pos, pt, page, kp, vp, sw, q8)
-            out = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # (S,K)
+            with jax.named_scope("mx.head"):
+                out = jnp.argmax(logits,
+                                 axis=-1).astype(jnp.int32)  # (S, K)
             # longest accepted prefix: draft j survives iff every
             # draft 0..j matched the model's own emission AND j is
             # inside both the proposed count and the slot's spec cap

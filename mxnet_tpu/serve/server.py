@@ -852,7 +852,10 @@ class DecodeServer:
         self._pending = deque()
         self._stopping = False
         self._slots = [None] * self.pool_sizes[0]   # slot -> _Request
-        self._inflight = deque()   # (kind, arrays, slot_snapshot/req)
+        # (kind, arrays, slot_snapshot/req, seq of the dispatch)
+        self._inflight = deque()
+        self._seq = 0            # dispatches so far: the id phase spans,
+        self._phase_span = None  # and serve_request, name a dispatch by
         self._next_id = 0
         self._steps = 0
         self._occupied_lane_steps = 0
@@ -1158,9 +1161,38 @@ class DecodeServer:
         fully idle: nothing pending, nothing in flight — the loop
         thread sleeps on that)."""
         fault_point("serve.pump", server=self.telemetry_label)
+        try:
+            return self._pump()
+        finally:
+            self._phase(None)
+
+    def _phase(self, name, **ids):
+        """Close the scheduler's open phase span and open ``name``
+        (``None``: only close).  The phases are SIBLINGS that tile a
+        pump — ``mx:serve:cancel``, ``admit_build``, the dispatches
+        (``admit`` / ``admit_hit`` / ``chunk`` / ``verify`` / ``step``,
+        each with its ``seq``), ``draft``, ``drain_wait`` and ``route``
+        (each with ``cause`` = the ``seq`` whose readback it handles);
+        ``mx:serve:idle`` is the loop thread's wait.  No span encloses
+        them: a trace reduction labels an idle gap of the device by the
+        span that covers most of it, and an outer span would take every
+        label.  Free when no trace runs (``telemetry.span``)."""
+        if self._phase_span is not None:
+            self._phase_span.__exit__(None, None, None)
+            self._phase_span = None
+        if name is not None:
+            self._phase_span = telemetry.span(name, **ids)
+            self._phase_span.__enter__()
+
+    def _next_seq(self):
+        self._seq += 1
+        return self._seq
+
+    def _pump(self):
         # cancellations FIRST: a cancelled slot frees at this step
         # boundary, so the admission below can re-fill it in the same
         # pump — no wasted masked lane, no extra dispatch
+        self._phase("mx:serve:cancel")
         worked = self._process_cancels()
         if self.sync_mode:
             return self._pump_sync() or worked
@@ -1179,6 +1211,7 @@ class DecodeServer:
                 # the liveness check repeats below
                 worked |= self._flush_drain()
                 if self._live_slots():
+                    self._phase("mx:serve:draft")
                     drafts = self._build_drafts()
             if drafts:
                 self._dispatch_verify(drafts)
@@ -1219,7 +1252,8 @@ class DecodeServer:
                     if self._stopping:
                         return
                     if not self._pending and not self._inflight:
-                        self._work.wait(0.05)
+                        with telemetry.span("mx:serve:idle"):
+                            self._work.wait(0.05)
 
     def _watch_dispatch(self, fn):
         """Re-arm the wedge gauge for one dispatch — or SUSPEND it when
@@ -1521,6 +1555,7 @@ class DecodeServer:
         spills a backlog larger than the biggest ``A`` bucket (or than
         the free slots) into follow-up dispatches in the same pump."""
         admitted = may_retire = False
+        self._phase("mx:serve:admit_build")
         self._maybe_grow()
         cap = self.admit_sizes[-1]
         while True:
@@ -1659,6 +1694,7 @@ class DecodeServer:
         in the wave's reserved pages via the page-row operand."""
         fault_point("serve.admit", server=self.telemetry_label,
                     wave=len(wave))
+        self._phase("mx:serve:admit_build")
         A = _bucket_for(self.admit_sizes, len(wave))
         P = _bucket_for(self.prefill_buckets,
                         max(req.prompt.size for _, req in wave))
@@ -1707,20 +1743,22 @@ class DecodeServer:
         S = len(self._slots)
         busy = sum(r is not None for r in self._slots)
         occ = busy / S if S else 0.0
+        seq = self._next_seq()
         for _slot, req in wave:
             wait = now - req.stream.submit_time
             req.span.update(queue_wait_s=wait, wave=len(wave),
                             a_bucket=A, p_bucket=P,
-                            occupancy_at_admit=occ)
+                            occupancy_at_admit=occ, admit_seq=seq)
             self._tele["wait"].observe(wait)
         telemetry.emit("serve_admit", server=self.telemetry_label,
                        wave=len(wave), a_bucket=A, p_bucket=P,
                        pool=S, occupancy=round(occ, 4))
         param_vals, q8, sw = self._progs.operands
-        with telemetry.annotation("mx:serve:admit"):
-            new_state, (first, done) = fn(param_vals, prompts, meta,
-                                          dls, pages, zpages,
-                                          *self._state)
+        self._phase("mx:serve:admit", seq=seq, wave=len(wave),
+                    a_bucket=A, p_bucket=P,
+                    requests=[r.stream.request_id for _, r in wave])
+        new_state, (first, done) = fn(param_vals, prompts, meta, dls,
+                                      pages, zpages, *self._state)
         self._state = new_state
         if self._torn:
             # the watchdog tore the server down while this dispatch was
@@ -1729,7 +1767,7 @@ class DecodeServer:
             self._state = None
             return
         self._count("admit_dispatches")
-        self._inflight.append(("admit", (first, done), list(wave)))
+        self._inflight.append(("admit", (first, done), list(wave), seq))
         if self._prefix is not None:
             # index the wave's FULL prompt pages for future COW hits
             # (device-written by the dispatch just queued; any
@@ -1855,9 +1893,11 @@ class DecodeServer:
         slot state — no model forward, zero prefill dispatches, and the
         request's first token arrives from the NEXT regular step
         (TTFT ≈ one decode step)."""
+        self._phase("mx:serve:admit_build")
         A = _bucket_for(self.admit_sizes, len(hits))
         fn = self._progs.admit_hit_fn(A)
         self._watch_dispatch(fn)
+        seq = self._next_seq()
         sentinel = self._progs.num_pages
         meta = onp.zeros((A, schema.meta_width("hit")), onp.int32)
         meta[:, schema.meta_col("hit", "true_len")] = 1
@@ -1893,16 +1933,17 @@ class DecodeServer:
             wait = now - req.stream.submit_time
             req.span.update(queue_wait_s=wait, wave=len(hits),
                             a_bucket=A, p_bucket=0,
-                            occupancy_at_admit=occ)
+                            occupancy_at_admit=occ, admit_seq=seq)
             self._tele["wait"].observe(wait)
             telemetry.emit("prefix_cache_hit",
                            server=self.telemetry_label,
                            request_id=req.stream.request_id,
                            shared_pages=plan["shared"],
                            cow_copy=plan["src"] >= 0, partial=False)
-        with telemetry.annotation("mx:serve:admit_hit"):
-            new_state = fn(meta, dls, srcs, dsts, zpages,
-                           *self._state)
+        self._phase("mx:serve:admit_hit", seq=seq, wave=len(hits),
+                    a_bucket=A, p_bucket=0,
+                    requests=[p["req"].stream.request_id for p in hits])
+        new_state = fn(meta, dls, srcs, dsts, zpages, *self._state)
         self._state = new_state
         if self._torn:
             self._state = None
@@ -1944,6 +1985,7 @@ class DecodeServer:
         this was the final chunk."""
         req, slot, off = rec["req"], rec["slot"], rec["off"]
         fault_point("serve.chunk", server=self.telemetry_label)
+        self._phase("mx:serve:admit_build")
         L = int(req.prompt.size)
         remaining = L - off
         top = self.prefill_buckets[-1]
@@ -1978,10 +2020,11 @@ class DecodeServer:
         if zero:
             zrow[:len(zero)] = zero
         param_vals, q8, sw = self._progs.operands
-        with telemetry.annotation("mx:serve:chunk"):
-            new_state, (first, done) = fn(param_vals, q8, sw, toks,
-                                          meta, dl, ptrow, zrow,
-                                          *self._state)
+        seq = self._next_seq()
+        self._phase("mx:serve:chunk", seq=seq, c_bucket=C,
+                    requests=[req.stream.request_id])
+        new_state, (first, done) = fn(param_vals, q8, sw, toks, meta, dl,
+                                      ptrow, zrow, *self._state)
         self._state = new_state
         if self._torn:
             self._state = None
@@ -1994,13 +2037,13 @@ class DecodeServer:
         if final:
             wait = time.perf_counter() - req.stream.submit_time
             req.span.update(queue_wait_s=wait, wave=1, a_bucket=1,
-                            p_bucket=C)
+                            p_bucket=C, admit_seq=seq)
             self._tele["wait"].observe(wait)
             if self._prefix is not None:
                 self._prefix.register(req.prompt, L,
                                       self._slot_pages[slot])
             self._inflight.append(("admit", (first, done),
-                                   [(slot, req)]))
+                                   [(slot, req)], seq))
         return final
 
     # speculative decoding -------------------------------------------------- #
@@ -2060,10 +2103,10 @@ class DecodeServer:
             block[slot, :d.size] = d
         param_vals, q8, sw = self._progs.operands
         now = onp.float32(self._clock() - self._epoch)
-        with telemetry.annotation("mx:serve:verify"):
-            new_state, out = fn(param_vals, q8, sw, now,
-                                self._page_table(), block, nd,
-                                *self._state)
+        seq = self._next_seq()
+        self._phase("mx:serve:verify", seq=seq, k_bucket=k)
+        new_state, out = fn(param_vals, q8, sw, now, self._page_table(),
+                            block, nd, *self._state)
         self._state = new_state
         if self._torn:
             self._state = None
@@ -2075,7 +2118,7 @@ class DecodeServer:
         self._tele["occ"].set(busy / S)
         self._tele["pages"].set(self._pages.in_use)
         self._inflight.append(("verify", out,
-                               (list(self._slots), nd, k)))
+                               (list(self._slots), nd, k), seq))
 
     # the step ------------------------------------------------------------ #
     def _dispatch_step(self):
@@ -2086,10 +2129,10 @@ class DecodeServer:
         # call — never a retrace), against which the executable checks
         # every slot's deadline
         now = onp.float32(self._clock() - self._epoch)
-        with telemetry.annotation("mx:serve:step"):
-            new_state, out = self._progs.step_fn()(
-                param_vals, q8, sw, now, self._page_table(),
-                *self._state)
+        seq = self._next_seq()
+        self._phase("mx:serve:step", seq=seq)
+        new_state, out = self._progs.step_fn()(
+            param_vals, q8, sw, now, self._page_table(), *self._state)
         self._state = new_state
         if self._torn:
             # late completion of a wedged dispatch after watchdog
@@ -2104,7 +2147,7 @@ class DecodeServer:
         self._capacity_lane_steps += len(self._slots)
         self._tele["occ"].set(busy / len(self._slots))
         self._tele["pages"].set(self._pages.in_use)
-        self._inflight.append(("step", out, list(self._slots)))
+        self._inflight.append(("step", out, list(self._slots), seq))
 
     # drain ---------------------------------------------------------------- #
     def _drain_admits(self):
@@ -2113,21 +2156,23 @@ class DecodeServer:
         and step entries only touch other, older requests)."""
         rest = deque()
         while self._inflight:
-            kind, arrays, meta = self._inflight.popleft()
-            if kind != "admit":
-                rest.append((kind, arrays, meta))
+            entry = self._inflight.popleft()
+            if entry[0] != "admit":
+                rest.append(entry)
                 continue
-            self._route_admit(arrays, meta)
+            self._route_admit(*entry[1:])
         self._inflight = rest
 
-    def _route_admit(self, arrays, wave):
+    def _route_admit(self, arrays, wave, seq):
         """Route one admission wave's ``(first_tok, done)`` readback to
         its requests' streams, in wave order — which IS submission
         order, so per-request stream order is preserved.  (A final
         CHUNK's scalar readback rides this path too, as a wave of
         one — hence the flatten.)"""
+        self._phase("mx:serve:drain_wait", cause=seq)
         first = onp.asarray(arrays[0]).reshape(-1)
         done = onp.asarray(arrays[1]).reshape(-1)
+        self._phase("mx:serve:route", cause=seq)
         for i, (slot, req) in enumerate(wave):
             if req.cancelled:
                 continue   # retired aside; the lane's output is void
@@ -2155,19 +2200,23 @@ class DecodeServer:
             keep = 0
         worked = False
         while len(self._inflight) > keep:
-            kind, arrays, meta = self._inflight.popleft()
+            kind, arrays, meta, seq = self._inflight.popleft()
             worked = True
             if kind == "admit":
-                self._route_admit(arrays, meta)
+                self._route_admit(arrays, meta, seq)
             elif kind == "verify":
-                self._route_verify(arrays, meta)
+                self._route_verify(arrays, meta, seq)
             else:
+                self._phase("mx:serve:drain_wait", cause=seq)
                 toks, emitted, done = (onp.asarray(a) for a in arrays)
+                self._phase("mx:serve:route", cause=seq)
                 snapshot = meta
                 for slot, req in enumerate(snapshot):
                     if req is None or req.cancelled \
                             or not emitted[slot]:
                         continue
+                    req.span.setdefault("first_step_seq", seq)
+                    req.span["last_step_seq"] = seq
                     tok = int(toks[slot])
                     req.stream._push(tok)
                     if done[slot]:
@@ -2183,7 +2232,7 @@ class DecodeServer:
                             self._free_slot_pages(slot)
         return worked
 
-    def _route_verify(self, arrays, meta):
+    def _route_verify(self, arrays, meta, seq):
         """Route one verify dispatch's ``(tokens (S, K), advance (S,),
         done (S,))`` readback: every live lane emits its accepted
         prefix plus the executable's own next token (``advance``
@@ -2193,7 +2242,9 @@ class DecodeServer:
         accepted + rejected == proposed holds per stream, per server
         and in the recording (``telemetry_report --check-serve``
         re-derives it)."""
+        self._phase("mx:serve:drain_wait", cause=seq)
         toks, adv, done = (onp.asarray(a) for a in arrays)
+        self._phase("mx:serve:route", cause=seq)
         snapshot, nd, k_bucket = meta
         proposed_t = accepted_t = rejected_t = 0
         for slot, req in enumerate(snapshot):
@@ -2202,6 +2253,8 @@ class DecodeServer:
             n = int(adv[slot])
             if n < 1:
                 continue   # masked lane (inactive this dispatch)
+            req.span.setdefault("first_step_seq", seq)
+            req.span["last_step_seq"] = seq
             for t in toks[slot, :n]:
                 req.stream._push(int(t))
             proposed = int(nd[slot])
@@ -2293,6 +2346,9 @@ class DecodeServer:
             wave=sp.get("wave"), a_bucket=sp.get("a_bucket"),
             p_bucket=sp.get("p_bucket"),
             occupancy_at_admit=sp.get("occupancy_at_admit"),
+            admit_seq=sp.get("admit_seq"),
+            first_step_seq=sp.get("first_step_seq"),
+            last_step_seq=sp.get("last_step_seq"),
             draft_accepted=st.draft_accepted,
             draft_rejected=st.draft_rejected)
 

@@ -17,10 +17,11 @@ code, hand-called ``profiler_xla.hlo_op_count``):
   programs, offline decode) emits a ``compile`` event — retrace
   regressions become a queryable stream instead of a test-only
   assertion.
-- **device-timeline bridge** (:func:`annotation` / :func:`span`):
-  serve/train phases appear as ``jax.profiler.TraceAnnotation`` ranges
-  whenever a device trace is being captured, and cost a no-op context
-  otherwise.
+- **device-timeline bridge** (:func:`span` / :func:`spans`): while a
+  device trace is being captured (``mx.profiler.start()``) serve/train
+  phases appear as ``jax.profiler.TraceAnnotation`` ranges carrying
+  their ids, and are kept in memory on the ``time.perf_counter()``
+  clock; otherwise a span is a no-op context.
 - **memory axis** (:mod:`.memory`, ISSUE 10): per-executable
   ``memory_analysis()`` bytes on compile events under
   ``MXNET_TELEMETRY_MEM=1``, the process-wide :data:`ACCOUNTANT`
@@ -42,6 +43,7 @@ friends are views over it).  See docs/TELEMETRY.md.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import time
 
@@ -64,34 +66,86 @@ __all__ = [
     "emit", "events", "clear_events", "add_sink", "remove_sink",
     "add_jsonl_sink", "JsonlSink", "telemetry_enabled",
     "fault_point", "parse_fault_spec", "reset_faults",
-    "instrument_jit", "annotation", "span",
+    "instrument_jit", "span", "spans", "clear_spans",
     "memory", "ACCOUNTANT", "MemoryAccountant", "memory_analysis",
     "mem_enabled", "nbytes_of", "per_device_bytes", "live_device_bytes",
     "parse_bytes", "format_bytes", "reconcile",
 ]
 
 
-def annotation(name):
-    """A ``jax.profiler.TraceAnnotation`` context while a device trace
-    is being captured (``mx.profiler.start()``), else a free no-op — so
-    serve/train phases land in the device timeline exactly when someone
-    is looking at one."""
+# the phase spans of the last profiled stretch: ``(name, t0, t1, seq,
+# cause, fields)`` with ``time.perf_counter()`` stamps, oldest first.  A
+# bounded ring (a span is a few per scheduler step; an operator's trace of
+# minutes fits), cleared by ``mx.profiler.start()``.
+SPAN_RING = 1 << 16
+_SPANS = collections.deque(maxlen=SPAN_RING)
+_NULL = contextlib.nullcontext()
+
+
+class _Span:
+    """One phase span while a trace runs: a ``TraceAnnotation`` whose
+    stats are the span's ids and fields, and a row of the ring."""
+
+    __slots__ = ("name", "seq", "cause", "fields", "_hist", "_traced",
+                 "_ann", "_t0")
+
+    def __init__(self, name, seq, cause, fields, hist, traced):
+        self.name, self.seq, self.cause = name, seq, cause
+        self.fields, self._hist, self._traced = fields, hist, traced
+
+    def __enter__(self):
+        if self._traced:
+            import jax
+
+            stats = {k: v if isinstance(v, (int, float, str)) else
+                     " ".join(map(str, v))     # ids of a wave; no commas
+                     for k, v in self.fields.items()}
+            if self.seq is not None:
+                stats["seq"] = self.seq
+            if self.cause is not None:
+                stats["cause"] = self.cause
+            self._ann = jax.profiler.TraceAnnotation(self.name, **stats)
+            self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.perf_counter()
+        if self._traced:
+            self._ann.__exit__(*exc)
+            _SPANS.append((self.name, self._t0, t1, self.seq, self.cause,
+                           self.fields))
+        if self._hist is not None:
+            REGISTRY.histogram(self._hist).observe(t1 - self._t0)
+        return False
+
+
+def span(name, seq=None, cause=None, hist=None, **fields):
+    """A phase of a host loop (``mx:serve:step``, ``mx:train:feed``).
+
+    While a device trace is being captured (``mx.profiler.start()``) the
+    phase is (a) a ``jax.profiler.TraceAnnotation`` on the timeline with
+    ``seq`` (the id of what it dispatches), ``cause`` (the ``seq`` whose
+    result it handles) and ``fields`` as the event's stats, and (b) a row
+    ``(name, t0, t1, seq, cause, fields)`` of :func:`spans`, stamped with
+    ``time.perf_counter()`` — so phases land in the timeline, and in
+    memory, exactly when someone is looking.  Otherwise it is a free
+    no-op context.  ``hist`` names a histogram that times the phase
+    whether or not a trace runs."""
     from .. import profiler
 
-    if profiler._state["running"]:
-        import jax
+    traced = profiler._state["running"]
+    if not traced and hist is None:
+        return _NULL
+    return _Span(name, seq, cause, fields, hist, traced)
 
-        return jax.profiler.TraceAnnotation(name)
-    return contextlib.nullcontext()
+
+def spans(name=None):
+    """The spans recorded since the last ``mx.profiler.start()`` (all, or
+    those called ``name``), oldest first."""
+    rows = list(_SPANS)
+    return rows if name is None else [r for r in rows if r[0] == name]
 
 
-@contextlib.contextmanager
-def span(name, hist=None, **labels):
-    """Time a phase into histogram ``hist`` (default
-    ``f"{name}_seconds"``) and bridge it to the device timeline via
-    :func:`annotation`."""
-    h = REGISTRY.histogram(hist or f"{name}_seconds", **labels)
-    t0 = time.perf_counter()
-    with annotation(name):
-        yield h
-    h.observe(time.perf_counter() - t0)
+def clear_spans():
+    _SPANS.clear()

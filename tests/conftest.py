@@ -74,3 +74,51 @@ def _seed():
     onp.random.seed(0)
     mx.random.seed(0)
     yield
+
+
+def build_xspace(planes):
+    """A serialized xspace (the bytes of an ``.xplane.pb``) from
+    ``[{"name": plane, "lines": {line: [(event name, start_ps, dur_ps,
+    {metadata stat: value}), ...]}}]`` — built by JAX's own serializer, so
+    what reads it is checked against the real wire format.  As in a v5e
+    trace, an operation's provenance (``tf_op``), category, FLOPs and
+    bytes are stats of its event METADATA, keyed by the event's name."""
+    from jax.profiler import ProfileData
+
+    def quoted(s):
+        return '"' + str(s).replace("\\", "\\\\").replace('"', '\\"') + '"'
+
+    out = []
+    for pid, plane in enumerate(planes, 1):
+        stat_ids, meta_ids, metas, lines = {}, {}, [], []
+        for lid, (line, events) in enumerate(plane["lines"].items(), 1):
+            rows = []
+            for name, start, dur, *rest in events:
+                if name not in meta_ids:
+                    meta_ids[name] = len(meta_ids) + 1
+                    stats = []
+                    for k, v in (rest[0] if rest else {}).items():
+                        sid = stat_ids.setdefault(k, len(stat_ids) + 1)
+                        kind = "str_value: " + quoted(v) \
+                            if isinstance(v, str) else f"int64_value: {v}"
+                        stats.append(f"stats {{ metadata_id: {sid} {kind} }}")
+                    short = name.split(" = ", 1)[0].lstrip("%")
+                    metas.append(
+                        f"event_metadata {{ key: {meta_ids[name]} value {{ "
+                        f"id: {meta_ids[name]} name: {quoted(name)} "
+                        f"display_name: {quoted(short)} "
+                        f"{' '.join(stats)} }} }}")
+                rows.append(f"events {{ metadata_id: {meta_ids[name]} "
+                            f"offset_ps: {start} duration_ps: {dur} }}")
+            lines.append(f"lines {{ id: {lid} name: {quoted(line)} "
+                         f"{' '.join(rows)} }}")
+        stat_meta = [f"stat_metadata {{ key: {i} value {{ id: {i} name: "
+                     f"{quoted(k)} }} }}" for k, i in stat_ids.items()]
+        out.append(f"planes {{ id: {pid} name: {quoted(plane['name'])} "
+                   f"{' '.join(lines + metas + stat_meta)} }}")
+    return ProfileData.text_proto_to_serialized_xspace("\n".join(out))
+
+
+@pytest.fixture
+def make_xspace():
+    return build_xspace

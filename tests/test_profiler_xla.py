@@ -1,76 +1,58 @@
 """Trace-parsing device profiler (SURVEY.md §5.1 — per-op aggregate table
 recovered inside fused jit steps)."""
-import glob
-import gzip
-import json
-import os
-
 import pytest
 
 from mxnet_tpu import profiler_xla
 
 
-def _fake_trace(tmp_path, events):
-    session = tmp_path / "plugins" / "profile" / "2026_01_01_00_00_00"
-    session.mkdir(parents=True)
-    with gzip.open(session / "host.trace.json.gz", "wt") as f:
-        json.dump({"traceEvents": events}, f)
-    return str(tmp_path)
+def _fusion(n, **stats):
+    return f"%fusion.{n} = f32[8]{{0}} fusion(f32[8]{{0}} %p), kind=kLoop", \
+        stats
 
 
-def _device_meta():
-    return [
-        {"ph": "M", "pid": 3, "name": "process_name",
-         "args": {"name": "/device:TPU:0"}},
-        {"ph": "M", "pid": 3, "tid": 3, "name": "thread_name",
-         "args": {"name": "XLA Ops"}},
-        {"ph": "M", "pid": 701, "name": "process_name",
-         "args": {"name": "/host:CPU"}},
-    ]
+def _trace(make_xspace, ops):
+    """One ``/device:TPU:0`` plane whose run of ``jit_step`` holds ``ops``
+    (``(name, start_ps, dur_ps, metadata stats)``), between two short runs
+    so that it lies whole in the trace, and a host plane beside it."""
+    return make_xspace([
+        {"name": "/device:TPU:0", "lines": {
+            "XLA Modules": [("jit_step(123)", 0, 5_000_000),
+                            ("jit_step(123)", 10_000_000, 60_000_000),
+                            ("jit_step(123)", 80_000_000, 5_000_000)],
+            "XLA Ops": ops}},
+        {"name": "/host:CPU", "lines": {
+            "python": [("PjitFunction(step)", 0, 99_000_000)]}}])
 
 
-def test_parse_trace_device_lane_only(tmp_path):
-    events = _device_meta() + [
-        # device op with full args
-        {"ph": "X", "pid": 3, "tid": 3, "ts": 0, "dur": 12.6,
-         "name": "fusion",
-         "args": {"device_duration_ps": "12600000",
-                  "hlo_category": "convolution fusion",
-                  "model_flops": "2147483648",
-                  "raw_bytes_accessed": "6291456",
-                  "tf_op": "jit(step)/dot_general:"}},
-        # host event on a python thread — must be skipped
-        {"ph": "X", "pid": 701, "tid": 1, "ts": 0, "dur": 99.0,
-         "name": "PjitFunction(step)"},
-        # device event on a non-op lane (XLA Modules) — skipped
-        {"ph": "X", "pid": 3, "tid": 2, "ts": 0, "dur": 50.0,
-         "name": "jit_step(123)"},
-    ]
-    recs = profiler_xla.parse_trace(_fake_trace(tmp_path, events))
-    assert len(recs) == 1
-    r = recs[0]
-    assert r["name"] == "fusion"
+def test_parse_xplane_device_lane_only(make_xspace):
+    name, stats = _fusion(
+        1, hlo_category="convolution fusion", model_flops=2147483648,
+        raw_bytes_accessed=6291456, tf_op="jit(step)/dot_general:")
+    # the host plane's event and the device's "XLA Modules" lane are not
+    # operations: only the "XLA Ops" event comes back
+    parsed = profiler_xla.parse_xplane(_trace(
+        make_xspace, [(name, 12_000_000, 12_600_000, stats)]))
+    assert len(parsed["ops"]) == 1
+    r = parsed["ops"][0]
+    assert r["name"] == "fusion.1"
+    assert r["long_name"] == name
     assert r["category"] == "convolution fusion"
-    assert abs(r["dur_us"] - 12.6) < 1e-6      # ps field preferred
+    assert abs(r["dur_us"] - 12.6) < 1e-6       # picoseconds in the trace
+    assert abs(r["start_us"] - 12.0) < 1e-6
     assert r["flops"] == 2147483648
     assert r["bytes"] == 6291456
     assert r["tf_op"].startswith("jit(step)")
+    assert r["module"] == "jit_step" and parsed["runs"][r["run"]]["whole"]
 
 
-def test_aggregate_and_format(tmp_path):
-    events = _device_meta() + [
-        {"ph": "X", "pid": 3, "tid": 3, "ts": 0, "dur": 10.0,
-         "name": "fusion", "args": {
-             "device_duration_ps": "10000000", "hlo_category": "fusion",
-             "model_flops": "1000000000", "raw_bytes_accessed": "1000",
-             "tf_op": "jit(f)/dot_general:"}},
-        {"ph": "X", "pid": 3, "tid": 3, "ts": 20, "dur": 30.0,
-         "name": "fusion.1", "args": {
-             "device_duration_ps": "30000000", "hlo_category": "fusion",
-             "model_flops": "0", "raw_bytes_accessed": "4000",
-             "tf_op": "jit(f)/add:"}},
-    ]
-    recs = profiler_xla.parse_trace(_fake_trace(tmp_path, events))
+def test_aggregate_and_format(make_xspace):
+    n1, s1 = _fusion(1, hlo_category="fusion", model_flops=1000000000,
+                     raw_bytes_accessed=1000, tf_op="jit(f)/dot_general:")
+    n2, s2 = _fusion(2, hlo_category="fusion", model_flops=0,
+                     raw_bytes_accessed=4000, tf_op="jit(f)/add:")
+    recs = profiler_xla.parse_xplane(_trace(make_xspace, [
+        (n1, 12_000_000, 10_000_000, s1),
+        (n2, 30_000_000, 30_000_000, s2)]))["ops"]
     by_cat = profiler_xla.aggregate(recs, by="category")
     assert len(by_cat) == 1 and by_cat[0]["calls"] == 2
     assert abs(by_cat[0]["dur_us"] - 40.0) < 1e-6
@@ -85,9 +67,12 @@ def test_aggregate_and_format(tmp_path):
     assert "jit(f)/add:" in table and "TOTAL" in table and "MFU%" in table
 
 
-def test_latest_session_missing(tmp_path):
-    with pytest.raises(FileNotFoundError):
-        profiler_xla.latest_session(str(tmp_path))
+def test_no_trace_or_no_device_plane_is_none(tmp_path, make_xspace):
+    assert profiler_xla.read_xplane(str(tmp_path)) is None
+    assert profiler_xla.parse_xplane(str(tmp_path)) is None
+    host_only = make_xspace([{"name": "/host:CPU", "lines": {
+        "python": [("PjitFunction(step)", 0, 99)]}}])
+    assert profiler_xla.parse_xplane(host_only) is None
 
 
 def test_profile_fn_cpu_no_crash():
@@ -101,21 +86,18 @@ def test_profile_fn_cpu_no_crash():
     assert isinstance(recs, list)
 
 
-def test_profiler_facade_device_dumps(tmp_path, monkeypatch):
-    """mx.profiler.device_dumps() renders the table for the last window."""
+def test_profiler_facade_device_dumps(make_xspace, monkeypatch):
+    """mx.profiler.device_dumps() renders the table for the last window
+    from the bytes ``stop()`` kept."""
     from mxnet_tpu import profiler
 
-    events = _device_meta() + [
-        {"ph": "X", "pid": 3, "tid": 3, "ts": 0, "dur": 5.0,
-         "name": "fusion", "args": {
-             "device_duration_ps": "5000000", "hlo_category": "fusion",
-             "model_flops": "0", "raw_bytes_accessed": "128",
-             "tf_op": "jit(f)/mul:"}},
-    ]
-    td = _fake_trace(tmp_path, events)
-    monkeypatch.setitem(profiler._state, "trace_dir", td)
-    out = profiler.device_dumps(by="tf_op")
-    assert "jit(f)/mul:" in out
+    name, stats = _fusion(1, hlo_category="fusion", raw_bytes_accessed=128,
+                          tf_op="jit(f)/mx.attn/mul:")
+    raw = _trace(make_xspace, [(name, 12_000_000, 5_000_000, stats)])
+    monkeypatch.setitem(profiler._state, "xplane", raw)
+    monkeypatch.setitem(profiler._state, "parsed", None)
+    assert "jit(f)/mx.attn/mul:" in profiler.device_dumps(by="tf_op")
+    assert "mx.attn" in profiler.device_dumps(by="region")
 
 
 # --------------------------------------------------------------------- #

@@ -248,14 +248,15 @@ class TestCompileWatch:
 
 class TestSpan:
     def test_span_observes_histogram(self):
-        with telemetry.span("t_span_phase") as h:
+        with telemetry.span("t_span_phase", hist="t_span_phase_seconds"):
             pass
-        assert h is telemetry.histogram("t_span_phase_seconds")
-        assert h.count == 1
+        assert telemetry.histogram("t_span_phase_seconds").count == 1
 
-    def test_annotation_is_noop_without_profiler(self):
-        with telemetry.annotation("t_ann"):
-            pass   # nullcontext — nothing to assert beyond no crash
+    def test_span_is_noop_without_profiler(self):
+        before = len(telemetry.spans())
+        with telemetry.span("t_ann", seq=1) as sp:
+            pass   # the shared nullcontext: no annotation, no record
+        assert sp is None and len(telemetry.spans()) == before
 
 
 # --------------------------------------------------------------------- #

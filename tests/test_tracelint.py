@@ -2584,12 +2584,10 @@ class TestSeededContractDrift:
         admission dispatch loses an operand (TL018)."""
         src = open(os.path.join(
             REPO, "mxnet_tpu", "serve", "server.py")).read()
-        needle = ("fn(meta, dls, srcs, dsts, zpages,\n"
-                  "                           *self._state)")
+        needle = "fn(meta, dls, srcs, dsts, zpages, *self._state)"
         assert needle in src
         seeded = src.replace(
-            needle, "fn(meta, dls, srcs, dsts,\n"
-                    "                           *self._state)", 1)
+            needle, "fn(meta, dls, srcs, dsts, *self._state)", 1)
         self._mirror(tmp_path, "server.py", seeded)
         r = cli([str(tmp_path), "--select", "TL018", "--format=json"])
         assert r.returncode == 1
