@@ -120,20 +120,77 @@ _KV_CODE_DTYPE = jnp.int8
 _KV_SCALE_DTYPE = jnp.float32
 
 
+# -- the page pool's device layout --------------------------------------- #
+# A K or V pool is ``(NL, NPAGES, page, KV·D)``: one layer's page is one
+# contiguous ``(page, KV·D)`` block whose minor dimension fills whole
+# 128-lane tiles for every KV·D that is a multiple of 128 (a minor
+# dimension of D = 64 made the chip keep the pool NPAGES-minor and re-lay
+# it out for every consumer, PERF.md PR 27).  An int8 pool's codes take the
+# same layout and its scales are ``(NL, NPAGES, KV)``.  Two access idioms
+# keep every program in place on the donated pools: READ pages with one
+# gather at ``(layer, page id)`` out of the whole pool, WRITE with a scatter
+# at explicit ``(layer, page id[, row])`` indices — the layer is always an
+# index, never a window dimension or a scan-sliced axis.
+
+def _layer_ids(arr, ids):
+    """``arange(NL)`` shaped to broadcast against page ids ``ids``, whose
+    own leading axis is the layer axis (``ids[None]`` or ``(NL, ...)``)."""
+    return jnp.arange(arr.shape[0]).reshape((-1,) + (1,) * (ids.ndim - 1))
+
+
+def _pages_get(arr, pg, layer=None):
+    """Pages ``pg`` (any int shape) of a pool array out of the WHOLE
+    array: ``(*pg.shape, *arr.shape[2:])`` for one ``layer`` (a traced
+    scalar), ``(NL, *pg.shape, ...)`` for every layer.  The table
+    sentinel ``NPAGES`` is CLAMPED onto the last page, in bounds, not
+    filled with zeros: every reader masks by position (``idx <= pos``
+    gives weight exactly 0 to what the sentinel stands for, as it does to
+    the stale columns of a frontier page) or DROPS what it built from the
+    gather on the way back, so the values behind a sentinel never reach a
+    sum or the pool."""
+    pg = jnp.minimum(pg, arr.shape[1] - 1)
+    if layer is None:
+        pg = pg[None]
+        layer = _layer_ids(arr, pg)
+    return arr.at[layer, pg].get(mode="promise_in_bounds")
+
+
+def _pages_set(arr, pg, vals):
+    """Whole pages ``pg`` of every layer := ``vals`` ``(NL, *pg.shape,
+    ...)``; sentinel ids DROP (a retired slot cannot touch a freed
+    page)."""
+    pg = pg[None]
+    return arr.at[_layer_ids(arr, pg), pg].set(vals, mode="drop")
+
+
+def _rows_set(arr, pg, off, rows):
+    """Token rows ``(pg, off)`` of every layer := ``rows`` ``(NL,
+    *pg.shape, KV·D)``; sentinel ids DROP."""
+    pg, off = pg[None], off[None]
+    return arr.at[_layer_ids(arr, pg), pg, off].set(rows, mode="drop")
+
+
+def _kv_lanes(scales, lanes):
+    """Per-(page, head) scales ``(..., KV)`` -> ``(..., 1, KV·D)``: one
+    per lane of the page's rows."""
+    return jnp.repeat(scales, lanes // scales.shape[-1],
+                      axis=-1)[..., None, :]
+
+
 def _kv_dequant(codes, scales, dtype):
-    """Int8 KV page codes -> ``dtype`` values: ``codes * scale`` with
-    the per-page-per-head f32 scale broadcast over the trailing
-    ``(page, D)`` axes.  A sentinel gather fills codes AND scales with
-    zeros, so unmapped pages dequantize to the exact zeros the f32
-    pool's fill would have produced."""
+    """Int8 KV page codes ``(..., page, KV·D)`` -> ``dtype`` values:
+    ``codes * scale`` with the per-page-per-head f32 scale ``(..., KV)``
+    broadcast over the page's rows and the head's D lanes."""
     return (codes.astype(_KV_SCALE_DTYPE)
-            * scales[..., None, None]).astype(dtype)
+            * _kv_lanes(scales, codes.shape[-1])).astype(dtype)
 
 
-def _kv_requant(vals, floor_scales):
-    """Symmetric per-page-row int8 quantization of ``vals`` over its
-    trailing ``(page, D)`` axes, with the new scale FLOORED at the
-    page's previous scale (pass ``0.0`` for fresh pages).  The floor
+def _kv_requant(vals, floor_scales, kv):
+    """Symmetric int8 quantization of pages ``vals`` ``(..., page,
+    KV·D)``, one scale per page and each of its ``kv`` K/V heads (the
+    maximum over the page's rows and the head's D lanes), with the new
+    scale FLOORED at the page's previous scale ``(..., KV)`` (pass
+    ``0.0`` for fresh pages).  The floor
     is what keeps the read-modify-write page rewrites lossless for
     untouched columns: when a new column does not raise the page's
     dynamic range the scale is unchanged and every existing code
@@ -155,64 +212,77 @@ def _kv_requant(vals, floor_scales):
       scale may be coarser than one-shot quantization of the surviving
       contents."""
     v32 = vals.astype(_KV_SCALE_DTYPE)
-    amax = jnp.max(jnp.abs(v32), axis=(-2, -1))
+    heads = v32.reshape(v32.shape[:-1] + (kv, v32.shape[-1] // kv))
+    amax = jnp.max(jnp.abs(heads), axis=(-3, -1))
     s = jnp.maximum(jnp.maximum(amax / 127.0, floor_scales), 1e-8)
-    codes = jnp.round(v32 / s[..., None, None]).astype(_KV_CODE_DTYPE)
+    codes = jnp.round(v32 / _kv_lanes(s, v32.shape[-1])) \
+        .astype(_KV_CODE_DTYPE)
     return codes, s
 
 
-def _kv_step_rmw(pool, pg, iB, offs, newcol):
-    """Requantizing single-column page rewrite for the paged pool STEP:
-    gather each slot's frontier page ``pg[b]`` (codes + scale),
-    dequantize, land slot ``b``'s new K or V column at page offset
+@jax.named_scope("mx.paged_view")
+def _paged_rows(pool, pt, layer, dtype):
+    """Layer ``layer``'s pages ``pt`` ``(..., MAXP)`` of a K or V pool as
+    token rows ``(..., T, KV·D)``: ONE gather at ``(layer, page id)``
+    straight out of the whole pool, whose ``(..., MAXP, page, KV·D)``
+    result IS the row view (``t = j * page + o``) — no per-layer slice of
+    the pool, no transpose.  An int8 pool, a ``(codes, scales)`` pair,
+    dequantizes to ``dtype`` in the same gather."""
+    if isinstance(pool, tuple):
+        g = _kv_dequant(_pages_get(pool[0], pt, layer),
+                        _pages_get(pool[1], pt, layer), dtype)
+    else:
+        g = _pages_get(pool, pt, layer)
+    return g.reshape(pt.shape[:-1] + (-1, g.shape[-1]))
+
+
+def _kv_step_rmw(pool, pg, offs, newrow):
+    """Requantizing single-row page rewrite for the paged pool STEP:
+    gather each slot's frontier page ``pg[b]`` of every layer (codes +
+    scale), dequantize, land slot ``b``'s new K or V row at page offset
     ``offs[b]``, re-quantize with the old scale as floor, and scatter
     codes+scales back (``mode="drop"``: a retired lane's sentinel page
-    id cannot touch a freed page).  ``newcol`` is ``(B, NL, KV, D)``
-    — the advanced-index layout of the dense per-slot scatter this
-    replaces.  Write pages are exclusively owned (COW guarantees the
-    shared prefix never holds a slot's write frontier), so the
-    whole-page scatter never races another slot."""
+    id cannot touch a freed page).  ``newrow`` is ``(NL, B, KV·D)``.
+    Write pages are exclusively owned (COW guarantees the shared prefix
+    never holds a slot's write frontier), so the whole-page scatter
+    never races another slot."""
     codes, scales = pool
-    old_s = scales.at[:, pg].get(mode="fill", fill_value=0)
-    vals = _kv_dequant(codes.at[:, pg].get(mode="fill", fill_value=0),
-                       old_s, jnp.float32)       # (NL, B, KV, page, D)
-    vals = vals.at[:, iB, :, offs, :].set(newcol.astype(jnp.float32))
-    q, s = _kv_requant(vals, old_s)
-    return (codes.at[:, pg].set(q, mode="drop"),
-            scales.at[:, pg].set(s, mode="drop"))
+    old_s = _pages_get(scales, pg)                       # (NL, B, KV)
+    vals = _kv_dequant(_pages_get(codes, pg), old_s,
+                       jnp.float32)                # (NL, B, page, KV·D)
+    vals = vals.at[:, jnp.arange(pg.shape[0]), offs].set(
+        newrow.astype(jnp.float32))
+    q, s = _kv_requant(vals, old_s, scales.shape[-1])
+    return _pages_set(codes, pg, q), _pages_set(scales, pg, s)
 
 
-def _kv_chunk_rmw(pool, wpgs, loc, new_cd, page, ntp):
+def _kv_chunk_rmw(pool, wpgs, loc, new_rows):
     """Requantizing page-WINDOW rewrite for ``chunk_tokens``: the
     chunk's ``C`` consecutive positions touch at most ``ntp``
     consecutive pages of one slot's row.  Gather the window,
-    dequantize, land the chunk columns at their window-local offsets
+    dequantize, land the chunk rows at their window-local offsets
     ``loc`` (out-of-window entries DROP — bucket-padded tails and
     positions past the cache horizon never land), re-quantize each
-    window page with its old scale as floor, scatter back.  ``new_cd``
-    is ``(NL, KV, C, D)``."""
+    window page with its old scale as floor, scatter back.  ``new_rows``
+    is ``(NL, C, KV·D)``."""
     codes, scales = pool
-    old_s = scales.at[:, wpgs].get(mode="fill", fill_value=0)
-    win = _kv_dequant(codes.at[:, wpgs].get(mode="fill", fill_value=0),
-                      old_s, jnp.float32)        # (NL, NTP, KV, page, D)
-    NL, _, KV, _, D = win.shape
-    flat = jnp.moveaxis(win, 2, 1).reshape(NL, KV, ntp * page, D)
-    flat = flat.at[:, :, loc, :].set(new_cd.astype(jnp.float32),
-                                     mode="drop")
-    win = jnp.moveaxis(flat.reshape(NL, KV, ntp, page, D), 2, 1)
-    q, s = _kv_requant(win, old_s)
-    return (codes.at[:, wpgs].set(q, mode="drop"),
-            scales.at[:, wpgs].set(s, mode="drop"))
+    old_s = _pages_get(scales, wpgs)                     # (NL, NTP, KV)
+    win = _kv_dequant(_pages_get(codes, wpgs), old_s,
+                      jnp.float32)               # (NL, NTP, page, KV·D)
+    flat = win.reshape(win.shape[0], -1, win.shape[-1])  # window rows
+    flat = flat.at[:, loc].set(new_rows.astype(jnp.float32), mode="drop")
+    q, s = _kv_requant(flat.reshape(win.shape), old_s, scales.shape[-1])
+    return _pages_set(codes, wpgs, q), _pages_set(scales, wpgs, s)
 
 
-def _kv_verify_rmw(pool, wpgs, iB, loc, new_bd, page, ntp):
+def _kv_verify_rmw(pool, wpgs, loc, new_rows):
     """Requantizing per-slot page-window rewrite for
     ``pool_verify_paged``: like ``_kv_chunk_rmw`` batched over slots —
     slot ``b``'s block touches window pages ``wpgs[b]`` with
-    window-local column offsets ``loc[b]``.  Slots' write windows are
+    window-local row offsets ``loc[b]``.  Slots' write windows are
     disjoint (every window page belongs to its slot's reserved,
     exclusively-owned range), so the batched whole-page scatter never
-    collides.  ``new_bd`` is ``(B, C, NL, KV, D)``.
+    collides.  ``new_rows`` is ``(NL, B, C, KV·D)``.
 
     Known deviation (documented in PARITY.md): all ``C`` drafted
     columns quantize here BEFORE acceptance is known.  Rejection rolls
@@ -225,17 +295,58 @@ def _kv_verify_rmw(pool, wpgs, iB, loc, new_bd, page, ntp):
     ``scale/2`` code-step bound; the end-to-end effect is covered by
     the pinned greedy-agreement tolerance."""
     codes, scales = pool
-    old_s = scales.at[:, wpgs].get(mode="fill", fill_value=0)
-    win = _kv_dequant(codes.at[:, wpgs].get(mode="fill", fill_value=0),
-                      old_s, jnp.float32)     # (NL, B, NTP, KV, page, D)
-    NL, B, _, KV, _, D = win.shape
-    flat = jnp.moveaxis(win, 3, 2).reshape(NL, B, KV, ntp * page, D)
-    flat = flat.at[:, iB[:, None], :, loc, :].set(
-        new_bd.astype(jnp.float32), mode="drop")
-    win = jnp.moveaxis(flat.reshape(NL, B, KV, ntp, page, D), 3, 2)
-    q, s = _kv_requant(win, old_s)
-    return (codes.at[:, wpgs].set(q, mode="drop"),
-            scales.at[:, wpgs].set(s, mode="drop"))
+    old_s = _pages_get(scales, wpgs)                  # (NL, B, NTP, KV)
+    win = _kv_dequant(_pages_get(codes, wpgs), old_s,
+                      jnp.float32)            # (NL, B, NTP, page, KV·D)
+    NL, B = win.shape[:2]
+    flat = win.reshape(NL, B, -1, win.shape[-1])         # window rows
+    flat = flat.at[:, jnp.arange(B)[:, None], loc].set(
+        new_rows.astype(jnp.float32), mode="drop")
+    q, s = _kv_requant(flat.reshape(win.shape), old_s, scales.shape[-1])
+    return _pages_set(codes, wpgs, q), _pages_set(scales, wpgs, s)
+
+
+def _rope_rows(t, base, offset):
+    """Rotary embedding of ``(B, C, heads, D)`` token rows at absolute
+    positions ``offset + j`` (``offset`` a scalar or per-row ``(B,)``);
+    the ``rope`` op itself takes ``(B, heads, L, D)``."""
+    from ..ops.attention import rope
+
+    return rope.__wrapped__(t.transpose(0, 2, 1, 3), base=base,
+                            position_offset=offset).transpose(0, 2, 1, 3)
+
+
+def _flat_attention(q, kc, vc, mask, scale, cdtype):
+    """Attention of ``q`` ``(B, C, H, D)`` (C query positions a row)
+    against K and V views in the pool's own row layout, ``(B, T, KV·D)``,
+    without a transposed or head-split copy of either view: the heads of
+    a row lie side by side in its lanes, so the queries are spread
+    block-diagonally over them — ``Qb[b, k·D + d, (c, k', g)]`` is
+    ``q[b, c, k'·G + g, d]`` where ``k' == k`` and 0 elsewhere — and one
+    contraction over the whole row gives every head's scores at once
+    (the same products as the per-head einsum plus exact zeros, float32
+    accumulation).  ``p @ V`` over whole rows likewise gives ``(B, C·H,
+    KV·D)``, of which each head keeps its own D lanes.  Both read their
+    view once, as it is stored.  ``mask`` ``(B or 1, C, T)`` is true
+    where a query may look; mask, scale, the float32 softmax and the
+    cast of ``p`` to ``cdtype`` are the dense step's.  Returns ``(B, C,
+    H·D)``."""
+    B, C, H, D = q.shape
+    T, F = kc.shape[1], kc.shape[2]
+    KV = F // D
+    G = H // KV
+    own = jnp.eye(KV, dtype=jnp.bool_)                   # head k' == k
+    qg = q.reshape(B, C, KV, G, D).transpose(0, 2, 4, 1, 3)
+    qb = jnp.where(own[None, :, None, None, :, None],    # (B,KV,D,C,KV,G)
+                   qg[:, :, :, :, None, :], 0).reshape(B, F, C * H)
+    s = jnp.einsum("bfm,btf->bmt", qb, kc,
+                   preferred_element_type=jnp.float32) * scale
+    s = jnp.where(mask[:, :, None, :], s.reshape(B, C, H, T), -1e30)
+    p = jax.nn.softmax(s, axis=-1).astype(cdtype)
+    o = jnp.einsum("bmt,btf->bmf", p.reshape(B, C * H, T), vc)
+    o = jnp.where(own[None, None, :, None, :, None],
+                  o.reshape(B, C, KV, G, KV, D), 0).sum(axis=4)
+    return o.reshape(B, C, H * D)
 
 
 def _gpt_act_type(model):
@@ -741,16 +852,28 @@ class _DecodeEngine:
 
     def pool_token_paged(self, x_tok, pos, kp, vp, pt, page, sw, q8=None):
         """pool_token against a PAGED pool (``mxnet_tpu.serve``): the
-        caches are page pools ``(NL, NPAGES, KV, page, D)`` and each
+        caches are page pools ``(NL, NPAGES, page, KV·D)`` (one layer's
+        page a contiguous, lane-dense block of token rows; int8 pools a
+        ``(codes, scales (NL, NPAGES, KV))`` pair) and each
         slot reads/writes them through its page-table row ``pt[b]``
         (``pt``: (B, MAXP) int32, a TRACED operand — allocation churn
-        changes table VALUES, never shapes, so no retrace).  Rows of
-        retired/idle slots hold the one-past-the-end sentinel
-        ``NPAGES``: their gathers fill zeros and their scatters DROP,
-        which is what makes masked zombie lanes safe — a freed page can
-        never be corrupted by a slot that no longer owns it.  Token
-        order is ``t = j * page + o`` (page-major), so the gathered
-        dense view reproduces ``pool_token``'s attention bit-for-bit."""
+        changes table VALUES, never shapes, so no retrace).  The layer
+        scan runs over the layer NUMBER with the pools closed over:
+        layer ``l`` gathers its pages at ``(l, page id)`` straight out
+        of the whole pool into the ``(B, T, KV·D)`` row view, the new
+        row lands at ``(b, pos[b])``, attention contracts the stored
+        rows (``_flat_attention``), and after the scan all layers' new
+        rows scatter at ``(l, page, row)`` — in place on the donated
+        pools.  Rows of retired/idle slots hold the one-past-the-end
+        sentinel ``NPAGES``: their scatters DROP — a freed page can
+        never be corrupted by a slot that no longer owns it — and their
+        gathers clamp onto the last page, read garbage and are masked
+        by the caller, as a live slot's columns past ``pos`` (the stale
+        tail of its frontier page, a sentinel entry) are by the position
+        mask: weight exactly 0.  Token order is ``t = j * page + o``
+        (page-major); the logits match ``pool_token``'s within float32
+        summation order and greedy streams token for token
+        (``tests/test_paged_parity.py``)."""
         return self._scan_token(x_tok, pos, kp, vp, sw, q8,
                                 per_slot=True, pages=(pt, page))
 
@@ -795,30 +918,16 @@ class _DecodeEngine:
             # and costs the f32 path nothing
             quant = isinstance(ck, tuple)
 
-            @jax.named_scope("mx.paged_view")
-            def _paged_view(pool_l):
-                # (NPAGES, KV, page, D) pool layer -> (B, KV, T, D)
-                # per-slot dense views through the page table; sentinel
-                # entries (pt == NPAGES) gather zeros.  int8 pools
-                # dequantize in the SAME gather (per-page scales ride
-                # the scan xs next to the codes).
-                if quant:
-                    cdl, scl = pool_l
-                    g = _kv_dequant(
-                        cdl.at[pt].get(mode="fill", fill_value=0),
-                        scl.at[pt].get(mode="fill", fill_value=0),
-                        cdtype)
-                else:
-                    g = pool_l.at[pt].get(mode="fill", fill_value=0)
-                return jnp.moveaxis(g, 2, 1).reshape(B, KV, self.total,
-                                                     D)
-
         @jax.named_scope("mx.dense")
         def body(x, xs):
-            w, kc, vc = xs                    # per-layer slices
             if pages is not None:
-                kc = _paged_view(kc)
-                vc = _paged_view(vc)
+                # the donated pools are closed over, whole: a pool sliced
+                # by the scan would be copied, a layer at a time
+                w, l = xs
+                kc = _paged_rows(ck, pt, l, cdtype)      # (B, T, KV·D)
+                vc = _paged_rows(cv, pt, l, cdtype)
+            else:
+                w, kc, vc = xs                # per-layer slices
             if llama:
                 h = _rms(x, w["rms1_g"], eps=eps1)
                 if int8:
@@ -840,8 +949,14 @@ class _DecodeEngine:
                     _fc(h, w["qkv_w"], w["qkv_b"], flatten=False)
                 q, k, v = (qkv[:, j * U:(j + 1) * U].reshape(B, H, 1, D)
                            for j in range(3))
+            if pages is not None:
+                # the new token's K and V as rows of the view's layout
+                k, v = k.reshape(B, KV * D), v.reshape(B, KV * D)
             with jax.named_scope("mx.kv_write"):
-                if per_slot:
+                if pages is not None:
+                    kc = kc.at[iB, pos].set(k)
+                    vc = vc.at[iB, pos].set(v)
+                elif per_slot:
                     kc = kc.at[iB, :, pos, :].set(k[:, :, 0, :])
                     vc = vc.at[iB, :, pos, :].set(v[:, :, 0, :])
                 else:
@@ -850,13 +965,20 @@ class _DecodeEngine:
                     vc = lax.dynamic_update_slice(vc, v,
                                                   (0, 0, pos, 0))
             with jax.named_scope("mx.attn"):
-                qg = q.reshape(B, KV, H // KV, D)
-                s = jnp.einsum(
-                    "bkgd,bktd->bkgt", qg, kc,
-                    preferred_element_type=jnp.float32) * scale
-                s = jnp.where(idx[:, :, None] <= pos_b, s, -1e30)
-                p = jax.nn.softmax(s, axis=-1).astype(cdtype)
-                o = jnp.einsum("bkgt,bktd->bkgd", p, vc).reshape(B, U)
+                if pages is not None:
+                    o = _flat_attention(
+                        q.reshape(B, 1, H, D), kc, vc,
+                        idx <= pos[:, None, None], scale,
+                        cdtype).reshape(B, U)
+                else:
+                    qg = q.reshape(B, KV, H // KV, D)
+                    s = jnp.einsum(
+                        "bkgd,bktd->bkgt", qg, kc,
+                        preferred_element_type=jnp.float32) * scale
+                    s = jnp.where(idx[:, :, None] <= pos_b, s, -1e30)
+                    p = jax.nn.softmax(s, axis=-1).astype(cdtype)
+                    o = jnp.einsum("bkgt,bktd->bkgd", p,
+                                   vc).reshape(B, U)
             if llama:
                 x = x + (_q8l(o, w["o"]) if int8 else
                          _fc(o, w["o_w"], None, no_bias=True,
@@ -886,36 +1008,36 @@ class _DecodeEngine:
                 x = x + _fc(hh, w["fc2_w"], w["fc2_b"], flatten=False)
             return x, (k, v)
 
-        # the scan's own slicing of the stacked pools (its xs: a copy of
-        # each layer's K and V pool on the v5e, PERF.md section 5) is the
-        # first leg of pool -> view, so the scan is ``mx.paged_view`` and
-        # its body ``mx.dense`` but for the regions named inside
+        # the dense caches ride the scan's xs, a layer a step; the paged
+        # pools are closed over and only the layer's number rides.  The
+        # scan is ``mx.paged_view`` (what it does to the caches is the
+        # first leg of cache -> view) and its body ``mx.dense`` but for
+        # the regions named inside
         with jax.named_scope("mx.paged_view"):
-            x, (knew, vnew) = lax.scan(body, x, (sw, ck, cv))
-        # knew/vnew: (NL, B, KV, 1, D) — all layers' new columns land in
-        # the carried caches as ONE update (slice, or per-slot scatter)
+            x, (knew, vnew) = lax.scan(
+                body, x, (sw, jnp.arange(self.NL)) if pages is not None
+                else (sw, ck, cv))
+        # knew/vnew: (NL, B, KV, 1, D), or (NL, B, KV·D) rows of the paged
+        # pool — all layers' new columns land in the carried caches as
+        # ONE update (slice, or per-slot scatter)
         with jax.named_scope("mx.page_write"):
             if pages is not None:
                 # slot b's position pos[b] lives at (page
-                # pt[b, pos//page], offset pos % page).  Retired slots
+                # pt[b, pos//page], row pos % page).  Retired slots
                 # carry the sentinel in their table rows so the scatter
                 # DROPS their zombie writes; the clip keeps a stale
                 # pos == T from indexing past the table (it would
                 # otherwise clamp onto a live entry).
                 pg = pt[iB, jnp.minimum(pos // page, maxp - 1)]
-                newk = jnp.moveaxis(knew[:, :, :, 0, :], 0, 1)
-                newv = jnp.moveaxis(vnew[:, :, :, 0, :], 0, 1)
                 if quant:
                     # requantizing page RMW: dequantize the frontier
-                    # page, land the column, re-quantize (old scale as
+                    # page, land the row, re-quantize (old scale as
                     # floor)
-                    ck = _kv_step_rmw(ck, pg, iB, pos % page, newk)
-                    cv = _kv_step_rmw(cv, pg, iB, pos % page, newv)
+                    ck = _kv_step_rmw(ck, pg, pos % page, knew)
+                    cv = _kv_step_rmw(cv, pg, pos % page, vnew)
                 else:
-                    ck = ck.at[:, pg, :, pos % page, :].set(newk,
-                                                            mode="drop")
-                    cv = cv.at[:, pg, :, pos % page, :].set(newv,
-                                                            mode="drop")
+                    ck = _rows_set(ck, pg, pos % page, knew)
+                    cv = _rows_set(cv, pg, pos % page, vnew)
             elif per_slot:
                 ck = ck.at[:, iB, :, pos, :].set(
                     jnp.moveaxis(knew[:, :, :, 0, :], 0, 1))
@@ -938,17 +1060,19 @@ class _DecodeEngine:
         ``mxnet_tpu.serve``): ``toks`` (C,) int32 occupy absolute
         positions ``off .. off+C-1`` of the slot whose page-table row
         is ``ptrow`` (MAXP,) int32.  The already-cached prefix is
-        gathered through the row, the chunk attends causally over
+        gathered through the row at ``(layer, page id)`` out of the
+        whole ``(NL, NPAGES, page, KV·D)`` pool, the chunk's rows land
+        in that ``(1, T, KV·D)`` view, the chunk attends causally over
         prefix + itself (scores masked at ``t <= off + j`` — the same
-        mask/softmax/einsum discipline as the decode step), chunk K/V
-        scatters back through the row (positions past the reserved
+        mask/softmax/contraction as the decode step,
+        ``_flat_attention``), chunk K/V scatters back through the row
+        at ``(layer, page, row)`` (positions past the reserved
         pages resolve to the sentinel and DROP), and the logits at
         absolute position ``off + nlast`` come back for the final
         chunk's first-token sample.  ``off``/``nlast`` ride as TRACED
         scalars, so one compiled program per chunk length C serves
         every landing offset — chunked admission never retraces on
         prompt length."""
-        from ..ops.attention import rope as _rope
         from ..ops.registry import get_op
 
         _fc = get_op("FullyConnected").fn
@@ -963,7 +1087,6 @@ class _DecodeEngine:
         act_t, scale, rope_base = self.act_t, self.scale, self.rope_base
         _q8l = self._dense_q8
         C = toks.shape[0]
-        G = H // KV
         maxp = T // page
         quant = isinstance(kp, tuple)      # int8 (codes, scales) pools
         npages = (kp[0] if quant else kp).shape[1]
@@ -978,25 +1101,10 @@ class _DecodeEngine:
 
         @jax.named_scope("mx.dense")
         def body(x, xs):
-            w, kpl, vpl = xs
-            # dense (1, KV, T, D) views of this slot's cached prefix,
-            # gathered through its page-table row (sentinel -> zeros;
-            # int8 pools dequantize in the same gather)
-            with jax.named_scope("mx.paged_view"):
-                if quant:
-                    kpl = _kv_dequant(
-                        kpl[0].at[ptrow].get(mode="fill", fill_value=0),
-                        kpl[1].at[ptrow].get(mode="fill", fill_value=0),
-                        cdtype)
-                    vpl = _kv_dequant(
-                        vpl[0].at[ptrow].get(mode="fill", fill_value=0),
-                        vpl[1].at[ptrow].get(mode="fill", fill_value=0),
-                        cdtype)
-                else:
-                    kpl = kpl.at[ptrow].get(mode="fill", fill_value=0)
-                    vpl = vpl.at[ptrow].get(mode="fill", fill_value=0)
-                kc = jnp.moveaxis(kpl, 1, 0).reshape(KV, T, D)[None]
-                vc = jnp.moveaxis(vpl, 1, 0).reshape(KV, T, D)[None]
+            w, l = xs               # the pools are closed over, whole
+            # this slot's cached prefix as (1, T, KV·D) rows
+            kc = _paged_rows(kp, ptrow, l, cdtype)[None]
+            vc = _paged_rows(vp, ptrow, l, cdtype)[None]
             if llama:
                 h = _rms(x, w["rms1_g"], eps=eps1)
                 if int8:
@@ -1011,36 +1119,24 @@ class _DecodeEngine:
                             flatten=False).reshape(1, C, KV, D)
                     v = _fc(h, w["v_w"], None, no_bias=True,
                             flatten=False).reshape(1, C, KV, D)
-                q = q.transpose(0, 2, 1, 3)               # (1, H, C, D)
-                k = k.transpose(0, 2, 1, 3)
-                v = v.transpose(0, 2, 1, 3)
-                q = _rope.__wrapped__(q, base=rope_base,
-                                      position_offset=off)
-                k = _rope.__wrapped__(k, base=rope_base,
-                                      position_offset=off)
+                q = _rope_rows(q, rope_base, off)
+                k = _rope_rows(k, rope_base, off)
             else:
                 h = _ln(x, w["ln1_g"], w["ln1_b"], eps=eps1)
                 qkv = _q8l(h[0], w["qkv"])[None] if int8 else \
                     _fc(h, w["qkv_w"], w["qkv_b"], flatten=False)
-                q, k, v = (qkv[..., j * U:(j + 1) * U]
-                           .reshape(1, C, H, D).transpose(0, 2, 1, 3)
+                q, k, v = (qkv[..., j * U:(j + 1) * U].reshape(1, C, H, D)
                            for j in range(3))
-            k = k.astype(cdtype)
-            v = v.astype(cdtype)
-            # chunk K/V lands in the dense view BEFORE attention, so
-            # one mask covers prefix and intra-chunk causality together
+            # the chunk's K and V as (1, C, KV·D) rows of the view
+            k = k.reshape(1, C, KV * D).astype(cdtype)
+            v = v.reshape(1, C, KV * D).astype(cdtype)
+            # chunk K/V lands in the view BEFORE attention, so one mask
+            # covers prefix and intra-chunk causality together
             with jax.named_scope("mx.kv_write"):
-                kc = lax.dynamic_update_slice(kc, k, (0, 0, off, 0))
-                vc = lax.dynamic_update_slice(vc, v, (0, 0, off, 0))
+                kc = lax.dynamic_update_slice(kc, k, (0, off, 0))
+                vc = lax.dynamic_update_slice(vc, v, (0, off, 0))
             with jax.named_scope("mx.attn"):
-                qg = q.reshape(1, KV, G, C, D)
-                s = jnp.einsum(
-                    "bkgcd,bktd->bkgct", qg, kc,
-                    preferred_element_type=jnp.float32) * scale
-                s = jnp.where(mask[None, None, None], s, -1e30)
-                p = jax.nn.softmax(s, axis=-1).astype(cdtype)
-                o = jnp.einsum("bkgct,bktd->bkgcd", p, vc)
-            o = o.transpose(0, 3, 1, 2, 4).reshape(1, C, U)
+                o = _flat_attention(q, kc, vc, mask[None], scale, cdtype)
             if llama:
                 x = x + (_q8l(o[0], w["o"])[None] if int8 else
                          _fc(o, w["o_w"], None, no_bias=True,
@@ -1073,20 +1169,20 @@ class _DecodeEngine:
             return x, (k, v)
 
         with jax.named_scope("mx.paged_view"):   # see _scan_token
-            x, (knew, vnew) = lax.scan(body, x, (sw, kp, vp))
-        # knew/vnew: (NL, 1, KV, C, D) — scatter every chunk column
-        # through the page-table row.  Positions past the reserved
-        # pages (bucket-padded tails) resolve to the sentinel and DROP;
-        # the explicit cpos < T guard covers tails that would otherwise
-        # CLIP onto the row's own last page and corrupt earlier tokens.
+            x, (knew, vnew) = lax.scan(body, x, (sw, jnp.arange(self.NL)))
+        # knew/vnew: (NL, 1, C, KV·D) — scatter every chunk row through
+        # the page-table row.  Positions past the reserved pages
+        # (bucket-padded tails) resolve to the sentinel and DROP; the
+        # explicit cpos < T guard covers tails that would otherwise CLIP
+        # onto the row's own last page and corrupt earlier tokens.
         with jax.named_scope("mx.page_write"):
             if quant:
-                # requantizing page-WINDOW RMW: the C consecutive columns
+                # requantizing page-WINDOW RMW: the C consecutive rows
                 # touch at most ntp consecutive pages of this row (static
                 # in C and page, so the program shape is unchanged).  Pad
-                # columns past ``nlast`` are masked OUT here — unlike the
+                # rows past ``nlast`` are masked OUT here — unlike the
                 # f32 path's harmless garbage-but-unreachable writes, a pad
-                # column would poison its page's shared SCALE.
+                # row would poison its page's shared SCALE.
                 ntp = (C + page - 2) // page + 1
                 p0 = off // page
                 widx = p0 + jnp.arange(ntp, dtype=jnp.int32)
@@ -1096,17 +1192,14 @@ class _DecodeEngine:
                 keepc = (jnp.arange(C, dtype=jnp.int32) <= nlast) & \
                     (cpos < T)
                 loc = jnp.where(keepc, cpos - p0 * page, ntp * page)
-                kp = _kv_chunk_rmw(kp, wpgs, loc, knew[:, 0], page, ntp)
-                vp = _kv_chunk_rmw(vp, wpgs, loc, vnew[:, 0], page, ntp)
+                kp = _kv_chunk_rmw(kp, wpgs, loc, knew[:, 0])
+                vp = _kv_chunk_rmw(vp, wpgs, loc, vnew[:, 0])
             else:
                 pgs = jnp.where(cpos < T,
                                 ptrow[jnp.minimum(cpos // page, maxp - 1)],
                                 npages)                        # (C,)
-                offs = cpos % page
-                kp = kp.at[:, pgs, :, offs, :].set(
-                    jnp.moveaxis(knew[:, 0], 2, 0), mode="drop")
-                vp = vp.at[:, pgs, :, offs, :].set(
-                    jnp.moveaxis(vnew[:, 0], 2, 0), mode="drop")
+                kp = _rows_set(kp, pgs, cpos % page, knew[:, 0])
+                vp = _rows_set(vp, pgs, cpos % page, vnew[:, 0])
         with jax.named_scope("mx.head"):
             x_last = lax.dynamic_slice(x, (0, nlast, 0),
                                        (1, 1, U))[:, 0]
@@ -1143,7 +1236,6 @@ class _DecodeEngine:
         per-slot paged views and q8 head (the parity contract: a
         verify column's logits come from the same projections and
         head as the plain step's)."""
-        from ..ops.attention import rope as _rope
         from ..ops.registry import get_op
 
         _fc = get_op("FullyConnected").fn
@@ -1158,7 +1250,6 @@ class _DecodeEngine:
         act_t, scale, rope_base = self.act_t, self.scale, self.rope_base
         _q8l = self._dense_q8
         C = toks.shape[1]
-        G = H // KV
         maxp = T // page
         quant = isinstance(kp, tuple)      # int8 (codes, scales) pools
         npages = (kp[0] if quant else kp).shape[1]
@@ -1184,25 +1275,9 @@ class _DecodeEngine:
 
         @jax.named_scope("mx.dense")
         def body(x, xs):
-            w, kpl, vpl = xs
-            # per-slot dense (B, KV, T, D) views through the page
-            # table; sentinel rows (retired slots) gather zeros (int8
-            # pools dequantize in the same gather)
-            with jax.named_scope("mx.paged_view"):
-                if quant:
-                    kpl = _kv_dequant(
-                        kpl[0].at[pt].get(mode="fill", fill_value=0),
-                        kpl[1].at[pt].get(mode="fill", fill_value=0),
-                        cdtype)
-                    vpl = _kv_dequant(
-                        vpl[0].at[pt].get(mode="fill", fill_value=0),
-                        vpl[1].at[pt].get(mode="fill", fill_value=0),
-                        cdtype)
-                else:
-                    kpl = kpl.at[pt].get(mode="fill", fill_value=0)
-                    vpl = vpl.at[pt].get(mode="fill", fill_value=0)
-                kc = jnp.moveaxis(kpl, 2, 1).reshape(B, KV, T, D)
-                vc = jnp.moveaxis(vpl, 2, 1).reshape(B, KV, T, D)
+            w, l = xs               # the pools are closed over, whole
+            kc = _paged_rows(kp, pt, l, cdtype)          # (B, T, KV·D)
+            vc = _paged_rows(vp, pt, l, cdtype)
             if llama:
                 h = _rms(x, w["rms1_g"], eps=eps1)
                 if int8:
@@ -1218,42 +1293,28 @@ class _DecodeEngine:
                             flatten=False).reshape(B, C, KV, D)
                     v = _fc(h, w["v_w"], None, no_bias=True,
                             flatten=False).reshape(B, C, KV, D)
-                q = q.transpose(0, 2, 1, 3)               # (B, H, C, D)
-                k = k.transpose(0, 2, 1, 3)
-                v = v.transpose(0, 2, 1, 3)
                 # per-slot rotary phase: rope broadcasts a (B,) offset
                 # to per-row absolute positions pos[b] + j
-                q = _rope.__wrapped__(q, base=rope_base,
-                                      position_offset=pos)
-                k = _rope.__wrapped__(k, base=rope_base,
-                                      position_offset=pos)
+                q = _rope_rows(q, rope_base, pos)
+                k = _rope_rows(k, rope_base, pos)
             else:
                 h = _ln(x, w["ln1_g"], w["ln1_b"], eps=eps1)
                 qkv = _q8l(h.reshape(B * C, U),
                            w["qkv"]).reshape(B, C, 3 * U) if int8 \
                     else _fc(h, w["qkv_w"], w["qkv_b"], flatten=False)
-                q, k, v = (qkv[..., j * U:(j + 1) * U]
-                           .reshape(B, C, H, D).transpose(0, 2, 1, 3)
+                q, k, v = (qkv[..., j * U:(j + 1) * U].reshape(B, C, H, D)
                            for j in range(3))
-            k = k.astype(cdtype)
-            v = v.astype(cdtype)
-            # block K/V lands in the dense views BEFORE attention
-            # (per-slot scatter — offsets vary per row), so one mask
-            # covers cached prefix and intra-block causality together
+            # the block's K and V as (B, C, KV·D) rows of the views
+            k = k.reshape(B, C, KV * D).astype(cdtype)
+            v = v.reshape(B, C, KV * D).astype(cdtype)
+            # block K/V lands in the views BEFORE attention (per-slot
+            # scatter — offsets vary per row), so one mask covers cached
+            # prefix and intra-block causality together
             with jax.named_scope("mx.kv_write"):
-                kc = kc.at[iB[:, None], :, wpos].set(
-                    k.transpose(0, 2, 1, 3), mode="drop")
-                vc = vc.at[iB[:, None], :, wpos].set(
-                    v.transpose(0, 2, 1, 3), mode="drop")
+                kc = kc.at[iB[:, None], wpos].set(k, mode="drop")
+                vc = vc.at[iB[:, None], wpos].set(v, mode="drop")
             with jax.named_scope("mx.attn"):
-                qg = q.reshape(B, KV, G, C, D)
-                s = jnp.einsum(
-                    "bkgcd,bktd->bkgct", qg, kc,
-                    preferred_element_type=jnp.float32) * scale
-                s = jnp.where(mask[:, None, None], s, -1e30)
-                p = jax.nn.softmax(s, axis=-1).astype(cdtype)
-                o = jnp.einsum("bkgct,bktd->bkgcd", p, vc)
-            o = o.transpose(0, 3, 1, 2, 4).reshape(B, C, U)
+                o = _flat_attention(q, kc, vc, mask, scale, cdtype)
             if llama:
                 x = x + (_q8l(o.reshape(B * C, U),
                               w["o"]).reshape(B, C, U) if int8 else
@@ -1289,15 +1350,15 @@ class _DecodeEngine:
             return x, (k, v)
 
         with jax.named_scope("mx.paged_view"):   # see _scan_token
-            x, (knew, vnew) = lax.scan(body, x, (sw, kp, vp))
-        # knew/vnew: (NL, B, KV, C, D) — scatter every block column of
-        # every slot through its page-table row.  Out-of-range columns
-        # (zombie lanes past T) resolve to the sentinel and DROP; the
-        # cpos < T guard keeps them from CLIPPING onto a live page.
+            x, (knew, vnew) = lax.scan(body, x, (sw, jnp.arange(self.NL)))
+        # knew/vnew: (NL, B, C, KV·D) — scatter every block row of every
+        # slot through its page-table row.  Out-of-range rows (zombie
+        # lanes past T) resolve to the sentinel and DROP; the cpos < T
+        # guard keeps them from CLIPPING onto a live page.
         with jax.named_scope("mx.page_write"):
             if quant:
                 # per-slot requantizing page-window RMW (the chunk write
-                # batched over slots): slot b's C columns touch at most ntp
+                # batched over slots): slot b's C rows touch at most ntp
                 # consecutive pages from its frontier page pos[b] // page
                 ntp = (C + page - 2) // page + 1
                 p0 = pos // page                               # (B,)
@@ -1308,24 +1369,15 @@ class _DecodeEngine:
                                  npages)                       # (B, NTP)
                 loc = jnp.where(cpos < T, cpos - p0[:, None] * page,
                                 ntp * page)                    # (B, C)
-                kp = _kv_verify_rmw(kp, wpgs, iB, loc,
-                                    jnp.transpose(knew, (1, 3, 0, 2, 4)),
-                                    page, ntp)
-                vp = _kv_verify_rmw(vp, wpgs, iB, loc,
-                                    jnp.transpose(vnew, (1, 3, 0, 2, 4)),
-                                    page, ntp)
+                kp = _kv_verify_rmw(kp, wpgs, loc, knew)
+                vp = _kv_verify_rmw(vp, wpgs, loc, vnew)
             else:
                 pgs = jnp.where(cpos < T,
                                 pt[iB[:, None], jnp.minimum(cpos // page,
                                                             maxp - 1)],
                                 npages)                        # (B, C)
-                offs = cpos % page
-                # result dims of the non-adjacent advanced indices go
-                # FIRST: value shape (B, C, NL, KV, D)
-                kp = kp.at[:, pgs, :, offs, :].set(
-                    jnp.transpose(knew, (1, 3, 0, 2, 4)), mode="drop")
-                vp = vp.at[:, pgs, :, offs, :].set(
-                    jnp.transpose(vnew, (1, 3, 0, 2, 4)), mode="drop")
+                kp = _rows_set(kp, pgs, cpos % page, knew)
+                vp = _rows_set(vp, pgs, cpos % page, vnew)
         with jax.named_scope("mx.head"):
             xl = _call(self.model.ln_f, x)
             # same head as the plain step (q8 when int8) — the greedy

@@ -1,8 +1,11 @@
 """Paged slot-pool decode programs — the device side of the continuous-
 batching server (``mxnet_tpu.serve.server``).
 
-The resident K/V store is a PAGE POOL: one ``(NL, NPAGES, KV, PAGE, D)``
-array pair shared by all in-flight sequences, addressed through per-slot
+The resident K/V store is a PAGE POOL: one ``(NL, NPAGES, PAGE, KV·D)``
+array pair shared by all in-flight sequences — one layer's page is one
+contiguous ``(PAGE, KV·D)`` block, a token a lane-dense row of it (an
+int8 pool: codes in that layout + ``(NL, NPAGES, KV)`` scales) —
+addressed through per-slot
 page tables (``(S, MAXP)`` int32 rows, host-owned, passed as TRACED
 OPERANDS on every dispatch — allocation churn changes table VALUES,
 never shapes, so the compiled programs survive any admit/retire/append
@@ -14,13 +17,24 @@ wall-clock-deadline state rides alongside, so admission and retirement —
 including deadline expiry against the step's ``now`` operand — stay
 device-side masked updates: no recompile, no host sync in the step.
 
-The one-past-the-end page id ``NPAGES`` is the table SENTINEL: gathers
-through it fill zeros and scatters through it DROP.  Retired/idle slots
+The one-past-the-end page id ``NPAGES`` is the table SENTINEL: scatters
+through it DROP, and gathers CLAMP it onto the last page, whose values
+never count — the position mask gives them weight exactly 0, or what was
+built from them drops on the way back.  Retired/idle slots
 carry all-sentinel rows, which is what makes masked zombie lanes safe —
 a freed (or reused) page can never be corrupted by a slot that no longer
 owns it, and the overwrite-before-unmask invariant (a decode step at
 position ``q`` writes its own column before attending) covers everything
 a live slot can read.
+
+Every executable below reads the pool with ONE gather at ``(layer, page
+id)`` out of the whole array and writes it with a scatter at explicit
+``(layer, page[, row])`` indices (``models.decoding._pages_get`` /
+``_pages_set`` / ``_rows_set``).  With the lane-dense layout those two
+idioms are what lets the chip's compiler keep the pool as declared and
+update the donated arrays in place: no per-layer slice, no layout
+conversion, no second pool as scratch (``tests/test_serve_pool_layout.py``
+holds a TPU-target compile to it; PERF.md PR 27 has the times).
 
 Compiled units per pool size ``S``:
 
@@ -62,6 +76,7 @@ import jax.numpy as jnp
 from .. import telemetry
 from ..base import MXNetError
 from ..models.decoding import (_DecodeEngine, _TRACE_LOCK, _kv_requant,
+                               _pages_get, _pages_set,
                                _KV_CODE_DTYPE, _KV_SCALE_DTYPE)
 from . import schema
 
@@ -198,7 +213,8 @@ def pool_state_init(progs, device=None):
     eng = progs.eng
     if device is None:
         device = jax.devices()[0]
-    shape = (eng.NL, progs.num_pages, eng.KV, progs.page, eng.D)
+    # one layer's page is one contiguous, lane-dense (page, KV·D) block
+    shape = (eng.NL, progs.num_pages, progs.page, eng.KV * eng.D)
     if progs.quant_kv:
         # int8 pool: each of K and V is a (codes, scales) PAIR riding
         # ONE state slot as a pytree — every executable threads, donates
@@ -295,7 +311,7 @@ class PoolPrograms:
         if self.num_pages < 1:
             raise MXNetError(f"num_pages must be >= 1, "
                              f"got {self.num_pages}")
-        # one-past-the-end page id: gathers fill zero, scatters drop
+        # one-past-the-end page id: gathers clamp it, scatters drop
         self.sentinel = self.num_pages
         self.temperature, self.top_k = float(temperature), int(top_k)
         self.eos_id = None if eos_id is None else int(eos_id)
@@ -490,18 +506,18 @@ class PoolPrograms:
                                     ck1, 0)
                     cv1 = jnp.where(colmask[None, :, None, :, None],
                                     cv1, 0)
-                c1 = ck1.reshape(NL, A, KV, npb, page, D) \
-                        .transpose(0, 1, 3, 2, 4, 5) \
-                        .reshape(NL, A * npb, KV, page, D)
-                v1 = cv1.reshape(NL, A, KV, npb, page, D) \
-                        .transpose(0, 1, 3, 2, 4, 5) \
-                        .reshape(NL, A * npb, KV, page, D)
+                # (NL, A, KV, Ppad, D) scratch -> (NL, A*NPB, page, KV·D)
+                # pages in the pool's row layout
+                c1, v1 = (c.reshape(NL, A, KV, npb, page, D)
+                           .transpose(0, 1, 3, 4, 2, 5)
+                           .reshape(NL, A * npb, page, KV * D)
+                          for c in (ck1, cv1))
                 if self.quant_kv:
                     # fresh whole pages: plain per-page quantization (no
                     # floor — nothing lived in these pages), then ONE
                     # masked scatter each for codes and scales
-                    qc1, sc1 = _kv_requant(c1, 0.0)
-                    qv1, sv1 = _kv_requant(v1, 0.0)
+                    qc1, sc1 = _kv_requant(c1, 0.0, KV)
+                    qv1, sv1 = _kv_requant(v1, 0.0, KV)
                     (kpc, kps), (vpc, vps) = kp, vp
                     # recycled-page reset: the pool free list is host-only
                     # bookkeeping, so a reallocated page still carries its
@@ -515,15 +531,15 @@ class PoolPrograms:
                     # pages' scales are immediately overwritten by the
                     # scatter below.  Sentinel entries DROP.
                     zf = zpages.reshape(A * zpages.shape[1])
-                    kps = kps.at[:, zf].set(0.0, mode="drop")
-                    vps = vps.at[:, zf].set(0.0, mode="drop")
-                    kp = (kpc.at[:, tgt_pg].set(qc1, mode="drop"),
-                          kps.at[:, tgt_pg].set(sc1, mode="drop"))
-                    vp = (vpc.at[:, tgt_pg].set(qv1, mode="drop"),
-                          vps.at[:, tgt_pg].set(sv1, mode="drop"))
+                    kps = _pages_set(kps, zf, 0.0)
+                    vps = _pages_set(vps, zf, 0.0)
+                    kp = (_pages_set(kpc, tgt_pg, qc1),
+                          _pages_set(kps, tgt_pg, sc1))
+                    vp = (_pages_set(vpc, tgt_pg, qv1),
+                          _pages_set(vps, tgt_pg, sv1))
                 else:
-                    kp = kp.at[:, tgt_pg].set(c1, mode="drop")
-                    vp = vp.at[:, tgt_pg].set(v1, mode="drop")
+                    kp = _pages_set(kp, tgt_pg, c1)
+                    vp = _pages_set(vp, tgt_pg, v1)
             # masked slot-state scatter: invalid rows target slot S
             # (out of bounds) and drop; valid rows carry distinct
             # host-assigned slots
@@ -599,10 +615,8 @@ class PoolPrograms:
             with jax.named_scope("mx.page_write"):
                 if self.quant_kv:
                     (kpc, kps), (vpc, vps) = kp, vp
-                    kcb = kpc.at[:, src].get(mode="fill", fill_value=0)
-                    ksb = kps.at[:, src].get(mode="fill", fill_value=0)
-                    vcb = vpc.at[:, src].get(mode="fill", fill_value=0)
-                    vsb = vps.at[:, src].get(mode="fill", fill_value=0)
+                    kcb, ksb = _pages_get(kpc, src), _pages_get(kps, src)
+                    vcb, vsb = _pages_get(vpc, src), _pages_get(vps, src)
                     # recycled-page reset (see admit_fn): zero the SCALES
                     # of every freshly-owned page in the wave — including
                     # each row's decode-frontier pages and the COW dst —
@@ -611,17 +625,15 @@ class PoolPrograms:
                     # same wave recycled it) and BEFORE the dst scatter
                     # below re-lands the copied scale.
                     zf = zpages.reshape(-1)
-                    kps = kps.at[:, zf].set(0.0, mode="drop")
-                    vps = vps.at[:, zf].set(0.0, mode="drop")
-                    kp = (kpc.at[:, dst].set(kcb, mode="drop"),
-                          kps.at[:, dst].set(ksb, mode="drop"))
-                    vp = (vpc.at[:, dst].set(vcb, mode="drop"),
-                          vps.at[:, dst].set(vsb, mode="drop"))
+                    kps = _pages_set(kps, zf, 0.0)
+                    vps = _pages_set(vps, zf, 0.0)
+                    kp = (_pages_set(kpc, dst, kcb),
+                          _pages_set(kps, dst, ksb))
+                    vp = (_pages_set(vpc, dst, vcb),
+                          _pages_set(vps, dst, vsb))
                 else:
-                    kblk = kp.at[:, src].get(mode="fill", fill_value=0)
-                    vblk = vp.at[:, src].get(mode="fill", fill_value=0)
-                    kp = kp.at[:, dst].set(kblk, mode="drop")
-                    vp = vp.at[:, dst].set(vblk, mode="drop")
+                    kp = _pages_set(kp, dst, _pages_get(kp, src))
+                    vp = _pages_set(vp, dst, _pages_get(vp, src))
             tgt = jnp.where(valid, slot, self.S)
             pos = pos.at[tgt].set(true_len - 1, mode="drop")
             tok = tok.at[tgt].set(last_tok, mode="drop")
@@ -698,8 +710,8 @@ class PoolPrograms:
                 # must keep the ratchet of earlier ones).
                 with jax.named_scope("mx.page_write"):
                     (kpc, kps), (vpc, vps) = kp, vp
-                    kp = (kpc, kps.at[:, zrow].set(0.0, mode="drop"))
-                    vp = (vpc, vps.at[:, zrow].set(0.0, mode="drop"))
+                    kp = (kpc, _pages_set(kps, zrow, 0.0))
+                    vp = (vpc, _pages_set(vps, zrow, 0.0))
             with _TRACE_LOCK, params_swapped(deng.params, param_vals):
                 logits, kp, vp = deng.chunk_tokens(
                     toks, off, nlast, ptrow, page, kp, vp, sw, q8)

@@ -833,22 +833,26 @@ class TestKVQuantPages:
         from mxnet_tpu.models.decoding import _kv_dequant, _kv_requant
 
         rng = onp.random.RandomState(0)
-        vals = jnp.asarray(rng.randn(2, 4, 16, 8).astype("float32"))
-        codes, scales = _kv_requant(vals, 0.0)
+        # two pages in the pool's row layout: (page 16, KV 4 x D 8)
+        vals = jnp.asarray(rng.randn(2, 16, 4 * 8).astype("float32"))
+        codes, scales = _kv_requant(vals, 0.0, 4)
         assert codes.dtype == jnp.int8 and scales.dtype == jnp.float32
+        assert codes.shape == (2, 16, 32) and scales.shape == (2, 4)
         deq = _kv_dequant(codes, scales, jnp.float32)
-        amax = onp.max(onp.abs(onp.asarray(vals)), axis=(-2, -1))
-        err = onp.max(onp.abs(onp.asarray(deq - vals)), axis=(-2, -1))
+        # one scale per page and K/V head: over its rows and its D lanes
+        heads = lambda a: onp.asarray(a).reshape(2, 16, 4, 8)
+        amax = onp.max(onp.abs(heads(vals)), axis=(1, 3))
+        err = onp.max(onp.abs(heads(deq - vals)), axis=(1, 3))
         assert onp.all(err <= amax / 254.0 * (1 + 1e-5))
         # drift-free: requantizing the dequantized page at its own
         # floor scale reproduces codes and scales bit-for-bit
-        codes2, scales2 = _kv_requant(deq, scales)
+        codes2, scales2 = _kv_requant(deq, scales, 4)
         assert onp.array_equal(onp.asarray(codes), onp.asarray(codes2))
         assert onp.array_equal(onp.asarray(scales),
                                onp.asarray(scales2))
         # scales only ratchet: a larger floor wins, a smaller one is
         # ignored
-        _, s_up = _kv_requant(deq, scales * 2)
+        _, s_up = _kv_requant(deq, scales * 2, 4)
         assert onp.allclose(onp.asarray(s_up),
                             onp.asarray(scales) * 2)
 

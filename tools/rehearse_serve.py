@@ -1,0 +1,211 @@
+#!/usr/bin/env python3
+"""Compile the serve pool executables for a TPU v5e that is described and
+not attached, and say what the chip's compiler made of the K/V pool.
+
+    JAX_PLATFORMS=cpu python tools/rehearse_serve.py
+    JAX_PLATFORMS=cpu python tools/rehearse_serve.py --layers 4 --admit 4x768
+    JAX_PLATFORMS=cpu python tools/rehearse_serve.py --kv-dtype int8 --hlo /tmp/hlo
+
+Rehearsal 3 of the ``on-chip-measurement`` guide for ``serve.step`` and one
+``serve.admit``: nothing runs, so this gives structure and bytes and never a
+time.  For each executable it prints one JSON line: ``memory_analysis()``
+(``temp_bytes`` is the scratch the program reserves beside its donated
+pools), the pool's entry layout, and every optimized-HLO instruction whose
+result is as large as the whole pool, by opcode — a ``copy`` or a plain
+fusion there is a pass over the whole pool every dispatch; the in-place
+scatters show as ``fusion:scatter``.  The defaults are
+the chip benchmark's serve configuration (GPT-2-large, 32 slots, 1,024
+pages of 16 tokens, bfloat16).  ``tests/test_serve_pool_layout.py`` holds a
+small engine to the same readings.
+"""
+from __future__ import annotations
+
+import argparse
+import json
+import math
+import os
+import re
+import sys
+import time
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+_INSTR = re.compile(
+    r"^\s*(?P<root>ROOT\s+)?%?(?P<name>[\w.\-]+) = (?P<type>.*?) "
+    r"(?P<op>[a-z][\w\-]*)\(")
+_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?(?P<name>[\w.\-]+) \(.*\{\s*$")
+_ARRAY = re.compile(r"[a-z]+[0-9]*\[([0-9,]*)\]")
+# results that hold the pool without being a pass over it
+_CARRIERS = ("parameter", "get-tuple-element", "tuple", "bitcast", "while")
+
+
+def v5e_chip():
+    """A ``SingleDeviceSharding`` on the first chip of a described
+    ``v5e:2x2`` (raises where the TPU compiler cannot describe one)."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _structs(tree, chip):
+    import jax
+
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip),
+        tree)
+
+
+def pool_shape(progs):
+    """The shape of one K/V pool array of ``progs`` (an int8 pool's codes)."""
+    import jax
+
+    from mxnet_tpu.serve.engine import pool_state_init
+
+    kp = jax.eval_shape(lambda: pool_state_init(progs))[0]
+    return (kp[0] if isinstance(kp, tuple) else kp).shape
+
+
+def compile_step(progs, chip):
+    """``serve.step`` of ``progs`` compiled for ``chip``."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.serve.engine import pool_state_init
+
+    state = jax.eval_shape(lambda: pool_state_init(progs))
+    now = jax.ShapeDtypeStruct((), jnp.float32)
+    pt = jax.ShapeDtypeStruct((progs.S, progs.maxp), jnp.int32)
+    args = (*progs.operands, now, pt, *state)
+    return progs.step_fn().lower(*_structs(args, chip)).compile()
+
+
+def compile_admit(progs, chip, a_bucket, p_bucket):
+    """``serve.admit`` of the ``(a_bucket, p_bucket)`` wave, likewise."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.serve import schema
+    from mxnet_tpu.serve.engine import pool_state_init
+
+    A, P = int(a_bucket), int(p_bucket)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
+    state = jax.eval_shape(lambda: pool_state_init(progs))
+    args = (progs.operands[0], i32(A, P),
+            i32(A, schema.meta_width("admit")),
+            jax.ShapeDtypeStruct((A,), jnp.float32),
+            i32(A, progs.pages_for(P)), i32(A, progs.maxp), *state)
+    return progs.admit_fn(A, P).lower(*_structs(args, chip)).compile()
+
+
+def pool_report(compiled, progs):
+    """What ``compiled`` does with the K/V pool, read off the optimized
+    HLO text: ``temp_bytes`` and the other ``memory_analysis()`` sizes, the
+    entry layout of the pool parameters, and ``pool_sized``: every
+    instruction with a result of as many elements as one pool array,
+    whatever its dimensions (the in-place scatters work on a 2-D bitcast),
+    as ``{label: [names]}``.  A fusion's label is ``fusion:<opcode of its
+    root>``; what only carries the pool (parameter, tuple, bitcast, the
+    ``while`` it rides through) is left out."""
+    shape = pool_shape(progs)
+    dims = "[" + ",".join(str(d) for d in shape) + "]"
+    n_pool = math.prod(shape)
+    text = compiled.as_text()
+    entry = re.search(r"entry_computation_layout=\{\((.*?)\)->", text)
+    layouts = sorted(set(re.findall(
+        r"[a-z0-9]+" + re.escape(dims) + r"\{[^}]*\}",
+        entry.group(1) if entry else "")))
+    roots, found, comp = {}, [], None
+    for line in text.splitlines():
+        c = _COMPUTATION.match(line)
+        if c is not None:
+            comp = c.group("name")
+            continue
+        m = _INSTR.match(line)
+        if m is None:
+            continue
+        if m.group("root"):
+            roots[comp] = m.group("op")
+        sizes = [math.prod(int(d) for d in a.split(",") if d)
+                 for a in _ARRAY.findall(m.group("type"))]
+        if n_pool in sizes and m.group("op") not in _CARRIERS:
+            called = re.search(r"calls=%?([\w.\-]+)", line)
+            found.append((comp, m.group("op"), m.group("name"),
+                          called.group(1) if called else None))
+    fused = {called for _, op, _, called in found if op == "fusion"}
+    sized = {}
+    for comp, op, name, called in found:
+        if comp in fused:       # the inside of a fusion that is listed
+            continue
+        if op == "fusion":
+            op = f"fusion:{roots.get(called, '?')}"
+        sized.setdefault(op, []).append(name)
+    ma = compiled.memory_analysis()
+    return {"temp_bytes": ma.temp_size_in_bytes,
+            "argument_bytes": ma.argument_size_in_bytes,
+            "output_bytes": ma.output_size_in_bytes,
+            "alias_bytes": ma.alias_size_in_bytes,
+            "pool_dims": dims, "pool_entry_layouts": layouts,
+            "pool_sized": sized}
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser(prog="tools/rehearse_serve.py")
+    ap.add_argument("--layers", type=int, default=36)
+    ap.add_argument("--units", type=int, default=1280)
+    ap.add_argument("--heads", type=int, default=20)
+    ap.add_argument("--hidden", type=int, default=5120)
+    ap.add_argument("--vocab", type=int, default=50257)
+    ap.add_argument("--total", type=int, default=1024)
+    ap.add_argument("--slots", type=int, default=32)
+    ap.add_argument("--pages", type=int, default=1024)
+    ap.add_argument("--page-size", type=int, default=16)
+    ap.add_argument("--dtype", default="bfloat16")
+    ap.add_argument("--kv-dtype", default="native")
+    ap.add_argument("--admit", default="4x768",
+                    help="AxP wave to compile beside the step; '' for none")
+    ap.add_argument("--hlo", default=None,
+                    help="directory to write each optimized HLO text to")
+    args = ap.parse_args(argv)
+
+    import mxnet_tpu as mx
+    from mxnet_tpu import models
+    from mxnet_tpu.serve.engine import PoolPrograms
+
+    net = models.GPT(models.GPTConfig(
+        vocab_size=args.vocab, num_layers=args.layers, units=args.units,
+        num_heads=args.heads, hidden_size=args.hidden,
+        max_length=args.total, dtype=args.dtype))
+    net.collect_params().setattr("grad_req", "null")
+    net.initialize(mx.init.Zero())
+    progs = PoolPrograms(net, args.slots, args.total,
+                         page_size=args.page_size, num_pages=args.pages,
+                         kv_dtype=args.kv_dtype)
+    chip = v5e_chip()
+    todo = [("serve.step", lambda: compile_step(progs, chip))]
+    if args.admit:
+        a, p = (int(v) for v in args.admit.split("x"))
+        todo.append((f"serve.admit({a},{p})",
+                     lambda: compile_admit(progs, chip, a, p)))
+    for name, build in todo:
+        t0 = time.time()
+        compiled = build()
+        row = {"executable": name, **pool_report(compiled, progs),
+               "compile_s": round(time.time() - t0, 1)}
+        if args.hlo:
+            os.makedirs(args.hlo, exist_ok=True)
+            path = os.path.join(args.hlo, re.sub(r"\W+", "_", name) + ".hlo")
+            with open(path, "w") as fh:
+                fh.write(compiled.as_text())
+            row["hlo"] = path
+        print(json.dumps(row), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
