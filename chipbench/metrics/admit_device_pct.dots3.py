@@ -1,0 +1,12 @@
+"""Share of the device's busy time in the traced window that the admission
+executables take (here: the question chunk of every admission)."""
+
+
+def read(run):
+    trace = run.get("trace")
+    if not trace or not trace["busy_s"]:
+        return None
+    return 100.0 * sum(row["seconds"] for name, row in
+                       trace["modules"].items()
+                       if name.startswith(("jit_admit", "jit_hit",
+                                           "jit_chunk"))) / trace["busy_s"]

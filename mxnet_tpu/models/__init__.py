@@ -16,6 +16,7 @@ from .gpt import GPT, GPTConfig, gpt2_small, gpt2_medium, gpt2_large, \
 from .bert import BERTModel, BERTConfig, bert_base, bert_large
 from .llama import (Llama, LlamaConfig, llama_tp_rules, llama_tiny,
                     llama_7b)
+from .dots3 import Dots3, Dots3Config, dots3_tiny
 from .seq2seq import (CrossAttention, Seq2SeqEncoder, Seq2SeqDecoder,
                       Seq2SeqDecoderCell, TransformerSeq2Seq)
 
@@ -27,5 +28,6 @@ __all__ = [
     "CrossAttention", "Seq2SeqEncoder", "Seq2SeqDecoder",
     "Seq2SeqDecoderCell", "TransformerSeq2Seq",
     "Llama", "LlamaConfig", "llama_tp_rules", "llama_tiny", "llama_7b",
+    "Dots3", "Dots3Config", "dots3_tiny",
     "kv_generate", "decode_mode", "decode_step_program",
 ]

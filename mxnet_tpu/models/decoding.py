@@ -492,6 +492,35 @@ def decode_mode(model, batch=1, total=32, weights="native", fused="auto",
     return "unrolled"
 
 
+def layer_description(model):
+    """The per-layer description the serving engine consumes: for each
+    layer its attention kind, feed-forward kind and cache kind
+    (``serve.schema.POOL_ROWS`` declares what a cache kind stores).  A
+    model exports its own through ``decode_description()``; GPT and Llama
+    are every layer alike, dense K/V attention under the main page table,
+    and stay on the stacked-layer scan of ``_DecodeEngine``."""
+    fn = getattr(model, "decode_description", None)
+    if fn is not None:
+        return fn()
+    is_llama = hasattr(model.blocks[0], "rms1")
+    return [{"attn": {"kind": "mha"},
+             "ffn": {"kind": "swiglu" if is_llama else "mlp"},
+             "cache": "kv"} for _ in model.blocks]
+
+
+def decode_engine(model, B, P, total, temperature, top_k, prefill,
+                  weights, fused, stacked):
+    """The engine that serves ``model``, chosen by its description alone:
+    the uniform K/V kind has the stacked scan, everything else the
+    kind-driven ``layered.LayeredEngine``."""
+    if all(d["cache"] == "kv" for d in layer_description(model)):
+        return _DecodeEngine(model, B, P, total, temperature, top_k,
+                             prefill, weights, fused, stacked)
+    from .layered import LayeredEngine
+    return LayeredEngine(model, B, P, total, temperature, top_k, prefill,
+                         weights, fused, stacked)
+
+
 class _DecodeEngine:
     """Per-call decode program builder: family/geometry detection, weight
     preparation (q8 codes / Pallas pack / stacked arrays — all cached on

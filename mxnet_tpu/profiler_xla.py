@@ -213,14 +213,28 @@ def _device_planes(data):
     return planes
 
 
-def region_of(provenance):
+# device kernels whose events carry no provenance at all, by the start of
+# their operation's name: the grouped product (``lax.ragged_dot``, emitted
+# by ``ops/moe.routed_experts`` alone) runs as a custom kernel named
+# ``ragged-dot-none[.n]`` with an empty ``tf_op`` (read off a v5e trace,
+# PR 29), so it would read ``unscoped`` whatever scope it was traced under
+_KERNEL_REGIONS = (("ragged-dot", "mx.moe_experts"),)
+
+
+def region_of(provenance, name=None):
     """The region of a device operation: the INNERMOST ``mx.*`` component
     of its provenance path (``jit(step)/mx.dense/while/body/mx.attn/mul``
     and ``transpose(jvp(mx.attn))/dot_general`` are both ``mx.attn``),
-    ``"unscoped"`` where the path has none.  The vocabulary is
-    docs/TELEMETRY.md's."""
+    ``"unscoped"`` where the path has none — but for the few kernels that
+    never carry one, which are known by ``name`` (``_KERNEL_REGIONS``).
+    The vocabulary is docs/TELEMETRY.md's."""
     found = _REGION.findall(provenance or "")
-    return found[-1] if found else UNSCOPED
+    if found:
+        return found[-1]
+    for start, region in _KERNEL_REGIONS:
+        if name and name.startswith(start):
+            return region
+    return UNSCOPED
 
 
 def _self_ps(events):
@@ -337,7 +351,7 @@ def device_regions(parsed):
         if op["run"] is None or not parsed["runs"][op["run"]]["whole"]:
             continue
         regions = out[op["module"]]["regions"]
-        key = region_of(op["tf_op"])
+        key = region_of(op["tf_op"], op["name"])
         regions[key] = regions.get(key, 0.0) + op["self_us"] / 1e6
     return out
 
@@ -354,7 +368,7 @@ def aggregate(records, by="category"):
     """
     groups = defaultdict(lambda: [0, 0.0, 0, 0])
     for r in records:
-        k = region_of(r["tf_op"]) if by == "region" else \
+        k = region_of(r["tf_op"], r["name"]) if by == "region" else \
             r[by] or "<none>"
         g = groups[k]
         g[0] += 1

@@ -76,7 +76,7 @@ import jax.numpy as jnp
 from .. import telemetry
 from ..base import MXNetError
 from ..models.decoding import (_DecodeEngine, _TRACE_LOCK, _kv_requant,
-                               _pages_get, _pages_set,
+                               _pages_get, _pages_set, decode_engine,
                                _KV_CODE_DTYPE, _KV_SCALE_DTYPE)
 from . import schema
 
@@ -171,7 +171,8 @@ def pool_state_bytes(progs, num_slots=None, num_pages=None):
     for BOTH dtypes."""
     S = progs.S if num_slots is None else int(num_slots)
     npages = S * progs.maxp if num_pages is None else int(num_pages)
-    return npages * progs.page_bytes() + S * _SLOT_STATE_BYTES
+    return npages * progs.page_bytes() + S * _SLOT_STATE_BYTES \
+        + progs.window_pages * progs.window_page_bytes()
 
 
 def admit_scratch_bytes(progs, a_bucket):
@@ -186,8 +187,29 @@ def admit_scratch_bytes(progs, a_bucket):
     about the admission spike."""
     e = progs.eng
     A = int(a_bucket)
+    if progs.layered:
+        # a layered engine prefills straight through the page tables:
+        # no dense scratch cache, only the wave's slot-state rows
+        return A * _SLOT_STATE_BYTES
     return 2 * e.NL * A * e.KV * progs.Tp * e.D \
         * jnp.dtype(e.cdtype).itemsize + A * _SLOT_STATE_BYTES
+
+
+def _kv_pool_zeros(progs):
+    """The uniform K/V kind's pool pair."""
+    eng = progs.eng
+    # one layer's page is one contiguous, lane-dense (page, KV·D) block
+    shape = (eng.NL, progs.num_pages, progs.page, eng.KV * eng.D)
+    if progs.quant_kv:
+        # int8 pool: each of K and V is a (codes, scales) PAIR riding
+        # ONE state slot as a pytree — every executable threads, donates
+        # and scans it exactly like the single f32 array it replaces
+        sshape = (eng.NL, progs.num_pages, eng.KV)
+        return ((jnp.zeros(shape, _KV_CODE_DTYPE),
+                 jnp.zeros(sshape, _KV_SCALE_DTYPE)),
+                (jnp.zeros(shape, _KV_CODE_DTYPE),
+                 jnp.zeros(sshape, _KV_SCALE_DTYPE)))
+    return jnp.zeros(shape, eng.cdtype), jnp.zeros(shape, eng.cdtype)
 
 
 def pool_state_init(progs, device=None):
@@ -213,20 +235,13 @@ def pool_state_init(progs, device=None):
     eng = progs.eng
     if device is None:
         device = jax.devices()[0]
-    # one layer's page is one contiguous, lane-dense (page, KV·D) block
-    shape = (eng.NL, progs.num_pages, progs.page, eng.KV * eng.D)
-    if progs.quant_kv:
-        # int8 pool: each of K and V is a (codes, scales) PAIR riding
-        # ONE state slot as a pytree — every executable threads, donates
-        # and scans it exactly like the single f32 array it replaces
-        sshape = (eng.NL, progs.num_pages, eng.KV)
-        kpool = (jnp.zeros(shape, _KV_CODE_DTYPE),
-                 jnp.zeros(sshape, _KV_SCALE_DTYPE))
-        vpool = (jnp.zeros(shape, _KV_CODE_DTYPE),
-                 jnp.zeros(sshape, _KV_SCALE_DTYPE))
+    if progs.layered:
+        # the declared row kinds of the model's cache kinds: main-table
+        # arrays in ``kp``, window-table arrays in ``vp``
+        kpool, vpool = eng.pool_zeros(progs.num_pages, progs.window_pages,
+                                      progs.page)
     else:
-        kpool = jnp.zeros(shape, eng.cdtype)
-        vpool = jnp.zeros(shape, eng.cdtype)
+        kpool, vpool = _kv_pool_zeros(progs)
     state = (kpool,                          # K page pool
              vpool,                          # V page pool
              jnp.zeros((S,), jnp.int32),     # pos: next write index
@@ -249,6 +264,10 @@ def pool_state_grow(state, new_s, new_pages=None):
     the grown pool before the next dispatch (the server regenerates
     them from its allocator every dispatch, so this is automatic)."""
     kp, vp, pos, tok, active, stop, keys, dl, spec = state
+    if isinstance(kp, tuple) and not isinstance(vp, tuple):
+        raise MXNetError("a pool of declared row kinds with a window "
+                         "table has one size: pin a single pool_sizes "
+                         "entry")
     kp0 = kp[0] if isinstance(kp, tuple) else kp
     grow = new_s - pos.shape[0]
     if grow <= 0:
@@ -285,7 +304,7 @@ class PoolPrograms:
     def __init__(self, model, num_slots, max_total, temperature=0.0,
                  top_k=0, eos_id=None, weights="native",
                  telemetry_label=None, page_size=16, num_pages=None,
-                 kv_dtype="native"):
+                 kv_dtype="native", window_pages=None, max_chunk=None):
         self.model = model
         self.telemetry_label = telemetry_label
         # "native" stores pages at the engine cache dtype (the exact
@@ -316,10 +335,24 @@ class PoolPrograms:
         self.temperature, self.top_k = float(temperature), int(top_k)
         self.eos_id = None if eos_id is None else int(eos_id)
         self.weights = weights
-        self.eng = _DecodeEngine(model, self.S, 1, self.Tp, temperature,
-                                 top_k, "batched", weights, "off",
-                                 "auto")
-        if self.eng.mode != "stacked":
+        self.eng = decode_engine(model, self.S, 1, self.Tp, temperature,
+                                 top_k, "batched", weights, "off", "auto")
+        # a layered engine (per-layer kinds) brings its own row kinds and,
+        # where a kind keeps a window, a second page table: a ring of
+        # ``ring`` entries a slot, wide enough for the window plus the
+        # longest chunk a dispatch runs
+        self.layered = self.eng.mode == "layered"
+        self.window = getattr(self.eng, "window", None)
+        self.ring, self.window_pages = 0, 0
+        if self.layered and self.quant_kv:
+            raise MXNetError("kv_dtype='int8' is not implemented for "
+                             "pools of declared row kinds")
+        if self.window is not None:
+            self.ring = self.eng.window_span_pages(
+                self.page, int(max_chunk or self.page)) + 1
+            self.window_pages = self.S * self.ring \
+                if window_pages is None else int(window_pages)
+        if self.eng.mode not in ("stacked", "layered"):
             raise MXNetError(
                 "slot-pool serving needs the stacked-layer scan decode "
                 "step (uniform GPT/Llama stack — see ops/decode_fused."
@@ -344,15 +377,38 @@ class PoolPrograms:
         float32 pool is what converts an HBM budget into ~2x resident
         sequences at equal bytes."""
         e = self.eng
+        if self.layered:
+            return e.main_page_bytes(self.page)
         if self.quant_kv:
             return schema.kv_page_int8_bytes(e.NL, e.KV, self.page,
                                              e.D)
         return 2 * e.NL * e.KV * self.page * e.D \
             * jnp.dtype(e.cdtype).itemsize
 
+    def window_page_bytes(self):
+        """Device bytes of ONE window-table page over every layer that
+        keeps a window (0 where the model has none)."""
+        return self.eng.window_page_bytes(self.page) \
+            if self.window is not None else 0
+
     def pages_for(self, total_len):
         """Pages a sequence of ``total_len`` cached positions needs."""
         return -(-int(total_len) // self.page)
+
+    def key_pages_for(self, c_bucket, reach):
+        """How many of a slot's table pages a ``c_bucket``-token chunk
+        whose last token is position ``reach - 1`` is compiled to read:
+        ``None`` (all ``maxp``) but for a layered engine's long chunks,
+        whose selecting attention scores every cached position — those
+        take the smallest quarter of ``maxp`` that covers ``reach``, so a
+        long prompt's early chunks do not pay for its whole horizon (four
+        executables a long bucket, all met while the first long prompt
+        streams in)."""
+        if not self.layered or c_bucket < self.eng.dense_chunk:
+            return None
+        need = self.pages_for(reach)
+        return next(kp for kp in (-(-self.maxp * i // 4) for i in (1, 2, 3, 4))
+                    if kp >= need)
 
     # -- sampling ------------------------------------------------------- #
     @jax.named_scope("mx.head")
@@ -407,7 +463,7 @@ class PoolPrograms:
         def step(param_vals, q8, sw, now, pt, kp, vp, pos, tok, active,
                  stop, keys, dl, spec):
             with _TRACE_LOCK, params_swapped(deng.params, param_vals):
-                logits, kp, vp = deng.pool_token_paged(
+                logits, kp, vp, *aux = deng.pool_token_paged(
                     tok, pos, kp, vp, pt, page, sw, q8)
                 nxt = eng._sample_slots(keys, logits, pos)
             nxt = jnp.where(active, nxt, tok)
@@ -416,7 +472,11 @@ class PoolPrograms:
             emitted = active
             new_state = (kp, vp, newpos, nxt, active & ~done, stop,
                          keys, dl, spec)
-            return new_state, (nxt, emitted, done)
+            # an engine with counters of its own (experts' load, keys
+            # selected) hands them back reduced over the live slots: a
+            # fourth readback array the scheduler adds up
+            extra = (deng.step_counters(aux[0], active),) if aux else ()
+            return new_state, (nxt, emitted, done) + extra
 
         self._step = telemetry.instrument_jit(
             jax.jit(step, donate_argnums=schema.jit_donate("step", step)),
@@ -467,11 +527,70 @@ class PoolPrograms:
         page = self.page
         ppad = -(-P // page) * page     # prompt bucket in whole pages
         npb = ppad // page
-        peng = _DecodeEngine(self.model, A, P, ppad,
-                             self.temperature, self.top_k, "batched",
-                             self.weights, "off", "auto")
-        peng.take_operands()    # server-held operands are the only refs
-        NL, KV, D = peng.NL, peng.KV, peng.D
+        if self.layered:
+            # the layered engine runs the wave through each row's own
+            # table rows (``zpages``): no dense scratch, no page scatter
+            peng = self.eng
+        else:
+            peng = _DecodeEngine(self.model, A, P, ppad,
+                                 self.temperature, self.top_k, "batched",
+                                 self.weights, "off", "auto")
+            peng.take_operands()   # server-held operands are the only refs
+            NL, KV, D = peng.NL, peng.KV, peng.D
+
+        @jax.named_scope("mx.page_write")
+        def land(kp, vp, ck1, cv1, true_len, pages, zpages):
+            """The stacked engine's dense prefill scratch into the wave's
+            reserved pages."""
+            # page scatter: the dense (A, Ppad) scratch splits into A*NPB
+            # page-shaped rows that land at their reserved pool pages in
+            # one masked scatter per array (sentinel rows DROP)
+            tgt_pg = pages.reshape(A * npb)
+            if self.quant_kv:
+                # the padded tail's garbage columns are unreachable in
+                # the f32 pool but would poison the per-page SCALES
+                # here — zero them before the per-page quantization
+                colmask = jnp.arange(ppad, dtype=jnp.int32)[None] \
+                    < true_len[:, None]                     # (A, ppad)
+                ck1 = jnp.where(colmask[None, :, None, :, None],
+                                ck1, 0)
+                cv1 = jnp.where(colmask[None, :, None, :, None],
+                                cv1, 0)
+            # (NL, A, KV, Ppad, D) scratch -> (NL, A*NPB, page, KV·D)
+            # pages in the pool's row layout
+            c1, v1 = (c.reshape(NL, A, KV, npb, page, D)
+                       .transpose(0, 1, 3, 4, 2, 5)
+                       .reshape(NL, A * npb, page, KV * D)
+                      for c in (ck1, cv1))
+            if self.quant_kv:
+                # fresh whole pages: plain per-page quantization (no
+                # floor — nothing lived in these pages), then ONE
+                # masked scatter each for codes and scales
+                qc1, sc1 = _kv_requant(c1, 0.0, KV)
+                qv1, sv1 = _kv_requant(v1, 0.0, KV)
+                (kpc, kps), (vpc, vps) = kp, vp
+                # recycled-page reset: the pool free list is host-only
+                # bookkeeping, so a reallocated page still carries its
+                # previous tenant's codes AND scale.  A zero SCALE is a
+                # full reset — stale codes dequantize to exact zeros
+                # and the first RMW requantizes from floor 0.0, so the
+                # old tenant's dynamic range can never ratchet the new
+                # tenant's scale.  ``zpages`` holds every page the wave
+                # reserved (decode-frontier pages included — those are
+                # first WRITTEN by the step/verify RMWs); the prompt
+                # pages' scales are immediately overwritten by the
+                # scatter below.  Sentinel entries DROP.
+                zf = zpages.reshape(A * zpages.shape[1])
+                kps = _pages_set(kps, zf, 0.0)
+                vps = _pages_set(vps, zf, 0.0)
+                kp = (_pages_set(kpc, tgt_pg, qc1),
+                      _pages_set(kps, tgt_pg, sc1))
+                vp = (_pages_set(vpc, tgt_pg, qv1),
+                      _pages_set(vps, tgt_pg, sv1))
+            else:
+                kp = _pages_set(kp, tgt_pg, c1)
+                vp = _pages_set(vp, tgt_pg, v1)
+            return kp, vp
 
         def admit(param_vals, prompts, meta, dls, pages, zpages, kp, vp,
                   pos, tok, active, stop, keys, dl, spec):
@@ -483,63 +602,20 @@ class PoolPrograms:
             spec_d = meta[:, _AM["spec_depth"]]
             keys_a = jax.vmap(jax.random.PRNGKey)(seed)       # (A, 2)
             with _TRACE_LOCK, params_swapped(peng.params, param_vals):
-                ck1, cv1 = peng.zero_caches()
-                logits, ck1, cv1 = peng.prefill_batch(
-                    prompts, ck1, cv1, last_index=true_len - 1)
+                if self.layered:
+                    logits, kp, vp = peng.admit_tokens(
+                        prompts, true_len - 1, zpages, page, kp, vp)
+                else:
+                    ck1, cv1 = peng.zero_caches()
+                    logits, ck1, cv1 = peng.prefill_batch(
+                        prompts, ck1, cv1, last_index=true_len - 1)
                 first = self._sample_slots(keys_a, logits,
                                            true_len - 1)
             done = stop_pos <= true_len
             if self.eos_id is not None:
                 done = done | (first == self.eos_id)
-            with jax.named_scope("mx.page_write"):
-                # page scatter: the dense (A, Ppad) scratch splits into A*NPB
-                # page-shaped rows that land at their reserved pool pages in
-                # one masked scatter per array (sentinel rows DROP)
-                tgt_pg = pages.reshape(A * npb)
-                if self.quant_kv:
-                    # the padded tail's garbage columns are unreachable in
-                    # the f32 pool but would poison the per-page SCALES
-                    # here — zero them before the per-page quantization
-                    colmask = jnp.arange(ppad, dtype=jnp.int32)[None] \
-                        < true_len[:, None]                     # (A, ppad)
-                    ck1 = jnp.where(colmask[None, :, None, :, None],
-                                    ck1, 0)
-                    cv1 = jnp.where(colmask[None, :, None, :, None],
-                                    cv1, 0)
-                # (NL, A, KV, Ppad, D) scratch -> (NL, A*NPB, page, KV·D)
-                # pages in the pool's row layout
-                c1, v1 = (c.reshape(NL, A, KV, npb, page, D)
-                           .transpose(0, 1, 3, 4, 2, 5)
-                           .reshape(NL, A * npb, page, KV * D)
-                          for c in (ck1, cv1))
-                if self.quant_kv:
-                    # fresh whole pages: plain per-page quantization (no
-                    # floor — nothing lived in these pages), then ONE
-                    # masked scatter each for codes and scales
-                    qc1, sc1 = _kv_requant(c1, 0.0, KV)
-                    qv1, sv1 = _kv_requant(v1, 0.0, KV)
-                    (kpc, kps), (vpc, vps) = kp, vp
-                    # recycled-page reset: the pool free list is host-only
-                    # bookkeeping, so a reallocated page still carries its
-                    # previous tenant's codes AND scale.  A zero SCALE is a
-                    # full reset — stale codes dequantize to exact zeros
-                    # and the first RMW requantizes from floor 0.0, so the
-                    # old tenant's dynamic range can never ratchet the new
-                    # tenant's scale.  ``zpages`` holds every page the wave
-                    # reserved (decode-frontier pages included — those are
-                    # first WRITTEN by the step/verify RMWs); the prompt
-                    # pages' scales are immediately overwritten by the
-                    # scatter below.  Sentinel entries DROP.
-                    zf = zpages.reshape(A * zpages.shape[1])
-                    kps = _pages_set(kps, zf, 0.0)
-                    vps = _pages_set(vps, zf, 0.0)
-                    kp = (_pages_set(kpc, tgt_pg, qc1),
-                          _pages_set(kps, tgt_pg, sc1))
-                    vp = (_pages_set(vpc, tgt_pg, qv1),
-                          _pages_set(vps, tgt_pg, sv1))
-                else:
-                    kp = _pages_set(kp, tgt_pg, c1)
-                    vp = _pages_set(vp, tgt_pg, v1)
+            if not self.layered:
+                kp, vp = land(kp, vp, ck1, cv1, true_len, pages, zpages)
             # masked slot-state scatter: invalid rows target slot S
             # (out of bounds) and drop; valid rows carry distinct
             # host-assigned slots
@@ -631,6 +707,11 @@ class PoolPrograms:
                           _pages_set(kps, dst, ksb))
                     vp = (_pages_set(vpc, dst, vcb),
                           _pages_set(vps, dst, vsb))
+                elif self.layered:
+                    # main-table arrays only: a window-table page is
+                    # never copied, the planner cuts such a match back
+                    kp = jax.tree.map(lambda a: _pages_set(
+                        a, dst, _pages_get(a, src)), kp)
                 else:
                     kp = _pages_set(kp, dst, _pages_get(kp, src))
                     vp = _pages_set(vp, dst, _pages_get(vp, src))
@@ -653,9 +734,10 @@ class PoolPrograms:
         self._hits[A] = fn
         return fn
 
-    def chunk_fn(self, c_bucket):
+    def chunk_fn(self, c_bucket, key_pages=None):
         """The jitted CHUNKED-PREFILL program for one ``C``-token slice
-        of a single prompt (cached per chunk bucket): ``chunk(
+        of a single prompt (cached per chunk bucket, and per
+        ``key_pages_for`` bound where that gives one): ``chunk(
         param_vals, q8, sw, toks (C,) int32, meta (8,) int32 =
         [final, slot, true_len, stop_pos, seed, nlast, off,
         spec_depth], dls scalar f32, ptrow (MAXP,) int32, zrow (MAXP,)
@@ -678,7 +760,7 @@ class PoolPrograms:
         for ``off`` tokens, the same program fills only the divergent
         tail."""
         C = int(c_bucket)
-        fn = self._chunks.get(C)
+        fn = self._chunks.get((C, key_pages))
         if fn is not None:
             return fn
         if not 0 < C <= self.Tp:
@@ -688,6 +770,7 @@ class PoolPrograms:
 
         deng = self.eng
         page = self.page
+        bound = {} if key_pages is None else {"key_pages": int(key_pages)}
 
         def chunk(param_vals, q8, sw, toks, meta, dls, ptrow, zrow, kp,
                   vp, pos, tok, active, stop, keys, dl, spec):
@@ -714,7 +797,7 @@ class PoolPrograms:
                     vp = (vpc, _pages_set(vps, zrow, 0.0))
             with _TRACE_LOCK, params_swapped(deng.params, param_vals):
                 logits, kp, vp = deng.chunk_tokens(
-                    toks, off, nlast, ptrow, page, kp, vp, sw, q8)
+                    toks, off, nlast, ptrow, page, kp, vp, sw, q8, **bound)
                 first = self._sample_slots(key1[None], logits,
                                            (true_len - 1)[None])[0]
             done = stop_pos <= true_len
@@ -738,12 +821,12 @@ class PoolPrograms:
             jax.jit(chunk,
                     donate_argnums=schema.jit_donate("chunk", chunk)),
             "serve.chunk",
-            key=(self.telemetry_label, self.S, C),
+            key=(self.telemetry_label, self.S, C, key_pages),
             fields={"server": self.telemetry_label, "pool": self.S,
-                    "c_bucket": C,
+                    "c_bucket": C, "key_pages": key_pages,
                     # one slot's dense gather scratch per layer slice
                     "cache_bytes": self.eng.cache_bytes() // self.S})
-        self._chunks[C] = fn
+        self._chunks[(C, key_pages)] = fn
         return fn
 
     def verify_fn(self, k_bucket):
@@ -779,6 +862,11 @@ class PoolPrograms:
             return fn
         if k < 1:
             raise MXNetError(f"verify bucket {k} must be >= 1")
+        if self.layered:
+            raise MXNetError(
+                "draft-and-verify is not implemented for models served "
+                "from a per-layer description (latent / windowed / "
+                "routed kinds): serve them with spec=False")
         if self.temperature != 0.0:
             raise MXNetError(
                 "draft-and-verify acceptance is exact only for greedy "

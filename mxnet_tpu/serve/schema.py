@@ -31,7 +31,8 @@ modules) so standalone tools can load it by file path.
 """
 from __future__ import annotations
 
-__all__ = ["EXECUTABLES", "SLOT_STATE", "KV_PAGE_INT8",
+__all__ = ["EXECUTABLES", "SLOT_STATE", "KV_PAGE_INT8", "POOL_ROWS",
+           "LANE_TILE", "row_lanes", "pool_rows",
            "executable_names", "operands",
            "arity", "donate_argnums", "donated_operands", "jit_donate",
            "state_operands", "state_arity", "slot_state_fields",
@@ -127,9 +128,45 @@ EXECUTABLES = {
 # page_bytes`` prices pages from it.
 KV_PAGE_INT8 = {"codes": "int8", "scales": "float32"}
 
+# -- the pools' row kinds ------------------------------------------------ #
+# What a layer keeps of a cached token, by the CACHE KIND its model's
+# per-layer description names (``models.decoding.layer_description``).
+# ``table`` says which page table addresses the rows: ``main`` (a slot
+# holds a page for every position it has cached; the prefix index shares
+# them) or ``window`` (a ring a slot: pages for its last ``window``
+# positions only, released as it advances).  ``rows`` are the stored row
+# kinds, each one array ``(layers of the kind, pages, page, lanes)``; the
+# state tuple's ``kp`` holds the main-table arrays and ``vp`` the
+# window-table ones (a uniform K/V model: ``kp`` = K, ``vp`` = V, both
+# main).  A row's lanes are its width rounded up to whole 128-lane tiles
+# (``row_lanes``): a 64-wide minor dimension made the chip keep the pool
+# page-minor and re-lay it out for every consumer (PERF.md, PR 27).
+POOL_ROWS = {
+    "kv": {"table": "main", "rows": ("k", "v")},
+    "latent_index": {"table": "main", "rows": ("latent", "index_key")},
+    "latent_window": {"table": "window", "rows": ("latent",)},
+}
+LANE_TILE = 128
+
 _ITEMSIZE = {"bool": 1, "int8": 1, "uint8": 1, "int16": 2, "uint16": 2,
              "int32": 4, "uint32": 4, "float32": 4, "int64": 8,
              "uint64": 8, "float64": 8}
+
+
+def row_lanes(width):
+    """Stored lanes of a row ``width`` wide: whole 128-lane tiles."""
+    return -(-int(width) // LANE_TILE) * LANE_TILE
+
+
+def pool_rows(cache_kind):
+    """``(table, row kinds)`` of a declared cache kind."""
+    try:
+        entry = POOL_ROWS[cache_kind]
+    except KeyError:
+        raise ValueError(
+            f"no cache kind {cache_kind!r} in the pool schema "
+            f"(declared: {', '.join(POOL_ROWS)})") from None
+    return entry["table"], entry["rows"]
 
 
 def executable_names():

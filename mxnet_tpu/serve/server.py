@@ -362,13 +362,28 @@ class _PrefixIndex:
     ``evict`` drops least-recently-touched LEAF nodes when the
     allocator runs dry, so the cache is exactly the pages nothing else
     wants yet.  Scheduler-thread-only, like the ``PagePool`` under
-    it."""
+    it.
 
-    def __init__(self, page_size, pool):
+    With a WINDOW pool under it (``wpool``; a model some of whose layers
+    keep only the last ``window`` positions), a cached prefix is only
+    enterable where the index also holds those layers' rows for the
+    positions just before its end.  ``register`` therefore keeps, on the
+    node of the producer's last full page, the TAIL: the window pages of
+    the ``tail_pages`` logical pages ending there, one index-owned
+    refcount each.  ``match`` cuts a chain back to the deepest length a
+    tail covers (or misses) — never a hit served from a wrong window.
+    Tails are the first to go when the window pool runs dry
+    (``evict_tails``, least recently touched first), so what every hit
+    touches stays."""
+
+    def __init__(self, page_size, pool, wpool=None, tail_pages=0):
         self.page = int(page_size)
         self.pool = pool
+        self.wpool = wpool
+        self.tail_pages = int(tail_pages)
         self._nodes = {}    # (parent_id, chunk_bytes) -> node dict
         self._by_id = {}    # node id -> node (parent chains, eviction)
+        self._tails = {}    # node id -> node, for the nodes with a tail
         self._ids = itertools.count(1)
         self._tick = itertools.count(1)
 
@@ -379,26 +394,43 @@ class _PrefixIndex:
         return (parent,
                 prompt[c * self.page:(c + 1) * self.page].tobytes())
 
-    def match(self, prompt):
+    def match(self, prompt, limit=None):
         """Longest chain of cached FULL pages covering a prefix of
-        ``prompt``: ``(num_matched_pages, [pool page ids])``."""
-        pages, parent = [], 0
+        ``prompt``: ``(num_matched_pages, [pool page ids])``; over a
+        window pool ``(m, pages, {logical page: window page})``, the chain
+        cut back to the deepest length ``m <= limit`` whose window a tail
+        covers."""
+        pages, chain, parent = [], [], 0
         for c in range(prompt.size // self.page):
             node = self._nodes.get(self._chunk_key(prompt, parent, c))
             if node is None:
                 break
             node["last"] = next(self._tick)
             pages.append(node["page"])
+            chain.append(node)
             parent = node["id"]
-        return len(pages), pages
+        if self.wpool is None:
+            return len(pages), pages
+        top = len(pages) if limit is None else min(limit, len(pages))
+        for m in range(top, 0, -1):
+            need = range(max(0, m - self.tail_pages + 1), m)
+            # the tail kept at this length, or at the page behind it
+            for node in chain[m - 1:m + 1]:
+                tail = node.get("tail")
+                if tail is not None and all(lp in tail for lp in need):
+                    return m, pages[:m], {lp: tail[lp] for lp in need}
+        return 0, [], {}
 
-    def register(self, prompt, length, slot_pages):
+    def register(self, prompt, length, slot_pages, window_pages=None):
         """Index ``prompt[:length]``'s full pages, backed by the
         producer slot's ``slot_pages`` row.  Only NEWLY created nodes
         incref their page (existing nodes already own theirs); pages
         past the last FULL page are never indexed — their K/V columns
-        get overwritten by the producer's own decode steps."""
-        parent = 0
+        get overwritten by the producer's own decode steps.
+        ``window_pages`` ``{logical page: window page}`` is what the
+        producer holds of the window pool: the last full page's node
+        keeps the tail out of it."""
+        parent, node = 0, None
         for c in range(min(length // self.page, len(slot_pages))):
             key = self._chunk_key(prompt, parent, c)
             node = self._nodes.get(key)
@@ -414,6 +446,30 @@ class _PrefixIndex:
             else:
                 node["last"] = next(self._tick)
             parent = node["id"]
+        if node is not None and window_pages is not None \
+                and "tail" not in node:
+            c = length // self.page - 1
+            lps = range(max(0, c - self.tail_pages + 1), c + 1)
+            if all(lp in window_pages for lp in lps):
+                node["tail"] = {lp: window_pages[lp] for lp in lps}
+                for wp in node["tail"].values():
+                    self.wpool.incref(wp)
+                self._tails[node["id"]] = node
+
+    def evict_tails(self, need):
+        """Drop least-recently-touched tails until ``need`` window pages
+        have come free (a page frees once no slot maps it either).
+        Returns pages freed."""
+        before = self.wpool.free_pages
+        while self.wpool.free_pages - before < need and self._tails:
+            self._drop_tail(min(self._tails.values(),
+                                key=lambda nd: nd["last"]))
+        return self.wpool.free_pages - before
+
+    def _drop_tail(self, node):
+        for wp in node.pop("tail").values():
+            self.wpool.decref(wp)
+        del self._tails[node["id"]]
 
     def evict(self, need, protect=()):
         """Drop LRU leaf nodes (never pages in ``protect``) until
@@ -432,6 +488,8 @@ class _PrefixIndex:
         return self.pool.free_pages - before
 
     def _drop(self, node):
+        if "tail" in node:
+            self._drop_tail(node)
         del self._nodes[node["key"]]
         del self._by_id[node["id"]]
         if node["parent"]:
@@ -440,6 +498,8 @@ class _PrefixIndex:
 
     def drop_all(self):
         """Release every index-owned page ref (server teardown)."""
+        for node in list(self._tails.values()):
+            self._drop_tail(node)
         for node in self._by_id.values():
             self.pool.decref(node["page"])
         self._nodes.clear()
@@ -636,7 +696,7 @@ class DecodeServer:
                  step_timeout=None, page_size=None, num_pages=None,
                  prefix_cache=None, spec=None, spec_depth=None,
                  spec_sizes=None, drafter=None, kv_dtype=None,
-                 autostart=True):
+                 num_window_pages=None, autostart=True):
         from ..telemetry.memory import parse_bytes
         from .draft import NGramDrafter
         from .engine import PagePool, PoolPrograms, pool_state_init
@@ -791,7 +851,9 @@ class DecodeServer:
                     top_k, eos_id, weights,
                     telemetry_label=self.telemetry_label,
                     page_size=self.page_size, num_pages=num_pages,
-                    kv_dtype=self.kv_dtype)
+                    kv_dtype=self.kv_dtype,
+                    window_pages=num_window_pages,
+                    max_chunk=self.prefill_buckets[-1])
             except MXNetError as e:
                 # models the slot-pool gate rejects still serve, one
                 # request at a time, through the kv_generate fallback
@@ -810,6 +872,18 @@ class DecodeServer:
                 f"{'' if self.sync_reason is None else ': ' + self.sync_reason}"
                 ") — per-request decode caches are unmetered",
                 stacklevel=2)
+        if not self.sync_mode and self._progs.layered:
+            # a model served from its per-layer description: one pool
+            # size (its window ring does not grow) and no draft-and-verify
+            if len(self.pool_sizes) > 1 and self._progs.window is not None:
+                raise MXNetError(
+                    "a model with windowed layers serves from one pool "
+                    f"size, not {self.pool_sizes}")
+            if spec and self.spec_enabled:
+                raise MXNetError(
+                    "draft-and-verify is not implemented for models "
+                    "served from a per-layer description: pass spec=False")
+            self.spec_enabled = False
         if not self.sync_mode:
             # price the MINIMUM USABLE configuration before allocating
             # anything: the smallest pool plus the smallest admission
@@ -839,9 +913,26 @@ class DecodeServer:
         self._pages = None if self.sync_mode \
             else PagePool(self._progs.num_pages)
         self._slot_pages = [[] for _ in range(self.pool_sizes[0])]
+        self._pt_rows = {}      # slot -> (row list, its int32 array)
         self._chunk_slots = set()
         self._chunking = deque()   # {"req", "slot", "off"} records
-        self._prefix = _PrefixIndex(self._progs.page, self._pages) \
+        # the WINDOW pool's bookkeeping (models with windowed layers):
+        # its allocator, per slot the window pages it holds by logical
+        # page, and the host's mirror of each live slot's next position,
+        # by which pages are taken as a slot advances and let go behind it
+        windowed = not self.sync_mode and self._progs.window is not None
+        self._wpages = PagePool(self._progs.window_pages) if windowed \
+            else None
+        self._wback = self._progs.eng.window_back_pages(
+            self._progs.page) if windowed else 0
+        self._slot_wpages = [{} for _ in range(self.pool_sizes[0])]
+        self._slot_pos = [0] * self.pool_sizes[0]
+        self._wheld_max = 0     # most window pages a stepping slot held
+        self._prompt_tokens = self._prompt_cached = 0
+        self._step_sums = {}    # the step's own counters, added up
+        self._prefix = _PrefixIndex(
+            self._progs.page, self._pages, self._wpages,
+            self._wback + 1 if windowed else 0) \
             if not self.sync_mode and self.prefix_cache_enabled \
             else None
 
@@ -886,6 +977,10 @@ class DecodeServer:
             # pool_bytes from (None in sync mode: no resident pool)
             page_bytes=None if self.sync_mode
             else self._progs.page_bytes(),
+            window_pages=0 if self.sync_mode
+            else self._progs.window_pages,
+            window_page_bytes=0 if self.sync_mode
+            else self._progs.window_page_bytes(),
             prefix_cache=self.prefix_cache_enabled,
             spec=self.spec_enabled, spec_depth=self.spec_depth,
             spec_sizes=list(self.spec_sizes))
@@ -1086,11 +1181,61 @@ class DecodeServer:
             else self._pages.in_use,
             "prefix_nodes": 0 if self._prefix is None
             else len(self._prefix),
+            # the window pool (0 / None without windowed layers): a slot
+            # holds pages for its window only, the prefix index the tails
+            "window_pages_total": 0 if self._wpages is None
+            else self._wpages.num_pages,
+            "window_pages_in_use": 0 if self._wpages is None
+            else self._wpages.in_use,
+            "window_page_bytes": 0 if self.sync_mode
+            else self._progs.window_page_bytes(),
+            "window_pages_slot_max": self._wheld_max,
+            "window_pages_slot_bound": self._wback + 2
+            if self._wpages is not None else 0,
+            "prefix_tails": 0 if self._prefix is None
+            else len(self._prefix._tails),
+            # prompt tokens admitted, and how many of them the prefix
+            # cache served
+            "prompt_tokens": self._prompt_tokens,
+            "prompt_tokens_cached": self._prompt_cached,
+            **self._step_stats(),
             "counters": dict(self.counters),
             "ttft": self._tele["ttft"].summary(),
             "token_gap": self._tele["gap"].summary(),
             "queue_wait": self._tele["wait"].summary(),
         }
+
+    def _step_stats(self):
+        """What the step executable counted itself (an engine with routed
+        experts or a selecting attention), summed over the steps routed so
+        far: mean over routed layers and steps of the busiest held expert's
+        tokens over the mean, share of (layer, expert) cells a step
+        touched, tokens a held expert a step, keys selected a query."""
+        t = self._step_sums
+        out = {}
+        if t.get("cells"):
+            out["moe_tokens_per_expert_step"] = t["tokens"] / t["cells"]
+            out["moe_experts_touched_share"] = t["touched"] / t["cells"]
+            out["moe_load_max_over_mean"] = \
+                t["ratio_sum"] / t["ratio_n"] if t["ratio_n"] else None
+        if t.get("queries"):
+            out["selected_keys_per_query"] = t["selected"] / t["queries"]
+        return out
+
+    def _add_step_counters(self, c):
+        t = self._step_sums
+        if "expert_load" in c:
+            load = onp.asarray(c["expert_load"], onp.float64)
+            mean, top = load.mean(axis=1), load.max(axis=1)
+            live = mean > 0
+            for k, v in (("tokens", load.sum()), ("cells", load.size),
+                         ("touched", (load > 0).sum()),
+                         ("ratio_sum", (top[live] / mean[live]).sum()),
+                         ("ratio_n", live.sum())):
+                t[k] = t.get(k, 0) + float(v)
+        if "selected" in c:
+            t["selected"] = t.get("selected", 0) + int(c["selected"])
+            t["queries"] = t.get("queries", 0) + int(c["queries"])
 
     def close(self, drain=True, timeout=60.0):
         """Stop the scheduler.  ``drain=True`` serves everything already
@@ -1529,7 +1674,8 @@ class DecodeServer:
                              telemetry_label=self.telemetry_label,
                              page_size=self.page_size,
                              num_pages=new_pages,
-                             kv_dtype=self.kv_dtype)
+                             kv_dtype=self.kv_dtype,
+                             max_chunk=self.prefill_buckets[-1])
         # the old pool's in-flight readbacks refer to old slot indices
         # and page ids; they stay valid — slots and pages only ever grow
         self._progs = progs
@@ -1541,6 +1687,8 @@ class DecodeServer:
         with self._lock:
             self._slots.extend([None] * (new_s - S))
         self._slot_pages.extend([] for _ in range(new_s - S))
+        self._slot_wpages.extend({} for _ in range(new_s - S))
+        self._slot_pos.extend([0] * (new_s - S))
         self._count("pool_grows")
 
     def _admit_pending(self):
@@ -1724,6 +1872,12 @@ class DecodeServer:
         # its own page writes; f32 pools ignore the operand.
         zpages = onp.full((A, self._progs.maxp), self._progs.num_pages,
                           onp.int32)
+        if self._wpages is not None:
+            # a windowed model's wave runs through each row's own table
+            # rows: the pair (main rows, window rings)
+            zpages = (zpages, self._window_table(
+                [slot for slot, _ in wave], A))
+        zmain = zpages[0] if self._wpages is not None else zpages
         for i, (slot, req) in enumerate(wave):
             n = req.prompt.size
             prompts[i, :n] = req.prompt
@@ -1736,7 +1890,7 @@ class DecodeServer:
             row = self._slot_pages[slot]
             k = min(npb, len(row))
             pages[i, :k] = row[:k]
-            zpages[i, :len(row)] = row
+            zmain[i, :len(row)] = row
         # request-span admission fields + one serve_admit event per
         # dispatch (waves are step-boundary-rare, not per-token)
         now = time.perf_counter()
@@ -1768,13 +1922,8 @@ class DecodeServer:
             return
         self._count("admit_dispatches")
         self._inflight.append(("admit", (first, done), list(wave), seq))
-        if self._prefix is not None:
-            # index the wave's FULL prompt pages for future COW hits
-            # (device-written by the dispatch just queued; any
-            # consumer's read is a later dispatch on the same stream)
-            for slot, req in wave:
-                self._prefix.register(req.prompt, req.prompt.size,
-                                      self._slot_pages[slot])
+        for slot, req in wave:
+            self._prompt_landed(slot, req)
 
     # paged admission planning ------------------------------------------- #
     def _alloc_pages(self, n, protect=()):
@@ -1795,6 +1944,87 @@ class DecodeServer:
         self._slot_pages[slot] = []
         for p in row:
             self._pages.decref(p)
+        held = self._slot_wpages[slot]
+        self._slot_wpages[slot] = {}
+        for p in held.values():
+            self._wpages.decref(p)
+
+    # the window pool ------------------------------------------------------ #
+    def _window_take(self, slot, first_pos, last_pos, tail=None):
+        """Make ``slot`` hold a window page for every logical page from
+        ``first_pos``'s to ``last_pos``'s: out of ``tail`` (the prefix
+        index's pages for a cached prompt's end, shared read-only) where
+        it has them, fresh ones otherwise; the index's least recently
+        touched tails make room when the pool is dry.  False, with nothing
+        taken, when even that is not enough."""
+        PG = self._progs.page
+        held = self._slot_wpages[slot]
+        lps = [lp for lp in range(max(first_pos, 0) // PG,
+                                  last_pos // PG + 1) if lp not in held]
+        fresh = [lp for lp in lps if tail is None or lp not in tail]
+        got = self._wpages.alloc(len(fresh))
+        if got is None and self._prefix is not None:
+            self._prefix.evict_tails(len(fresh) - self._wpages.free_pages)
+            got = self._wpages.alloc(len(fresh))
+        if got is None:
+            return False
+        held.update(zip(fresh, got))
+        for lp in lps:
+            if lp not in held:
+                held[lp] = tail[lp]
+                self._wpages.incref(tail[lp])
+        return True
+
+    def _window_need(self, slot, first_pos, last_pos, tail=None):
+        if not self._window_take(slot, first_pos, last_pos, tail):
+            raise MXNetError(
+                f"serve window pool exhausted: {self._wpages.num_pages} "
+                "pages cannot hold every live slot's window and the "
+                "chunk in flight — raise num_window_pages or pin fewer "
+                "slots / smaller prefill buckets")
+
+    def _window_release(self, slot, next_pos):
+        """Let go of the window pages behind what a query at ``next_pos``
+        can still reach."""
+        held = self._slot_wpages[slot]
+        keep = next_pos // self._progs.page - self._wback
+        for lp in [lp for lp in held if lp < keep]:
+            self._wpages.decref(held.pop(lp))
+
+    def _prompt_landed(self, slot, req):
+        """The dispatch just queued writes the last of ``req``'s prompt
+        into ``slot``'s pages: index its FULL pages for future hits (any
+        consumer's read is a later dispatch on the same stream; a windowed
+        model's tail with them) and let the slot step from its end."""
+        L = int(req.prompt.size)
+        if self._prefix is not None:
+            self._prefix.register(
+                req.prompt, L, self._slot_pages[slot],
+                self._slot_wpages[slot]
+                if self._wpages is not None else None)
+        self._slot_entered(slot, L)
+
+    def _slot_entered(self, slot, next_pos):
+        """``slot`` steps from ``next_pos`` on (the host's mirror of the
+        device's position; a windowed model's pages follow it)."""
+        self._slot_pos[slot] = next_pos
+        if self._wpages is not None:
+            self._window_release(slot, next_pos)
+
+    def _window_table(self, slots, rows=None):
+        """The ``(rows, ring)`` window-table operand for ``slots`` in
+        order: each slot's pages at ``logical page % ring``, sentinel
+        elsewhere."""
+        progs = self._progs
+        ring = progs.ring
+        out = onp.full((len(slots) if rows is None else rows, ring),
+                       progs.window_pages, onp.int32)
+        for i, slot in enumerate(slots):
+            if slot is None:
+                continue
+            for lp, pg in self._slot_wpages[slot].items():
+                out[i, lp % ring] = pg
+        return out
 
     def _drop_chunk_record(self, slot):
         """Forget a mid-chunked-prefill slot (cancel/teardown paths)."""
@@ -1824,8 +2054,43 @@ class DecodeServer:
         PG = progs.page
         L = int(req.prompt.size)
         need = progs.pages_for(L + req.max_new)
-        m, shared = (self._prefix.match(req.prompt)
-                     if self._prefix is not None else (0, []))
+        windowed, tail = self._wpages is not None, None
+        if self._prefix is None:
+            m, shared = 0, []
+        elif windowed:
+            # a window page is never copied, so a match stops short of
+            # the whole prompt (the first write lands in a page of the
+            # slot's own), and where the index kept no tail for a length
+            # the chain is cut back to one it did, or missed
+            m, shared, tail = self._prefix.match(req.prompt,
+                                                 limit=(L - 1) // PG)
+        else:
+            m, shared = self._prefix.match(req.prompt)
+        plan = self._plan_pages(req, slot, L, need, m, shared)
+        if plan is None:
+            return None
+        if windowed:
+            # the window pages the admission dispatch itself reads and
+            # writes: an admit's whole prompt, a hit's tail; a chunk takes
+            # its own as it goes
+            first = 0 if plan["mode"] == "admit" else m * PG
+            last = L - 1 if plan["mode"] == "admit" else m * PG - 1
+            if not self._window_take(slot, first - self._wback * PG
+                                     if m else first, last, tail):
+                self._free_slot_pages(slot)
+                return None
+            if plan["mode"] == "hit":
+                self._slot_entered(slot, L - 1)
+        self._prompt_tokens += L
+        if plan["mode"] != "admit":
+            self._prompt_cached += m * PG
+        return plan
+
+    def _plan_pages(self, req, slot, L, need, m, shared):
+        """``_plan_admission``'s main-pool half: reserve the pages, map the
+        shared ones, name the mode."""
+        progs = self._progs
+        PG = progs.page
         if m and m * PG >= L - 1:
             # full hit.  The consumer enters at pos = L-1 and its first
             # step RE-WRITES that position's K/V — when the cached
@@ -1881,9 +2146,20 @@ class DecodeServer:
         progs = self._progs
         pt = onp.full((len(self._slots), progs.maxp), progs.num_pages,
                       onp.int32)
+        live = []
         for i, row in enumerate(self._slot_pages):
-            if row and i not in self._chunk_slots:
-                pt[i, :len(row)] = row
+            ok = bool(row) and i not in self._chunk_slots
+            live.append(i if ok else None)
+            if ok:
+                # a slot's row is fixed from admission to retirement: its
+                # array is made once
+                ent = self._pt_rows.get(i)
+                if ent is None or ent[0] is not row:
+                    ent = self._pt_rows[i] = (row, onp.asarray(row,
+                                                               onp.int32))
+                pt[i, :len(row)] = ent[1]
+        if self._wpages is not None:
+            return pt, self._window_table(live)
         return pt
 
     def _dispatch_hits(self, hits):
@@ -1994,7 +2270,8 @@ class DecodeServer:
         else:
             C = _bucket_for(self.prefill_buckets, remaining)
             final, ntok = True, remaining
-        fn = self._progs.chunk_fn(C)
+        fn = self._progs.chunk_fn(
+            C, self._progs.key_pages_for(C, off + ntok))
         self._watch_dispatch(fn)
         toks = onp.zeros((C,), onp.int32)
         toks[:ntok] = req.prompt[off:off + ntok]
@@ -2009,6 +2286,11 @@ class DecodeServer:
                          onp.int32)
         row = self._slot_pages[slot]
         ptrow[:len(row)] = row
+        if self._wpages is not None:
+            # the window pages this slice writes (those it reads behind
+            # its offset the slot still holds, or took from a tail)
+            self._window_need(slot, off, off + ntok - 1)
+            ptrow = (ptrow, self._window_table([slot])[0])
         # int8 recycled-page reset operand: the slot's freshly
         # allocated pages ride the FIRST chunk dispatch only (their
         # stale scales must be zeroed before the first RMW floors on
@@ -2039,11 +2321,11 @@ class DecodeServer:
             req.span.update(queue_wait_s=wait, wave=1, a_bucket=1,
                             p_bucket=C, admit_seq=seq)
             self._tele["wait"].observe(wait)
-            if self._prefix is not None:
-                self._prefix.register(req.prompt, L,
-                                      self._slot_pages[slot])
+            self._prompt_landed(slot, req)
             self._inflight.append(("admit", (first, done),
                                    [(slot, req)], seq))
+        elif self._wpages is not None:
+            self._window_release(slot, rec["off"])
         return final
 
     # speculative decoding -------------------------------------------------- #
@@ -2129,10 +2411,23 @@ class DecodeServer:
         # call — never a retrace), against which the executable checks
         # every slot's deadline
         now = onp.float32(self._clock() - self._epoch)
+        stepping = [i for i, r in enumerate(self._slots)
+                    if r is not None and i not in self._chunk_slots]
+        if self._wpages is not None:
+            # each stepping slot writes at its position: take that page
+            # if it is a new one, let go of what fell out of the window
+            for i in stepping:
+                p = self._slot_pos[i]
+                self._window_need(i, p, p)
+                self._window_release(i, p)
+                self._wheld_max = max(self._wheld_max,
+                                      len(self._slot_wpages[i]))
         seq = self._next_seq()
         self._phase("mx:serve:step", seq=seq)
         new_state, out = self._progs.step_fn()(
             param_vals, q8, sw, now, self._page_table(), *self._state)
+        for i in stepping:
+            self._slot_pos[i] += 1
         self._state = new_state
         if self._torn:
             # late completion of a wedged dispatch after watchdog
@@ -2208,8 +2503,10 @@ class DecodeServer:
                 self._route_verify(arrays, meta, seq)
             else:
                 self._phase("mx:serve:drain_wait", cause=seq)
-                toks, emitted, done = (onp.asarray(a) for a in arrays)
+                toks, emitted, done = (onp.asarray(a) for a in arrays[:3])
                 self._phase("mx:serve:route", cause=seq)
+                if len(arrays) > 3:
+                    self._add_step_counters(arrays[3])
                 snapshot = meta
                 for slot, req in enumerate(snapshot):
                     if req is None or req.cancelled \

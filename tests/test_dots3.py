@@ -1,0 +1,435 @@
+"""``models.dots3`` against the plain float32 reference
+(``chipbench/reference_dots3.py``) on seeded weights, at a tiny size on the
+CPU, comparing LOGITS: the full forward; prefill then decode through the
+paged pools, past the window and past a tiny ``index_topk`` (and equal to
+dense attention below it); a chunk against a cached prefix; hit against miss;
+the window pool's bound; the eight expert shares; the router's bias.
+
+Tolerance ``TOL``: program and reference are both float32 here and differ in
+the ORDER of their sums only (absorbed against expanded latent attention, a
+gather of selected rows against a masked dense softmax, a grouped product
+against every expert for every token): logits of magnitude 3-4 agree to a few
+1e-6, and 2e-4 leaves two orders of room while a dropped term (a missing
+gate, rope on the wrong half, one expert left out) moves them by 1e-2 or
+more.
+"""
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import mxnet_tpu as mx
+from chipbench import reference_dots3 as ref
+from chipbench import weights_dots3
+from mxnet_tpu import models, serve
+from mxnet_tpu.models import decoding, dots3, layered
+from mxnet_tpu.ops import moe
+from mxnet_tpu.serve import schema
+
+TOL = 2e-4
+
+
+def _build(seed=7, **over):
+    net, cfg = dots3.dots3_tiny(**over)
+    net.collect_params().setattr("grad_req", "null")
+    net.initialize(mx.init.Zero())
+    w = weights_dots3.make(dots3.parameter_shapes(cfg), seed,
+                           {"score_gain": 0.7, "expert_out_gain": 3.0})
+    for n, p in net.collect_params().items():
+        p.set_data(w[n[len(net.prefix):]])
+    rcfg = {k: getattr(cfg, k) for k in cfg.__dataclass_fields__}
+    return net, cfg, w, rcfg
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    return _build(held_experts=(4, 8))
+
+
+def _tokens(n, seed=0, rows=None):
+    shape = (n,) if rows is None else (rows, n)
+    return np.random.default_rng(seed).integers(0, 96, shape).astype(
+        np.int32)
+
+
+@functools.partial(jax.jit, static_argnames=("frozen",))
+def _ref_jit(w, toks, frozen):
+    return ref.full_logits(w, dict(frozen), toks)
+
+
+def _ref_logits(w, rcfg, toks, pad=None):
+    """The reference's logits of ``toks``, jitted once a length; ``pad``
+    right-pads to one length for every caller (a causal model's earlier
+    rows do not see the padding)."""
+    toks = np.asarray(toks, np.int32)
+    n = toks.size
+    if pad is not None:
+        toks = np.concatenate([toks, np.zeros(pad - n, np.int32)])
+    return np.asarray(_ref_jit(w, jnp.asarray(toks), ref.freeze(rcfg)))[:n]
+
+
+def _is_ref_stream(w, rcfg, prompt, served):
+    """Is ``served`` the reference's greedy stream after ``prompt``?  One
+    teacher-forced pass: every served token is the reference's first choice
+    at its position (in float32, up to exact ties, the same claim as
+    decoding the reference token by token)."""
+    z = _ref_logits(w, rcfg, np.concatenate([prompt, served[:-1]]),
+                    pad=128)
+    want = z[len(prompt) - 1:].argmax(-1)
+    return list(want) == list(served)
+
+
+# --------------------------------------------------------------------------- #
+# the forward pass
+# --------------------------------------------------------------------------- #
+
+@pytest.fixture(params=["gather_form", "dense_form"])
+def form(request, monkeypatch):
+    """The selecting attention's two forms: rows gathered by position
+    (decode steps, short chunks) and every cached row scored under the
+    selection's mask (chunks of ``dense_chunk`` queries and more; 256 as
+    served, 8 here so that a 32-token chunk takes it)."""
+    if request.param == "dense_form":
+        monkeypatch.setattr(layered.LayeredEngine, "dense_chunk", 8)
+    return request.param
+
+
+@pytest.mark.parametrize("index_topk", [8, 4096],
+                         ids=["sparse_top8", "dense_below_topk"])
+def test_full_forward_matches_reference(index_topk, form):
+    """40 positions: past the window of 9 and, at top-8, past the indexer's
+    budget; at top-4096 every position is selected and the layer IS dense
+    latent attention."""
+    net, cfg, w, rcfg = _build(index_topk=index_topk)
+    toks = _tokens(40, rows=2)
+    out = np.asarray(net(jnp.asarray(toks)))
+    assert out.shape == (2, 40, 96)
+    for b in range(2):
+        np.testing.assert_allclose(out[b], _ref_logits(w, rcfg, toks[b]),
+                                   atol=TOL, rtol=0)
+
+
+def test_sparse_selection_changes_the_answer(tiny):
+    """The comparison can tell top-8 from dense: the two references part by
+    far more than ``TOL`` once there are more than 8 positions."""
+    _, _, w, rcfg = tiny
+    toks = _tokens(40)
+    a = _ref_logits(w, rcfg, toks)
+    b = _ref_logits(w, dict(rcfg, index_topk=4096), toks)
+    np.testing.assert_allclose(a[:8], b[:8], atol=TOL, rtol=0)
+    assert np.abs(a[16:] - b[16:]).max() > 100 * TOL
+
+
+def test_reference_tail_equals_its_full_pass(tiny):
+    _, _, w, rcfg = tiny
+    toks = _tokens(48, seed=3)
+    full = _ref_logits(w, rcfg, toks)
+    tail = np.asarray(jax.jit(lambda w, t: ref.tail_logits(
+        w, rcfg, t, 44, 6))(w, jnp.asarray(toks)))
+    np.testing.assert_allclose(tail, full[38:44], atol=1e-5, rtol=0)
+    assert ref.tail_rows(rcfg, 48, 6) == [48, 30, 22, 14, 6]
+
+
+@pytest.mark.parametrize("chunk", [8, 5], ids=["aligned", "ragged"])
+def test_paged_prefill_then_decode_logits(tiny, chunk):
+    """Prefill in chunks, then one token at a time to 48 positions, through
+    scattered pages and a ring of 6 window pages (the window needs 4): the
+    logits of every position against the reference's full pass."""
+    net, cfg, w, rcfg = tiny
+    page, T = 4, 48
+    eng = layered.LayeredEngine(net, 1, 1, T)
+    weights = net.weights()
+    toks = _tokens(T, seed=5)
+    want = _ref_logits(w, rcfg, toks)
+    ring = eng.window_span_pages(page, chunk) + 1
+    perm = np.random.default_rng(1).permutation(16)[:T // page]
+    ptm = jnp.asarray(perm[None].astype(np.int32))
+    pools = eng.pool_zeros(16, ring, page)
+    ptw = jnp.asarray(np.arange(ring, dtype=np.int32)[None])
+    run = jax.jit(lambda tk, off, pools, last: eng.tokens_paged(
+        weights, tk, off, (ptm, ptw), pools, page, last)[:3])
+    pos, prefill = 0, 24
+    while pos < T:
+        n = min(chunk, prefill - pos) if pos < prefill else 1
+        logits, kp, vp = run(jnp.asarray(toks[None, pos:pos + n]),
+                             jnp.asarray([pos], jnp.int32), pools,
+                             jnp.asarray([n - 1], jnp.int32))
+        pools = (kp, vp)
+        pos += n
+        np.testing.assert_allclose(np.asarray(logits)[0], want[pos - 1],
+                                   atol=TOL, rtol=0)
+
+
+# --------------------------------------------------------------------------- #
+# through DecodeServer
+# --------------------------------------------------------------------------- #
+
+def _server(net, **over):
+    kw = dict(max_total_len=128, pool_sizes=(4,), admit_sizes=(1, 2),
+              prefill_buckets=(8, 32), page_size=4, num_pages=96,
+              num_window_pages=64, spec=False, autostart=False)
+    kw.update(over)
+    return serve.DecodeServer(net, **kw)
+
+
+def _drain(srv, streams):
+    for _ in range(400):
+        if all(s.done for s in streams):
+            break
+        srv.pump()
+    return [s.tokens(timeout=0) for s in streams]
+
+
+def test_served_streams_match_reference(tiny, form):
+    """Admit waves (5 and 13 tokens, 21 in the wide bucket), chunked prefill
+    (50 and 100 tokens: the long chunks' reach grows by quarters of the
+    table) and 12 decode steps each: token for token the reference's greedy
+    stream (float32: identical up to exact ties)."""
+    net, _, w, rcfg = tiny
+    srv = _server(net)
+    long_one = _tokens(100, seed=100)
+    got, = _drain(srv, [srv.submit(long_one, max_new_tokens=6)])
+    assert _is_ref_stream(w, rcfg, long_one, got)
+    if form == "dense_form":
+        # 32 pages of table: the 32-token chunks were compiled for a reach
+        # of 8, 16 and 24 pages as the prompt streamed in
+        assert {k for k in srv._progs._chunks if k[0] == 32} == {
+            (32, 8), (32, 16), (32, 24)}
+    else:
+        assert set(srv._progs._chunks) <= {(8, None), (32, None)}
+    assert not srv.sync_mode and srv._progs.layered
+    prompts = [_tokens(n, seed=n) for n in (5, 21, 50, 13)]
+    got = _drain(srv, [srv.submit(p, max_new_tokens=12) for p in prompts])
+    for p, g in zip(prompts, got):
+        assert len(g) == 12 and _is_ref_stream(w, rcfg, p, g)
+    c = srv.stats()["counters"]
+    assert c["admit_dispatches"] >= 1 and c["chunk_dispatches"] >= 2
+    assert c["step_dispatches"] == srv.stats()["steps"]
+    srv.close()
+
+
+@pytest.mark.parametrize("extra", [0, 7, 1], ids=["same", "question",
+                                                  "one_more"])
+def test_hit_and_miss_streams_identical(tiny, extra):
+    """A cached 50-token document, then the document (+ a question): the
+    prefix pages are mapped read-only, the window enters from the tail the
+    index kept, only the rest is chunked — and the stream is the miss's."""
+    net, _, w, rcfg = tiny
+    doc = _tokens(50, seed=50)
+    prompt = np.concatenate([doc, _tokens(extra, seed=9)])
+    miss = _server(net, prefix_cache=False)
+    want, = _drain(miss, [miss.submit(prompt, max_new_tokens=10)])
+    miss.close()
+    assert len(want) == 10 and _is_ref_stream(w, rcfg, prompt, want)
+    srv = _server(net)
+    _drain(srv, [srv.submit(doc, max_new_tokens=1)])
+    srv.reset_counters()
+    st0 = srv.stats()
+    got, = _drain(srv, [srv.submit(prompt, max_new_tokens=10)])
+    assert got == want
+    st = srv.stats()
+    assert st["counters"]["prefix_hits"] == 1
+    assert st["counters"]["admit_dispatches"] == 0
+    cached = st["prompt_tokens_cached"] - st0["prompt_tokens_cached"]
+    # whole pages short of the whole prompt: a window page is never copied
+    assert cached == min(50 // 4, (prompt.size - 1) // 4) * 4
+    srv.close()
+
+
+def test_match_is_cut_back_where_no_tail_was_kept(tiny):
+    """The index's chain for a prompt is only enterable where a tail covers
+    the window in front of it: with the tails evicted the same prompt
+    misses (a chunked prefill from 0), and still serves the same stream."""
+    net, _, w, rcfg = tiny
+    doc = _tokens(50, seed=51)
+    srv = _server(net)
+    first, = _drain(srv, [srv.submit(doc, max_new_tokens=6)])
+    assert srv.stats()["prefix_tails"] >= 1
+    srv._prefix.evict_tails(10 ** 6)
+    assert srv.stats()["prefix_tails"] == 0
+    assert srv._prefix.match(doc, limit=12) == (0, [], {})
+    srv.reset_counters()
+    again, = _drain(srv, [srv.submit(doc, max_new_tokens=6)])
+    assert again == first and _is_ref_stream(w, rcfg, doc, first)
+    assert srv.stats()["counters"]["prefix_hits"] == 0
+    srv.close()
+
+
+@pytest.mark.parametrize("prefix_cache", [False, True])
+def test_window_pages_released_and_bounded(tiny, prefix_cache):
+    """A slot holds window pages for its window only (9 positions = at most
+    4 pages of 4 with the one being written), whatever its length; retired
+    slots hold none; what the index keeps are whole tails."""
+    net, _, _, _ = tiny
+    srv = _server(net, prefix_cache=prefix_cache)
+    streams = [srv.submit(_tokens(n, seed=n), max_new_tokens=60)
+               for n in (50, 9, 30)]
+    peak = 0
+    for _ in range(400):
+        if all(s.done for s in streams):
+            break
+        srv.pump()
+        peak = max(peak, srv.stats()["window_pages_in_use"])
+    st = srv.stats()
+    assert 0 < st["window_pages_slot_max"] <= st["window_pages_slot_bound"]
+    assert st["window_pages_slot_bound"] == 4
+    # three live slots, a chunk of 32 in flight and three tails at most
+    assert peak <= 3 * 4 + 8 + 3 * 3
+    held = {p for t in (srv._prefix._tails.values() if prefix_cache else ())
+            for p in t["tail"].values()}
+    assert st["window_pages_in_use"] == len(held)
+    assert all(not d for d in srv._slot_wpages)
+    srv.close()
+    assert srv._wpages.in_use == 0 and srv._pages.in_use == 0
+
+
+def test_window_pool_too_small_fails_loudly(tiny):
+    net, _, _, _ = tiny
+    srv = _server(net, num_window_pages=3, prefix_cache=False)
+    s = srv.submit(_tokens(50, seed=1), max_new_tokens=4)
+    with pytest.raises(mx.base.MXNetError, match="window pool exhausted"):
+        for _ in range(50):
+            srv.pump()
+    assert s.done or True
+    srv.close(drain=False)
+
+
+def test_step_counters_reach_stats(tiny):
+    net, _, _, _ = tiny
+    srv = _server(net)
+    _drain(srv, [srv.submit(_tokens(20, seed=2), max_new_tokens=16)])
+    st = srv.stats()
+    assert st["selected_keys_per_query"] == pytest.approx(8.0, abs=0.3)
+    assert 0.0 < st["moe_experts_touched_share"] <= 1.0
+    assert st["moe_load_max_over_mean"] >= 1.0
+    # 4 of 16 experts a token, 8 of them held: half a token's choices
+    assert 0.05 < st["moe_tokens_per_expert_step"] < 1.0
+    srv.close()
+
+
+def test_speculation_is_refused_loudly(tiny):
+    net, _, _, _ = tiny
+    with pytest.raises(mx.base.MXNetError, match="draft-and-verify"):
+        _server(net, spec=True)
+    srv = _server(net)
+    assert srv.spec_enabled is False
+    with pytest.raises(mx.base.MXNetError, match="draft-and-verify"):
+        srv._progs.verify_fn(2)
+    srv.close()
+
+
+# --------------------------------------------------------------------------- #
+# the description, the row kinds, the routed layer
+# --------------------------------------------------------------------------- #
+
+def test_description_drives_the_engine(tiny):
+    net, cfg, _, _ = tiny
+    desc = decoding.layer_description(net)
+    assert [d["cache"] for d in desc] == ["latent_index"] * 2 \
+        + ["latent_window"] * 3
+    assert [d["ffn"]["kind"] for d in desc] == ["swiglu"] + ["routed"] * 4
+    assert desc[1]["ffn"]["held"] == (4, 8) \
+        and desc[1]["ffn"]["experts"] == 16
+    eng = decoding.decode_engine(net, 2, 1, 32, 0.0, 0, "batched", "native",
+                                 "off", "auto")
+    assert isinstance(eng, layered.LayeredEngine)
+    assert schema.pool_rows("latent_index") == ("main",
+                                                ("latent", "index_key"))
+    assert schema.pool_rows("latent_window") == ("window", ("latent",))
+
+
+@pytest.mark.parametrize("family", ["gpt", "llama"])
+def test_uniform_families_stay_on_the_stacked_scan(family):
+    if family == "gpt":
+        net = models.GPT(models.GPTConfig(vocab_size=64, num_layers=2,
+                                          units=32, num_heads=2,
+                                          hidden_size=64, max_length=32))
+    else:
+        net, _ = models.llama_tiny()
+    net.initialize()
+    desc = decoding.layer_description(net)
+    assert {d["cache"] for d in desc} == {"kv"}
+    assert schema.pool_rows("kv") == ("main", ("k", "v"))
+    eng = decoding.decode_engine(net, 2, 1, 32, 0.0, 0, "batched", "native",
+                                 "off", "auto")
+    assert type(eng) is decoding._DecodeEngine and eng.mode == "stacked"
+
+
+@pytest.mark.parametrize("width,lanes", [(576, 640), (1088, 1152),
+                                         (128, 128), (64, 128)])
+def test_rows_are_whole_lane_tiles(width, lanes):
+    """The published rows (512 + 64, 1024 + 64) are not multiples of the
+    128-lane tile: they are stored padded (PERF.md, PR 27)."""
+    assert schema.row_lanes(width) == lanes and lanes % schema.LANE_TILE == 0
+
+
+def test_expert_shares_add_up_to_the_uncut_layer():
+    """Eight chips' shares of the routed sum (2 of 16 experts each), with
+    the shared expert counted once, are the reference's uncut layer."""
+    net, cfg, w, rcfg = _build(held_experts=(0, 16))
+    lw = ref.layer_weights(w, 1)
+    x = jnp.asarray(np.random.default_rng(3).normal(size=(24, 32)),
+                    jnp.float32)
+    want = np.asarray(ref.ffn(rcfg, lw, False, x, ref.mm_f32))
+    h = layered._rms(x, lw["norm2_gamma"], cfg.rms_norm_eps)
+    idx, wts = moe.route(h, lw["router_weight"], lw["router_bias"], 4)
+    total, loads = moe.swiglu(h, lw["sgu_weight"], lw["sdown_weight"]), []
+    for lo in range(0, 16, 2):
+        y, load = moe.routed_experts(h, idx, wts, lw["egu_weight"][lo:lo + 2],
+                                     lw["edown_weight"][lo:lo + 2], lo)
+        total = total + y
+        loads.append(np.asarray(load))
+        # and one share alone is what the reference gives for that share
+        part = np.asarray(ref.ffn(dict(rcfg, held_experts=(lo, 2)), dict(
+            lw, egu_weight=lw["egu_weight"][lo:lo + 2],
+            edown_weight=lw["edown_weight"][lo:lo + 2]), False, x,
+            ref.mm_f32))
+        np.testing.assert_allclose(
+            np.asarray(y + moe.swiglu(h, lw["sgu_weight"],
+                                      lw["sdown_weight"])),
+            part, atol=TOL, rtol=0)
+    np.testing.assert_allclose(np.asarray(total), want, atol=TOL, rtol=0)
+    assert np.concatenate(loads).sum() == 24 * 4      # no token dropped
+
+
+def test_router_bias_chooses_and_does_not_weigh():
+    rng = np.random.default_rng(0)
+    h = jnp.asarray(rng.normal(size=(6, 32)), jnp.float32)
+    wr = jnp.asarray(rng.normal(size=(32, 16)) / 32 ** 0.5, jnp.float32)
+    plain_idx, _ = moe.route(h, wr, jnp.zeros(16), 4)
+    bias = jnp.zeros(16).at[3].set(10.0)
+    idx, wts = moe.route(h, wr, bias, 4)
+    idx, wts = np.asarray(idx), np.asarray(wts)
+    assert (idx == 3).any(axis=1).all()         # the bias put expert 3 in
+    assert not (np.asarray(plain_idx) == 3).any(axis=1).all()
+    s = np.asarray(jax.nn.sigmoid(h @ wr))
+    chosen = np.take_along_axis(s, idx, axis=1)
+    np.testing.assert_allclose(wts, chosen / chosen.sum(1, keepdims=True),
+                               atol=1e-6)
+    np.testing.assert_allclose(wts.sum(1), 1.0, atol=1e-6)
+
+
+@pytest.mark.parametrize("N,T,k,ties", [
+    (4, 300, 64, False), (3, 1000, 128, True), (5, 130, 130, False),
+    (2, 33152, 2048, False), (3, 500, 100, True)],
+    ids=["plain", "ties", "all", "cell_size", "ties_few_valid"])
+def test_top_positions_is_an_exact_top_k(N, T, k, ties):
+    """The sort-free selection is the stable top-k's SET, in ascending
+    position, whatever the ties and however few positions are valid."""
+    rng = np.random.default_rng(0)
+    s = rng.normal(size=(N, T)).astype(np.float32)
+    if ties:
+        s = np.round(s * 3) / 3
+    nvalid = rng.integers(1, T + 1, size=N)
+    nvalid[0], nvalid[-1] = T, min(T, k // 2 + 1)
+    valid = np.arange(T)[None] < nvalid[:, None]
+    got = np.asarray(jax.jit(layered.top_positions, static_argnums=2)(
+        jnp.asarray(s), jnp.asarray(valid), k))
+    assert got.shape == (N, k)
+    for n in range(N):
+        assert (np.diff(got[n]) > 0).all()
+        v = int(nvalid[n])
+        want = np.lexsort((np.arange(v), -s[n, :v]))[:min(k, v)]
+        assert set(got[n][got[n] < v].tolist()) == set(want.tolist())

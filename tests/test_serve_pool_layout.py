@@ -104,3 +104,100 @@ def test_admit_scratch_is_the_prefill_not_the_pool(reports, progs):
     """``serve.admit``'s scratch is its own dense prefill, not a copy of
     the pool (which is sized here to dwarf a 2 x 32 wave)."""
     assert reports["admit"]["temp_bytes"] < _pool_bytes(progs) // 4
+
+
+# --------------------------------------------------------------------------- #
+# pools of declared row kinds (a model served from its per-layer description:
+# latent rows and index keys under the main table, window rows under a ring)
+# --------------------------------------------------------------------------- #
+
+# pools too large for the chip to stage whole in its vector memory (a pool
+# of a few MB is prefetched there in one async copy, which no real pool is)
+L_SLOTS, L_TOTAL, L_PAGES, L_WPAGES = 4, 2048, 32768, 8192
+
+
+@pytest.fixture(scope="module")
+def layered_progs():
+    """Lane widths as the published model has them in kind — a 192 + 64
+    latent row, a 128-lane index key, a 320 + 64 window row, all padded to
+    whole tiles — at a small depth and hidden size."""
+    from mxnet_tpu.models import dots3
+    net, _ = dots3.dots3_tiny(
+        dtype="bfloat16", hidden_size=256, intermediate_size=384,
+        num_attention_heads=4, q_lora_rank=128, kv_lora_rank=192,
+        qk_nope_head_dim=64, qk_rope_head_dim=64, v_head_dim=64,
+        index_n_heads=4, index_head_dim=128, index_topk=64,
+        swa_num_attention_heads=4, swa_q_lora_rank=128, swa_kv_lora_rank=320,
+        swa_qk_nope_head_dim=64, swa_qk_rope_head_dim=64, swa_v_head_dim=64,
+        sliding_window_size=65, moe_intermediate_size=128, vocab_size=512,
+        vocab_slice=(0, 512), max_length=L_TOTAL)
+    net.collect_params().setattr("grad_req", "null")
+    net.initialize(mx.init.Zero())
+    return PoolPrograms(net, L_SLOTS, L_TOTAL, page_size=PAGE,
+                        num_pages=L_PAGES, window_pages=L_WPAGES,
+                        max_chunk=64)
+
+
+@pytest.fixture(scope="module")
+def layered_reports(chip, layered_progs):
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from tools import rehearse_serve as rs
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        p = layered_progs
+        return {
+            "step": rs.pool_report(rs.compile_step(p, chip), p),
+            "chunk": rs.pool_report(rs.compile_chunk(p, chip, 64), p),
+            "admit_hit": rs.pool_report(rs.compile_hit(p, chip, 2), p)}
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+def test_layered_pools_are_whole_lane_tiles(layered_progs):
+    import jax
+
+    from mxnet_tpu.serve.engine import pool_state_init
+    kp, vp = jax.eval_shape(lambda: pool_state_init(layered_progs))[:2]
+    shapes = [a.shape for a in jax.tree.leaves((kp, vp))]
+    assert shapes == [(2, L_PAGES, PAGE, 256), (2, L_PAGES, PAGE, 128),
+                      (3, L_WPAGES, PAGE, 384)]
+    assert all(s[-1] % 128 == 0 for s in shapes)
+    # priced as allocated: main pages, window pages, slot state
+    from mxnet_tpu.serve.engine import pool_state_bytes
+    want = sum(onp.prod(s) * 2 for s in shapes) + L_SLOTS * 29
+    assert pool_state_bytes(layered_progs, num_pages=L_PAGES) == want
+
+
+@pytest.mark.parametrize("which", ["step", "chunk", "admit_hit"])
+def test_layered_no_pass_over_a_whole_pool(layered_reports, which):
+    """Every pool-sized result is an in-place scatter into a donated pool:
+    no ``copy``, no fusion that rewrites one."""
+    sized = layered_reports[which]["pool_sized"]
+    assert set(sized) <= {"fusion:scatter", "scatter"}, sized
+    # the step and a chunk write a latent row and an index key a full layer
+    # and a window row a sliding layer; a hit copies main-table pages only
+    want = 2 if which == "admit_hit" else 2 + 2 + 3
+    assert sum(len(v) for v in sized.values()) == want, sized
+
+
+@pytest.mark.parametrize("which", ["step", "chunk", "admit_hit"])
+def test_layered_pools_keep_the_declared_layout(layered_reports, which):
+    layouts = layered_reports[which]["pool_entry_layouts"]
+    assert len(layouts) == 3, layered_reports[which]
+    for lay in layouts:
+        assert lay.split("{")[1].startswith("3,2,1,0"), lay
+
+
+@pytest.mark.parametrize("which", ["step", "chunk", "admit_hit"])
+def test_layered_scratch_is_no_second_pool(layered_reports, which):
+    """The scratch stays under a quarter of the SMALLEST pool (the index
+    keys, 268 MB here): no pool-sized copy can hide in it."""
+    smallest = 2 * L_PAGES * PAGE * 128 * 2
+    assert layered_reports[which]["temp_bytes"] < smallest // 4, \
+        layered_reports[which]

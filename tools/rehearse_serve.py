@@ -5,6 +5,8 @@ not attached, and say what the chip's compiler made of the K/V pool.
     JAX_PLATFORMS=cpu python tools/rehearse_serve.py
     JAX_PLATFORMS=cpu python tools/rehearse_serve.py --layers 4 --admit 4x768
     JAX_PLATFORMS=cpu python tools/rehearse_serve.py --kv-dtype int8 --hlo /tmp/hlo
+    JAX_PLATFORMS=cpu python tools/rehearse_serve.py \
+        --config chipbench/configs/dots3_note_serve.json
 
 Rehearsal 3 of the ``on-chip-measurement`` guide for ``serve.step`` and one
 ``serve.admit``: nothing runs, so this gives structure and bytes and never a
@@ -15,7 +17,11 @@ result is as large as the whole pool, by opcode — a ``copy`` or a plain
 fusion there is a pass over the whole pool every dispatch; the in-place
 scatters show as ``fusion:scatter``.  The defaults are
 the chip benchmark's serve configuration (GPT-2-large, 32 slots, 1,024
-pages of 16 tokens, bfloat16).  ``tests/test_serve_pool_layout.py`` holds a
+pages of 16 tokens, bfloat16).  ``--config`` names a chip-benchmark
+configuration file of a model served from its per-layer description (its
+``server`` group gives the pools): then the step, every chunk bucket and the
+hit admission are compiled, each against all of its pools (latent rows,
+index keys, window rows).  ``tests/test_serve_pool_layout.py`` holds a
 small engine to the same readings.
 """
 from __future__ import annotations
@@ -61,14 +67,30 @@ def _structs(tree, chip):
         tree)
 
 
-def pool_shape(progs):
-    """The shape of one K/V pool array of ``progs`` (an int8 pool's codes)."""
+def pool_shapes(progs):
+    """The shapes of the pool arrays of ``progs``: one for the uniform K/V
+    kind (K and V alike; an int8 pool's codes), one a declared row kind
+    otherwise."""
     import jax
 
     from mxnet_tpu.serve.engine import pool_state_init
 
-    kp = jax.eval_shape(lambda: pool_state_init(progs))[0]
-    return (kp[0] if isinstance(kp, tuple) else kp).shape
+    kp, vp = jax.eval_shape(lambda: pool_state_init(progs))[:2]
+    if not progs.layered:
+        return [(kp[0] if isinstance(kp, tuple) else kp).shape]
+    return [a.shape for a in jax.tree.leaves((kp, vp))]
+
+
+def _tables(progs, *lead):
+    """The page-table operand of ``lead`` rows: the main table, paired
+    with the window ring where the model keeps a window."""
+    import jax
+    import jax.numpy as jnp
+
+    main = jax.ShapeDtypeStruct((*lead, progs.maxp), jnp.int32)
+    if progs.window is None:
+        return main
+    return main, jax.ShapeDtypeStruct((*lead, progs.ring), jnp.int32)
 
 
 def compile_step(progs, chip):
@@ -80,9 +102,42 @@ def compile_step(progs, chip):
 
     state = jax.eval_shape(lambda: pool_state_init(progs))
     now = jax.ShapeDtypeStruct((), jnp.float32)
-    pt = jax.ShapeDtypeStruct((progs.S, progs.maxp), jnp.int32)
-    args = (*progs.operands, now, pt, *state)
+    args = (*progs.operands, now, _tables(progs, progs.S), *state)
     return progs.step_fn().lower(*_structs(args, chip)).compile()
+
+
+def compile_chunk(progs, chip, c_bucket):
+    """``serve.chunk`` of ``c_bucket`` tokens, likewise."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.serve import schema
+    from mxnet_tpu.serve.engine import pool_state_init
+
+    C = int(c_bucket)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
+    state = jax.eval_shape(lambda: pool_state_init(progs))
+    args = (*progs.operands, i32(C), i32(schema.meta_width("chunk")),
+            jax.ShapeDtypeStruct((), jnp.float32), _tables(progs),
+            i32(progs.maxp), *state)
+    return progs.chunk_fn(C).lower(*_structs(args, chip)).compile()
+
+
+def compile_hit(progs, chip, a_bucket):
+    """``serve.admit_hit`` of ``a_bucket`` rows, likewise."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.serve import schema
+    from mxnet_tpu.serve.engine import pool_state_init
+
+    A = int(a_bucket)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
+    state = jax.eval_shape(lambda: pool_state_init(progs))
+    args = (i32(A, schema.meta_width("hit")),
+            jax.ShapeDtypeStruct((A,), jnp.float32), i32(A), i32(A),
+            i32(A, progs.maxp), *state)
+    return progs.admit_hit_fn(A).lower(*_structs(args, chip)).compile()
 
 
 def compile_admit(progs, chip, a_bucket, p_bucket):
@@ -99,7 +154,7 @@ def compile_admit(progs, chip, a_bucket, p_bucket):
     args = (progs.operands[0], i32(A, P),
             i32(A, schema.meta_width("admit")),
             jax.ShapeDtypeStruct((A,), jnp.float32),
-            i32(A, progs.pages_for(P)), i32(A, progs.maxp), *state)
+            i32(A, progs.pages_for(P)), _tables(progs, A), *state)
     return progs.admit_fn(A, P).lower(*_structs(args, chip)).compile()
 
 
@@ -112,14 +167,14 @@ def pool_report(compiled, progs):
     as ``{label: [names]}``.  A fusion's label is ``fusion:<opcode of its
     root>``; what only carries the pool (parameter, tuple, bitcast, the
     ``while`` it rides through) is left out."""
-    shape = pool_shape(progs)
-    dims = "[" + ",".join(str(d) for d in shape) + "]"
-    n_pool = math.prod(shape)
+    shapes = pool_shapes(progs)
+    dims = ["[" + ",".join(str(d) for d in shape) + "]" for shape in shapes]
+    n_pools = {math.prod(shape) for shape in shapes}
     text = compiled.as_text()
     entry = re.search(r"entry_computation_layout=\{\((.*?)\)->", text)
-    layouts = sorted(set(re.findall(
-        r"[a-z0-9]+" + re.escape(dims) + r"\{[^}]*\}",
-        entry.group(1) if entry else "")))
+    layouts = sorted({m for d in dims for m in re.findall(
+        r"[a-z0-9]+" + re.escape(d) + r"\{[^}]*\}",
+        entry.group(1) if entry else "")})
     roots, found, comp = {}, [], None
     for line in text.splitlines():
         c = _COMPUTATION.match(line)
@@ -133,7 +188,7 @@ def pool_report(compiled, progs):
             roots[comp] = m.group("op")
         sizes = [math.prod(int(d) for d in a.split(",") if d)
                  for a in _ARRAY.findall(m.group("type"))]
-        if n_pool in sizes and m.group("op") not in _CARRIERS:
+        if n_pools & set(sizes) and m.group("op") not in _CARRIERS:
             called = re.search(r"calls=%?([\w.\-]+)", line)
             found.append((comp, m.group("op"), m.group("name"),
                           called.group(1) if called else None))
@@ -150,7 +205,8 @@ def pool_report(compiled, progs):
             "argument_bytes": ma.argument_size_in_bytes,
             "output_bytes": ma.output_size_in_bytes,
             "alias_bytes": ma.alias_size_in_bytes,
-            "pool_dims": dims, "pool_entry_layouts": layouts,
+            "pool_dims": dims[0] if len(dims) == 1 else dims,
+            "pool_entry_layouts": layouts,
             "pool_sized": sized}
 
 
@@ -171,7 +227,13 @@ def main(argv=None):
                     help="AxP wave to compile beside the step; '' for none")
     ap.add_argument("--hlo", default=None,
                     help="directory to write each optimized HLO text to")
+    ap.add_argument("--config", default=None,
+                    help="a chipbench configuration of a model served from "
+                         "its per-layer description, in place of the GPT-2 "
+                         "arguments")
     args = ap.parse_args(argv)
+    if args.config:
+        return _rehearse_config(args)
 
     import mxnet_tpu as mx
     from mxnet_tpu import models
@@ -192,14 +254,45 @@ def main(argv=None):
         a, p = (int(v) for v in args.admit.split("x"))
         todo.append((f"serve.admit({a},{p})",
                      lambda: compile_admit(progs, chip, a, p)))
+    return _report(todo, progs, args.hlo)
+
+
+def _rehearse_config(args):
+    """The step, every chunk bucket and the hit admission of the
+    configuration file's model at its own pools."""
+    import mxnet_tpu as mx
+    from chipbench import dots3, harness
+    from mxnet_tpu.serve.engine import PoolPrograms
+
+    config = harness.read_json(args.config)
+    net, _ = dots3.build(config)
+    net.collect_params().setattr("grad_req", "null")
+    net.initialize(mx.init.Zero())
+    srv = config["server"]
+    progs = PoolPrograms(net, srv["pool_sizes"][0], srv["max_total_len"],
+                         page_size=srv.get("page_size", 16),
+                         num_pages=srv["num_pages"],
+                         window_pages=srv.get("num_window_pages"),
+                         max_chunk=srv["prefill_buckets"][-1])
+    chip = v5e_chip()
+    todo = [("serve.step", lambda: compile_step(progs, chip))]
+    todo += [(f"serve.chunk({c})",
+              lambda c=c: compile_chunk(progs, chip, c))
+             for c in srv["prefill_buckets"]]
+    todo.append((f"serve.admit_hit({srv['admit_sizes'][0]})",
+                 lambda: compile_hit(progs, chip, srv["admit_sizes"][0])))
+    return _report(todo, progs, args.hlo)
+
+
+def _report(todo, progs, hlo):
     for name, build in todo:
         t0 = time.time()
         compiled = build()
         row = {"executable": name, **pool_report(compiled, progs),
                "compile_s": round(time.time() - t0, 1)}
-        if args.hlo:
-            os.makedirs(args.hlo, exist_ok=True)
-            path = os.path.join(args.hlo, re.sub(r"\W+", "_", name) + ".hlo")
+        if hlo:
+            os.makedirs(hlo, exist_ok=True)
+            path = os.path.join(hlo, re.sub(r"\W+", "_", name) + ".hlo")
             with open(path, "w") as fh:
                 fh.write(compiled.as_text())
             row["hlo"] = path

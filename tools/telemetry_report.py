@@ -538,7 +538,10 @@ def check_serve(events):
         slots = st.get("num_slots")
         if None in (pb, page_bytes, total, slots) or pb == 0:
             continue   # sync mode / torn-down pool: nothing resident
-        priced = total * page_bytes
+        # a model with windowed layers keeps a second pool, priced by
+        # its own page size (absent from older recordings: 0)
+        priced = total * page_bytes + (st.get("window_pages_total") or 0) \
+            * (st.get("window_page_bytes") or 0)
         if schema is not None:
             # the slot-state layout declaration is on hand: the scalar
             # state must price to EXACTLY slots * slot_state_bytes()
