@@ -66,6 +66,7 @@ __all__ = ["kv_generate", "decode_mode", "decode_step_program"]
 # ``params_swapped`` site (kv_generate, the serving loop, _CachedOp);
 # defined next to the swap it guards.
 from ..gluon.parameter import _TRACE_LOCK
+from ..ops import paged_attention as _paged
 
 
 def _call(layer, *vals):
@@ -887,24 +888,38 @@ class _DecodeEngine:
         slot reads/writes them through its page-table row ``pt[b]``
         (``pt``: (B, MAXP) int32, a TRACED operand — allocation churn
         changes table VALUES, never shapes, so no retrace).  The layer
-        scan runs over the layer NUMBER with the pools closed over:
-        layer ``l`` gathers its pages at ``(l, page id)`` straight out
-        of the whole pool into the ``(B, T, KV·D)`` row view, the new
-        row lands at ``(b, pos[b])``, attention contracts the stored
-        rows (``_flat_attention``), and after the scan all layers' new
-        rows scatter at ``(l, page, row)`` — in place on the donated
-        pools.  Rows of retired/idle slots hold the one-past-the-end
-        sentinel ``NPAGES``: their scatters DROP — a freed page can
-        never be corrupted by a slot that no longer owns it — and their
-        gathers clamp onto the last page, read garbage and are masked
-        by the caller, as a live slot's columns past ``pos`` (the stale
-        tail of its frontier page, a sentinel entry) are by the position
-        mask: weight exactly 0.  Token order is ``t = j * page + o``
+        scan runs over the layer NUMBER with the pools closed over.
+        Where ``walks_pages`` holds (a native pool of whole tiles) and
+        the step is lowered for a TPU, layer ``l`` WALKS each slot's
+        pages at ``(l, page id)`` only as far as its length, inside
+        ``ops/paged_attention.py``'s kernel, the new token's K and V
+        beside them as operands.  Otherwise (an int8 pool, other
+        shapes, another platform) layer ``l`` gathers its pages
+        straight out of the whole pool into the ``(B, T, KV·D)`` row
+        view, the new row lands at ``(b, pos[b])`` and attention
+        contracts the stored rows (``_flat_attention``).  After the
+        scan all layers' new rows scatter at ``(l, page, row)`` — in
+        place on the donated pools.  Rows of retired/idle slots hold
+        the one-past-the-end sentinel ``NPAGES``: their scatters DROP —
+        a freed page can never be corrupted by a slot that no longer
+        owns it — their walk is empty, and their gathers clamp onto the
+        last page, read garbage and are masked by the caller, as a live
+        slot's columns past ``pos`` (the stale tail of its frontier
+        page, a sentinel entry) are by the position mask: weight
+        exactly 0.  Token order is ``t = j * page + o``
         (page-major); the logits match ``pool_token``'s within float32
         summation order and greedy streams token for token
         (``tests/test_paged_parity.py``)."""
         return self._scan_token(x_tok, pos, kp, vp, sw, q8,
                                 per_slot=True, pages=(pt, page))
+
+    def walks_pages(self, page, quant=False):
+        """Whether the single-query paged step WALKS each slot's pages as
+        far as its length (``ops/paged_attention.py``, on a TPU) or builds
+        the T-wide view: a static property of the pool — native dtype, rows
+        and pages of whole tiles."""
+        return not quant and _paged.supports(
+            self.KV * self.D, self.cdtype, page, self.H, self.D)
 
     # what no inner scope names is ``mx.dense`` (an operation's region is
     # its INNERMOST ``mx.*`` scope): the embeddings here
@@ -939,6 +954,7 @@ class _DecodeEngine:
         # (1,1,1,T) <= scalar pos, or <= (B,1,1,1) per-slot positions
         pos_b = pos[:, None, None, None] if per_slot else pos
         iB = jnp.arange(B)
+        walk = False
         if pages is not None:
             pt, page = pages
             maxp = self.total // page
@@ -946,6 +962,27 @@ class _DecodeEngine:
             # python structure, so the branch is resolved at trace time
             # and costs the f32 path nothing
             quant = isinstance(ck, tuple)
+            # a native pool of whole lane and sublane tiles is WALKED: the
+            # kernel reads each slot's pages as far as its length, on a
+            # TPU; everything else (an int8 pool, other shapes, another
+            # platform) builds the T-wide view
+            walk = self.walks_pages(page, quant)
+            if walk:
+                lengths = _paged.walk_lengths(pt, pos, page, ck.shape[1])
+
+            def _view_attention(q, k, v, l):
+                """The view path: gather the slot's whole table row, land
+                the new row in the view, contract all T columns."""
+                kc = _paged_rows(ck, pt, l, cdtype)      # (B, T, KV·D)
+                vc = _paged_rows(cv, pt, l, cdtype)
+                with jax.named_scope("mx.kv_write"):
+                    kc = kc.at[iB, pos].set(k)
+                    vc = vc.at[iB, pos].set(v)
+                with jax.named_scope("mx.attn"):
+                    return _flat_attention(
+                        q.reshape(B, 1, H, D), kc, vc,
+                        idx <= pos[:, None, None], scale,
+                        cdtype).reshape(B, U)
 
         @jax.named_scope("mx.dense")
         def body(x, xs):
@@ -953,8 +990,6 @@ class _DecodeEngine:
                 # the donated pools are closed over, whole: a pool sliced
                 # by the scan would be copied, a layer at a time
                 w, l = xs
-                kc = _paged_rows(ck, pt, l, cdtype)      # (B, T, KV·D)
-                vc = _paged_rows(cv, pt, l, cdtype)
             else:
                 w, kc, vc = xs                # per-layer slices
             if llama:
@@ -979,27 +1014,27 @@ class _DecodeEngine:
                 q, k, v = (qkv[:, j * U:(j + 1) * U].reshape(B, H, 1, D)
                            for j in range(3))
             if pages is not None:
-                # the new token's K and V as rows of the view's layout
+                # the new token's K and V as rows of the pool's layout
                 k, v = k.reshape(B, KV * D), v.reshape(B, KV * D)
-            with jax.named_scope("mx.kv_write"):
-                if pages is not None:
-                    kc = kc.at[iB, pos].set(k)
-                    vc = vc.at[iB, pos].set(v)
-                elif per_slot:
-                    kc = kc.at[iB, :, pos, :].set(k[:, :, 0, :])
-                    vc = vc.at[iB, :, pos, :].set(v[:, :, 0, :])
+                if walk:
+                    with jax.named_scope("mx.attn"):
+                        o = _paged.paged_attention(
+                            q.reshape(B, H, D), k, v, ck, cv, l, pt,
+                            lengths, scale,
+                            lambda: _view_attention(q, k, v, l))
                 else:
-                    kc = lax.dynamic_update_slice(kc, k,
-                                                  (0, 0, pos, 0))
-                    vc = lax.dynamic_update_slice(vc, v,
-                                                  (0, 0, pos, 0))
-            with jax.named_scope("mx.attn"):
-                if pages is not None:
-                    o = _flat_attention(
-                        q.reshape(B, 1, H, D), kc, vc,
-                        idx <= pos[:, None, None], scale,
-                        cdtype).reshape(B, U)
-                else:
+                    o = _view_attention(q, k, v, l)
+            else:
+                with jax.named_scope("mx.kv_write"):
+                    if per_slot:
+                        kc = kc.at[iB, :, pos, :].set(k[:, :, 0, :])
+                        vc = vc.at[iB, :, pos, :].set(v[:, :, 0, :])
+                    else:
+                        kc = lax.dynamic_update_slice(kc, k,
+                                                      (0, 0, pos, 0))
+                        vc = lax.dynamic_update_slice(vc, v,
+                                                      (0, 0, pos, 0))
+                with jax.named_scope("mx.attn"):
                     qg = q.reshape(B, KV, H // KV, D)
                     s = jnp.einsum(
                         "bkgd,bktd->bkgt", qg, kc,
@@ -1040,9 +1075,10 @@ class _DecodeEngine:
         # the dense caches ride the scan's xs, a layer a step; the paged
         # pools are closed over and only the layer's number rides.  The
         # scan is ``mx.paged_view`` (what it does to the caches is the
-        # first leg of cache -> view) and its body ``mx.dense`` but for
-        # the regions named inside
-        with jax.named_scope("mx.paged_view"):
+        # first leg of cache -> view) — but where the pages are walked,
+        # which builds no view — and its body ``mx.dense`` but for the
+        # regions named inside
+        with jax.named_scope("mx.dense" if walk else "mx.paged_view"):
             x, (knew, vnew) = lax.scan(
                 body, x, (sw, jnp.arange(self.NL)) if pages is not None
                 else (sw, ck, cv))

@@ -217,8 +217,12 @@ def _device_planes(data):
 # their operation's name: the grouped product (``lax.ragged_dot``, emitted
 # by ``ops/moe.routed_experts`` alone) runs as a custom kernel named
 # ``ragged-dot-none[.n]`` with an empty ``tf_op`` (read off a v5e trace,
-# PR 29), so it would read ``unscoped`` whatever scope it was traced under
-_KERNEL_REGIONS = (("ragged-dot", "mx.moe_experts"),)
+# PR 29), so it would read ``unscoped`` whatever scope it was traced under.
+# The paged-attention kernel (``ops/paged_attention.py``) is a
+# ``pallas_call`` named for this table: its custom call keeps its provenance
+# in the HLO, and is known by name should an event of it come without
+_KERNEL_REGIONS = (("ragged-dot", "mx.moe_experts"),
+                   ("mx_paged_attention", "mx.attn"))
 
 
 def region_of(provenance, name=None):

@@ -359,6 +359,10 @@ class PoolPrograms:
                 "stacked_decode_supported); this model resolved to "
                 f"{self.eng.mode!r}.  MXNET_SERVE_SYNC=1 serves it "
                 "through the synchronous kv_generate fallback instead.")
+        # whether the step walks each slot's pages as far as its length
+        # (the paged-attention kernel) or builds the T-wide view
+        self.step_walks = not self.layered and self.eng.walks_pages(
+            self.page, self.quant_kv)
         # the server owns the weight operands (engine refs dropped so
         # the cached executables' closures can't pin stale arrays)
         param_vals, q8, _packed, sw = self.eng.take_operands()

@@ -949,6 +949,11 @@ class DecodeServer:
         self._phase_span = None  # and serve_request, name a dispatch by
         self._next_id = 0
         self._steps = 0
+        # over the step dispatches and their stepping slots: the pages
+        # that hold a slot's cached tokens, which is what a step that
+        # walks its pages reads, and the width of its table row, which
+        # is what the view reads (0 / 0 where the step builds the view)
+        self._pages_walked = self._pages_table = 0
         self._occupied_lane_steps = 0
         self._capacity_lane_steps = 0   # sums len(_slots) per step, so
         # occupancy stays honest across pool growth (S changes mid-run)
@@ -1130,6 +1135,7 @@ class DecodeServer:
         for k in self.counters:
             self.counters[k] = 0
         self._steps = 0
+        self._pages_walked = self._pages_table = 0
         self._occupied_lane_steps = 0
         self._capacity_lane_steps = 0
 
@@ -1181,6 +1187,9 @@ class DecodeServer:
             else self._pages.in_use,
             "prefix_nodes": 0 if self._prefix is None
             else len(self._prefix),
+            # how much of its table the step's page walk reads
+            "step_pages_walked": self._pages_walked,
+            "step_pages_table": self._pages_table,
             # the window pool (0 / None without windowed layers): a slot
             # holds pages for its window only, the prefix index the tails
             "window_pages_total": 0 if self._wpages is None
@@ -2426,6 +2435,11 @@ class DecodeServer:
         self._phase("mx:serve:step", seq=seq)
         new_state, out = self._progs.step_fn()(
             param_vals, q8, sw, now, self._page_table(), *self._state)
+        if self._progs.step_walks:
+            page, maxp = self._progs.page, self._progs.maxp
+            self._pages_walked += sum(
+                min(-(-self._slot_pos[i] // page), maxp) for i in stepping)
+            self._pages_table += len(stepping) * maxp
         for i in stepping:
             self._slot_pos[i] += 1
         self._state = new_state
@@ -2554,6 +2568,7 @@ class DecodeServer:
             req.span["last_step_seq"] = seq
             for t in toks[slot, :n]:
                 req.stream._push(int(t))
+            self._slot_pos[slot] += n    # the device advanced as far
             proposed = int(nd[slot])
             if proposed:
                 accepted = n - 1

@@ -5,6 +5,10 @@ attached (``tools/rehearse_serve.py``; nothing runs), and the optimized
 HLO is held to what the chip measured as the difference between a 159 ms
 and a 15 ms decode step — no pass over the whole pool, the pool kept in
 the layout the program declares, no second pool in the program's scratch.
+Since ISSUE 30 the native pool's step WALKS its pages: the compile for the
+chip must hold the paged-attention kernel (a Mosaic custom call, so the test
+cannot pass on the view path the CPU lowers) and no ``(S, T, KV·D)`` view;
+the int8 pool keeps the view path.
 
 Every test here uses the ``chip`` fixture, which describes the topology
 inside the test's own process and skips where the TPU compiler cannot.
@@ -53,12 +57,17 @@ def reports(chip, progs):
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
+    # what the chip would compile: not the interpreted Pallas kernels that
+    # other test files switch on for the whole process as they are imported
+    env = pytest.MonkeyPatch()
+    env.delenv("MXNET_FLASH_INTERPRET", raising=False)
     try:
         return {
             "step": rs.pool_report(rs.compile_step(progs, chip), progs),
             "admit": rs.pool_report(rs.compile_admit(progs, chip, 2, 32),
                                     progs)}
     finally:
+        env.undo()
         jax.config.update("jax_enable_compilation_cache", was)
         compilation_cache.reset_cache()
 
@@ -89,6 +98,21 @@ def test_pool_keeps_the_declared_layout(reports, which):
     assert layouts, reports[which]
     for lay in layouts:
         assert lay.split("{")[1].startswith("3,2,1,0"), lay
+
+
+def test_native_step_walks_its_pages_in_the_kernel(reports, progs):
+    """The TPU lowering of a native pool's step holds the paged-attention
+    kernel and builds no view of it; an int8 pool's step gathers the view
+    (which it dequantizes on the way) and holds no kernel."""
+    step = reports["step"]
+    if progs.quant_kv:
+        assert not progs.step_walks
+        assert step["kernels"] == [] and step["view_sized"]
+        return
+    assert progs.step_walks
+    assert len(step["kernels"]) == 1, step["kernels"]
+    assert step["kernels"][0].startswith("mx_paged_attention")
+    assert step["view_sized"] == []
 
 
 def test_step_scratch_is_a_few_views(reports, progs):

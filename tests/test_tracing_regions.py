@@ -32,22 +32,28 @@ def _toy_server(**kw):
 
 def _lowered_serve_step():
     srv = _toy_server()
-    pv, q8, sw = srv._progs.operands
-    return srv._progs.step_fn().lower(
-        pv, q8, sw, np.float32(0), srv._page_table(), *srv._state)
+    try:
+        pv, q8, sw = srv._progs.operands
+        return srv._progs.step_fn().lower(
+            pv, q8, sw, np.float32(0), srv._page_table(), *srv._state)
+    finally:
+        srv.close()     # or its pool stays accounted for the whole process
 
 
 def _lowered_serve_admit():
     from mxnet_tpu.serve import schema
     srv = _toy_server()
-    progs, (A, P) = srv._progs, (2, 16)
-    pv, _, _ = progs.operands
-    npb = -(-P // progs.page)
-    return progs.admit_fn(A, P).lower(
-        pv, np.zeros((A, P), np.int32),
-        np.zeros((A, schema.meta_width("admit")), np.int32),
-        np.full((A,), np.inf, np.float32), np.zeros((A, npb), np.int32),
-        np.zeros((A, progs.maxp), np.int32), *srv._state)
+    try:
+        progs, (A, P) = srv._progs, (2, 16)
+        pv, _, _ = progs.operands
+        npb = -(-P // progs.page)
+        return progs.admit_fn(A, P).lower(
+            pv, np.zeros((A, P), np.int32),
+            np.zeros((A, schema.meta_width("admit")), np.int32),
+            np.full((A,), np.inf, np.float32), np.zeros((A, npb), np.int32),
+            np.zeros((A, progs.maxp), np.int32), *srv._state)
+    finally:
+        srv.close()
 
 
 def _toy_trainer():
@@ -104,6 +110,20 @@ def test_region_names_in_lowered_text(lower, regions):
         "none", "empty"])
 def test_region_is_the_innermost_mx_component(path, region):
     assert profiler_xla.region_of(path) == region
+
+
+@pytest.mark.parametrize("name, provenance, region", [
+    ("ragged-dot-none.3", "", "mx.moe_experts"),
+    ("mx_paged_attention.8", "", "mx.attn"),
+    ("mx_paged_attention.8",
+     "jit(step)/mx.dense/while/body/mx.attn/mx_paged_attention/pallas_call",
+     "mx.attn"),
+    ("fusion.170", "", "unscoped"),
+], ids=["grouped_product", "paged_attention", "provenance_wins", "other"])
+def test_region_of_a_kernel_known_by_name(name, provenance, region):
+    """A custom kernel whose device events carry no provenance is known by
+    the start of its operation's name."""
+    assert profiler_xla.region_of(provenance, name) == region
 
 
 def _two_executables(make_xspace):

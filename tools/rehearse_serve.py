@@ -166,16 +166,26 @@ def pool_report(compiled, progs):
     whatever its dimensions (the in-place scatters work on a 2-D bitcast),
     as ``{label: [names]}``.  A fusion's label is ``fusion:<opcode of its
     root>``; what only carries the pool (parameter, tuple, bitcast, the
-    ``while`` it rides through) is left out."""
+    ``while`` it rides through) is left out.  ``kernels`` names the Mosaic
+    custom calls (the paged-attention kernel of a step that walks its
+    pages) and ``view_sized`` every instruction, fused ones too, whose
+    result is one layer's view of every slot: ``(S, T, KV·D)``, or ``(S,
+    MAXP, page, KV·D)`` as the gather hands it over."""
     shapes = pool_shapes(progs)
     dims = ["[" + ",".join(str(d) for d in shape) + "]" for shape in shapes]
     n_pools = {math.prod(shape) for shape in shapes}
+    # one layer's T-wide view of every slot, which the view path builds
+    # and a step that walks its pages does not
+    views = () if progs.layered else (
+        f"[{progs.S},{progs.Tp},{shapes[0][-1]}]",
+        f"[{progs.S},{progs.maxp},{progs.page},{shapes[0][-1]}]")
     text = compiled.as_text()
     entry = re.search(r"entry_computation_layout=\{\((.*?)\)->", text)
     layouts = sorted({m for d in dims for m in re.findall(
         r"[a-z0-9]+" + re.escape(d) + r"\{[^}]*\}",
         entry.group(1) if entry else "")})
     roots, found, comp = {}, [], None
+    kernels, view_sized = [], []
     for line in text.splitlines():
         c = _COMPUTATION.match(line)
         if c is not None:
@@ -188,6 +198,10 @@ def pool_report(compiled, progs):
             roots[comp] = m.group("op")
         sizes = [math.prod(int(d) for d in a.split(",") if d)
                  for a in _ARRAY.findall(m.group("type"))]
+        if 'custom_call_target="tpu_custom_call"' in line:
+            kernels.append(m.group("name"))
+        if any(v in m.group("type") for v in views):
+            view_sized.append(m.group("name"))
         if n_pools & set(sizes) and m.group("op") not in _CARRIERS:
             called = re.search(r"calls=%?([\w.\-]+)", line)
             found.append((comp, m.group("op"), m.group("name"),
@@ -207,7 +221,9 @@ def pool_report(compiled, progs):
             "alias_bytes": ma.alias_size_in_bytes,
             "pool_dims": dims[0] if len(dims) == 1 else dims,
             "pool_entry_layouts": layouts,
-            "pool_sized": sized}
+            "pool_sized": sized,
+            "kernels": kernels,
+            "view_sized": view_sized}
 
 
 def main(argv=None):
