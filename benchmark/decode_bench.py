@@ -3,13 +3,10 @@
 full-recompute ``GPT.generate`` loop.  Prints one JSON line per mode.
 
 Batch-1 arms sweep the per-token step implementation (unrolled per-layer
-/ stacked-layer scan / Pallas megakernel where its TPU gate passes) and
-report, next to the timings, the **ops/step column**: the optimized-HLO
-instruction count of ONE compiled decode step
-(``models.decode_step_program`` + ``profiler_xla.hlo_op_count``).  The
-r4 profile showed decode is sequencer-bound (~230 device ops x ~2.5 us
-of fixed per-op cost, BASELINE.md) — this column is the CAUSE metric the
-stacked-scan path collapses, measurable on any backend.
+/ stacked-layer scan) and report, next to the timings, the **ops/step
+column**: the optimized-HLO instruction count of ONE compiled decode step
+(``models.decode_step_program`` + ``profiler_xla.hlo_op_count``) — what
+the stacked-scan path collapses, measurable on any backend.
 
 The full run also carries the **ragged-arrival arm** (shared with
 ``serve_bench.py``): one ragged workload served as static padded
@@ -43,14 +40,13 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import numpy as onp
 
 
-def _step_ops(net, total, weights, fused, stacked):
+def _step_ops(net, total, weights, stacked):
     """ops/step for one compiled batch-1 decode step of this arm."""
     from mxnet_tpu import profiler_xla
     from mxnet_tpu.models import decode_step_program
 
     fn, args = decode_step_program(net, batch=1, total=total,
-                                   weights=weights, fused=fused,
-                                   stacked=stacked)
+                                   weights=weights, stacked=stacked)
     return profiler_xla.hlo_op_count(fn, *args)
 
 
@@ -116,7 +112,7 @@ def smoke():
                                 temperature=0.0, stacked=skw,
                                 weights=wmode)
         dt = time.perf_counter() - t0
-        ops = _step_ops(net, P + N, wmode, "off", skw)
+        ops = _step_ops(net, P + N, wmode, skw)
         rows.append((arm, ops))
         print(json.dumps({"bench": "decode_smoke", "mode": arm,
                           "ops_per_step": ops,
@@ -170,7 +166,6 @@ def main():
     import jax
 
     import mxnet_tpu as mx
-    from mxnet_tpu.base import MXNetError
     from mxnet_tpu.models import GPT, GPTConfig, decode_mode, kv_generate
 
     platform = jax.devices()[0].platform
@@ -194,7 +189,7 @@ def main():
     kv_generate(net, prompt, max_new_tokens=N, temperature=0.0)
     dt = time.perf_counter() - t0
     print(json.dumps({"bench": "decode", "mode": "kv_cache",
-                      "step": decode_mode(net, B, P + N),
+                      "step": decode_mode(net),
                       "tokens_per_sec": round(B * N / dt, 1),
                       "tokens_per_dispatch": 1.0,  # 1 token/step scan
                       "batch": B, "new_tokens": N,
@@ -205,31 +200,20 @@ def main():
     # prompt as ONE causal forward, then N-1 scan decode steps; the timed
     # wall covers prefill + decode, so ms_per_token = wall / N is the
     # honest serving latency per emitted token.  Arms: per-layer
-    # unrolled vs stacked-layer scan (any backend), the Pallas megakernel
-    # where its gate passes (fused='on' raises otherwise), each with the
-    # int8 weight stream where covered.
+    # unrolled vs stacked-layer scan, each with the int8 weight stream.
     p1 = prompt[:1]
-    arms = [("native", "off", "off", "kv_cache_batch1"),
-            ("native", "off", "on", "kv_cache_batch1_stacked"),
-            ("native", "on", "off", "kv_cache_batch1_fused"),
-            ("int8", "off", "off", "kv_cache_batch1_int8"),
-            ("int8", "off", "on", "kv_cache_batch1_int8_stacked"),
-            ("int8", "on", "off", "kv_cache_batch1_int8_fused")]
-    for wmode, fmode, smode, tag in arms:
+    arms = [("native", "off", "kv_cache_batch1"),
+            ("native", "on", "kv_cache_batch1_stacked"),
+            ("int8", "off", "kv_cache_batch1_int8"),
+            ("int8", "on", "kv_cache_batch1_int8_stacked")]
+    for wmode, smode, tag in arms:
         kw = dict(max_new_tokens=N, temperature=0.0, weights=wmode,
-                  fused=fmode, stacked=smode)
-        try:
-            kv_generate(net, p1, **kw)  # compile
-        except MXNetError as e:
-            print(json.dumps({"bench": "decode", "mode": tag,
-                              "skipped": str(e)[:80],
-                              "platform": platform}))
-            sys.stdout.flush()
-            continue
+                  stacked=smode)
+        kv_generate(net, p1, **kw)  # compile
         t0 = time.perf_counter()
         kv_generate(net, p1, **kw)
         dt = time.perf_counter() - t0
-        ops = _step_ops(net, P + N, wmode, fmode, smode)
+        ops = _step_ops(net, P + N, wmode, smode)
         print(json.dumps({"bench": "decode", "mode": tag,
                           "new_tokens_per_sec": round(N / dt, 1),
                           "ms_per_token": round(dt / N * 1e3, 3),

@@ -7,36 +7,40 @@ cache updated with ``lax.dynamic_update_slice``, the WHOLE decode loop
 (prefill + sampling) compiled as ONE ``lax.scan`` program — no per-token
 dispatch, no retraces, O(L) work per token.
 
-Three per-token step implementations share the program skeleton
-(``decode_mode`` picks one):
+Two per-token step implementations share the program skeleton; the
+MODEL'S OWN STRUCTURE picks one (``decode_mode``, through the gate
+``stacked_decode_supported``) unless the caller's ``stacked=`` says
+``"on"`` or ``"off"``:
 
-- **stacked** (default where supported): every layer's weights are
-  stacked into ``(NL, ...)`` arrays (``ops.decode_fused.
-  stack_decode_weights``) and the per-token layer loop is ONE
+- **stacked** (wherever the gate passes: a uniform GPT or Llama/GQA
+  layer stack): every layer's weights are stacked into ``(NL, ...)``
+  arrays (``stack_decode_weights``) and the per-token layer loop is ONE
   ``lax.scan`` over the layer axis — the compiled step contains one
-  layer-body's worth of HLO instead of NL unrolled copies.  The r4
-  profile showed the decode scan is SEQUENCER-bound (~230 device ops ×
-  ~2.5 µs/step of fixed per-op cost, BASELINE.md), so collapsing the op
-  count is the measured fix, and it is portable XLA — it lands on CPU CI
-  as well as TPU.  Covers the ``weights="int8"`` stream too (stacked q8
-  codes ride the scan xs through ``q8_matvec``), and a per-slot variant
-  (``pool_token``) is the serving step of ``mxnet_tpu.serve``.
-  ``MXNET_STACKED_DECODE=0`` restores the unrolled path bit-for-bit.
-- **unrolled**: the r3 generalization path (VERDICT r2 item 8) — the
-  per-layer math is DERIVED FROM THE MODEL'S OWN BLOCKS (``ln1``/
-  ``attn.qkv``/``ffn``/… invoked as Gluon layers on traced values via
-  the same swap discipline as ``SPMDTrainer``), so a model variant that
-  changes normalization, activation, or bias structure inside those
-  sublayers decodes correctly with no decoder change.  Only the
-  cache-attention core is decoder-specific math.  This is the fallback
-  for non-uniform layer stacks (and any block variant the stacked gate
-  rejects), in both native and int8 weight modes.
-- **fused**: the TPU Pallas megakernel (``ops/decode_fused.py``) — ALL
-  layers in one kernel launch per token.  Explicit opt-in only
-  (``fused="on"``): the kernel is TPU-only and narrowly gated (batch ≤ 4,
-  bf16 cache, chunk-tileable dims — see PARITY.md "Decode path support
-  matrix"), so the portable stacked path is the default op-count
-  collapse.
+  layer-body's worth of HLO instead of NL unrolled copies.  Portable
+  XLA: it lands on CPU CI as well as the TPU.  Covers the
+  ``weights="int8"`` stream too (stacked q8 codes ride the scan xs
+  through ``q8_matvec``).
+- **unrolled**: the per-layer math is DERIVED FROM THE MODEL'S OWN
+  BLOCKS (``ln1``/``attn.qkv``/``ffn``/… invoked as Gluon layers on
+  traced values via the same swap discipline as ``SPMDTrainer``), so a
+  model variant that changes normalization, activation, or bias
+  structure inside those sublayers decodes correctly with no decoder
+  change.  Only the cache-attention core is decoder-specific math.  It
+  is the only step that runs a non-uniform layer stack (and any block
+  variant the gate rejects), in both weight modes, and
+  ``stacked="off"`` is the reference arm the stacked step is tested
+  against (``tests/test_stacked_decode.py``).
+
+Which engine serves what (``decode_engine``, from the model's
+``layer_description`` alone): a model whose layers all keep dense K/V
+rows gets ``_DecodeEngine`` below; anything else (latent rows, windows,
+routed experts) the kind-driven ``layered.LayeredEngine``.
+``mxnet_tpu.serve`` runs ``_DecodeEngine``'s PAGED per-slot steps
+(``pool_token_paged``, ``chunk_tokens``, ``pool_verify_paged``) over the
+stacked weights; ``pool_token``, the same step on dense
+``(NL, B, KV, T, D)`` caches, has no caller in the package: it is the
+dense reference that ``tests/test_paged_parity.py`` and
+``tests/test_paged_attention_kernel.py`` hold the paged steps to.
 
 Decodable protocol — two block families are recognized:
 - GPT/_TransformerCell: ``wte``+``wpe`` embeddings, blocks with ``ln1``,
@@ -52,8 +56,6 @@ Reference counterpart: none in-tree (GluonNLP-era beam/sampling ran the
 full-prefix path); this is a NEW capability like flash/ring attention.
 """
 from __future__ import annotations
-
-import os
 
 import jax
 import jax.numpy as jnp
@@ -363,7 +365,7 @@ def _gpt_act_type(model):
     return getattr(act, "_act_type", None) if act is not None else None
 
 
-def _check_args(prefill, weights, fused, stacked):
+def _check_args(prefill, weights, stacked):
     """Shared argument validation — runs even on the max_new_tokens<=0
     early return so a typo fails fast in 0-token smoke calls."""
     if prefill not in ("batched", "scan"):
@@ -372,9 +374,6 @@ def _check_args(prefill, weights, fused, stacked):
     if weights not in ("native", "int8"):
         raise ValueError(f"weights must be 'native' or 'int8', "
                          f"got {weights!r}")
-    if fused not in ("auto", "on", "off"):
-        raise ValueError(f"fused must be 'auto', 'on' or 'off', "
-                         f"got {fused!r}")
     if stacked not in ("auto", "on", "off"):
         raise ValueError(f"stacked must be 'auto', 'on' or 'off', "
                          f"got {stacked!r}")
@@ -410,10 +409,10 @@ def _family_tables(is_llama):
 
 def _layer_weight_srcs(model, is_llama):
     """Pinned strong refs to every per-layer weight/bias/norm array —
-    the cache-invalidation key shared by the Pallas pack and the stacked
-    export: a train step rebinds parameter arrays, so comparing these by
-    ``is`` detects staleness without hashing (and without the recycled-
-    ``id()`` hazard documented at the q8 cache)."""
+    the cache-invalidation key of the stacked export: a train step
+    rebinds parameter arrays, so comparing these by ``is`` detects
+    staleness without hashing (and without the recycled-``id()`` hazard
+    documented at the q8 cache)."""
     proj, norms = _family_tables(is_llama)
     srcs = []
     for blk in model.blocks:
@@ -439,56 +438,86 @@ def _pinned_cache(model, attr, srcs, build):
     return cache["val"]
 
 
-def decode_mode(model, batch=1, total=32, weights="native", fused="auto",
-                stacked="auto"):
+def stack_decode_weights(blocks):
+    """Stack every block's ``decode_layer_arrays`` export into one
+    (NL, ...) array per slot — the operand set of the stacked-layer
+    ``lax.scan`` step: each slot rides the scan's xs axis, so the
+    compiled step contains ONE layer-body's worth of HLO instead of NL
+    unrolled copies.  Callers cache the result pinned on the source
+    arrays (``_pinned_cache``: a train step rebinds parameter arrays and
+    triggers restacking)."""
+    per = [blk.decode_layer_arrays() for blk in blocks]
+    keys = list(per[0])
+    if any(list(p) != keys for p in per[1:]):
+        from ..base import MXNetError
+        raise MXNetError("stack_decode_weights: blocks export different "
+                         "decode slot sets — cannot stack")
+    return {k: jnp.stack([p[k] for p in per]) for k in keys}
+
+
+def stacked_decode_supported(model) -> bool:
+    """Gate for the stacked-layer scan decode path (XLA, any backend).
+
+    Requires: a block family that exports ``decode_layer_arrays`` (GPT
+    ``_TransformerCell`` or ``LlamaCell``), uniform geometry / norm
+    epsilons / FFN activation across layers (the scan compiles ONE body
+    for all of them), and materialized parameters.  Anything else falls
+    back to the per-layer unrolled path, which derives its math from the
+    model's own sublayers and so covers arbitrary variants."""
+    from ..base import MXNetError
+    blocks = getattr(model, "blocks", None)
+    if not blocks or not hasattr(model, "stacked_decode_weights"):
+        return False
+    if not all(hasattr(b, "decode_layer_arrays") for b in blocks):
+        return False
+    try:
+        if hasattr(blocks[0], "rms1"):            # Llama family
+            eps = {(float(b.rms1._eps), float(b.rms2._eps))
+                   for b in blocks}
+        else:                                     # GPT family
+            eps = {(float(b.ln1._eps), float(b.ln2._eps))
+                   for b in blocks}
+            acts = {getattr(b.ffn.fc1.act, "_act_type", None)
+                    if b.ffn.fc1.act is not None else None
+                    for b in blocks}
+            if len(acts) != 1:
+                return False
+        if len(eps) != 1:
+            return False
+        per0 = blocks[0].decode_layer_arrays()
+        for b in blocks[1:]:
+            p = b.decode_layer_arrays()
+            if list(p) != list(per0) or any(
+                    p[k].shape != per0[k].shape
+                    or p[k].dtype != per0[k].dtype for k in per0):
+                return False
+    except (AttributeError, TypeError, MXNetError):
+        # a structurally different variant, or un-materialized params
+        # (``Parameter.data()`` raises MXNetError)
+        return False
+    return True
+
+
+def decode_mode(model, weights="native", stacked="auto"):
     """Select the per-token step implementation ``kv_generate`` will run.
 
-    Returns ``"fused"`` | ``"stacked"`` | ``"unrolled"``.
-
-    ``fused="on"`` requires the Pallas megakernel (raises ``MXNetError``
-    when its gate — TPU backend, batch ≤ 4, bf16, tileable dims —
-    rejects the config); ``"auto"``/``"off"`` never select it: the
-    kernel is TPU-only and shipped unmeasured (VERDICT r5), so it is
-    explicit opt-in.  ``stacked="on"`` requires the stacked-layer scan
-    (raises when the model is not stackable); ``"auto"`` uses it
-    whenever supported — for both ``weights`` modes (the int8 stream
-    stacks its q8 codes); ``"off"`` never.  The
-    ``MXNET_STACKED_DECODE=0`` escape hatch disables the stacked path
-    globally — with ``stacked="on"`` that conflict raises rather than
-    silently overriding either request."""
+    Returns ``"stacked"`` | ``"unrolled"``.  ``stacked="auto"`` takes the
+    stacked-layer scan whenever ``stacked_decode_supported(model)`` — for
+    both ``weights`` modes (the int8 stream stacks its q8 codes);
+    ``"on"`` requires it and raises ``MXNetError`` when the model is not
+    stackable; ``"off"`` never takes it (the unrolled reference arm)."""
     from ..base import MXNetError
-    from ..ops.decode_fused import (fused_decode_supported,
-                                    stacked_decode_supported)
 
-    _check_args("batched", weights, fused, stacked)
-    if fused == "on":
-        if stacked == "on":
-            raise MXNetError("stacked='on' conflicts with fused='on' — "
-                             "the Pallas megakernel replaces the layer "
-                             "loop entirely")
-        cdtype = model.wte.weight.data()._data.dtype
-        ok = fused_decode_supported(model._cfg, batch, total, cdtype)
-        if ok and not hasattr(model.blocks[0], "rms1"):
-            ok = _gpt_act_type(model) in (None, "gelu", "relu")
-        if not ok:
-            raise MXNetError(
-                "fused='on' but the fused decode kernel does not support "
-                "this model/batch/dtype (see ops/decode_fused.py "
-                "fused_decode_supported)")
-        return "fused"
-    env_on = os.environ.get("MXNET_STACKED_DECODE", "1") != "0"
+    _check_args("batched", weights, stacked)
     if stacked == "on":
-        if not env_on:
-            raise MXNetError("stacked='on' but MXNET_STACKED_DECODE=0 "
-                             "disables the stacked decode path")
         if not stacked_decode_supported(model):
             raise MXNetError(
                 "stacked='on' but this model's layer stack cannot be "
                 "stacked (non-uniform geometry/eps/activation or an "
-                "unrecognized block family — see ops/decode_fused.py "
+                "unrecognized block family — see models/decoding.py "
                 "stacked_decode_supported)")
         return "stacked"
-    if stacked == "auto" and env_on and stacked_decode_supported(model):
+    if stacked == "auto" and stacked_decode_supported(model):
         return "stacked"
     return "unrolled"
 
@@ -510,33 +539,33 @@ def layer_description(model):
 
 
 def decode_engine(model, B, P, total, temperature, top_k, prefill,
-                  weights, fused, stacked):
+                  weights, stacked):
     """The engine that serves ``model``, chosen by its description alone:
     the uniform K/V kind has the stacked scan, everything else the
     kind-driven ``layered.LayeredEngine``."""
     if all(d["cache"] == "kv" for d in layer_description(model)):
         return _DecodeEngine(model, B, P, total, temperature, top_k,
-                             prefill, weights, fused, stacked)
+                             prefill, weights, stacked)
     from .layered import LayeredEngine
     return LayeredEngine(model, B, P, total, temperature, top_k, prefill,
-                         weights, fused, stacked)
+                         weights)
 
 
 class _DecodeEngine:
     """Per-call decode program builder: family/geometry detection, weight
-    preparation (q8 codes / Pallas pack / stacked arrays — all cached on
+    preparation (q8 codes / stacked arrays — all cached on
     the model pinned to their source arrays, all riding as TRACED
     ARGUMENTS so weight updates never invalidate the compiled program),
     and the per-token step bodies the jitted ``run`` composes."""
 
     def __init__(self, model, B, P, total, temperature, top_k, prefill,
-                 weights, fused, stacked):
+                 weights, stacked):
         with _TRACE_LOCK:
             self._init(model, B, P, total, temperature, top_k, prefill,
-                       weights, fused, stacked)
+                       weights, stacked)
 
     def _init(self, model, B, P, total, temperature, top_k, prefill,
-              weights, fused, stacked):
+              weights, stacked):
         cfg = model._cfg
         self.model = model
         self.cfg = cfg
@@ -552,7 +581,7 @@ class _DecodeEngine:
         self.KV = getattr(cfg, "num_kv_heads", self.H) if self.is_llama \
             else self.H
         self.rope_base = float(getattr(cfg, "rope_base", 10000.0))
-        _check_args(prefill, weights, fused, stacked)
+        _check_args(prefill, weights, stacked)
         self.use_int8 = weights == "int8"
 
         # weights ride as TRACED ARGUMENTS (swap discipline shared with
@@ -577,11 +606,9 @@ class _DecodeEngine:
                 float(getattr(model.blocks[0].ln1, "_eps", 1e-5)),
                 float(getattr(model.blocks[0].ln2, "_eps", 1e-5)))
 
-        self.mode = decode_mode(model, B, total, weights, fused, stacked)
-        self.packed = self.q8v = self.sw = None
-        if self.mode == "fused":
-            self.packed = self._build_packed()
-        elif self.mode == "stacked":
+        self.mode = decode_mode(model, weights, stacked)
+        self.q8v = self.sw = None
+        if self.mode == "stacked":
             if self.use_int8:
                 # int8 stacked: the scan streams per-layer q8 codes as
                 # xs; only the LM head rides through the q8v operand
@@ -598,24 +625,6 @@ class _DecodeEngine:
             self.q8v = self._build_q8()
 
     # -- weight preparation -------------------------------------------- #
-    def _build_packed(self):
-        """Pallas megakernel stream, cached pinned on the source arrays
-        (a train step rebinds arrays → repack)."""
-        from ..ops.decode_fused import (pack_gpt_weights,
-                                        pack_llama_weights)
-        model, cfg, cdtype = self.model, self.cfg, self.cdtype
-        if self.is_llama:
-            return _pinned_cache(
-                model, "_fused_decode_cache",
-                [self.use_int8] + _layer_weight_srcs(model, True),
-                lambda: pack_llama_weights(model.blocks, cfg, cdtype,
-                                           quant=self.use_int8))
-        return _pinned_cache(
-            model, "_fused_decode_cache",
-            [self.use_int8] + _layer_weight_srcs(model, False),
-            lambda: pack_gpt_weights(model.blocks, cdtype,
-                                     quant=self.use_int8))
-
     def _head_arrays(self):
         """(head weight (V, U), head bias or None) — the tied ``wte``
         weight when the model has no separate head Block."""
@@ -868,8 +877,9 @@ class _DecodeEngine:
                                 per_slot=False)
 
     def pool_token(self, x_tok, pos, ck, cv, sw, q8=None):
-        """stacked_token with PER-ROW positions — the slot-pool serving
-        step (``mxnet_tpu.serve``): every batch row is an independent
+        """stacked_token with PER-ROW positions on DENSE caches — the
+        reference of the paged serving steps (no caller in the package;
+        see the module docstring): every batch row is an independent
         sequence at its own depth ``pos[b]``, so the attention mask,
         rotary angles and cache-column writes are per-slot (the writes
         are scatters at ``(b, pos[b])`` instead of one
@@ -1450,24 +1460,8 @@ class _DecodeEngine:
             logits = self._head_logits(xl.reshape(B * C, U), q8)
             return logits.reshape(B, C, -1), kp, vp
 
-    def fused_token(self, x_tok, pos, ck, cv, packed_t, q8=None):
-        """one_token's Pallas twin: embeddings and head stay XLA ops;
-        every transformer layer runs inside ONE Pallas kernel
-        (ops/decode_fused.py decode_step).  In int8 mode the layer
-        stream is int8 codes and the head goes through q8_matvec, same
-        as the unfused q8 path."""
-        from ..ops.decode_fused import decode_step
-
-        x = self._embed(x_tok, pos)
-        x, ck, cv = decode_step(pos, x, packed_t, ck, cv, self.cfg,
-                                self.act_t, self.norm_eps[0])
-        xl = _call(self.model.ln_f, x)
-        return self._head_logits(xl, q8), ck, cv
-
-    def token_step(self, tok, t, ck, cv, q8, packed_t, sw):
+    def token_step(self, tok, t, ck, cv, q8, sw):
         """Dispatch one per-token step through the selected mode."""
-        if self.mode == "fused":
-            return self.fused_token(tok, t, ck, cv, packed_t, q8)
         if self.mode == "stacked":
             return self.stacked_token(tok, t, ck, cv, sw, q8)
         return self.one_token(tok, t, ck, cv, q8)
@@ -1580,17 +1574,17 @@ class _DecodeEngine:
             * jnp.dtype(self.cdtype).itemsize
 
     def take_operands(self):
-        """Hand the weight operands (param values + prepared q8/packed/
+        """Hand the weight operands (param values + prepared q8 /
         stacked arrays) to the caller and DROP the engine's own refs:
         the compiled program closure keeps the engine alive, and it must
         not pin the first call's arrays after a train-step rebind."""
-        operands = (self.param_vals, self.q8v, self.packed, self.sw)
-        self.param_vals = self.q8v = self.packed = self.sw = None
+        operands = (self.param_vals, self.q8v, self.sw)
+        self.param_vals = self.q8v = self.sw = None
         return operands
 
     def build_run(self):
         """The whole-decode program (prefill + sampled scan) to be
-        jitted: run(param_vals, q8, packed_t, sw, prompt_dev, key0) →
+        jitted: run(param_vals, q8, sw, prompt_dev, key0) →
         (N, B) new tokens."""
         from ..gluon.parameter import params_swapped
 
@@ -1598,7 +1592,7 @@ class _DecodeEngine:
         P, total = self.P, self.total
 
         if self.prefill == "batched":
-            def run(param_vals, q8, packed_t, sw, prompt_dev, key0):
+            def run(param_vals, q8, sw, prompt_dev, key0):
                 with _TRACE_LOCK, params_swapped(eng.params, param_vals):
                     ck, cv = eng.zero_caches()
                     logits, ck, cv = eng.prefill_batch(prompt_dev, ck, cv)
@@ -1607,7 +1601,7 @@ class _DecodeEngine:
                     def scan_body(carry, t):
                         tok, ck, cv = carry
                         logits, ck, cv = eng.token_step(
-                            tok, t, ck, cv, q8, packed_t, sw)
+                            tok, t, ck, cv, q8, sw)
                         nxt = eng._sample(logits, t, key0)
                         return (nxt, ck, cv), nxt
 
@@ -1616,7 +1610,7 @@ class _DecodeEngine:
                         jnp.arange(P, total - 1))
                     return jnp.concatenate([first[None], toks])  # (N, B)
         else:
-            def run(param_vals, q8, packed_t, sw, prompt_dev, key0):
+            def run(param_vals, q8, sw, prompt_dev, key0):
                 with _TRACE_LOCK, params_swapped(eng.params, param_vals):
 
                     def scan_body(carry, t):
@@ -1626,7 +1620,7 @@ class _DecodeEngine:
                                         prompt_dev[:, jnp.minimum(t, P - 1)],
                                         tok)
                         logits, ck, cv = eng.token_step(
-                            cur, t, ck, cv, q8, packed_t, sw)
+                            cur, t, ck, cv, q8, sw)
                         nxt = eng._sample(logits, t, key0)
                         return (nxt, ck, cv), nxt
 
@@ -1642,7 +1636,7 @@ class _DecodeEngine:
 
 def kv_generate(model, prompt_tokens, max_new_tokens=32, temperature=1.0,
                 top_k=0, seed=0, prefill="batched", weights="native",
-                fused="auto", stacked="auto"):
+                stacked="auto"):
     """Sample ``max_new_tokens`` continuations for a (B, P) prompt.
 
     Greedy when ``temperature == 0``; ``top_k > 0`` restricts the sample
@@ -1663,33 +1657,19 @@ def kv_generate(model, prompt_tokens, max_new_tokens=32, temperature=1.0,
     dequantizing inside the dot with f32 accumulation.  Both families
     (GPT fused-QKV and Llama split-projection/SwiGLU).  An approximate
     path — greedy tokens can differ from the exact native path (~0.4%
-    weight error); measured r4: the decode step is sequencer-bound at
-    GPT-2-small size, so int8's byte savings pay off only on larger
-    models (BASELINE.md decode section).  int8 runs the stacked-layer
+    weight error).  int8 runs the stacked-layer
     scan wherever the native path does (stacked q8 codes ride the scan
     xs; see PARITY.md decode support matrix), falling back to the
     per-layer unrolled step like native weights.
 
     ``stacked``: ``"auto"`` (default) runs the decode scan step as ONE
     ``lax.scan`` over stacked (NL, ...) layer weights whenever the model
-    qualifies (uniform GPT or Llama/GQA layer stack, native weights) —
-    the compiled step carries one layer-body's worth of HLO instead of
-    NL copies, collapsing the measured ~230-op/step sequencer overhead
-    (BASELINE.md r4) on ANY backend; ``"on"`` requires it (raises if
-    unsupported); ``"off"`` keeps the per-layer unrolled step.
-    ``MXNET_STACKED_DECODE=0`` restores the unrolled path bit-for-bit.
-
-    ``fused``: ``"on"`` runs the decode scan step through the
-    one-kernel-per-token Pallas megakernel (ops/decode_fused.py),
-    raising if its gate rejects the config (TPU backend, batch ≤ 4,
-    bf16 cache, chunk-tileable dims — PARITY.md support matrix).
-    ``"auto"``/``"off"`` never select it: the kernel is TPU-only and
-    unmeasured (VERDICT r5), so since the stacked-scan landing it is
-    explicit opt-in only.  Hidden states can differ from the unfused
-    path by ~1 bf16 ulp (chunked f32 accumulation order in fc2) —
-    greedy token parity is asserted in tests on the covered sizes.
+    qualifies (``stacked_decode_supported``: a uniform GPT or Llama/GQA
+    layer stack) — the compiled step carries one layer-body's worth of
+    HLO instead of NL copies, on ANY backend; ``"on"`` requires it
+    (raises if unsupported); ``"off"`` keeps the per-layer unrolled step.
     """
-    _check_args(prefill, weights, fused, stacked)
+    _check_args(prefill, weights, stacked)
     prompt = onp.asarray(
         prompt_tokens.asnumpy() if hasattr(prompt_tokens, "asnumpy")
         else prompt_tokens, dtype=onp.int32)
@@ -1702,7 +1682,7 @@ def kv_generate(model, prompt_tokens, max_new_tokens=32, temperature=1.0,
                          f"{model._cfg.max_length}")
 
     eng = _DecodeEngine(model, B, P, total, temperature, top_k, prefill,
-                        weights, fused, stacked)
+                        weights, stacked)
     cache_key = (B, P, max_new_tokens, float(temperature), int(top_k),
                  str(eng.cdtype), prefill, weights, eng.mode)
     cache = model.__dict__.setdefault("_kv_decode_cache", {})
@@ -1728,26 +1708,24 @@ def kv_generate(model, prompt_tokens, max_new_tokens=32, temperature=1.0,
 
 
 def decode_step_program(model, batch=1, total=32, temperature=0.0,
-                        top_k=0, weights="native", fused="auto",
-                        stacked="auto", seed=0):
+                        top_k=0, weights="native", stacked="auto",
+                        seed=0):
     """ONE decode step as a ``(jitted_fn, example_args)`` pair — the unit
     ``profiler_xla.hlo_op_count`` measures and the op-count regression
     test / ``benchmark/decode_bench.py`` ops/step column assert on.
 
-    ``fn(param_vals, q8, packed_t, sw, tok, pos, ck, cv, key0)`` →
+    ``fn(param_vals, q8, sw, tok, pos, ck, cv, key0)`` →
     ``(next_tok (B,), ck, cv)`` for a token at position ``pos`` against
     a ``total``-slot cache; the weight operands in ``example_args`` are
     the same traced-argument set the full ``kv_generate`` program uses,
     so the counted HLO is the per-step slice of the real decode scan."""
     eng = _DecodeEngine(model, batch, max(total - 1, 1), total,
-                        temperature, top_k, "batched", weights, fused,
-                        stacked)
+                        temperature, top_k, "batched", weights, stacked)
     from ..gluon.parameter import params_swapped
 
-    def step(param_vals, q8, packed_t, sw, tok, pos, ck, cv, key0):
+    def step(param_vals, q8, sw, tok, pos, ck, cv, key0):
         with _TRACE_LOCK, params_swapped(eng.params, param_vals):
-            logits, ck, cv = eng.token_step(tok, pos, ck, cv, q8,
-                                            packed_t, sw)
+            logits, ck, cv = eng.token_step(tok, pos, ck, cv, q8, sw)
             nxt = eng._sample(logits, pos, key0)
         return nxt, ck, cv
 

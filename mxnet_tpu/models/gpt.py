@@ -102,8 +102,8 @@ class GPT(HybridBlock):
         four LayerNorm rows) — the operand set of the stacked-layer
         ``lax.scan`` decode path in ``models.kv_generate``, which runs
         ONE layer-body's worth of HLO instead of ``num_layers`` unrolled
-        copies.  See ``ops.decode_fused.stack_decode_weights``."""
-        from ..ops.decode_fused import stack_decode_weights
+        copies.  See ``decoding.stack_decode_weights``."""
+        from .decoding import stack_decode_weights
         return stack_decode_weights(self.blocks)
 
     def generate(self, prompt_tokens, max_new_tokens=32, temperature=1.0,

@@ -178,8 +178,7 @@ class LayeredEngine:
     dense_chunk = 256
 
     def __init__(self, model, B, P, total, temperature=0.0, top_k=0,
-                 prefill="batched", weights="native", fused="off",
-                 stacked="auto"):
+                 prefill="batched", weights="native"):
         if weights != "native":
             from ..base import MXNetError
             raise MXNetError("the layered decode engine serves native "
@@ -213,7 +212,7 @@ class LayeredEngine:
 
     # -- what serve.engine.PoolPrograms reads --------------------------- #
     def take_operands(self):
-        operands = (self.param_vals, None, None, None)
+        operands = (self.param_vals, None, None)
         self.param_vals = None
         return operands
 

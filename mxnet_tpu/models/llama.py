@@ -182,8 +182,8 @@ class Llama(HybridBlock):
         arrays — the Llama/GQA operand set of the stacked-layer
         ``lax.scan`` decode path (``models.kv_generate``).  See
         ``GPT.stacked_decode_weights`` and
-        ``ops.decode_fused.stack_decode_weights``."""
-        from ..ops.decode_fused import stack_decode_weights
+        ``decoding.stack_decode_weights``."""
+        from .decoding import stack_decode_weights
         return stack_decode_weights(self.blocks)
 
     def generate(self, prompt_tokens, max_new_tokens=32, temperature=1.0,

@@ -110,7 +110,7 @@ class _TransformerCell(HybridBlock):
     def decode_layer_arrays(self):
         """This layer's decode weights as a flat dict of device arrays —
         one slot per projection/bias/norm row, uniform across the GPT
-        family so ``ops.decode_fused.stack_decode_weights`` can stack the
+        family so ``models.decoding.stack_decode_weights`` can stack the
         whole block list into (NL, ...) arrays for the stacked-layer scan
         decode (``models.kv_generate``).  Missing biases are exported as
         zeros so every layer stacks to the same pytree."""

@@ -188,19 +188,6 @@ def Convolution(data, weight, bias=None, *, kernel=(), stride=(), dilate=(),
         feat_axis = data.ndim - 1
     dn = lax.conv_dimension_numbers(data.shape, weight.shape,
                                     (dn_in, dn_ker, dn_out))
-    if feat_axis == data.ndim - 1 and ndim == 2 and \
-            all(p == 0 for p in pad):
-        # NHWC 1x1 stride-1: route through the fused Pallas backward
-        # (dgrad+wgrad in one HBM pass — BASELINE.md ResNet section;
-        # the gate re-checks shape/stride/groups and falls back here)
-        from .conv_fused import conv1x1_nhwc, fused_bwd_supported
-        if fused_bwd_supported(data.shape, weight.shape, stride, dilate,
-                               num_group,
-                               itemsize=jnp.dtype(data.dtype).itemsize):
-            out = conv1x1_nhwc(data, weight)
-            if not no_bias and bias is not None:
-                out = out + bias.reshape((1,) * (out.ndim - 1) + (-1,))
-            return out
     out = lax.conv_general_dilated(
         data, weight,
         window_strides=stride,

@@ -336,7 +336,7 @@ class PoolPrograms:
         self.eos_id = None if eos_id is None else int(eos_id)
         self.weights = weights
         self.eng = decode_engine(model, self.S, 1, self.Tp, temperature,
-                                 top_k, "batched", weights, "off", "auto")
+                                 top_k, "batched", weights, "auto")
         # a layered engine (per-layer kinds) brings its own row kinds and,
         # where a kind keeps a window, a second page table: a ring of
         # ``ring`` entries a slot, wide enough for the window plus the
@@ -355,7 +355,7 @@ class PoolPrograms:
         if self.eng.mode not in ("stacked", "layered"):
             raise MXNetError(
                 "slot-pool serving needs the stacked-layer scan decode "
-                "step (uniform GPT/Llama stack — see ops/decode_fused."
+                "step (uniform GPT/Llama stack — see models/decoding."
                 "stacked_decode_supported); this model resolved to "
                 f"{self.eng.mode!r}.  MXNET_SERVE_SYNC=1 serves it "
                 "through the synchronous kv_generate fallback instead.")
@@ -365,8 +365,7 @@ class PoolPrograms:
             self.page, self.quant_kv)
         # the server owns the weight operands (engine refs dropped so
         # the cached executables' closures can't pin stale arrays)
-        param_vals, q8, _packed, sw = self.eng.take_operands()
-        self.operands = (param_vals, q8, sw)
+        self.operands = self.eng.take_operands()
         self._step = None
         self._admits = {}          # (A, P) bucket pair -> jitted fn
         self._hits = {}            # A bucket -> jitted hit-admission fn
@@ -538,7 +537,7 @@ class PoolPrograms:
         else:
             peng = _DecodeEngine(self.model, A, P, ppad,
                                  self.temperature, self.top_k, "batched",
-                                 self.weights, "off", "auto")
+                                 self.weights, "auto")
             peng.take_operands()   # server-held operands are the only refs
             NL, KV, D = peng.NL, peng.KV, peng.D
 

@@ -333,11 +333,20 @@ def test_description_drives_the_engine(tiny):
     assert desc[1]["ffn"]["held"] == (4, 8) \
         and desc[1]["ffn"]["experts"] == 16
     eng = decoding.decode_engine(net, 2, 1, 32, 0.0, 0, "batched", "native",
-                                 "off", "auto")
+                                 "auto")
     assert isinstance(eng, layered.LayeredEngine)
     assert schema.pool_rows("latent_index") == ("main",
                                                 ("latent", "index_key"))
     assert schema.pool_rows("latent_window") == ("window", ("latent",))
+
+
+def test_layered_engine_hands_over_three_operands(tiny):
+    """Same face as ``_DecodeEngine.take_operands``: (parameter values, q8,
+    stacked weights), the last two empty here, and the engine keeps none."""
+    eng = layered.LayeredEngine(tiny[0], 2, 1, 32)
+    param_vals, q8, sw = eng.take_operands()
+    assert len(param_vals) == len(eng.params) > 0
+    assert q8 is None and sw is None and eng.param_vals is None
 
 
 @pytest.mark.parametrize("family", ["gpt", "llama"])
@@ -353,7 +362,7 @@ def test_uniform_families_stay_on_the_stacked_scan(family):
     assert {d["cache"] for d in desc} == {"kv"}
     assert schema.pool_rows("kv") == ("main", ("k", "v"))
     eng = decoding.decode_engine(net, 2, 1, 32, 0.0, 0, "batched", "native",
-                                 "off", "auto")
+                                 "auto")
     assert type(eng) is decoding._DecodeEngine and eng.mode == "stacked"
 
 

@@ -322,3 +322,38 @@ class TestBNHandWrittenBackward:
         for c, a, nm in zip(gc, ga, ("dx", "dgamma", "dbeta")):
             onp.testing.assert_allclose(onp.asarray(c), onp.asarray(a),
                                         rtol=2e-4, atol=2e-5, err_msg=nm)
+
+
+class TestConvolutionNHWC1x1:
+    """NHWC 1x1 stride-1 ``Convolution`` (every bottleneck conv of an NHWC
+    ResNet) lowers to ``lax.conv_general_dilated`` like every other conv:
+    output and both gradients must equal the channel einsum's."""
+
+    @pytest.mark.parametrize("shape", [(2, 8, 8, 64, 256),
+                                       (1, 4, 4, 128, 32),
+                                       (4, 8, 8, 256, 64)])
+    def test_matches_channel_einsum(self, shape):
+        import jax
+        import jax.numpy as jnp
+        from mxnet_tpu.ops import nn as nn_ops
+        n, h, w_, ci, co = shape
+        rng = onp.random.RandomState(0)
+        x = jnp.asarray(rng.randn(n, h, w_, ci), jnp.float32)
+        w = jnp.asarray(rng.randn(co, ci, 1, 1) * 0.05, jnp.float32)
+
+        def conv(x, w):
+            return nn_ops.Convolution.__wrapped__(
+                x, w, kernel=(1, 1), num_filter=co, no_bias=True,
+                layout="NHWC")
+
+        def ref(x, w):
+            return jnp.einsum("bhwc,oc->bhwo", x, w[:, :, 0, 0])
+
+        y, vjp = jax.vjp(conv, x, w)
+        y_ref, vjp_ref = jax.vjp(ref, x, w)
+        onp.testing.assert_allclose(y, y_ref, rtol=1e-5, atol=1e-5)
+        dy = jnp.asarray(rng.randn(*y.shape), jnp.float32)
+        (dx, dw), (dx_ref, dw_ref) = vjp(dy), vjp_ref(dy)
+        onp.testing.assert_allclose(dx, dx_ref, rtol=2e-4, atol=1e-4)
+        onp.testing.assert_allclose(dw, dw_ref, rtol=2e-4, atol=1e-3)
+        assert dw.dtype == w.dtype and dw.shape == w.shape

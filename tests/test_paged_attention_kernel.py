@@ -198,9 +198,9 @@ def test_paged_step_through_the_kernel_is_greedy_exact(family, lowering,
     else:
         monkeypatch.delenv("MXNET_FLASH_INTERPRET", raising=False)
     eng = _DecodeEngine(_net(family), S_B, 1, S_T, 0.0, 0, "batched",
-                        "native", "off", "auto")
+                        "native", "auto")
     assert eng.walks_pages(S_PAGE) and not eng.walks_pages(S_PAGE, True)
-    param_vals, q8, _, sw = eng.take_operands()
+    param_vals, q8, sw = eng.take_operands()
     NL, KV, Dh = eng.NL, eng.KV, eng.D
     rng = onp.random.RandomState(7)
     pos = onp.array([5, 17, 40], onp.int32)

@@ -61,10 +61,9 @@ def _pages_of(dense, table, fill):
 @pytest.mark.parametrize("family", ["gpt_mha", "llama_gqa"])
 def test_paged_step_matches_dense_step(family, pool_dtype, scenario):
     net = _gpt() if family == "gpt_mha" else _llama()
-    eng = _DecodeEngine(net, B, 1, T, 0.0, 0, "batched", "native", "off",
-                        "auto")
+    eng = _DecodeEngine(net, B, 1, T, 0.0, 0, "batched", "native", "auto")
     assert eng.mode == "stacked"
-    param_vals, q8, _, sw = eng.take_operands()
+    param_vals, q8, sw = eng.take_operands()
     NL, KV, D = eng.NL, eng.KV, eng.D
     rng = onp.random.RandomState(7)
     quant = pool_dtype == "int8"

@@ -57,17 +57,12 @@ def reports(chip, progs):
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    # what the chip would compile: not the interpreted Pallas kernels that
-    # other test files switch on for the whole process as they are imported
-    env = pytest.MonkeyPatch()
-    env.delenv("MXNET_FLASH_INTERPRET", raising=False)
     try:
         return {
             "step": rs.pool_report(rs.compile_step(progs, chip), progs),
             "admit": rs.pool_report(rs.compile_admit(progs, chip, 2, 32),
                                     progs)}
     finally:
-        env.undo()
         jax.config.update("jax_enable_compilation_cache", was)
         compilation_cache.reset_cache()
 

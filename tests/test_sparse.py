@@ -446,7 +446,7 @@ class TestJittableCSRUnion:
 class TestSparseScalarDtypeGate:
     """Scalar mul/div storage-preservation is gated to floating dtypes
     and nonzero divisors — int sparse must promote like the dense op
-    instead of truncating the scale factor to 0 (ADVICE.md item)."""
+    instead of truncating the scale factor to 0 (a review finding, since fixed)."""
 
     def _int_rs(self):
         d = onp.zeros((4, 5), "int32")

@@ -1,13 +1,14 @@
 """Stacked-layer scan decode (models/decoding.py stacked_token +
-ops/decode_fused.stack_decode_weights): ONE lax.scan over the layer axis
-must reproduce the per-layer unrolled step token-for-token (greedy AND
-sampled, GPT and Llama/GQA), collapse the compiled step's HLO op count
-under the ROADMAP ceiling, and keep the whole token loop on one
-executable.  The perf claims live in benchmark/decode_bench.py and
-BASELINE.md."""
+stack_decode_weights): ONE lax.scan over the layer axis must reproduce
+the per-layer unrolled step token-for-token (greedy AND sampled, GPT and
+Llama/GQA), collapse the compiled step's HLO op count under the ROADMAP
+ceiling, and keep the whole token loop on one executable; the choice
+between the two steps (``stacked_decode_supported``, ``decode_mode``) is
+held here too."""
 import os
 import subprocess
 import sys
+from types import SimpleNamespace
 
 import numpy as onp
 import pytest
@@ -92,9 +93,9 @@ class TestStackedParity:
 
     def test_weight_update_invalidates_stack(self):
         """The stacked arrays must restack after a weight rebind (the
-        pinned-source discipline shared with the Pallas pack and q8
-        caches) — and the already-compiled program must pick up the new
-        values through its traced weight operands."""
+        pinned-source discipline shared with the q8 caches) — and the
+        already-compiled program must pick up the new values through
+        its traced weight operands."""
         from mxnet_tpu.models import kv_generate
         net = _gpt(init=0.15)
         prompt = onp.random.RandomState(3).randint(0, 97, (1, 4))
@@ -118,25 +119,6 @@ class TestStackedGating:
         lnet, _ = _llama()
         assert decode_mode(lnet) == "stacked"
 
-    def test_env_hatch_restores_unrolled(self, monkeypatch):
-        from mxnet_tpu.base import MXNetError
-        from mxnet_tpu.models import decode_mode, kv_generate
-        net = _gpt()
-        monkeypatch.setenv("MXNET_STACKED_DECODE", "0")
-        assert decode_mode(net) == "unrolled"
-        prompt = onp.random.RandomState(4).randint(0, 97, (1, 4))
-        out = kv_generate(net, prompt, max_new_tokens=3, temperature=0.0)
-        key_modes = {k[-1] for k in net._kv_decode_cache}
-        assert key_modes == {"unrolled"}
-        # an explicit stacked='on' conflicts with the kill switch
-        with pytest.raises(MXNetError, match="MXNET_STACKED_DECODE"):
-            kv_generate(net, prompt, max_new_tokens=3, temperature=0.0,
-                        stacked="on")
-        # hatch off again: same prompt now compiles the stacked program
-        monkeypatch.delenv("MXNET_STACKED_DECODE")
-        ref = kv_generate(net, prompt, max_new_tokens=3, temperature=0.0)
-        onp.testing.assert_array_equal(out, ref)
-
     def test_int8_runs_stacked_where_supported(self):
         """The q8 stream rides the stacked scan by default (ROADMAP PR 5
         remainder); the unrolled fallback still covers it when the stack
@@ -149,20 +131,6 @@ class TestStackedGating:
         net.blocks[1].ln1._eps = 1e-3          # non-uniform stack
         assert decode_mode(net, weights="int8") == "unrolled"
 
-    def test_fused_requires_explicit_opt_in(self):
-        """VERDICT r5: fused='auto' must NOT select the unmeasured
-        Pallas megakernel — 'auto' resolves to stacked/unrolled, and
-        'on' raises where the TPU gate rejects the config (always on
-        CPU without interpret mode)."""
-        from mxnet_tpu.base import MXNetError
-        from mxnet_tpu.models import decode_mode
-        net = _gpt()
-        assert decode_mode(net, fused="auto") == "stacked"
-        with pytest.raises(MXNetError, match="fused"):
-            decode_mode(net, fused="on")
-        with pytest.raises(ValueError, match="stacked"):
-            decode_mode(net, stacked="sideways")
-
     def test_invalid_args_raise_even_with_zero_new_tokens(self):
         """Argument validation runs ahead of the max_new_tokens<=0 early
         return (post-review regression: a typo must fail fast in 0-token
@@ -171,7 +139,7 @@ class TestStackedGating:
         net = _gpt()
         prompt = onp.zeros((1, 4), onp.int32)
         for bad in (dict(weights="int4"), dict(prefill="batch"),
-                    dict(fused="always"), dict(stacked="sideways")):
+                    dict(stacked="sideways")):
             with pytest.raises(ValueError):
                 kv_generate(net, prompt, max_new_tokens=0, **bad)
 
@@ -213,7 +181,7 @@ class TestStackedGating:
         the unrolled path (which derives math from the model's own
         sublayers) with correct output."""
         from mxnet_tpu.models import decode_mode, kv_generate
-        from mxnet_tpu.ops.decode_fused import stacked_decode_supported
+        from mxnet_tpu.models.decoding import stacked_decode_supported
         net = _gpt()
         net.blocks[1].ln1._eps = 1e-3
         assert not stacked_decode_supported(net)
@@ -237,6 +205,89 @@ class TestStackedGating:
         gsw = gnet.stacked_decode_weights()
         assert gsw["qkv_w"].shape == (3, 96, 32)
         assert gsw["fc1_b"].shape == (3, 64)
+
+
+def _differing_eps():
+    net = _gpt()
+    net.blocks[1].ln1._eps = 1e-3
+    return net
+
+
+def _differing_activation():
+    net = _gpt()
+    net.blocks[1].ffn.fc1.act._act_type = "relu"
+    return net
+
+
+def _block_without_export():
+    net = _gpt()
+    return SimpleNamespace(blocks=[net.blocks[0], object()],
+                           stacked_decode_weights=net.stacked_decode_weights)
+
+
+def _unmaterialised():
+    from mxnet_tpu.models import GPT, GPTConfig
+    return GPT(GPTConfig(vocab_size=97, max_length=64, num_layers=2,
+                         units=32, num_heads=4, hidden_size=64))
+
+
+def _differing_slot_shapes():
+    net = _gpt()
+    return SimpleNamespace(
+        blocks=[net.blocks[0], _gpt(hidden=128).blocks[0]],
+        stacked_decode_weights=net.stacked_decode_weights)
+
+
+class TestStepChoice:
+    """The one fork that remains: which of the two steps a model gets.
+    Pure Python over block attributes — no decode is compiled here."""
+
+    @pytest.mark.parametrize("build,expect", [
+        (_gpt, True),
+        (lambda: _llama()[0], True),
+        (_differing_eps, False),
+        (_differing_activation, False),
+        (_block_without_export, False),
+        (_unmaterialised, False),
+        (_differing_slot_shapes, False),
+    ], ids=["uniform_gpt", "uniform_llama_gqa", "differing_norm_eps",
+            "differing_fc1_activation", "block_without_export",
+            "unmaterialised_parameters", "differing_slot_shapes"])
+    def test_stacked_decode_supported(self, build, expect):
+        from mxnet_tpu.models.decoding import stacked_decode_supported
+        assert stacked_decode_supported(build()) is expect
+
+    @pytest.mark.parametrize("stacked,expect", [
+        ("auto", "stacked"), ("on", "stacked"), ("off", "unrolled")])
+    @pytest.mark.parametrize("family", ["gpt", "llama"])
+    def test_decode_mode(self, family, stacked, expect):
+        from mxnet_tpu.models import decode_mode
+        net = _gpt() if family == "gpt" else _llama()[0]
+        for weights in ("native", "int8"):
+            assert decode_mode(net, weights=weights,
+                               stacked=stacked) == expect
+
+    def test_stacked_on_raises_on_a_non_uniform_stack(self):
+        from mxnet_tpu.base import MXNetError
+        from mxnet_tpu.models import decode_mode
+        net = _differing_eps()
+        assert decode_mode(net) == "unrolled"
+        with pytest.raises(MXNetError, match="stacked_decode_supported"):
+            decode_mode(net, stacked="on")
+        with pytest.raises(ValueError, match="stacked"):
+            decode_mode(net, stacked="sideways")
+
+    def test_take_operands_hands_over_three_and_keeps_none(self):
+        """(parameter values, q8, stacked weights): the engine's cached
+        program closes over it, so it must not hold what it handed on."""
+        from mxnet_tpu.models.decoding import _DecodeEngine
+        eng = _DecodeEngine(_gpt(), 1, 1, 8, 0.0, 0, "batched", "int8",
+                            "auto")
+        param_vals, q8, sw = eng.take_operands()
+        assert len(param_vals) == len(eng.params) > 0
+        assert set(q8) == {"head"} and "qkv" in sw
+        assert eng.param_vals is None and eng.q8v is None \
+            and eng.sw is None
 
 
 class TestInt8StackedParity:

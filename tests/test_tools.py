@@ -155,3 +155,77 @@ class TestProfiler:
         import json
         trace = json.load(open(str(tmp_path / "prof.json")))
         assert any(ev["name"] == "dot" for ev in trace["traceEvents"])
+
+
+def _import_time_env_writes(tree):
+    """(line, name) of every write of an ``MXNET_*`` environment variable
+    that runs when the module is imported: anywhere but inside a function
+    body (class bodies and module-level ``if`` / ``try`` / ``with`` run)."""
+    import ast
+
+    def mxnet_names(node):
+        return [n.value for n in ast.walk(node)
+                if isinstance(n, ast.Constant) and isinstance(n.value, str)
+                and n.value.startswith("MXNET_")] + \
+               [k.arg for k in getattr(node, "keywords", [])
+                if k.arg and k.arg.startswith("MXNET_")]
+
+    def is_environ(node):
+        return (isinstance(node, ast.Attribute) and node.attr == "environ") \
+            or (isinstance(node, ast.Name) and node.id == "environ")
+
+    found = []
+
+    def visit(node):
+        if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef,
+                             ast.Lambda)):
+            return
+        if isinstance(node, (ast.Assign, ast.Delete)):
+            for t in node.targets:
+                if isinstance(t, ast.Subscript) and is_environ(t.value):
+                    found.extend((node.lineno, n)
+                                 for n in mxnet_names(t.slice))
+        if isinstance(node, ast.Call) and isinstance(node.func, ast.Attribute):
+            f = node.func
+            if f.attr in ("putenv", "unsetenv") or (
+                    is_environ(f.value)
+                    and f.attr in ("setdefault", "update", "pop")):
+                found.extend((node.lineno, n) for n in mxnet_names(node))
+        for child in ast.iter_child_nodes(node):
+            visit(child)
+
+    visit(tree)
+    return found
+
+
+class TestNoImportTimeEnvWrites:
+    def test_checker_sees_the_forms(self):
+        import ast
+        src = ("import os\n"
+               "os.environ.setdefault('MXNET_A', '1')\n"
+               "os.environ['MXNET_B'] = '1'\n"
+               "if True:\n"
+               "    os.environ.update(MXNET_C='1')\n"
+               "class K:\n"
+               "    os.putenv('MXNET_D', '1')\n"
+               "os.environ['JAX_PLATFORMS'] = 'cpu'\n"
+               "def f():\n"
+               "    os.environ['MXNET_E'] = '1'\n")
+        assert [n for _, n in _import_time_env_writes(ast.parse(src))] == \
+            ["MXNET_A", "MXNET_B", "MXNET_C", "MXNET_D"]
+
+    def test_no_test_module_writes_mxnet_env_at_import(self):
+        """A test module that sets an ``MXNET_*`` variable while it is
+        imported sets it for every test the worker runs after it, so the
+        suite's outcome depends on which files share a process (PERF.md,
+        PR 30 "Lost": a TPU-target compile held the INTERPRETED kernel).
+        Set it per test, through ``monkeypatch``."""
+        import ast
+        import pathlib
+        here = pathlib.Path(__file__).resolve().parent
+        bad = []
+        for path in sorted(here.rglob("*.py")):
+            tree = ast.parse(path.read_text(), filename=str(path))
+            bad += [f"{path.relative_to(here)}:{line} {name}"
+                    for line, name in _import_time_env_writes(tree)]
+        assert not bad, bad
