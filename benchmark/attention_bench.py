@@ -10,6 +10,13 @@ table; ``tests/test_attention.py`` asserts the dispatch matches it.
 
     python benchmark/attention_bench.py            # full sweep
     python benchmark/attention_bench.py --seqs 512,2048
+    python benchmark/attention_bench.py --batch 8 --heads 16 --seqs 1024
+    python benchmark/attention_bench.py --seqs 1024 --blocks 128,256,512
+
+``--batch`` / ``--heads`` set the shape (the table's is B4 H8, the train
+cell's B8 H16); ``--blocks`` times each Pallas kernel alone (forward, dq,
+dk/dv) over every pair of the listed block sizes instead — the sweep behind
+``_block_for`` / ``_block_dkv_for``.
 
 Every timed region ends in a device->host readback (as in bench.py), and
 dispatch is amortized by looping the op inside one jit via lax.scan.
@@ -18,6 +25,7 @@ from __future__ import annotations
 
 import argparse
 import functools
+import itertools
 import json
 import os
 import sys
@@ -33,6 +41,11 @@ def main():
     ap.add_argument("--seqs", default="512,1024,2048,4096,8192")
     ap.add_argument("--budget", type=float, default=1.5,
                     help="target device-seconds per timed dispatch")
+    ap.add_argument("--batch", type=int, default=4)
+    ap.add_argument("--heads", type=int, default=8)
+    ap.add_argument("--blocks", default="",
+                    help="comma-separated block sizes: time each Pallas "
+                         "kernel alone over every (resident, streamed) pair")
     args = ap.parse_args()
 
     import jax
@@ -42,21 +55,26 @@ def main():
     from mxnet_tpu.ops import attention as attn
 
     platform = jax.devices()[0].platform
-    B, H, D = 4, 8, 64
+    B, H, D = args.batch, args.heads, 64
     dtype = jnp.bfloat16 if platform == "tpu" else jnp.float32
 
     def bench(fn, *args_):
         """Adaptive timing: calibrate with a short run, then size the
         in-dispatch rep count so device work dwarfs the per-dispatch host
         cost.  Each iteration feeds its first
-        output back as the first input (same (B,H,L,D) shape) so XLA
+        outputs back as the first input (same (B,H,L,D) shape) so XLA
         cannot hoist the loop-invariant op out of the scan."""
         def make(inner):
             @jax.jit
             def looped(q0, *rest):
                 def body(c, _):
                     out = fn(c, *rest)
-                    nxt = out[0] if isinstance(out, tuple) else out
+                    # every q-shaped output feeds the next iteration: one
+                    # left unused (dk, dv) is dead code, and XLA drops
+                    # the kernel that made it
+                    outs = out if isinstance(out, tuple) else (out,)
+                    nxt = sum(o.astype(jnp.float32) for o in outs
+                              if o is not None and o.shape == q0.shape)
                     return nxt.astype(q0.dtype), None
                 c, _ = lax.scan(body, q0, None, length=inner)
                 return jnp.sum(c.astype(jnp.float32))
@@ -79,14 +97,25 @@ def main():
 
     results = {}
 
-    def emit(seq, impl, pas, ms):
-        # fwd: 2 matmuls (QK^T, PV) = 4*B*H*L^2*D flops; bwd ~2.5x fwd
+    def emit(seq, impl, pas, timed):
+        try:
+            ms = timed()
+        except Exception as e:      # a point too large for the chip's HBM
+            print(json.dumps({"bench": "flash_attention", "batch": B,
+                              "heads": H, "seq": seq, "impl": impl,
+                              "pass": pas, "error": str(e)[:200]}))
+            return
+        # fwd: 2 matmuls (QK^T, PV) = 4*B*H*L^2*D flops; bwd ~2.5x fwd.
+        # The causal count is the half a causal model needs
+        # (chipbench/shapes.train_flops_per_token counts that one).
         flops = 4 * B * H * seq * seq * D * (1 if pas == "fwd" else 3.5)
         results[(seq, impl, pas)] = ms
         print(json.dumps({
-            "bench": "flash_attention", "seq": seq, "impl": impl,
-            "pass": pas, "ms": round(ms, 3),
+            "bench": "flash_attention", "batch": B, "heads": H, "seq": seq,
+            "impl": impl, "pass": pas, "ms": round(ms, 3),
             "tflops": round(flops / ms / 1e9, 2),
+            "causal_gflop": round(flops / 2 / 1e9, 2),
+            "causal_tflops": round(flops / 2 / ms / 1e9, 2),
             "platform": platform}))
         sys.stdout.flush()
 
@@ -99,22 +128,57 @@ def main():
                             else (lambda: False))
 
     scale = 1.0 / D ** 0.5
+    blocks = [int(b) for b in args.blocks.split(",") if b]
     for seq in [int(s) for s in args.seqs.split(",")]:
         rng = onp.random.RandomState(0)
         q, k, v = (jnp.asarray(rng.randn(B, H, seq, D), dtype)
                    for _ in range(3))
 
+        if blocks:
+            # each kernel alone; "resident" is the block that stays while
+            # the innermost grid axis walks the "streamed" one
+            force_pallas(True)
+            out, lse = attn._pallas_fwd(q, k, v, scale, True)
+            lse = lse.reshape(B * H, 1, seq)
+            delta = jnp.sum(out.astype(jnp.float32) ** 2,
+                            axis=-1).reshape(B * H, 1, seq)
+            for res, stream in itertools.product(blocks, blocks):
+                if seq % res or seq % stream:
+                    continue
+                kernels = {
+                    "fwd": (functools.partial(
+                        attn._pallas_fwd, scale=scale, causal=True,
+                        block_q=res, block_k=stream), (q, k, v)),
+                    "bwd_dq": (functools.partial(
+                        attn._pallas_bwd_dq, scale=scale, causal=True,
+                        block_q=res, block_k=stream),
+                        (q, k, v, out, lse, delta)),
+                    "bwd_dkv": (functools.partial(
+                        attn._pallas_bwd_dkv, scale=scale, causal=True,
+                        block_k=res, block_q=stream),
+                        (q, k, v, out, lse, delta)),
+                }
+                for name, (fn, operands) in kernels.items():
+                    print(json.dumps({
+                        "bench": "flash_blocks", "batch": B, "heads": H,
+                        "seq": seq, "kernel": name, "resident": res,
+                        "streamed": stream,
+                        "ms": round(bench(fn, *operands), 4),
+                        "platform": platform}))
+                    sys.stdout.flush()
+            continue
+
         # ---------------- forward ----------------
         if platform == "tpu":
             force_pallas(True)
-            emit(seq, "pallas", "fwd", bench(functools.partial(
+            emit(seq, "pallas", "fwd", lambda: bench(functools.partial(
                 attn._pallas_fwd, scale=scale, causal=True), q, k, v))
-        emit(seq, "xla_blockwise", "fwd", bench(
+        emit(seq, "xla_blockwise", "fwd", lambda: bench(
             lambda q, k, v: attn._blockwise_attn(
                 q, k, v, None, jnp.uint32(0), scale, True, 0.0, 128),
             q, k, v))
         if seq <= 4096:  # plain materializes O(L^2); OOM-prone past 4k
-            emit(seq, "plain", "fwd", bench(functools.partial(
+            emit(seq, "plain", "fwd", lambda: bench(functools.partial(
                 attn._plain_attn, bias=None, scale=scale, causal=True),
                 q, k, v))
 
@@ -131,15 +195,15 @@ def main():
 
         if platform == "tpu":
             force_pallas(True)
-            emit(seq, "pallas", "fwd+bwd",
-                 bench(jax.grad(flash_loss, argnums=(0, 1, 2)), q, k, v))
+            emit(seq, "pallas", "fwd+bwd", lambda: bench(
+                jax.grad(flash_loss, argnums=(0, 1, 2)), q, k, v))
         force_pallas(False)
-        emit(seq, "xla_blockwise", "fwd+bwd",
-             bench(jax.grad(flash_loss, argnums=(0, 1, 2)), q, k, v))
+        emit(seq, "xla_blockwise", "fwd+bwd", lambda: bench(
+            jax.grad(flash_loss, argnums=(0, 1, 2)), q, k, v))
         force_pallas(True)
         if seq <= 4096:
-            emit(seq, "plain", "fwd+bwd",
-                 bench(jax.grad(plain_loss, argnums=(0, 1, 2)), q, k, v))
+            emit(seq, "plain", "fwd+bwd", lambda: bench(
+                jax.grad(plain_loss, argnums=(0, 1, 2)), q, k, v))
 
     # summary: fastest impl per (seq, pass)
     best = {}

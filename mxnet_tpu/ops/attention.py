@@ -6,9 +6,13 @@ Reference counterpart: the BERT-era fused attention matmuls
 The TPU-native answer (SURVEY.md §5.7 — NEW capability, not parity) is:
 
 - ``flash_attention``: blockwise online-softmax attention, O(L) memory.
-  On TPU both the forward AND backward run as Pallas kernels (MXU-tiled
-  128-blocks, fp32 accumulation); everywhere else a ``lax.scan`` blockwise
-  implementation that XLA fuses.  Padding masks (additive bias of layout
+  On TPU both the forward AND backward run as Pallas kernels (blocks of
+  up to 1,024 rows at the head's own width); everywhere else a
+  ``lax.scan`` blockwise implementation that XLA fuses.  On every path the
+  matrix products take their operands in the dtype they arrive in
+  (bfloat16 q, k, v, do; the probabilities and ``ds`` cast to match) and
+  accumulate in float32; scores, softmax statistics, ``lse`` and ``delta``
+  are float32.  Padding masks (additive bias of layout
   ``(B|1, 1, 1, Lk)``) and attention dropout run INSIDE the kernels;
   general dense biases (e.g. ALiBi tables) take the XLA blockwise path.
   Backward recomputes blockwise from the saved log-sum-exp (the
@@ -28,6 +32,7 @@ Shapes follow (batch, heads, seq, head_dim) throughout.
 from __future__ import annotations
 
 import functools
+import inspect
 import os
 from typing import Optional
 
@@ -138,7 +143,6 @@ def _blockwise_attn(q, k, v, bias, seed, scale, causal, dropout, q_block):
             (bias.shape[0], bias.shape[1], Lq, Lk))
         bias = jnp.pad(bias, ((0, 0), (0, 0), (0, pad_q), (0, 0))) \
             if pad_q else bias
-    v32 = v.astype(jnp.float32)
     kpos = lax.broadcasted_iota(jnp.int32, (1, Lk), 1)
     bh = (lax.broadcasted_iota(jnp.int32, (B, H), 0) * H +
           lax.broadcasted_iota(jnp.int32, (B, H), 1))[..., None, None]
@@ -162,7 +166,10 @@ def _blockwise_attn(q, k, v, bias, seed, scale, causal, dropout, q_block):
             keep = _keep(seed, bh, qpos[None, None], kpos[None, None],
                          dropout)
             p = jnp.where(keep, p, 0.0) / (1.0 - dropout)
-        o = jnp.einsum("bhqk,bhkd->bhqd", p, v32) / jnp.maximum(l, 1e-30)
+        # p goes to the MXU in v's dtype, as q and k did (_plain_attn)
+        o = jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v,
+                       preferred_element_type=jnp.float32) \
+            / jnp.maximum(l, 1e-30)
         lse = (m + jnp.log(jnp.maximum(l, 1e-30)))[..., 0]
         return o, lse
 
@@ -190,52 +197,182 @@ def _kmask_arrays(bias, B):
         bias.shape[0], 1, bias.shape[3])
 
 
-def _pad_heads(x, D):
-    if x.shape[-1] == D:
-        return x
-    return jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, D - x.shape[-1]),))
-
-
-# residual layout: lse/delta are stored lane-replicated at width 128
-# ((BH, L, 128)) — the same scheme as jax.experimental.pallas.ops.tpu.
-# flash_attention — so the backward kernels can read (block_q, 1) columns
-# without any in-kernel transpose.
+# per-row statistics (lse, delta, the key mask's gradient) live in HBM as
+# lane-dense (BH, 1, L) rows — L x 4 bytes a head, where a lane-replicated
+# (BH, L, 128) copy is 128 times that and outweighs q, k, v and g together.
+# A kernel that wants one as a column turns a block of it once per q (or
+# k) block; in VMEM the running statistics stay lane-replicated.
 _LANES = 128
 
 
-def _rep(x):
-    """(BH, L) -> (BH, L, 128) lane-replicated."""
-    return jnp.broadcast_to(x[..., None], x.shape + (_LANES,))
+def _col_to_row(x):
+    """(n, 128) lane-replicated column -> (1, n) row."""
+    return x.T[:1]
 
 
-def _block_q_for(L):
-    """Larger q blocks at length cut k/v HBM re-streaming (traffic scales
-    with L/block_q) while staying within VMEM."""
-    for bq in (512, 256, 128):
-        if L % bq == 0:
-            return bq
-    return _BLOCK
+def _row_to_col(row):
+    """(1, n) row -> (n, 128) lane-replicated column."""
+    return jnp.broadcast_to(row, (_LANES, row.shape[1])).T
 
 
-def _pallas_fwd(q, k, v, scale, causal, kmask=None, seed=None, dropout=0.0,
-                block_q=None, block_k=_BLOCK):
-    """Flash forward on TPU.  Grid (batch·heads, q_blocks, k_blocks) with
-    the k axis innermost: VMEM holds one q/k/v block at a time (O(block·D)
-    VMEM — long sequences stream from HBM) while running max / sum / output
-    accumulators live in VMEM scratch across the k sweep.  head_dim is
-    padded to the 128-lane width so every model head size hits the MXU.
-    ``kmask`` is an optional (Nb, 1, Lk) additive bias (key padding mask);
-    ``dropout``/``seed`` apply in-kernel attention dropout via the shared
-    position hash."""
-    if block_q is None:
-        block_q = _block_q_for(q.shape[2])
+def _block_for(L, dtype):
+    """Rows of a block of the forward and dq kernels, q and k side alike:
+    the whole sequence up to 1,024 rows, the largest multiple of 128 that
+    divides a longer one.  The cost is a block's, not a row's: on the
+    v5e sweep (2026-10-03, B8 H16 D64 bfloat16 causal, ms a kernel at
+    L 1,024: q/k blocks 128/128 3.10 fwd 2.65 dq, 512/512 0.80 / 0.66,
+    1,024/1,024 0.50 / 0.60; at L 2,048: 512/512 2.78 / 2.34, 1,024/1,024
+    1.80 / 2.12) a whole-sequence block that masks its upper triangle beats
+    four blocks that skip one of them.  Inputs wider than two bytes stop
+    at 512: the dq kernel's float32 blocks of 1,024 x 128 beside its four
+    score-sized temporaries do not fit the 16 MiB of VMEM a kernel may
+    take (refused by the compile for a described v5e, not swept)."""
+    cap = 1024 if jnp.dtype(dtype).itemsize <= 2 else 512
+    return max(b for b in range(_BLOCK, cap + 1, _BLOCK) if L % b == 0)
+
+
+def _block_dkv_for(L, dtype):
+    """Rows of a block of the dk/dv kernel, k and q side alike: half of
+    ``_block_for`` where that is still whole 128-lane tiles.  Its steps are
+    cheaper (no running statistics to carry), so skipping a quarter of the
+    scores pays (same sweep, ms at L 768 / 1,024 / 2,048: 0.46 / 0.71 /
+    2.40 at half, 0.49 / 0.84 / 2.48 at the full block; at 4,096 the full
+    block leads, 4.00 against 4.22.  With the diagonal block cut in two,
+    ``_causal_tiles``: 0.60 at half against 0.66 at L 1,024, 2.33 against
+    2.21 at 2,048)."""
+    b = _block_for(L, dtype)
+    return b // 2 if b % 256 == 0 else b
+
+
+_NT = (((1,), (1,)), ((), ()))      # a @ b.T
+_NN = (((1,), (0,)), ((), ()))      # a @ b
+
+
+def _mxu(a, b, dims):
+    """A product on the MXU in its operands' own dtype (bfloat16 stays
+    bfloat16: one pass, where a float32 product takes several), float32
+    accumulation.  The framework's default precision, ``highest``
+    (base.py), is a float32 product's to keep; Mosaic refuses it on
+    narrower operands ("Bad lhs type")."""
+    return lax.dot_general(
+        a, b, dims, preferred_element_type=jnp.float32,
+        precision=None if a.dtype == jnp.float32 else lax.Precision.DEFAULT)
+
+
+def _block_scores(rows, cols, scale, qpos, kpos, causal, add=None):
+    """float32 scores of one block, ``rows @ cols.T``: q rows by k columns
+    in the forward and dq kernels, k rows by q columns in dk/dv —
+    ``qpos`` / ``kpos`` are a column and a row of positions, or a row and
+    a column, to match."""
+    s = _mxu(rows, cols, _NT) * scale
+    if add is not None:
+        s = s + add
+    if causal:
+        s = jnp.where(qpos >= kpos, s, _NEG_INF)
+    return s
+
+
+def _causal_tiles(qi, kj, block_q, block_k, causal, split_along, tile):
+    """Run ``tile(q_rows, k_rows, masked)`` — static slices of the
+    resident q and k blocks — over what the causal mask leaves of block
+    ``(qi, kj)``: nothing above the diagonal, the whole block unmasked
+    below it, one masked tile on it.  With ``split_along`` a square block
+    on the diagonal is cut in two along the side whose rows the kernel
+    accumulates over ("q" in dq, "k" in dk/dv) and its upper-right quarter
+    skipped: on the v5e sweep (2026-10-03, B8 H16 D64 bfloat16, L 1,024)
+    dq went 0.594 -> 0.464 ms and dk/dv 0.687 -> 0.598; the forward, whose
+    two halves each carry the running statistics, went 0.496 -> 0.561 and
+    passes ``None``."""
+    from jax.experimental import pallas as pl
+
+    whole = slice(0, block_q), slice(0, block_k)
+    if not causal:
+        tile(*whole, False)
+    elif split_along and block_q == block_k and block_q % (2 * _BLOCK) == 0:
+        lo, hi = slice(0, block_q // 2), slice(block_q // 2, block_q)
+        halves = ((lo, lo), (hi, whole[1])) if split_along == "q" else \
+            ((whole[0], lo), (hi, hi))
+        pl.when(kj < qi)(lambda: tile(*whole, False))
+
+        @pl.when(kj == qi)
+        def _on_the_diagonal():
+            for q_rows, k_rows in halves:
+                tile(q_rows, k_rows, True)
+    else:
+        pl.when((qi + 1) * block_q > kj * block_k)(
+            lambda: tile(*whole, True))
+
+
+def _streamed_k_at(block_q, block_k, causal):
+    """Index map of the k and v blocks of a (BH, q blocks, k blocks) grid:
+    a causal block the mask removes re-names the last one its q block
+    reads, so a skipped step fetches nothing."""
+    if not causal:
+        return lambda b, i, j: (b, j, 0)
+    return lambda b, i, j: (
+        b, jnp.minimum(j, ((i + 1) * block_q - 1) // block_k), 0)
+
+
+def _lead_operands(seed, kmask, H, block_k, k_axis):
+    """What every kernel takes first: the dropout seed in SMEM and, where
+    there is one, the (Nb, 1, Lk) key mask by k block (``k_axis`` is the
+    grid axis that walks k)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    B, H, L, D0 = q.shape
+    specs = [pl.BlockSpec((1, 1), lambda *g: (0, 0),
+                          memory_space=pltpu.SMEM)]
+    args = [jnp.full((1, 1), 0 if seed is None else seed, jnp.uint32)]
+    if kmask is not None:
+        if kmask.shape[0] == 1:
+            km_idx = lambda *g: (0, 0, g[k_axis])
+        else:
+            km_idx = lambda *g: (g[0] // H, 0, g[k_axis])
+        specs.append(pl.BlockSpec((1, 1, block_k), km_idx,
+                                  memory_space=pltpu.VMEM))
+        args.append(kmask)
+    return specs, args
+
+
+def _kernel_jit(fn):
+    """``jax.jit`` around a kernel's launcher, everything but the arrays
+    static: the layers of a model then trace the kernel and lower it to
+    Mosaic once, not once a layer — at 24 layers 1.4 s of every process
+    start in place of 5.7 s, compile cache warm or not (lowered for a
+    described v5e).  ``interpret`` is read here, outside the cache, and is
+    part of its key."""
+    static = {"scale", "causal", "dropout", "need_dbias", "block_q",
+              "block_k", "interpret"}
+    jitted = jax.jit(fn, static_argnames=sorted(
+        static & set(inspect.signature(fn).parameters)))
+
+    @functools.wraps(fn)
+    def launch(*args, **kwargs):
+        return jitted(*args, interpret=_interpret(), **kwargs)
+    return launch
+
+
+@_kernel_jit
+def _pallas_fwd(q, k, v, scale, causal, kmask=None, seed=None, dropout=0.0,
+                block_q=None, block_k=None, interpret=False):
+    """Flash forward on TPU.  Grid (batch·heads, q_blocks, k_blocks) with
+    the k axis innermost: VMEM holds one q/k/v block at a time (O(block·D)
+    VMEM — long sequences stream from HBM) while running max / sum / output
+    accumulators live in VMEM scratch across the k sweep.  The products
+    take q, k, v as they arrive and ``p`` cast to match; everything else is
+    float32.  A causal block above the diagonal is neither computed nor
+    fetched.  ``kmask`` is an optional (Nb, 1, Lk) additive bias (key
+    padding mask); ``dropout``/``seed`` apply in-kernel attention dropout
+    via the shared position hash.  Returns (out, lse) with lse (B, H, L)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, H, L, D = q.shape
     Lk = k.shape[2]
-    D = max(128, -(-D0 // 128) * 128)
-    q, k, v = (_pad_heads(x, D) for x in (q, k, v))
+    if block_q is None:
+        block_q = _block_for(L, q.dtype)
+    if block_k is None:
+        block_k = _block_for(Lk, k.dtype)
     nq = L // block_q
     nk = Lk // block_k
     inv_keep = 1.0 / (1.0 - dropout) if dropout > 0.0 else 1.0
@@ -255,91 +392,66 @@ def _pallas_fwd(q, k, v, scale, causal, kmask=None, seed=None, dropout=0.0,
             l_s[:] = jnp.zeros_like(l_s)
             acc_s[:] = jnp.zeros_like(acc_s)
 
-        run = True
-        if causal:
-            # skip fully-masked blocks above the diagonal
-            run = (qi + 1) * block_q > kj * block_k
-
-        @pl.when(run if causal else True)
-        def _compute():
-            qb = q_ref[0].astype(jnp.float32)
-            kb = k_ref[0].astype(jnp.float32)
-            vb = v_ref[0].astype(jnp.float32)
-            s = jax.lax.dot_general(
-                qb, kb, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-            if kmask is not None:
-                s = s + km_ref[0]                       # (1, bk) broadcast
-            qpos = qi * block_q + lax.broadcasted_iota(
-                jnp.int32, (block_q, 1), 0)
-            kpos = kj * block_k + lax.broadcasted_iota(
-                jnp.int32, (1, block_k), 1)
-            if causal:
-                s = jnp.where(qpos >= kpos, s, _NEG_INF)
-            m_prev = m_s[:]
+        def tile(qs, ks, masked):
+            nq_, nk_ = qs.stop - qs.start, ks.stop - ks.start
+            qpos = qi * block_q + qs.start + lax.broadcasted_iota(
+                jnp.int32, (nq_, 1), 0)
+            kpos = kj * block_k + ks.start + lax.broadcasted_iota(
+                jnp.int32, (1, nk_), 1)
+            s = _block_scores(
+                q_ref[0, qs], k_ref[0, ks], scale, qpos, kpos, masked,
+                km_ref[0, :, ks] if kmask is not None else None)
+            m_prev = m_s[qs]
             m_new = jnp.maximum(
                 m_prev, jnp.broadcast_to(
-                    jnp.max(s, axis=-1, keepdims=True), (block_q, _LANES)))
+                    jnp.max(s, axis=-1, keepdims=True), (nq_, _LANES)))
             alpha = jnp.exp(m_prev - m_new)
             p = jnp.exp(s - m_new[:, :1])
-            # fully-masked rows/blocks: exp(-1e30 - (-1e30)) == 1 poison
-            p = jnp.where(s <= _NEG_INF * 0.5, 0.0, p)
-            m_s[:] = m_new
-            l_s[:] = l_s[:] * alpha + jnp.broadcast_to(
-                jnp.sum(p, axis=-1, keepdims=True), (block_q, _LANES))
+            if kmask is not None:
+                # a row whose keys so far are all masked:
+                # exp(-1e30 - (-1e30)) == 1 poison.  (Causal alone never
+                # has one: key 0 is open to every row in the first block.)
+                p = jnp.where(s <= _NEG_INF * 0.5, 0.0, p)
+            m_s[qs] = m_new
+            l_s[qs] = l_s[qs] * alpha + jnp.broadcast_to(
+                jnp.sum(p, axis=-1, keepdims=True), (nq_, _LANES))
             if dropout > 0.0:
                 keep = _keep(seed_ref[0, 0], bhi, qpos, kpos, dropout)
                 p = jnp.where(keep, p, 0.0) * inv_keep
-            acc_s[:] = acc_s[:] * alpha[:, :1] + jax.lax.dot_general(
-                p, vb, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+            acc_s[qs] = acc_s[qs] * alpha[:, :1] + _mxu(
+                p.astype(v_ref.dtype), v_ref[0, ks], _NN)
+
+        _causal_tiles(qi, kj, block_q, block_k, causal, None, tile)
 
         @pl.when(kj == nk - 1)
         def _finalize():
             l = jnp.maximum(l_s[:], 1e-30)
             o_ref[0] = (acc_s[:] / l[:, :1]).astype(o_ref.dtype)
-            lse_ref[0] = m_s[:] + jnp.log(l)
+            lse_ref[0] = _col_to_row(m_s[:] + jnp.log(l))
 
-    grid = (B * H, nq, nk)
-    qr = q.reshape(B * H, L, D)
-    kr = k.reshape(B * H, Lk, D)
-    vr = v.reshape(B * H, Lk, D)
-    in_specs = [
-        pl.BlockSpec((1, 1), lambda b, i, j: (0, 0),
-                     memory_space=pltpu.SMEM),
-    ]
-    args = [jnp.full((1, 1), 0 if seed is None else seed, jnp.uint32)]
-    if kmask is not None:
-        Nb = kmask.shape[0]
-        if Nb == 1:
-            km_idx = lambda b, i, j: (0, 0, j)
-        else:
-            km_idx = lambda b, i, j: (b // H, 0, j)
-        in_specs.append(pl.BlockSpec((1, 1, block_k), km_idx,
-                                     memory_space=pltpu.VMEM))
-        args.append(kmask)
+    kv_at = _streamed_k_at(block_q, block_k, causal)
+    in_specs, args = _lead_operands(seed, kmask, H, block_k, 2)
     in_specs += [
         pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0),
-                     memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, block_k, D), kv_at, memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, block_k, D), kv_at, memory_space=pltpu.VMEM),
     ]
-    args += [qr, kr, vr]
-    out, lse_rep = pl.pallas_call(
+    args += [q.reshape(B * H, L, D), k.reshape(B * H, Lk, D),
+             v.reshape(B * H, Lk, D)]
+    out, lse = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(B * H, nq, nk),
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_q, _LANES), lambda b, i, j: (b, i, 0),
+            pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i),
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * H, L, D), q.dtype),
-            jax.ShapeDtypeStruct((B * H, L, _LANES), jnp.float32),
+            jax.ShapeDtypeStruct((B * H, 1, L), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, _LANES), jnp.float32),
@@ -348,31 +460,32 @@ def _pallas_fwd(q, k, v, scale, causal, kmask=None, seed=None, dropout=0.0,
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_interpret(),
+        interpret=interpret,
+        name="mx_flash_fwd",
     )(*args)
-    out = out.reshape(B, H, L, D)
-    if D != D0:
-        out = out[..., :D0]
-    return out, lse_rep[..., 0].reshape(B, H, L)
+    return out.reshape(B, H, L, D), lse.reshape(B, H, L)
 
 
 # --------------------------------------------------------------------------- #
 # Pallas TPU backward kernels (flash-attention-2: recompute from lse)
 # --------------------------------------------------------------------------- #
 
-def _pallas_bwd_dq(q, k, v, g, lse_rep, dlt_rep, scale, causal, kmask=None,
-                   seed=None, dropout=0.0, block_q=None, block_k=_BLOCK):
+@_kernel_jit
+def _pallas_bwd_dq(q, k, v, g, lse, delta, scale, causal, kmask=None,
+                   seed=None, dropout=0.0, block_q=None, block_k=None,
+                   interpret=False):
     """dq kernel: grid (BH, nq, nk), k innermost; dq accumulates in VMEM.
-    ``lse_rep``/``dlt_rep`` are the lane-replicated (BH, L, 128) residuals."""
-    if block_q is None:
-        block_q = _block_q_for(q.shape[2])
+    ``lse``/``delta`` are the (BH, 1, L) rows, turned to columns once per
+    q block.  Operands as in the forward: ``ds`` is cast to k's dtype."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    B, H, L, D0 = q.shape
+    B, H, L, D = q.shape
     Lk = k.shape[2]
-    D = max(128, -(-D0 // 128) * 128)
-    q, k, v, g = (_pad_heads(x, D) for x in (q, k, v, g))
+    if block_q is None:
+        block_q = _block_for(L, q.dtype)
+    if block_k is None:
+        block_k = _block_for(Lk, k.dtype)
     nq, nk = L // block_q, Lk // block_k
     inv_keep = 1.0 / (1.0 - dropout) if dropout > 0.0 else 1.0
 
@@ -380,7 +493,8 @@ def _pallas_bwd_dq(q, k, v, g, lse_rep, dlt_rep, scale, causal, kmask=None,
         if kmask is not None:
             km_ref = refs[0]
             refs = refs[1:]
-        q_ref, k_ref, v_ref, g_ref, lse_ref, dlt_ref, dq_ref, dq_s = refs
+        (q_ref, k_ref, v_ref, g_ref, lse_ref, dlt_ref, dq_ref,
+         dq_s, lse_s, dlt_s) = refs
         bhi = pl.program_id(0)
         qi = pl.program_id(1)
         kj = pl.program_id(2)
@@ -388,105 +502,85 @@ def _pallas_bwd_dq(q, k, v, g, lse_rep, dlt_rep, scale, causal, kmask=None,
         @pl.when(kj == 0)
         def _init():
             dq_s[:] = jnp.zeros_like(dq_s)
+            lse_s[:] = _row_to_col(lse_ref[0])
+            dlt_s[:] = _row_to_col(dlt_ref[0])
 
-        run = True
-        if causal:
-            run = (qi + 1) * block_q > kj * block_k
-
-        @pl.when(run if causal else True)
-        def _compute():
-            qb = q_ref[0].astype(jnp.float32)
-            kb = k_ref[0].astype(jnp.float32)
-            vb = v_ref[0].astype(jnp.float32)
-            gb = g_ref[0].astype(jnp.float32)
-            s = jax.lax.dot_general(
-                qb, kb, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
+        def tile(qs, ks, masked):
+            qpos = qi * block_q + qs.start + lax.broadcasted_iota(
+                jnp.int32, (qs.stop - qs.start, 1), 0)
+            kpos = kj * block_k + ks.start + lax.broadcasted_iota(
+                jnp.int32, (1, ks.stop - ks.start), 1)
+            s = _block_scores(
+                q_ref[0, qs], k_ref[0, ks], scale, qpos, kpos, masked,
+                km_ref[0, :, ks] if kmask is not None else None)
+            p = jnp.exp(s - lse_s[qs, :1])              # (q rows, k rows)
             if kmask is not None:
-                s = s + km_ref[0]
-            qpos = qi * block_q + lax.broadcasted_iota(
-                jnp.int32, (block_q, 1), 0)
-            kpos = kj * block_k + lax.broadcasted_iota(
-                jnp.int32, (1, block_k), 1)
-            if causal:
-                s = jnp.where(qpos >= kpos, s, _NEG_INF)
-            p = jnp.exp(s - lse_ref[0][:, :1])          # (bq, bk)
-            p = jnp.where(s <= _NEG_INF * 0.5, 0.0, p)
-            dp = jax.lax.dot_general(
-                gb, vb, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
+                p = jnp.where(s <= _NEG_INF * 0.5, 0.0, p)
+            dp = _mxu(g_ref[0, qs], v_ref[0, ks], _NT)
             if dropout > 0.0:
                 keep = _keep(seed_ref[0, 0], bhi, qpos, kpos, dropout)
                 dp = jnp.where(keep, dp, 0.0) * inv_keep
-            ds = p * (dp - dlt_ref[0][:, :1])
-            dq_s[:] = dq_s[:] + scale * jax.lax.dot_general(
-                ds, kb, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+            ds = p * (dp - dlt_s[qs, :1])
+            dq_s[qs] = dq_s[qs] + _mxu(ds.astype(k_ref.dtype),
+                                       k_ref[0, ks], _NN)
+
+        _causal_tiles(qi, kj, block_q, block_k, causal, "q", tile)
 
         @pl.when(kj == nk - 1)
         def _finalize():
-            dq_ref[0] = dq_s[:].astype(dq_ref.dtype)
+            dq_ref[0] = (dq_s[:] * scale).astype(dq_ref.dtype)
 
-    grid = (B * H, nq, nk)
-    in_specs = [pl.BlockSpec((1, 1), lambda b, i, j: (0, 0),
-                             memory_space=pltpu.SMEM)]
-    args = [jnp.full((1, 1), 0 if seed is None else seed, jnp.uint32)]
-    if kmask is not None:
-        Nb = kmask.shape[0]
-        km_idx = (lambda b, i, j: (0, 0, j)) if Nb == 1 else \
-            (lambda b, i, j: (b // H, 0, j))
-        in_specs.append(pl.BlockSpec((1, 1, block_k), km_idx,
-                                     memory_space=pltpu.VMEM))
-        args.append(kmask)
+    kv_at = _streamed_k_at(block_q, block_k, causal)
+    q_at = lambda b, i, j: (b, i, 0)
+    row_at = lambda b, i, j: (b, 0, i)
+    in_specs, args = _lead_operands(seed, kmask, H, block_k, 2)
     in_specs += [
-        pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_q, _LANES), lambda b, i, j: (b, i, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_q, _LANES), lambda b, i, j: (b, i, 0),
-                     memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, block_q, D), q_at, memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, block_k, D), kv_at, memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, block_k, D), kv_at, memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, block_q, D), q_at, memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, 1, block_q), row_at, memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, 1, block_q), row_at, memory_space=pltpu.VMEM),
     ]
     args += [q.reshape(B * H, L, D), k.reshape(B * H, Lk, D),
-             v.reshape(B * H, Lk, D), g.reshape(B * H, L, D),
-             lse_rep, dlt_rep]
+             v.reshape(B * H, Lk, D), g.reshape(B * H, L, D), lse, delta]
     dq = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(B * H, nq, nk),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0),
+        out_specs=pl.BlockSpec((1, block_q, D), q_at,
                                memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((B * H, L, D), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
+        out_shape=jax.ShapeDtypeStruct((B * H, L, D), q.dtype),
+        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32),
+                        pltpu.VMEM((block_q, _LANES), jnp.float32),
+                        pltpu.VMEM((block_q, _LANES), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_interpret(),
+        interpret=interpret,
+        name="mx_flash_bwd_dq",
     )(*args)
-    return dq.reshape(B, H, L, D)[..., :D0]
+    return dq.reshape(B, H, L, D)
 
 
-def _pallas_bwd_dkv(q, k, v, g, lse_rep, dlt_rep, scale, causal, kmask=None,
+@_kernel_jit
+def _pallas_bwd_dkv(q, k, v, g, lse, delta, scale, causal, kmask=None,
                     seed=None, dropout=0.0, need_dbias=False,
-                    block_q=_BLOCK, block_k=None):
-    """dk/dv kernel: grid (BH, nk, nq), q innermost.  Computation stays in
-    q-row orientation ((block_q, block_k) scores); dk/dv fall out of
-    contractions over the q dim, so no in-kernel transposes are needed.
-    Optionally also emits the q-and-lane-summed dbias for the k-mask
-    layout as (BH, 1, Lk)."""
-    if block_k is None:
-        block_k = _block_q_for(k.shape[2])
+                    block_q=None, block_k=None, interpret=False):
+    """dk/dv kernel: grid (BH, nk, nq), q innermost.  Scores are computed
+    k-row by q-column ((block_k, block_q)), so the (BH, 1, L) ``lse`` /
+    ``delta`` rows broadcast as they are and dk/dv are plain products
+    ``p.T @ g`` / ``ds.T @ q`` with nothing transposed.  A causal q block
+    before the diagonal is neither computed nor fetched.  Optionally also
+    emits the q-summed dbias for the k-mask layout as (BH, 1, Lk)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    B, H, L, D0 = q.shape
+    B, H, L, D = q.shape
     Lk = k.shape[2]
-    D = max(128, -(-D0 // 128) * 128)
-    q, k, v, g = (_pad_heads(x, D) for x in (q, k, v, g))
+    if block_k is None:
+        block_k = _block_dkv_for(Lk, k.dtype)
+    if block_q is None:
+        block_q = _block_dkv_for(L, q.dtype)
     nq, nk = L // block_q, Lk // block_k
     inv_keep = 1.0 / (1.0 - dropout) if dropout > 0.0 else 1.0
 
@@ -496,10 +590,13 @@ def _pallas_bwd_dkv(q, k, v, g, lse_rep, dlt_rep, scale, causal, kmask=None,
             refs = refs[1:]
         (q_ref, k_ref, v_ref, g_ref, lse_ref, dlt_ref) = refs[:6]
         refs = refs[6:]
+        km_s = db_ref = db_s = None
         if need_dbias:
-            dk_ref, dv_ref, db_ref, dk_s, dv_s, db_s = refs
+            dk_ref, dv_ref, db_ref, dk_s, dv_s, db_s, *refs = refs
         else:
-            dk_ref, dv_ref, dk_s, dv_s = refs
+            dk_ref, dv_ref, dk_s, dv_s, *refs = refs
+        if kmask is not None:
+            km_s, = refs
         bhi = pl.program_id(0)
         kj = pl.program_id(1)
         qi = pl.program_id(2)
@@ -510,94 +607,72 @@ def _pallas_bwd_dkv(q, k, v, g, lse_rep, dlt_rep, scale, causal, kmask=None,
             dv_s[:] = jnp.zeros_like(dv_s)
             if need_dbias:
                 db_s[:] = jnp.zeros_like(db_s)
-
-        run = True
-        if causal:
-            run = (qi + 1) * block_q > kj * block_k
-
-        @pl.when(run if causal else True)
-        def _compute():
-            qb = q_ref[0].astype(jnp.float32)
-            kb = k_ref[0].astype(jnp.float32)
-            vb = v_ref[0].astype(jnp.float32)
-            gb = g_ref[0].astype(jnp.float32)
-            s = jax.lax.dot_general(
-                qb, kb, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
             if kmask is not None:
-                s = s + km_ref[0]
-            qpos = qi * block_q + lax.broadcasted_iota(
-                jnp.int32, (block_q, 1), 0)
-            kpos = kj * block_k + lax.broadcasted_iota(
-                jnp.int32, (1, block_k), 1)
-            if causal:
-                s = jnp.where(qpos >= kpos, s, _NEG_INF)
-            p = jnp.exp(s - lse_ref[0][:, :1])          # (bq, bk)
-            p = jnp.where(s <= _NEG_INF * 0.5, 0.0, p)
-            dp = jax.lax.dot_general(
-                gb, vb, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
+                km_s[:] = _row_to_col(km_ref[0])
+
+        def tile(qs, ks, masked):
+            nk_ = ks.stop - ks.start
+            qpos = qi * block_q + qs.start + lax.broadcasted_iota(
+                jnp.int32, (1, qs.stop - qs.start), 1)
+            kpos = kj * block_k + ks.start + lax.broadcasted_iota(
+                jnp.int32, (nk_, 1), 0)
+            s = _block_scores(
+                k_ref[0, ks], q_ref[0, qs], scale, qpos, kpos, masked,
+                km_s[ks, :1] if kmask is not None else None)
+            p = jnp.exp(s - lse_ref[0, :, qs])          # (k rows, q rows)
+            if kmask is not None:
+                p = jnp.where(s <= _NEG_INF * 0.5, 0.0, p)
+            dp = _mxu(v_ref[0, ks], g_ref[0, qs], _NT)
             p_drop = p
             if dropout > 0.0:
                 keep = _keep(seed_ref[0, 0], bhi, qpos, kpos, dropout)
                 dp = jnp.where(keep, dp, 0.0) * inv_keep
                 p_drop = jnp.where(keep, p, 0.0) * inv_keep
-            ds = p * (dp - dlt_ref[0][:, :1])
-            # contract over the q dim — outputs land k-major, no transpose
-            dv_s[:] = dv_s[:] + jax.lax.dot_general(
-                p_drop, gb, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            dk_s[:] = dk_s[:] + scale * jax.lax.dot_general(
-                ds, qb, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+            ds = p * (dp - dlt_ref[0, :, qs])
+            dv_s[ks] = dv_s[ks] + _mxu(p_drop.astype(g_ref.dtype),
+                                       g_ref[0, qs], _NN)
+            dk_s[ks] = dk_s[ks] + _mxu(ds.astype(q_ref.dtype),
+                                       q_ref[0, qs], _NN)
             if need_dbias:
-                db_s[:] = db_s[:] + jnp.broadcast_to(
-                    jnp.sum(ds, axis=0, keepdims=True), db_s.shape)
+                db_s[ks] = db_s[ks] + jnp.broadcast_to(
+                    jnp.sum(ds, axis=1, keepdims=True), (nk_, _LANES))
+
+        _causal_tiles(qi, kj, block_q, block_k, causal, "k", tile)
 
         @pl.when(qi == nq - 1)
         def _finalize():
-            dk_ref[0] = dk_s[:].astype(dk_ref.dtype)
+            dk_ref[0] = (dk_s[:] * scale).astype(dk_ref.dtype)
             dv_ref[0] = dv_s[:].astype(dv_ref.dtype)
             if need_dbias:
-                db_ref[0] = db_s[:1]
+                db_ref[0] = _col_to_row(db_s[:])
 
-    grid = (B * H, nk, nq)
-    in_specs = [pl.BlockSpec((1, 1), lambda b, j, i: (0, 0),
-                             memory_space=pltpu.SMEM)]
-    args = [jnp.full((1, 1), 0 if seed is None else seed, jnp.uint32)]
-    if kmask is not None:
-        Nb = kmask.shape[0]
-        km_idx = (lambda b, j, i: (0, 0, j)) if Nb == 1 else \
-            (lambda b, j, i: (b // H, 0, j))
-        in_specs.append(pl.BlockSpec((1, 1, block_k), km_idx,
-                                     memory_space=pltpu.VMEM))
-        args.append(kmask)
+    if causal:
+        # q blocks before the diagonal re-name the first one read
+        live = lambda j, i: jnp.minimum(
+            jnp.maximum(i, j * block_k // block_q), nq - 1)
+    else:
+        live = lambda j, i: i
+    q_at = lambda b, j, i: (b, live(j, i), 0)
+    row_at = lambda b, j, i: (b, 0, live(j, i))
+    kv_at = lambda b, j, i: (b, j, 0)
+    in_specs, args = _lead_operands(seed, kmask, H, block_k, 1)
     in_specs += [
-        pl.BlockSpec((1, block_q, D), lambda b, j, i: (b, i, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_q, D), lambda b, j, i: (b, i, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_q, _LANES), lambda b, j, i: (b, i, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_q, _LANES), lambda b, j, i: (b, i, 0),
-                     memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, block_q, D), q_at, memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, block_k, D), kv_at, memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, block_k, D), kv_at, memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, block_q, D), q_at, memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, 1, block_q), row_at, memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, 1, block_q), row_at, memory_space=pltpu.VMEM),
     ]
     args += [q.reshape(B * H, L, D), k.reshape(B * H, Lk, D),
-             v.reshape(B * H, Lk, D), g.reshape(B * H, L, D),
-             lse_rep, dlt_rep]
+             v.reshape(B * H, Lk, D), g.reshape(B * H, L, D), lse, delta]
     out_specs = [
-        pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0),
-                     memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, block_k, D), kv_at, memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, block_k, D), kv_at, memory_space=pltpu.VMEM),
     ]
     out_shape = [
-        jax.ShapeDtypeStruct((B * H, Lk, D), jnp.float32),
-        jax.ShapeDtypeStruct((B * H, Lk, D), jnp.float32),
+        jax.ShapeDtypeStruct((B * H, Lk, D), k.dtype),
+        jax.ShapeDtypeStruct((B * H, Lk, D), v.dtype),
     ]
     scratch = [pltpu.VMEM((block_k, D), jnp.float32),
                pltpu.VMEM((block_k, D), jnp.float32)]
@@ -607,23 +682,25 @@ def _pallas_bwd_dkv(q, k, v, g, lse_rep, dlt_rep, scale, causal, kmask=None,
                          memory_space=pltpu.VMEM))
         out_shape.append(
             jax.ShapeDtypeStruct((B * H, 1, Lk), jnp.float32))
-        scratch.append(pltpu.VMEM((8, block_k), jnp.float32))
+        scratch.append(pltpu.VMEM((block_k, _LANES), jnp.float32))
+    if kmask is not None:
+        scratch.append(pltpu.VMEM((block_k, _LANES), jnp.float32))
     res = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(B * H, nk, nq),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_interpret(),
+        interpret=interpret,
+        name="mx_flash_bwd_dkv",
     )(*args)
-    dk = res[0].reshape(B, H, Lk, D)[..., :D0]
-    dv = res[1].reshape(B, H, Lk, D)[..., :D0]
+    dk = res[0].reshape(B, H, Lk, D)
+    dv = res[1].reshape(B, H, Lk, D)
     dbias = res[2].reshape(B, H, Lk) if need_dbias else None
     return dk, dv, dbias
-
 
 
 # --------------------------------------------------------------------------- #
@@ -666,12 +743,12 @@ def _flash_bwd(scale, causal, dropout, impl, res, g):
 
     if impl != "xla" and _pallas_eligible(q, k, bias):
         kmask = _kmask_arrays(bias, B) if bias is not None else None
-        lse_rep = _rep(lse.reshape(B * H, Lq))
-        dlt_rep = _rep(delta.reshape(B * H, Lq))
-        dq = _pallas_bwd_dq(q, k, v, g, lse_rep, dlt_rep, scale, causal,
+        lse_row = lse.reshape(B * H, 1, Lq)
+        dlt_row = delta.reshape(B * H, 1, Lq)
+        dq = _pallas_bwd_dq(q, k, v, g, lse_row, dlt_row, scale, causal,
                             kmask=kmask, seed=seed, dropout=dropout)
         dk, dv, dbias_bh = _pallas_bwd_dkv(
-            q, k, v, g, lse_rep, dlt_rep, scale, causal, kmask=kmask,
+            q, k, v, g, lse_row, dlt_row, scale, causal, kmask=kmask,
             seed=seed, dropout=dropout, need_dbias=bias is not None)
         if bias is None:
             dbias = None
@@ -680,16 +757,18 @@ def _flash_bwd(scale, causal, dropout, impl, res, g):
             if bias.shape[0] == 1:
                 db = db.sum(axis=0, keepdims=True)
             dbias = db.reshape(bias.shape).astype(bias.dtype)
-        return (dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype),
-                dbias, None)
+        return dq, dk, dv, dbias, None
 
-    q32, k32, v32 = (x.astype(jnp.float32) for x in (q, k, v))
+    # the five products below take q, k, v, g as they arrive and p / ds
+    # cast to match (float32 inputs: float32 products, as before);
+    # delta, lse, the scores and every accumulator are float32
+    f32 = jnp.float32
     block = min(512, Lk)
     nkb = -(-Lk // block)
     padk = nkb * block - Lk
     if padk:
-        k32 = jnp.pad(k32, ((0, 0), (0, 0), (0, padk), (0, 0)))
-        v32 = jnp.pad(v32, ((0, 0), (0, 0), (0, padk), (0, 0)))
+        k = jnp.pad(k, ((0, 0), (0, 0), (0, padk), (0, 0)))
+        v = jnp.pad(v, ((0, 0), (0, 0), (0, padk), (0, 0)))
     qpos = lax.broadcasted_iota(jnp.int32, (Lq, 1), 0)
     bh = (lax.broadcasted_iota(jnp.int32, (B, H), 0) * H +
           lax.broadcasted_iota(jnp.int32, (B, H), 1))[..., None, None]
@@ -704,9 +783,10 @@ def _flash_bwd(scale, causal, dropout, impl, res, g):
 
     def body(carry, j):
         dq_acc = carry
-        ks = lax.dynamic_slice_in_dim(k32, j * block, block, axis=2)
-        vs = lax.dynamic_slice_in_dim(v32, j * block, block, axis=2)
-        s = jnp.einsum("bhqd,bhkd->bhqk", q32, ks) * scale
+        ks = lax.dynamic_slice_in_dim(k, j * block, block, axis=2)
+        vs = lax.dynamic_slice_in_dim(v, j * block, block, axis=2)
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, ks,
+                       preferred_element_type=f32) * scale
         if bias32 is not None:
             s = s + lax.dynamic_slice_in_dim(bias32, j * block, block,
                                              axis=3)
@@ -717,17 +797,22 @@ def _flash_bwd(scale, causal, dropout, impl, res, g):
         s = jnp.where(valid, s, _NEG_INF)
         p = jnp.exp(s - lse[..., None])                 # (B,H,Lq,block)
         p = jnp.where(s <= _NEG_INF * 0.5, 0.0, p)
-        dp = jnp.einsum("bhqd,bhkd->bhqk", g32, vs)
+        dp = jnp.einsum("bhqd,bhkd->bhqk", g, vs,
+                        preferred_element_type=f32)
         p_drop = p
         if dropout > 0.0:
             keep = _keep(seed, bh, qpos[None, None], kpos[None, None],
                          dropout)
             dp = jnp.where(keep, dp, 0.0) / (1.0 - dropout)
             p_drop = jnp.where(keep, p, 0.0) / (1.0 - dropout)
-        dv = jnp.einsum("bhqk,bhqd->bhkd", p_drop, g32)
+        dv = jnp.einsum("bhqk,bhqd->bhkd", p_drop.astype(g.dtype), g,
+                        preferred_element_type=f32)
         ds = p * (dp - delta[..., None]) * scale
-        dq_acc = dq_acc + jnp.einsum("bhqk,bhkd->bhqd", ds, ks)
-        dk = jnp.einsum("bhqk,bhqd->bhkd", ds, q32)
+        dq_acc = dq_acc + jnp.einsum(
+            "bhqk,bhkd->bhqd", ds.astype(ks.dtype), ks,
+            preferred_element_type=f32)
+        dk = jnp.einsum("bhqk,bhqd->bhkd", ds.astype(q.dtype), q,
+                        preferred_element_type=f32)
         if bias is None:
             dbias_blk = jnp.zeros((), jnp.float32)
         else:
@@ -741,7 +826,7 @@ def _flash_bwd(scale, causal, dropout, impl, res, g):
             dbias_blk = db
         return dq_acc, (dk, dv, dbias_blk)
 
-    dq0 = jnp.zeros_like(q32)
+    dq0 = jnp.zeros(q.shape, f32)
     dq, (dks, dvs, dbs) = lax.scan(body, dq0, jnp.arange(nkb))
     D_ = q.shape[3]
     dk = jnp.moveaxis(dks, 0, 2).reshape(B, H, nkb * block, D_)[:, :, :Lk]
@@ -772,22 +857,39 @@ _PLAIN_ATTN_MAX_SCORES = 512 * 512
 # --------------------------------------------------------------------------- #
 # measured dispatch (VERDICT r2 item 4: "chosen path == fastest measured
 # path").  Constants are the crossover sequence lengths from
-# ``benchmark/attention_bench.py`` on v5e (causal, B4 H8 D64, bf16).
+# ``benchmark/attention_bench.py`` on v5e (causal, D64, bf16, at the
+# table's own shape B4 H8 and at the train cell's B8 H16).
 # Entries are (max_seq, impl); the first row whose bound covers
 # max(Lq, Lk) wins.  "plain" materializes O(L²)
 # scores (fused-softmax), "xla" is the blockwise lax.scan path, "pallas"
 # the Pallas kernels (fwd + bwd).
 # --------------------------------------------------------------------------- #
 _PATH_TABLE = {
-    # measured 2026-07-30 on v5e (builders' round-3 sweep):
-    #   fwd:   512 plain 0.80ms | 1k-4k xla (1.17/2.02/5.92ms, pallas
-    #          1.58/3.43/10.63) | 8k pallas 38.8ms (xla 39.0)
-    #   train: 512 plain 0.79ms | 1k xla 1.74ms (plain 2.12, pallas 2.27)
-    #          | 2k+ pallas 6.41/22.1/78.2ms (xla 6.88/25.1/122.5)
-    # (sequences <= 512 already took the plain path via
-    # _PLAIN_ATTN_MAX_SCORES before the table is consulted)
-    "fwd": ((4096, "xla"), (None, "pallas")),
-    "train": ((1024, "xla"), (None, "pallas")),
+    # measured 2026-10-03 on v5e (PR 32: every path feeds the MXU bf16),
+    # ms at B4 H8 | B8 H16, pallas / xla / plain:
+    #   fwd:   768   0.098 / 0.088 / 0.243 | 0.391 / 0.324 / 2.227
+    #          1,024 0.123 / 0.143 / 0.989 | 0.494 / 0.573 / 3.983
+    #          2,048 0.417 / 0.503 / 3.971 | 1.787 / 9.417 / 15.80
+    #          4,096 1.335 / 1.969 / 15.49 | 6.064 / 38.77 / 49.46
+    #          8,192 4.899 / 36.23 / -     |
+    #   train: 768   0.282 / 0.299 / 0.516 | 1.191 / 2.760 / 4.159
+    #          1,024 0.383 / 0.493 / 1.567 | 1.677 / 5.553 / 7.702
+    #          2,048 1.319 / 3.906 / 7.347 | 6.004 / 28.82 / 29.46
+    #          4,096 4.924 / 20.18 / 28.95 | 20.99 / 111.8 / out of memory
+    #          8,192 18.17 / 109.8 / -     |
+    # (train = forward, dq AND dk/dv: the bench now feeds all three
+    # gradients on, where the sweep of 2026-07-30 fed dq alone and XLA
+    # dropped the dk/dv kernel as dead code.  That sweep, float32 products
+    # and heads padded to 128 lanes, had pallas behind xla up to 4,096 fwd:
+    # 1.58 against 1.17 ms at 1,024, B4 H8.)
+    # Sequences <= 512 already took the plain path via
+    # _PLAIN_ATTN_MAX_SCORES before the table is consulted; there the
+    # same sweep reads, fwd, 0.077 / 0.047 / 0.046 | 0.306 / 0.182 / 1.027
+    # and, train, 0.184 / 0.108 / 0.122 | 0.756 / 0.788 / 1.618: plain
+    # holds at the small batch and trails at the large one, and the choice
+    # does not see the batch.
+    "fwd": ((768, "xla"), (None, "pallas")),
+    "train": ((None, "pallas"),),
 }
 
 
@@ -874,13 +976,16 @@ def flash_attention(q, k, v, bias=None, *, scale: Optional[float] = None,
     mode (``autograd.is_training()``) unless ``training`` overrides.
 
     The implementation is chosen from the MEASURED dispatch table
-    ``_PATH_TABLE`` (benchmark/attention_bench.py sweep): short sequences
-    take the unblocked fused-softmax path, the mid range the XLA blockwise
-    kernel, long sequences the Pallas kernels (fwd AND bwd).  ``training``
-    selects the train-tuned (fwd+bwd) vs inference-tuned column.  On the
-    Pallas path 128-unaligned lengths are padded inside the op (the pad
-    keys are masked via the key-mask bias channel); general dense biases
-    (not a ``(B|1,1,1,Lk)`` key mask) always use the XLA paths."""
+    ``_PATH_TABLE`` (benchmark/attention_bench.py sweep) by
+    ``(Lq, Lk, bias, training)``: up to 512 x 512 scores the unblocked
+    fused-softmax path; above that, in training, the Pallas kernels
+    (forward, dq, dk/dv) at every length, and in inference the XLA
+    blockwise path up to 768 and the Pallas forward beyond.  Every path
+    feeds the MXU the inputs' own dtype and accumulates in float32.  On
+    the Pallas path 128-unaligned lengths are padded inside the op (the
+    pad keys are masked via the key-mask bias channel); general dense
+    biases (not a ``(B|1,1,1,Lk)`` key mask), and every backend but the
+    TPU, always use the XLA paths."""
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
     if training is None:

@@ -213,16 +213,21 @@ def _device_planes(data):
     return planes
 
 
-# device kernels whose events carry no provenance at all, by the start of
+# device kernels whose events carry no provenance at all, by a part of
 # their operation's name: the grouped product (``lax.ragged_dot``, emitted
 # by ``ops/moe.routed_experts`` alone) runs as a custom kernel named
 # ``ragged-dot-none[.n]`` with an empty ``tf_op`` (read off a v5e trace,
 # PR 29), so it would read ``unscoped`` whatever scope it was traced under.
 # The paged-attention kernel (``ops/paged_attention.py``) is a
 # ``pallas_call`` named for this table: its custom call keeps its provenance
-# in the HLO, and is known by name should an event of it come without
+# in the HLO, and is known by name should an event of it come without; so
+# are the flash kernels of ``ops/attention.py`` (``mx_flash_fwd``,
+# ``mx_flash_bwd_dq``, ``mx_flash_bwd_dkv``), whose operations a
+# differentiated program names ``jvp_mx_flash_fwd_[.n]`` and
+# ``transpose_jvp_mx_flash_bwd_dq__[.n]`` — hence a part, not the start.
 _KERNEL_REGIONS = (("ragged-dot", "mx.moe_experts"),
-                   ("mx_paged_attention", "mx.attn"))
+                   ("mx_paged_attention", "mx.attn"),
+                   ("mx_flash", "mx.attn"))
 
 
 def region_of(provenance, name=None):
@@ -235,8 +240,8 @@ def region_of(provenance, name=None):
     found = _REGION.findall(provenance or "")
     if found:
         return found[-1]
-    for start, region in _KERNEL_REGIONS:
-        if name and name.startswith(start):
+    for part, region in _KERNEL_REGIONS:
+        if name and part in name:
             return region
     return UNSCOPED
 

@@ -137,8 +137,8 @@ def test_flash_bias_grad_matches_naive():
 
 
 def test_pallas_kernel_interpret_head_dim_64():
-    """head_dim 64 (every shipped model) must reach the kernel via lane
-    padding."""
+    """head_dim 64 (every shipped model) must reach the kernel, in blocks
+    of its own width (no padding to 128 lanes since PR 32)."""
     from mxnet_tpu.ops import attention as attn
 
     q, k, v = (_rand(1, 2, 256, 64) for _ in range(3))
@@ -417,6 +417,154 @@ def test_ring_attention_grads_match_full():
 
 
 # --------------------------------------------------------------------------- #
+# PR 32: the kernels at the train cell's dtype, at any block size, and the
+# operands of the XLA path
+# --------------------------------------------------------------------------- #
+
+def _fwd_and_grads(attn_fn, q, k, v, g):
+    import jax
+    out, vjp = jax.vjp(attn_fn, q, k, v)
+    return (out,) + vjp(g)
+
+
+@pytest.mark.parametrize("L", [256, 1024])
+@pytest.mark.parametrize("D", [64, 128])
+@pytest.mark.parametrize("causal", [False, True])
+def test_pallas_kernels_bfloat16_match_naive(monkeypatch, causal, D, L):
+    """bfloat16 q, k, v through the three kernels (interpret mode): the
+    products take bfloat16 operands, p and ds are rounded to bfloat16 once
+    each, everything else stays float32 — within 4 bfloat16 ulps (2**-6)
+    of the float32 naive reference's largest entry, as chip_smoke holds
+    the chip to."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops.attention import _flash
+    monkeypatch.setenv("MXNET_FLASH_INTERPRET", "1")
+    scale = 1.0 / D ** 0.5
+    ks = jax.random.split(jax.random.PRNGKey(L + D), 4)
+    q, k, v, g = (jax.random.normal(kk, (1, 2, L, D), jnp.float32)
+                  .astype(jnp.bfloat16) for kk in ks)
+    got = _fwd_and_grads(
+        lambda q, k, v: _flash(q, k, v, None, jnp.uint32(0), scale, causal,
+                               0.0, "pallas"), q, k, v, g)
+    ref = _fwd_and_grads(
+        lambda q, k, v: _naive(q, k, v, causal=causal, scale=scale),
+        q, k, v, g.astype(jnp.float32))
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, ref):
+        assert a.dtype == jnp.bfloat16, (name, a.dtype)
+        a = onp.asarray(a.astype(jnp.float32))
+        b = onp.asarray(b.astype(jnp.float32))
+        err = onp.abs(a - b).max() / onp.abs(b).max()
+        assert err < 2.0 ** -6, (name, err)
+
+
+@pytest.mark.parametrize("Lq, Lk", [(512, 512), (256, 512), (512, 256)])
+@pytest.mark.parametrize("block_q, block_k",
+                         [(128, 128), (128, 256), (256, 128), (256, 256)])
+def test_pallas_kernels_any_block_sizes(monkeypatch, block_q, block_k,
+                                        Lq, Lk):
+    """Every pair of block sizes gives the same causal result (float32,
+    the tolerance of the other interpret-mode tests): the diagonal skip
+    and the clamped index maps, which hand a skipped step the block that
+    is already resident, agree with the mask for square and oblong blocks
+    and for Lq != Lk."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import attention as attn
+    monkeypatch.setenv("MXNET_FLASH_INTERPRET", "1")
+    q, g = (jnp.asarray(_rand(1, 2, Lq, 64)) + i for i in range(2))
+    k, v = (jnp.asarray(_rand(1, 2, Lk, 64)) * (i + 1) for i in range(2))
+    out, lse = attn._pallas_fwd(q, k, v, 0.125, True, block_q=block_q,
+                                block_k=block_k)
+    delta = jnp.sum(out * g, axis=-1).reshape(2, 1, Lq)
+    dq = attn._pallas_bwd_dq(q, k, v, g, lse.reshape(2, 1, Lq), delta,
+                             0.125, True, block_q=block_q, block_k=block_k)
+    dk, dv, _ = attn._pallas_bwd_dkv(
+        q, k, v, g, lse.reshape(2, 1, Lq), delta, 0.125, True,
+        block_q=block_q, block_k=block_k)
+    ref = _fwd_and_grads(
+        lambda q, k, v: _naive(q, k, v, causal=True, scale=0.125),
+        q, k, v, g)
+    onp.testing.assert_allclose(onp.asarray(out), onp.asarray(ref[0]),
+                                rtol=3e-5, atol=3e-5)
+    for a, b in zip((dq, dk, dv), ref[1:]):
+        onp.testing.assert_allclose(onp.asarray(a), onp.asarray(b),
+                                    rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("L", [128, 256, 512])
+def test_pallas_kernels_causal_with_key_mask_and_dropout(monkeypatch, L):
+    """Causal + key-padding mask + dropout together through the three
+    kernels, dbias included, against the hash-identical naive reference:
+    at 128 a diagonal block is one masked tile, from 256 on the backward
+    kernels cut it in two and skip its upper-right quarter (dq by q rows,
+    dk/dv by k rows) — the mask's slice and the dropout positions must
+    follow the tile."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops.attention import _flash
+    monkeypatch.setenv("MXNET_FLASH_INTERPRET", "1")
+    q, k, v = (jnp.asarray(_rand(2, 2, L, 64)) * (i + 1) for i in range(3))
+    bias = onp.zeros((2, 1, 1, L), "float32")
+    bias[0, :, :, L - 40:] = -1e30
+    bias = jnp.asarray(bias)
+    seed = jnp.uint32(11)
+
+    def loss(fn):
+        return lambda q, k, v, b: jnp.sum(fn(q, k, v, b) ** 2)
+
+    flash = lambda q, k, v, b: _flash(q, k, v, b, seed, 0.125, True, 0.2,
+                                      "pallas")
+    naive = lambda q, k, v, b: _naive_dropout(q, k, v, b, 0.125, True, 0.2,
+                                              seed)
+    onp.testing.assert_allclose(
+        onp.asarray(flash(q, k, v, bias)), onp.asarray(naive(q, k, v, bias)),
+        rtol=3e-5, atol=3e-5)
+    g1 = jax.grad(loss(flash), argnums=(0, 1, 2, 3))(q, k, v, bias)
+    g2 = jax.grad(loss(naive), argnums=(0, 1, 2, 3))(q, k, v, bias)
+    for a, b in zip(g1, g2):        # relative to each gradient's largest
+        top = float(jnp.abs(b).max())
+        onp.testing.assert_allclose(onp.asarray(a) / top,
+                                    onp.asarray(b) / top, atol=2e-5)
+
+
+def _dot_operand_dtypes(jaxpr):
+    """The operand dtypes of every ``dot_general`` in ``jaxpr`` and the
+    jaxprs nested in it (scan bodies, custom-vjp calls)."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            found.append(tuple(v.aval.dtype for v in eqn.invars))
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)        # ClosedJaxpr
+                if hasattr(sub, "eqns"):
+                    found += _dot_operand_dtypes(sub)
+    return found
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_xla_path_feeds_products_the_input_dtype(causal):
+    """On bfloat16 inputs no product of the blockwise XLA path, forward or
+    backward, takes a float32 operand (a float32 product runs in several
+    MXU passes); two forward and five backward products are there."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops.attention import _flash
+    q, k, v = (jnp.asarray(_rand(1, 2, 640, 16), jnp.bfloat16)
+               for _ in range(3))
+
+    def loss(q, k, v):
+        return jnp.sum(_flash(q, k, v, None, jnp.uint32(0), 0.25, causal,
+                              0.0, "xla").astype(jnp.float32))
+
+    dots = _dot_operand_dtypes(
+        jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v).jaxpr)
+    assert len(dots) == 7, dots
+    assert all(d == jnp.bfloat16 for pair in dots for d in pair), dots
+
+
+# --------------------------------------------------------------------------- #
 # measured dispatch table (VERDICT r2 item 4)
 # --------------------------------------------------------------------------- #
 
@@ -439,11 +587,16 @@ class TestDispatch:
 
     def test_mid_range_follows_table(self):
         from mxnet_tpu.ops.attention import _PATH_TABLE
-        # training column: the table rows must be respected exactly
-        for bound, impl in _PATH_TABLE["train"]:
-            if bound is None or bound <= 512:
-                continue
-            assert self._choose(bound, training=True) == impl
+        # both columns: the table rows must be respected exactly
+        for column, training in (("train", True), ("fwd", False)):
+            for bound, impl in _PATH_TABLE[column]:
+                if bound is None or bound <= 512:
+                    continue
+                assert self._choose(bound, training=training) == impl
+        # the train cell's length, and the first length past the plain path
+        assert self._choose(1024, training=True) == "pallas"
+        assert self._choose(640, training=True) == "pallas"
+        assert self._choose(640, training=False) == "xla"
 
     def test_long_is_pallas(self):
         assert self._choose(8192, training=True) == "pallas"
@@ -464,13 +617,16 @@ class TestDispatch:
 
     def test_dispatch_matches_measured_best(self):
         """Frozen copy of the v5e sweep (benchmark/attention_bench.py,
-        2026-07-30): chosen path == fastest measured path at every
-        measured (seq, pass) point (VERDICT r2 item 4 done-criterion)."""
+        2026-10-03, B4 H8 and B8 H16 agreeing above 512): chosen path ==
+        fastest measured path at every measured (seq, pass) point
+        (VERDICT r2 item 4 done-criterion).  512 is the plain path's by
+        ``_PLAIN_ATTN_MAX_SCORES``, which the table does not override."""
         measured_best = {
             (512, False): "plain", (512, True): "plain",
-            (1024, False): "xla", (1024, True): "xla",
-            (2048, False): "xla", (2048, True): "pallas",
-            (4096, False): "xla", (4096, True): "pallas",
+            (768, False): "xla", (768, True): "pallas",
+            (1024, False): "pallas", (1024, True): "pallas",
+            (2048, False): "pallas", (2048, True): "pallas",
+            (4096, False): "pallas", (4096, True): "pallas",
             (8192, False): "pallas", (8192, True): "pallas",
         }
         for (seq, training), want in measured_best.items():

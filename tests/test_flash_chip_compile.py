@@ -1,0 +1,78 @@
+"""The flash-attention kernels lowered and compiled for a DESCRIBED TPU
+v5e — no chip attached, nothing runs (the ``on-chip-measurement`` guide,
+section 2).  This is what catches what interpret mode lets through: a block
+the chip's tiling refuses, a transpose Mosaic cannot do, a product whose
+operand types it rejects ("Bad lhs type": bfloat16 operands at the
+framework's ``highest`` default precision), more VMEM than a kernel may
+take.  A compile that passes is not a chip run and says nothing of speed.
+
+The topology is described inside a fixture, after a test of this file has
+started: only one process may load the TPU's library, so nothing here
+touches it at import, and every test of it lives in this one file.  Where
+the topology cannot be described the tests skip.
+"""
+import os
+
+import pytest
+
+SHAPES = {
+    # the train cell's attention: B 8 x H 16, L 1,024, D 64, bfloat16
+    "gpt2m_train": (128, 1024, 64, "bfloat16"),
+    "L2048_D128": (16, 2048, 128, "bfloat16"),
+    # a head width that is no multiple of 128 lanes nor of a bfloat16
+    # sublane tile still goes unpadded: its block spans the dimension
+    "D80_float32": (4, 256, 80, "float32"),
+}
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # no libtpu here, or its lock is held
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(one_chip, fn, *shapes):
+    import jax
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "bwd_dq", "bwd_dkv"])
+@pytest.mark.parametrize("shape", list(SHAPES))
+def test_kernel_compiles_for_v5e(one_chip, shape, kernel):
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import attention as attn
+    BH, L, D, dtype = SHAPES[shape]
+    scale = 1.0 / D ** 0.5
+    qkv = ((1, BH, L, D), jnp.dtype(dtype))
+    row = ((BH, 1, L), jnp.float32)
+    if kernel == "fwd":
+        _compile(one_chip,
+                 lambda q, k, v: attn._pallas_fwd(q, k, v, scale, True),
+                 qkv, qkv, qkv)
+    else:
+        fn = {"bwd_dq": attn._pallas_bwd_dq,
+              "bwd_dkv": attn._pallas_bwd_dkv}[kernel]
+        _compile(one_chip,
+                 lambda q, k, v, g, lse, delta: fn(
+                     q, k, v, g, lse, delta, scale, True),
+                 qkv, qkv, qkv, qkv, row, row)

@@ -115,14 +115,22 @@ def test_region_is_the_innermost_mx_component(path, region):
 @pytest.mark.parametrize("name, provenance, region", [
     ("ragged-dot-none.3", "", "mx.moe_experts"),
     ("mx_paged_attention.8", "", "mx.attn"),
+    ("mx_flash_fwd.3", "", "mx.attn"),
+    ("mx_flash_bwd_dq", "", "mx.attn"),
+    ("mx_flash_bwd_dkv.24", "", "mx.attn"),
+    ("jvp_mx_flash_fwd_.1", "", "mx.attn"),
+    ("transpose_jvp_mx_flash_bwd_dq__.7", "", "mx.attn"),
     ("mx_paged_attention.8",
      "jit(step)/mx.dense/while/body/mx.attn/mx_paged_attention/pallas_call",
      "mx.attn"),
     ("fusion.170", "", "unscoped"),
-], ids=["grouped_product", "paged_attention", "provenance_wins", "other"])
+], ids=["grouped_product", "paged_attention", "flash_fwd", "flash_bwd_dq",
+        "flash_bwd_dkv", "flash_fwd_differentiated",
+        "flash_bwd_dq_differentiated", "provenance_wins", "other"])
 def test_region_of_a_kernel_known_by_name(name, provenance, region):
     """A custom kernel whose device events carry no provenance is known by
-    the start of its operation's name."""
+    a part of its operation's name (a differentiated program wraps a
+    ``pallas_call``'s name: ``jvp_mx_flash_fwd_``)."""
     assert profiler_xla.region_of(provenance, name) == region
 
 
