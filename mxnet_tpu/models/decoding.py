@@ -541,9 +541,10 @@ def layer_description(model):
 def decode_engine(model, B, P, total, temperature, top_k, prefill,
                   weights, stacked):
     """The engine that serves ``model``, chosen by its description alone:
-    the uniform K/V kind has the stacked scan, everything else the
-    kind-driven ``layered.LayeredEngine``."""
-    if all(d["cache"] == "kv" for d in layer_description(model)):
+    plain multi-head attention over the uniform K/V kind has the stacked
+    scan, everything else the kind-driven ``layered.LayeredEngine``."""
+    if all(d["cache"] == "kv" and d["attn"]["kind"] == "mha"
+           for d in layer_description(model)):
         return _DecodeEngine(model, B, P, total, temperature, top_k,
                              prefill, weights, stacked)
     from .layered import LayeredEngine

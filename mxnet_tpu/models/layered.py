@@ -16,7 +16,20 @@ Kinds implemented here:
   positions; cache kind ``latent_window``: one latent row under the WINDOW
   page table, a ring of ``ring`` entries a slot indexed by ``(position //
   page) % ring``, so a slot holds pages for its window only;
+- attention ``ssm``: a Mamba-2 state-space mixer (``ops.ssd``); cache kind
+  ``ssm_state`` under the SLOT table: the recurrent state and the
+  convolution's tail, one entry a slot addressed by the slot's own index,
+  updated in place at every token, never shared, never paged;
+- attention ``gqa``: grouped-query attention without positions at the
+  description's own ``scale``; cache kind ``kv``: a K row and a V row under
+  the MAIN page table (the single-query step walks the pages in
+  ``ops.paged_attention``);
 - feed-forward ``swiglu`` and ``routed`` (``ops.moe``).
+
+A model whose ``weights()`` come as ``runs`` — the parameters of each maximal
+run of like layers stacked along a leading axis — has each run SCANNED
+(``_tokens_runs``): forty layers trace and compile as nine bodies.  A model
+that hands ``layers`` one by one keeps the Python loop.
 
 Every row is stored in whole 128-lane tiles (``serve.schema.row_lanes``):
 a 576- or 1088-wide minor dimension would make the chip re-lay the pool out
@@ -31,11 +44,15 @@ position.
 """
 from __future__ import annotations
 
+import itertools
+import math
+
 import jax
 import jax.numpy as jnp
 
-from ..ops import moe
-from ..serve.schema import row_lanes
+from ..ops import moe, ssd
+from ..ops import paged_attention as _paged
+from ..serve.schema import pool_rows, row_lanes
 
 __all__ = ["LayeredEngine", "top_mask", "mask_positions", "top_positions"]
 
@@ -89,6 +106,22 @@ def _table_pages(table, lp, sentinel):
     W = table.shape[1]
     pg = jnp.take_along_axis(table, jnp.clip(lp, 0, W - 1), axis=1)
     return jnp.where((lp >= 0) & (lp < W), pg, sentinel)
+
+
+def _slot_rows(arr, slots):
+    """Every layer's entries of slots ``slots`` ``(B,)`` out of a
+    slot-table array ``(layers, slots, ...)``: ``(layers, B, ...)``; a slot
+    past the end reads the last one (the caller drops what it builds from
+    it)."""
+    return arr.at[:, jnp.minimum(slots, arr.shape[1] - 1)].get(
+        mode="promise_in_bounds")
+
+
+def _slot_rows_set(arr, slots, vals):
+    """``arr`` with every layer's entries of ``slots`` := ``vals`` ``(layers,
+    B, ...)``, scattered in place; a slot past the end (an idle row of a
+    wave) is dropped."""
+    return arr.at[:, slots].set(vals.astype(arr.dtype), mode="drop")
 
 
 def _sortable(x):
@@ -176,6 +209,12 @@ class LayeredEngine:
     # (``key_pages``); a decode step's 32 queries are far better off
     # gathering (PERF.md, PR 29)
     dense_chunk = 256
+    # attention kind -> the body that runs it: a mixer ``_tokens_runs``
+    # scans over a stacked run, or the Python loop of ``tokens_paged`` (its
+    # two latent branches).  A model is served by one body, so it names
+    # kinds of one body only; a kind the table lacks raises at build
+    _KINDS = {"ssm": "_ssm_mixer", "gqa": "_gqa_mixer",
+              "latent_sparse": None, "latent_window": None}
 
     def __init__(self, model, B, P, total, temperature=0.0, top_k=0,
                  prefill="batched", weights="native"):
@@ -191,11 +230,39 @@ class LayeredEngine:
                        if p._data is not None]
         self.param_vals = [p._data._data for p in self.params]
         self.NL = len(self.desc)
+        kinds = {d["attn"]["kind"] for d in self.desc}
+        if not kinds <= set(self._KINDS) \
+                or len({self._KINDS[k] is None for k in kinds}) > 1:
+            from ..base import MXNetError
+            raise MXNetError(
+                f"the layered decode engine has no body for the attention "
+                f"kinds {sorted(kinds)} together: stacked runs serve "
+                f"{sorted(k for k, m in self._KINDS.items() if m)}, the "
+                f"layer loop "
+                f"{sorted(k for k, m in self._KINDS.items() if not m)}")
         self.cdtype = jnp.dtype(self.cfg.dtype)
-        self.full = [i for i, d in enumerate(self.desc)
-                     if d["cache"] == "latent_index"]
-        self.win = [i for i, d in enumerate(self.desc)
-                    if d["cache"] == "latent_window"]
+        # kinds with a mixer run as scans over the model's stacked runs
+        # (``weights()["runs"]``), the others layer by layer (``["layers"]``)
+        self.stacked = all(self._KINDS[k] for k in kinds)
+        of_kind = lambda kind: [i for i, d in enumerate(self.desc)
+                                if d["cache"] == kind]
+        self.full, self.win = of_kind("latent_index"), \
+            of_kind("latent_window")
+        self.kv, self.ssm = of_kind("kv"), of_kind("ssm_state")
+        # the cache kinds whose arrays are addressed by the slot's own
+        # index (``serve.schema.POOL_ROWS``: table "slot")
+        self.slot_kinds = sorted({d["cache"] for d in self.desc
+                                  if pool_rows(d["cache"])[0] == "slot"})
+        # maximal runs of like layers ``(first layer, layers)``: what a
+        # model that stacks its weights hands a scan each
+        self.runs, i = [], 0
+        for _, grp in itertools.groupby(self.desc):
+            n = len(list(grp))
+            self.runs.append((i, n))
+            i += n
+        if not self.full:
+            # no selecting attention: no dense form, no key-page bound
+            self.dense_chunk = None
         # stored row widths, in whole lane tiles
         self.rows = {}
         if self.full:
@@ -209,6 +276,25 @@ class LayeredEngine:
             self.window = int(a["window"])
         else:
             self.window = None
+        # a model of stacked runs with no layer of a kind keeps that kind's
+        # arrays with no layers in them
+        self.state_shape = (0, 0, 0)
+        if self.kv or self.ssm:
+            self.rows.update(k=0, v=0, conv_tail=0)
+        if self.kv:
+            a = self.desc[self.kv[0]]["attn"]
+            self.rows["k"] = self.rows["v"] = row_lanes(
+                a["kv_heads"] * a["head_dim"])
+        if self.ssm:
+            a = self.desc[self.ssm[0]]["attn"]
+            g = ssd.heads_per_row(a["heads"], a["head_dim"])
+            # a slot's state as stored (``ops.ssd``) and its tail of
+            # ``conv - 1`` inputs side by side in one row
+            self.state_shape = (a["heads"] // g, a["state"],
+                                g * a["head_dim"])
+            self.conv_width = a["heads"] * a["head_dim"] + 2 * a["state"]
+            self.rows["conv_tail"] = (a["conv"] - 1) * row_lanes(
+                self.conv_width)
 
     # -- what serve.engine.PoolPrograms reads --------------------------- #
     def take_operands(self):
@@ -232,16 +318,37 @@ class LayeredEngine:
     def main_page_bytes(self, page):
         """Bytes of one main-table page over every layer that has one."""
         w = self.rows.get("latent", 0) + self.rows.get("index_key", 0)
-        return len(self.full) * page * w * self.cdtype.itemsize
+        kv = self.rows.get("k", 0) + self.rows.get("v", 0)
+        return (len(self.full) * w + len(self.kv) * kv) * page \
+            * self.cdtype.itemsize
+
+    def slot_state_bytes(self):
+        """Bytes ONE slot keeps under the slot table over every layer that
+        has such state (0 where the model has none)."""
+        if not self.ssm:
+            return 0
+        return len(self.ssm) * (
+            math.prod(self.state_shape) * ssd.STATE_DTYPE.itemsize
+            + self.rows["conv_tail"] * self.cdtype.itemsize)
 
     def window_page_bytes(self, page):
         return len(self.win) * page * self.rows.get("window_latent", 0) \
             * self.cdtype.itemsize
 
-    def pool_zeros(self, num_pages, window_pages, page):
-        """``(kp, vp)``: the main-table pools ``(latent, index key)`` and
-        the window-table pool, each ``(layers, pages, page, lanes)``."""
+    def pool_zeros(self, num_pages, window_pages, page, slots=0):
+        """``(kp, vp)``: ``kp`` the main-table pools (``(latent, index
+        key)`` or ``(k, v)``), each ``(layers, pages, page, lanes)``;
+        ``vp`` the window-table pool, or — a model with state under the
+        SLOT table — ``(state, conv tail)``, each ``(layers, slots, ...)``."""
         z = lambda n, p, w: jnp.zeros((n, p, page, w), self.cdtype)
+        if self.ssm or self.kv:
+            kp = (z(len(self.kv), num_pages, self.rows["k"]),
+                  z(len(self.kv), num_pages, self.rows["v"]))
+            vp = (jnp.zeros((len(self.ssm), slots) + self.state_shape,
+                            ssd.STATE_DTYPE),
+                  jnp.zeros((len(self.ssm), slots, self.rows["conv_tail"]),
+                            self.cdtype))
+            return kp, vp
         kp = (z(len(self.full), num_pages, self.rows["latent"]),
               z(len(self.full), num_pages, self.rows["index_key"]))
         vp = z(len(self.win), window_pages, self.rows["window_latent"])
@@ -269,32 +376,43 @@ class LayeredEngine:
 
     # -- the programs' bodies ------------------------------------------- #
     def pool_token_paged(self, x_tok, pos, kp, vp, pt, page, sw=None,
-                         q8=None):
+                         q8=None, live=None):
         """The pool step: one token a slot.  Returns ``(logits, kp, vp,
-        aux)``; ``aux`` holds per-slot counters the step reduces."""
+        aux)``; ``aux`` holds per-slot counters the step reduces.  ``live``
+        ``(S,)``: the slots that step — state under the slot table is
+        left as it is for every other (a chunked prefill may be filling
+        it), as the sentinel rows of its page table leave its pages."""
         S = x_tok.shape[0]
         return self.tokens_paged(
             self.model.weights(), x_tok[:, None], pos, pt, (kp, vp),
-            page, jnp.zeros((S,), jnp.int32))
+            page, jnp.zeros((S,), jnp.int32), live=live)
 
     def chunk_tokens(self, toks, off, nlast, ptrow, page, kp, vp, sw=None,
-                     q8=None, key_pages=None):
-        """``C`` tokens of one slot at offset ``off``; ``key_pages`` bounds
-        the main-table pages the chunk can reach (its last position's)."""
-        tables = tuple(t[None] for t in ptrow)
+                     q8=None, key_pages=None, slot=None):
+        """``C`` tokens of slot ``slot`` at offset ``off``; ``key_pages``
+        bounds the main-table pages the chunk can reach (its last
+        position's).  State under the slot table starts from zero at
+        offset 0 and from what the last chunk stored after it."""
+        tables = tuple(t[None] for t in ptrow) \
+            if isinstance(ptrow, tuple) else ptrow[None]
         logits, kp, vp, _ = self.tokens_paged(
             self.model.weights(), toks[None], off[None], tables, (kp, vp),
-            page, nlast[None], key_pages=key_pages)
+            page, nlast[None], key_pages=key_pages,
+            slots=None if slot is None else slot[None])
         return logits, kp, vp
 
-    def admit_tokens(self, prompts, last, tables, page, kp, vp):
+    def admit_tokens(self, prompts, last, tables, page, kp, vp, slots=None):
         """An admission wave: ``(A, P)`` right-padded prompts from offset
         0 through each row's own table rows; only the pages a prompt of
-        ``P`` tokens can reach are read."""
+        ``P`` tokens can reach are read.  ``slots`` ``(A,)``: where each
+        row's state under the slot table lands (an idle row names the
+        one-past-the-end slot: dropped); it starts from zero whatever the
+        slot's last tenant left."""
         A, P = prompts.shape
         logits, kp, vp, _ = self.tokens_paged(
             self.model.weights(), prompts, jnp.zeros((A,), jnp.int32),
-            tables, (kp, vp), page, last, key_pages=-(-P // page))
+            tables, (kp, vp), page, last, key_pages=-(-P // page),
+            slots=slots)
         return logits, kp, vp
 
     def forward_dense(self, w, toks):
@@ -304,20 +422,25 @@ class LayeredEngine:
         page = 16
         npg = -(-L // page)
         ids = jnp.arange(B * npg, dtype=jnp.int32).reshape(B, npg)
-        pools = self.pool_zeros(B * npg, B * npg, page)
+        pools = self.pool_zeros(B * npg, B * npg, page, B)
         logits, _, _, _ = self.tokens_paged(
-            w, toks, jnp.zeros((B,), jnp.int32), (ids, ids), pools, page,
-            None)
+            w, toks, jnp.zeros((B,), jnp.int32),
+            ids if self.window is None else (ids, ids), pools, page, None,
+            slots=jnp.arange(B, dtype=jnp.int32))
         return logits
 
     @jax.named_scope("mx.dense")
     def tokens_paged(self, w, toks, off, tables, pools, page, last,
-                     key_pages=None):
+                     key_pages=None, slots=None, live=None):
         """``toks`` ``(B, C)`` at positions ``off[b] + c`` through
-        ``tables = (main (B, MAXP), window (B, ring))`` against ``pools =
-        ((latent, index key), window latent)``.  Returns ``(logits (B, V)
+        ``tables = (main (B, MAXP), window (B, ring))`` (the main table
+        alone where the model has no window) against ``pools = ((latent,
+        index key), window latent)``.  Returns ``(logits (B, V)
         float32 at column last[b] — every column, (B, C, V), when ``last``
         is None —, kp, vp, aux)``."""
+        if self.stacked:
+            return self._tokens_runs(w, toks, off, tables, pools, page,
+                                     last, key_pages, slots, live)
         cfg = self.cfg
         (lat, ikp), wlat = pools
         ptm, ptw = tables
@@ -401,6 +524,219 @@ class LayeredEngine:
                              preferred_element_type=jnp.float32)
         aux = {k: jnp.stack(v) for k, v in aux.items() if v}
         return logits, (lat, ikp), wlat, aux
+
+    # -- stacked runs: state-space and grouped-query layers --------------- #
+    def _tokens_runs(self, w, toks, off, tables, pools, page, last,
+                     key_pages, slots, live):
+        """``tokens_paged`` of a model whose weights come as stacked runs
+        of like layers: each run is ONE ``lax.scan`` over its layers.
+
+        ``C == 1`` is the step: every slot one token, the pools a run
+        writes — ``(k, v)`` under the main table ``tables``, ``(state, conv
+        tail)`` under the slot table — carried whole and updated in place at
+        ``(layer, ...)``; ``live`` masks the slot table's update.
+
+        ``C > 1`` is a prefill: the K and V rows go through the table as in
+        the step, but the slot table's entries of the rows' ``slots`` are
+        read out BEFORE the scans (zero for a row at offset 0, whatever its
+        slot held), ride each scan as its ``xs``, come back as its ``ys``
+        and are written in place AFTER the scans, so that the scans never
+        carry the whole state: carried, the chip's compiler gave it a
+        layout that suits the prefill's transposes and converted the WHOLE
+        array to it and back, every dispatch.  A row leaves the state of its
+        last TRUE token (column ``last[b]``)."""
+        cfg = self.cfg
+        kv_pools, slot_pools = pools
+        B, C = toks.shape
+        pos = off[:, None] + jnp.arange(C, dtype=jnp.int32)[None]
+        count = jnp.full((B,), C, jnp.int32) if last is None else last + 1
+        x = (w["wte"][toks].astype(jnp.float32)
+             * getattr(cfg, "embedding_multiplier", 1.0)).astype(self.cdtype)
+        ctx = {"pos": pos, "count": count, "page": page, "table": tables,
+               "key_pages": key_pages, "live": live}
+        step = C == 1
+        mem = None
+        if not step and self.ssm:
+            fresh = off == 0
+            mem = tuple(jnp.where(
+                fresh.reshape((1, B) + (1,) * (a.ndim - 2)), 0,
+                _slot_rows(a, slots)) for a in slot_pools)
+        seen, after = {"ssm": 0, "gqa": 0}, []
+        for rw, (first, n) in zip(w["runs"], self.runs):
+            d = self.desc[first]
+            kind = d["attn"]["kind"]
+            mixer = getattr(self, self._KINDS[kind])
+            carried = slot_pools if kind == "ssm" and step else \
+                kv_pools if kind == "gqa" else ()
+            lo = seen[kind]
+            rows = tuple(a[lo:lo + n] for a in mem) \
+                if kind == "ssm" and mem is not None else ()
+
+            def layer(carry, xs, d=d, mixer=mixer):
+                x, held = carry
+                lw, li, rows = xs
+                h = _rms(x, lw["norm1_gamma"], cfg.rms_norm_eps)
+                o, held, rows = mixer(lw, d["attn"], h, held, li, ctx, rows)
+                x = x + (o * d["residual"]).astype(x.dtype)
+                h = _rms(x, lw["norm2_gamma"], cfg.rms_norm_eps)
+                y, _ = self._ffn(lw, d["ffn"], h.reshape(B * C, -1))
+                x = x + (y.reshape(B, C, -1) * d["residual"]).astype(x.dtype)
+                return (x, held), rows
+
+            ids = lo + jnp.arange(n, dtype=jnp.int32)
+            (x, carried), rows = jax.lax.scan(layer, (x, carried),
+                                              (rw, ids, rows))
+            seen[kind] += n
+            if kind == "gqa":
+                kv_pools = carried
+            elif step:
+                slot_pools = carried
+            else:
+                after.append(rows)
+        if after:
+            slot_pools = tuple(
+                _slot_rows_set(a, slots, jnp.concatenate(parts))
+                for a, parts in zip(slot_pools, zip(*after)))
+        with jax.named_scope("mx.head"):
+            if last is not None:
+                x = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
+            # the tied head: the embedding's rows contracted as they lie
+            logits = jnp.einsum(
+                "...h,vh->...v", _rms(x, w["normf"], cfg.rms_norm_eps),
+                w["wte"], preferred_element_type=jnp.float32) \
+                / getattr(cfg, "logits_scaling", 1.0)
+        return logits, kv_pools, slot_pools, {}
+
+    def _ssm_mixer(self, lw, a, h, held, li, ctx, rows):
+        """One state-space layer over ``h`` ``(B, C, H)``.  The step (``C ==
+        1``) updates layer ``li`` of ``held = (state, conv tail)`` in place
+        for the live slots; a prefill starts from ``rows = (state (B, ...),
+        tail (B, ...))`` as stored and hands the rows' new entries back."""
+        B, C, _ = h.shape
+        hh, P, N, K = a["heads"], a["head_dim"], a["state"], a["conv"]
+        inner, W = hh * P, self.conv_width
+        Wl = row_lanes(W)
+        f32 = jnp.float32
+        zxdt = _dot(h, lw["in_weight"])
+        z, u, dt = zxdt[..., :inner], zxdt[..., inner:inner + W], \
+            zxdt[..., inner + W:]
+        dt = jax.nn.softplus(dt.astype(f32) + lw["dt_bias"])
+        a_neg = -jnp.exp(lw["a_log"].astype(f32))
+        step = C == 1
+        if step:
+            state, tail = held
+            live = jnp.ones((B,), jnp.bool_) if ctx["live"] is None \
+                else ctx["live"]
+        else:
+            s0, t0 = rows
+        with jax.named_scope("mx.ssm_conv"):
+            if step:
+                old = jax.lax.dynamic_index_in_dim(tail, li, 0, False)
+                u, t1 = ssd.conv_step(old.reshape(B, K - 1, Wl)[..., :W],
+                                      u[:, 0], lw["conv_weight"],
+                                      lw["conv_bias"])
+                t1 = _pad_last(t1, Wl).reshape(B, -1)
+                tail = jax.lax.dynamic_update_index_in_dim(
+                    tail, jnp.where(live[:, None], t1, old), li, 0)
+                u = u[:, None]
+            else:
+                u, t1 = ssd.conv_seq(t0.reshape(B, K - 1, Wl)[..., :W], u,
+                                     lw["conv_weight"], lw["conv_bias"],
+                                     ctx["count"])
+                t1 = _pad_last(t1, Wl).reshape(B, -1).astype(t0.dtype)
+        xs = u[..., :inner].reshape(B, C, hh, P)
+        bm, cm = u[..., inner:inner + N], u[..., inner + N:]
+        if step:
+            with jax.named_scope("mx.ssm_state"):
+                y, state = ssd.state_update(state, li, xs[:, 0], dt[:, 0],
+                                            a_neg, bm[:, 0], cm[:, 0], live)
+                y = y[:, None]
+        else:
+            with jax.named_scope("mx.ssm_scan"):
+                # padding adds nothing: dt = 0 past a row's true tokens
+                true = jnp.arange(C)[None] < ctx["count"][:, None]
+                y, s1 = ssd.chunk_scan(
+                    xs, jnp.where(true[..., None], dt, 0.0), a_neg, bm, cm,
+                    ssd.unpack(s0, P), a["chunk"])
+                s1 = ssd.pack(s1)
+        with jax.named_scope("mx.ssm_gate"):
+            y = y + lw["d_skip"].astype(f32)[:, None] * xs.astype(f32)
+            g = y.reshape(B, C, inner) * jax.nn.silu(z.astype(f32))
+            g = _rms(g, lw["gnorm_gamma"], self.cfg.rms_norm_eps
+                     ).astype(h.dtype)
+        o = _dot(g, lw["out_weight"]).astype(f32)
+        return (o, (state, tail), ()) if step else (o, held, (s1, t1))
+
+    def _gqa_mixer(self, lw, a, h, held, li, ctx, mem=()):
+        """One grouped-query attention layer without positions: the new K
+        and V rows go through the main table into layer ``li``'s pages;
+        the single-query step walks the slot's pages, a prefill reads the
+        rows its queries can reach back through the table."""
+        from .decoding import _flat_attention
+
+        kpool, vpool = held
+        B, C, _ = h.shape
+        hq, kvh, D = a["heads"], a["kv_heads"], a["head_dim"]
+        page, pos, pt = ctx["page"], ctx["pos"], ctx["table"]
+        q = _dot(h, lw["q_weight"]).reshape(B, C, hq, D)
+        kv = _dot(h, lw["kv_weight"])
+        k, v = kv[..., :kvh * D], kv[..., kvh * D:]
+        lanes = kpool.shape[-1]
+
+        def write(kpool, vpool):
+            with jax.named_scope("mx.kv_write"):
+                pg = _table_pages(pt, pos // page, kpool.shape[1])
+                return (kpool.at[li, pg, pos % page].set(
+                            _pad_last(k, lanes), mode="drop"),
+                        vpool.at[li, pg, pos % page].set(
+                            _pad_last(v, lanes), mode="drop"))
+
+        def rows(pool, n):
+            reach = jnp.minimum(pt[:, :n], pool.shape[1] - 1)
+            return pool.at[li, reach].get(mode="promise_in_bounds").reshape(
+                B, n * page, lanes)
+
+        if C == 1:
+            def view():
+                n = pt.shape[1]
+                iB, p0 = jnp.arange(B), jnp.minimum(pos[:, 0], n * page - 1)
+                kc = rows(kpool, n).at[iB, p0].set(_pad_last(k[:, 0], lanes))
+                vc = rows(vpool, n).at[iB, p0].set(_pad_last(v[:, 0], lanes))
+                ok = jnp.arange(n * page)[None, None] <= pos[..., None]
+                return _flat_attention(q, kc[..., :kvh * D],
+                                       vc[..., :kvh * D], ok, a["scale"],
+                                       self.cdtype)[:, 0]
+
+            with jax.named_scope("mx.attn"):
+                if _paged.supports(lanes, self.cdtype, page, hq, D) \
+                        and lanes == kvh * D:
+                    o = _paged.paged_attention(
+                        q[:, 0], k[:, 0], v[:, 0], kpool, vpool, li, pt,
+                        _paged.walk_lengths(pt, pos[:, 0], page,
+                                            kpool.shape[1]),
+                        a["scale"], view)
+                else:
+                    o = view()
+            kpool, vpool = write(kpool, vpool)
+            o = o[:, None]
+        else:
+            kpool, vpool = write(kpool, vpool)
+            n = pt.shape[1] if ctx["key_pages"] is None else ctx["key_pages"]
+            with jax.named_scope("mx.attn"):
+                kc = rows(kpool, n)[..., :kvh * D].reshape(B, -1, kvh, D)
+                vc = rows(vpool, n)[..., :kvh * D].reshape(B, -1, kvh, D)
+                s = jnp.einsum("bckgd,btkd->bkgct",
+                               q.reshape(B, C, kvh, hq // kvh, D), kc,
+                               preferred_element_type=jnp.float32) \
+                    * a["scale"]
+                ok = jnp.arange(n * page)[None, None] <= pos[..., None]
+                s = jnp.where(ok[:, None, None], s, -1e30)
+                p = jax.nn.softmax(s, axis=-1).astype(self.cdtype)
+                o = jnp.einsum("bkgct,btkd->bckgd", p, vc,
+                               preferred_element_type=jnp.float32
+                               ).astype(self.cdtype).reshape(B, C, hq * D)
+        return _dot(o.reshape(B, C, hq * D), lw["o_weight"]
+                    ).astype(jnp.float32), (kpool, vpool), mem
 
     # -- attention ------------------------------------------------------ #
     def _latent_qkv(self, lw, a, h, pos):

@@ -227,7 +227,8 @@ def _device_planes(data):
 # ``transpose_jvp_mx_flash_bwd_dq__[.n]`` — hence a part, not the start.
 _KERNEL_REGIONS = (("ragged-dot", "mx.moe_experts"),
                    ("mx_paged_attention", "mx.attn"),
-                   ("mx_flash", "mx.attn"))
+                   ("mx_flash", "mx.attn"),
+                   ("mx_ssm_update", "mx.ssm_state"))
 
 
 def region_of(provenance, name=None):

@@ -171,7 +171,8 @@ def pool_state_bytes(progs, num_slots=None, num_pages=None):
     for BOTH dtypes."""
     S = progs.S if num_slots is None else int(num_slots)
     npages = S * progs.maxp if num_pages is None else int(num_pages)
-    return npages * progs.page_bytes() + S * _SLOT_STATE_BYTES \
+    return npages * progs.page_bytes() \
+        + S * (_SLOT_STATE_BYTES + progs.slot_state_bytes()) \
         + progs.window_pages * progs.window_page_bytes()
 
 
@@ -237,9 +238,9 @@ def pool_state_init(progs, device=None):
         device = jax.devices()[0]
     if progs.layered:
         # the declared row kinds of the model's cache kinds: main-table
-        # arrays in ``kp``, window-table arrays in ``vp``
+        # arrays in ``kp``, window-table or slot-table arrays in ``vp``
         kpool, vpool = eng.pool_zeros(progs.num_pages, progs.window_pages,
-                                      progs.page)
+                                      progs.page, S)
     else:
         kpool, vpool = _kv_pool_zeros(progs)
     state = (kpool,                          # K page pool
@@ -343,10 +344,15 @@ class PoolPrograms:
         # longest chunk a dispatch runs
         self.layered = self.eng.mode == "layered"
         self.window = getattr(self.eng, "window", None)
+        # cache kinds kept under the SLOT table (a recurrent layer's
+        # state): one entry a slot beside its pages
+        self.slot_kinds = tuple(getattr(self.eng, "slot_kinds", ()))
         self.ring, self.window_pages = 0, 0
         if self.layered and self.quant_kv:
+            kinds = sorted({d["cache"] for d in self.eng.desc})
             raise MXNetError("kv_dtype='int8' is not implemented for "
-                             "pools of declared row kinds")
+                             "pools of declared row kinds "
+                             f"({', '.join(kinds)})")
         if self.window is not None:
             self.ring = self.eng.window_span_pages(
                 self.page, int(max_chunk or self.page)) + 1
@@ -394,6 +400,12 @@ class PoolPrograms:
         return self.eng.window_page_bytes(self.page) \
             if self.window is not None else 0
 
+    def slot_state_bytes(self):
+        """Device bytes ONE slot keeps under the slot table over every
+        layer with such state (0 where the model has none) — beside the
+        scalar columns, which ``pool_state_bytes`` prices itself."""
+        return self.eng.slot_state_bytes() if self.slot_kinds else 0
+
     def pages_for(self, total_len):
         """Pages a sequence of ``total_len`` cached positions needs."""
         return -(-int(total_len) // self.page)
@@ -407,7 +419,8 @@ class PoolPrograms:
         long prompt's early chunks do not pay for its whole horizon (four
         executables a long bucket, all met while the first long prompt
         streams in)."""
-        if not self.layered or c_bucket < self.eng.dense_chunk:
+        if not self.layered or self.eng.dense_chunk is None \
+                or c_bucket < self.eng.dense_chunk:
             return None
         need = self.pages_for(reach)
         return next(kp for kp in (-(-self.maxp * i // 4) for i in (1, 2, 3, 4))
@@ -465,9 +478,12 @@ class PoolPrograms:
 
         def step(param_vals, q8, sw, now, pt, kp, vp, pos, tok, active,
                  stop, keys, dl, spec):
+            # state under the slot table is rewritten by the step itself:
+            # only a live slot's may be
+            live = {"live": active} if self.slot_kinds else {}
             with _TRACE_LOCK, params_swapped(deng.params, param_vals):
                 logits, kp, vp, *aux = deng.pool_token_paged(
-                    tok, pos, kp, vp, pt, page, sw, q8)
+                    tok, pos, kp, vp, pt, page, sw, q8, **live)
                 nxt = eng._sample_slots(keys, logits, pos)
             nxt = jnp.where(active, nxt, tok)
             newpos = jnp.where(active, pos + 1, pos)
@@ -478,7 +494,8 @@ class PoolPrograms:
             # an engine with counters of its own (experts' load, keys
             # selected) hands them back reduced over the live slots: a
             # fourth readback array the scheduler adds up
-            extra = (deng.step_counters(aux[0], active),) if aux else ()
+            extra = (deng.step_counters(aux[0], active),) \
+                if aux and aux[0] else ()
             return new_state, (nxt, emitted, done) + extra
 
         self._step = telemetry.instrument_jit(
@@ -604,10 +621,15 @@ class PoolPrograms:
             seed = meta[:, _AM["seed"]]
             spec_d = meta[:, _AM["spec_depth"]]
             keys_a = jax.vmap(jax.random.PRNGKey)(seed)       # (A, 2)
+            # masked slot-state scatter: invalid rows target slot S
+            # (out of bounds) and drop; valid rows carry distinct
+            # host-assigned slots
+            tgt = jnp.where(valid, slot, self.S)
             with _TRACE_LOCK, params_swapped(peng.params, param_vals):
                 if self.layered:
                     logits, kp, vp = peng.admit_tokens(
-                        prompts, true_len - 1, zpages, page, kp, vp)
+                        prompts, true_len - 1, zpages, page, kp, vp,
+                        slots=tgt)
                 else:
                     ck1, cv1 = peng.zero_caches()
                     logits, ck1, cv1 = peng.prefill_batch(
@@ -619,10 +641,6 @@ class PoolPrograms:
                 done = done | (first == self.eos_id)
             if not self.layered:
                 kp, vp = land(kp, vp, ck1, cv1, true_len, pages, zpages)
-            # masked slot-state scatter: invalid rows target slot S
-            # (out of bounds) and drop; valid rows carry distinct
-            # host-assigned slots
-            tgt = jnp.where(valid, slot, self.S)
             pos = pos.at[tgt].set(true_len, mode="drop")
             tok = tok.at[tgt].set(first, mode="drop")
             active = active.at[tgt].set(~done, mode="drop")
@@ -798,9 +816,14 @@ class PoolPrograms:
                     (kpc, kps), (vpc, vps) = kp, vp
                     kp = (kpc, _pages_set(kps, zrow, 0.0))
                     vp = (vpc, _pages_set(vps, zrow, 0.0))
+            # where state lives under the slot table, EVERY chunk lands
+            # in the request's slot (the scalar columns below only on the
+            # final one)
+            where = {"slot": slot} if self.slot_kinds else {}
             with _TRACE_LOCK, params_swapped(deng.params, param_vals):
                 logits, kp, vp = deng.chunk_tokens(
-                    toks, off, nlast, ptrow, page, kp, vp, sw, q8, **bound)
+                    toks, off, nlast, ptrow, page, kp, vp, sw, q8, **bound,
+                    **where)
                 first = self._sample_slots(key1[None], logits,
                                            (true_len - 1)[None])[0]
             done = stop_pos <= true_len
@@ -865,6 +888,12 @@ class PoolPrograms:
             return fn
         if k < 1:
             raise MXNetError(f"verify bucket {k} must be >= 1")
+        if self.slot_kinds:
+            raise MXNetError(
+                "draft-and-verify is not implemented for a model with "
+                f"state under the slot table ({', '.join(self.slot_kinds)}"
+                "): a rejected draft would need that state rolled back; "
+                "serve it with spec=False")
         if self.layered:
             raise MXNetError(
                 "draft-and-verify is not implemented for models served "

@@ -32,7 +32,7 @@ modules) so standalone tools can load it by file path.
 from __future__ import annotations
 
 __all__ = ["EXECUTABLES", "SLOT_STATE", "KV_PAGE_INT8", "POOL_ROWS",
-           "LANE_TILE", "row_lanes", "pool_rows",
+           "POOL_TABLES", "LANE_TILE", "row_lanes", "pool_rows",
            "executable_names", "operands",
            "arity", "donate_argnums", "donated_operands", "jit_donate",
            "state_operands", "state_arity", "slot_state_fields",
@@ -133,19 +133,29 @@ KV_PAGE_INT8 = {"codes": "int8", "scales": "float32"}
 # per-layer description names (``models.decoding.layer_description``).
 # ``table`` says which page table addresses the rows: ``main`` (a slot
 # holds a page for every position it has cached; the prefix index shares
-# them) or ``window`` (a ring a slot: pages for its last ``window``
-# positions only, released as it advances).  ``rows`` are the stored row
-# kinds, each one array ``(layers of the kind, pages, page, lanes)``; the
-# state tuple's ``kp`` holds the main-table arrays and ``vp`` the
-# window-table ones (a uniform K/V model: ``kp`` = K, ``vp`` = V, both
-# main).  A row's lanes are its width rounded up to whole 128-lane tiles
-# (``row_lanes``): a 64-wide minor dimension made the chip keep the pool
-# page-minor and re-lay it out for every consumer (PERF.md, PR 27).
+# them), ``window`` (a ring a slot: pages for its last ``window``
+# positions only, released as it advances) or ``slot`` (no pages at all:
+# ONE entry a slot, addressed by the slot's own index, rewritten in place
+# at every token — a recurrent layer's state.  Never shared and never
+# mapped from a cached prefix, so a model with such a kind serves with the
+# prefix index off; an admission starts the entry from zero whatever the
+# slot's last tenant left, and retiring a slot needs no device work).
+# ``rows`` are the stored row kinds, each one array ``(layers of the kind,
+# pages, page, lanes)`` — under the slot table ``(layers of the kind,
+# slots, ...)``; the state tuple's ``kp`` holds the main-table arrays and
+# ``vp`` the window-table ones or, where a model has them, the slot-table
+# ones (a uniform K/V model: ``kp`` = K, ``vp`` = V, both main), so that
+# every executable donates them by the same two NAMES.  A row's lanes are
+# its width rounded up to whole 128-lane tiles (``row_lanes``): a 64-wide
+# minor dimension made the chip keep the pool page-minor and re-lay it out
+# for every consumer (PERF.md, PR 27).
 POOL_ROWS = {
     "kv": {"table": "main", "rows": ("k", "v")},
     "latent_index": {"table": "main", "rows": ("latent", "index_key")},
     "latent_window": {"table": "window", "rows": ("latent",)},
+    "ssm_state": {"table": "slot", "rows": ("state", "conv_tail")},
 }
+POOL_TABLES = ("main", "window", "slot")
 LANE_TILE = 128
 
 _ITEMSIZE = {"bool": 1, "int8": 1, "uint8": 1, "int16": 2, "uint16": 2,

@@ -874,16 +874,32 @@ class DecodeServer:
                 stacklevel=2)
         if not self.sync_mode and self._progs.layered:
             # a model served from its per-layer description: one pool
-            # size (its window ring does not grow) and no draft-and-verify
-            if len(self.pool_sizes) > 1 and self._progs.window is not None:
+            # size (its window ring and its per-slot state do not grow)
+            # and no draft-and-verify
+            kinds = ", ".join(self._progs.slot_kinds)
+            if len(self.pool_sizes) > 1 and (
+                    self._progs.window is not None or kinds):
                 raise MXNetError(
-                    "a model with windowed layers serves from one pool "
-                    f"size, not {self.pool_sizes}")
+                    "a model with windowed layers or per-slot state "
+                    f"serves from one pool size, not {self.pool_sizes}")
             if spec and self.spec_enabled:
                 raise MXNetError(
                     "draft-and-verify is not implemented for models "
-                    "served from a per-layer description: pass spec=False")
+                    "served from a per-layer description"
+                    + (f" (a rejected draft would need the {kinds} state "
+                       "rolled back)" if kinds else "")
+                    + ": pass spec=False")
             self.spec_enabled = False
+            if kinds:
+                # a cached prefix is pages, and pages cannot enter a layer
+                # whose memory is a state: no state is snapshot, so the
+                # prefix index is off for such a model
+                if prefix_cache:
+                    raise MXNetError(
+                        "prefix_cache=True is not implemented for a model "
+                        f"with state under the slot table ({kinds}): a "
+                        "cached prefix of pages holds no such state")
+                self.prefix_cache_enabled = False
         if not self.sync_mode:
             # price the MINIMUM USABLE configuration before allocating
             # anything: the smallest pool plus the smallest admission
@@ -929,6 +945,7 @@ class DecodeServer:
         self._slot_pos = [0] * self.pool_sizes[0]
         self._wheld_max = 0     # most window pages a stepping slot held
         self._prompt_tokens = self._prompt_cached = 0
+        self._state_resets = 0  # slots started from zero per-slot state
         self._step_sums = {}    # the step's own counters, added up
         self._prefix = _PrefixIndex(
             self._progs.page, self._pages, self._wpages,
@@ -986,6 +1003,8 @@ class DecodeServer:
             else self._progs.window_pages,
             window_page_bytes=0 if self.sync_mode
             else self._progs.window_page_bytes(),
+            slot_state_bytes=0 if self.sync_mode
+            else self._progs.slot_state_bytes(),
             prefix_cache=self.prefix_cache_enabled,
             spec=self.spec_enabled, spec_depth=self.spec_depth,
             spec_sizes=list(self.spec_sizes))
@@ -1187,6 +1206,15 @@ class DecodeServer:
             else self._pages.in_use,
             "prefix_nodes": 0 if self._prefix is None
             else len(self._prefix),
+            # off by the server's own rule for a model with state under
+            # the slot table (``slot_kinds``), whose bytes a slot and
+            # admissions started from zero state follow
+            "prefix_cache": self.prefix_cache_enabled,
+            "slot_kinds": [] if self.sync_mode
+            else list(self._progs.slot_kinds),
+            "state_bytes_per_slot": 0 if self.sync_mode
+            else self._progs.slot_state_bytes(),
+            "state_resets": self._state_resets,
             # how much of its table the step's page walk reads
             "step_pages_walked": self._pages_walked,
             "step_pages_table": self._pages_table,
@@ -1930,6 +1958,8 @@ class DecodeServer:
             self._state = None
             return
         self._count("admit_dispatches")
+        if self._progs.slot_kinds:
+            self._state_resets += len(wave)
         self._inflight.append(("admit", (first, done), list(wave), seq))
         for slot, req in wave:
             self._prompt_landed(slot, req)
@@ -2321,6 +2351,8 @@ class DecodeServer:
             self._state = None
             return True
         self._count("chunk_dispatches")
+        if self._progs.slot_kinds and off == 0:
+            self._state_resets += 1
         rec["off"] = off + ntok
         telemetry.emit("serve_chunk", server=self.telemetry_label,
                        request_id=req.stream.request_id, slot=slot,

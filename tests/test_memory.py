@@ -913,3 +913,21 @@ class TestCheckServeBudget:
         stats["pool_bytes"] = 0
         events[0]["pool_bytes"] = 0
         assert telemetry_report.check_serve(events) == []
+
+    def test_pool_bytes_count_the_slot_table(self):
+        """ISSUE 33: a model with state under the slot table reports
+        ``state_bytes_per_slot``; the identity counts it a slot, and a
+        pool that forgot it (or counted it twice) is flagged."""
+        from tools import telemetry_report
+
+        total, page_bytes, per_slot = 8, 512, 17920
+        events = _mem_stream(
+            pool_bytes=total * page_bytes + 2 * (29 + per_slot))
+        stats = next(e for e in events if e["kind"] == "serve_stats")
+        stats.update(pages_total=total, pages_in_use=0, num_slots=2,
+                     kv_dtype="native", page_bytes=page_bytes,
+                     state_bytes_per_slot=per_slot)
+        assert telemetry_report.check_serve(events) == []
+        stats["state_bytes_per_slot"] = 0
+        assert any("priced page bytes" in f
+                   for f in telemetry_report.check_serve(events))

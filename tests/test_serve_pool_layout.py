@@ -220,3 +220,90 @@ def test_layered_scratch_is_no_second_pool(layered_reports, which):
     smallest = 2 * L_PAGES * PAGE * 128 * 2
     assert layered_reports[which]["temp_bytes"] < smallest // 4, \
         layered_reports[which]
+
+
+# --------------------------------------------------------------------------- #
+# the slot table: a recurrent state beside the pages (ISSUE 33)
+# --------------------------------------------------------------------------- #
+
+H_SLOTS, H_TOTAL, H_PAGES = 8, 128, 64
+
+
+@pytest.fixture(scope="module")
+def hybrid_progs():
+    """Lane widths as the published model has them in kind — state rows of
+    two 64-wide heads a 128-lane tile, K and V rows of whole tiles — at a small
+    depth and hidden size."""
+    from mxnet_tpu.models import granite_hybrid as gh
+    net, _ = gh.granite_hybrid_tiny(
+        dtype="bfloat16", hidden_size=256, shared_intermediate_size=512,
+        num_attention_heads=8, num_key_value_heads=8, mamba_n_heads=8,
+        mamba_d_head=64, mamba_d_state=128, mamba_chunk_size=64,
+        vocab_size=512, max_length=H_TOTAL, attention_multiplier=0.125)
+    net.collect_params().setattr("grad_req", "null")
+    net.initialize(mx.init.Zero())
+    return PoolPrograms(net, H_SLOTS, H_TOTAL, page_size=PAGE,
+                        num_pages=H_PAGES)
+
+
+@pytest.fixture(scope="module")
+def hybrid_reports(chip, hybrid_progs):
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from tools import rehearse_serve as rs
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        p = hybrid_progs
+        out = {"step": rs.pool_report(rs.compile_step(p, chip), p),
+               "admit": rs.pool_report(rs.compile_admit(p, chip, 2, 64), p),
+               "chunk": rs.pool_report(rs.compile_chunk(p, chip, 64), p)}
+        for name, row in out.items():
+            row["faults"] = rs.slot_state_faults("serve." + name, row, p)
+        return out
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+def test_slot_table_is_priced_as_allocated(hybrid_progs):
+    import jax
+
+    from mxnet_tpu.serve.engine import pool_state_bytes, pool_state_init
+    p = hybrid_progs
+    assert p.slot_kinds == ("ssm_state",) and p.window is None
+    state = jax.eval_shape(lambda: pool_state_init(p))
+    shapes = [(a.shape, a.dtype.name) for a in jax.tree.leaves(state[:2])]
+    assert shapes == [
+        ((1, H_PAGES, PAGE, 256), "bfloat16"),      # K rows, main table
+        ((1, H_PAGES, PAGE, 256), "bfloat16"),      # V rows
+        ((5, H_SLOTS, 4, 128, 128), "float32"),     # state: (H/2, N, 2P)
+        ((5, H_SLOTS, 3 * 768), "bfloat16")]        # tail: 3 rows of 6 tiles
+    allocated = sum(int(onp.prod(a.shape)) * a.dtype.itemsize
+                    for a in jax.tree.leaves(state))
+    assert pool_state_bytes(p, num_pages=H_PAGES) == allocated
+    assert p.slot_state_bytes() == 5 * (4 * 128 * 128 * 4 + 3 * 768 * 2)
+
+
+@pytest.mark.parametrize("which", ["step", "admit", "chunk"])
+def test_slot_state_is_updated_in_place(hybrid_reports, which):
+    """No ``copy`` as large as a pool array, the step's scratch far under
+    one layer's state, and on the step the update is the Pallas kernel (a
+    Mosaic custom call: the CPU's lowering cannot pass this)."""
+    row = hybrid_reports[which]
+    assert row["faults"] == [], row
+    one_layer = H_SLOTS * 4 * 128 * 128 * 4
+    assert all(n < one_layer for n in row["copy_bytes"].values()), row
+    if which == "step":
+        assert any("mx_ssm_update" in k for k in row["kernels"]), row
+        assert row["temp_bytes"] < one_layer // 2, row
+
+
+@pytest.mark.parametrize("which", ["step", "admit", "chunk"])
+def test_slot_pools_keep_the_declared_layout(hybrid_reports, which):
+    for lay in hybrid_reports[which]["pool_entry_layouts"]:
+        dims = lay.split("{")[1]
+        assert dims.startswith(("4,3,2,1,0", "3,2,1,0", "2,1,0")), lay

@@ -152,3 +152,36 @@ class TestKvPagePricing:
         scale_bytes = jnp.dtype(decoding._KV_SCALE_DTYPE).itemsize
         assert schema.kv_page_int8_bytes(1, 1, 1, 1) == \
             2 * (1 + scale_bytes)
+
+
+class TestPoolTables:
+    """The declared row kinds and their tables (ISSUE 33: the slot table,
+    one entry a slot beside its pages)."""
+
+    def test_declared_kinds_and_tables(self):
+        assert schema.POOL_TABLES == ("main", "window", "slot")
+        assert {k: v["table"] for k, v in schema.POOL_ROWS.items()} == {
+            "kv": "main", "latent_index": "main",
+            "latent_window": "window", "ssm_state": "slot"}
+        assert schema.pool_rows("ssm_state") == (
+            "slot", ("state", "conv_tail"))
+        assert all(v["table"] in schema.POOL_TABLES
+                   for v in schema.POOL_ROWS.values())
+
+    def test_unknown_kind_names_the_declared_ones(self):
+        with pytest.raises(ValueError, match="ssm_state"):
+            schema.pool_rows("lstm_state")
+
+    @pytest.mark.parametrize("width,lanes", [(4352, 4352), (80, 128),
+                                             (128, 128), (8512, 8576)])
+    def test_slot_rows_are_whole_lane_tiles(self, width, lanes):
+        assert schema.row_lanes(width) == lanes
+
+    @pytest.mark.parametrize("name", ["step", "admit", "chunk"])
+    def test_slot_arrays_are_donated_by_position(self, name):
+        """The slot-table arrays ride in ``vp``: the donation positions the
+        literals give are where the engine's state tuple puts them."""
+        ops = schema.operands(name)
+        at = schema.donate_argnums(name)
+        assert [ops[i] for i in at] == ["kp", "vp"]
+        assert at[1] == len(ops) - schema.state_arity() + 1

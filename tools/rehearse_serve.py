@@ -21,7 +21,10 @@ pages of 16 tokens, bfloat16).  ``--config`` names a chip-benchmark
 configuration file of a model served from its per-layer description (its
 ``server`` group gives the pools): then the step, every chunk bucket and the
 hit admission are compiled, each against all of its pools (latent rows,
-index keys, window rows).  ``tests/test_serve_pool_layout.py`` holds a
+index keys, window rows).  A model with state under the slot table (a
+recurrent layer's) gets its step, widest admission wave and widest chunk
+compiled, and the exit code is 1 where one of them copies that state or the
+step reserves scratch as large as one layer's state of every slot.  ``tests/test_serve_pool_layout.py`` holds a
 small engine to the same readings.
 """
 from __future__ import annotations
@@ -166,7 +169,8 @@ def pool_report(compiled, progs):
     whatever its dimensions (the in-place scatters work on a 2-D bitcast),
     as ``{label: [names]}``.  A fusion's label is ``fusion:<opcode of its
     root>``; what only carries the pool (parameter, tuple, bitcast, the
-    ``while`` it rides through) is left out.  ``kernels`` names the Mosaic
+    ``while`` it rides through) is left out; ``copy_bytes`` gives the bytes
+    of each ``copy`` among them.  ``kernels`` names the Mosaic
     custom calls (the paged-attention kernel of a step that walks its
     pages) and ``view_sized`` every instruction, fused ones too, whose
     result is one layer's view of every slot: ``(S, T, KV·D)``, or ``(S,
@@ -185,7 +189,7 @@ def pool_report(compiled, progs):
         r"[a-z0-9]+" + re.escape(d) + r"\{[^}]*\}",
         entry.group(1) if entry else "")})
     roots, found, comp = {}, [], None
-    kernels, view_sized = [], []
+    kernels, view_sized, copy_bytes = [], [], {}
     for line in text.splitlines():
         c = _COMPUTATION.match(line)
         if c is not None:
@@ -202,6 +206,10 @@ def pool_report(compiled, progs):
             kernels.append(m.group("name"))
         if any(v in m.group("type") for v in views):
             view_sized.append(m.group("name"))
+        if n_pools & set(sizes) and m.group("op") == "copy":
+            bits = re.match(r"\(?[a-z]+?([0-9]+)\[", m.group("type"))
+            copy_bytes[m.group("name")] = max(sizes) * (
+                int(bits.group(1)) if bits else 8) // 8
         if n_pools & set(sizes) and m.group("op") not in _CARRIERS:
             called = re.search(r"calls=%?([\w.\-]+)", line)
             found.append((comp, m.group("op"), m.group("name"),
@@ -222,6 +230,7 @@ def pool_report(compiled, progs):
             "pool_dims": dims[0] if len(dims) == 1 else dims,
             "pool_entry_layouts": layouts,
             "pool_sized": sized,
+            "copy_bytes": copy_bytes,
             "kernels": kernels,
             "view_sized": view_sized}
 
@@ -275,13 +284,21 @@ def main(argv=None):
 
 def _rehearse_config(args):
     """The step, every chunk bucket and the hit admission of the
-    configuration file's model at its own pools."""
+    configuration file's model at its own pools; for a model with state
+    under the slot table (no prefix hits) the step, the widest admission
+    wave and the widest chunk, and exit code 1 where one of them copies
+    that state or the step reserves scratch of its size."""
+    import importlib
+
     import mxnet_tpu as mx
-    from chipbench import dots3, harness
+    from chipbench import harness
     from mxnet_tpu.serve.engine import PoolPrograms
 
     config = harness.read_json(args.config)
-    net, _ = dots3.build(config)
+    # the configuration's entry names its builder: ``serve_<module>``
+    builder = importlib.import_module(
+        "chipbench." + config["entry"].split("_", 1)[1])
+    net, _ = builder.build(config)
     net.collect_params().setattr("grad_req", "null")
     net.initialize(mx.init.Zero())
     srv = config["server"]
@@ -292,6 +309,13 @@ def _rehearse_config(args):
                          max_chunk=srv["prefill_buckets"][-1])
     chip = v5e_chip()
     todo = [("serve.step", lambda: compile_step(progs, chip))]
+    if progs.slot_kinds:
+        a, p = srv["admit_sizes"][-1], srv["prefill_buckets"][-1]
+        todo += [(f"serve.admit({a},{p})",
+                  lambda: compile_admit(progs, chip, a, p)),
+                 (f"serve.chunk({p})",
+                  lambda: compile_chunk(progs, chip, p))]
+        return _report(todo, progs, args.hlo, check=slot_state_faults)
     todo += [(f"serve.chunk({c})",
               lambda c=c: compile_chunk(progs, chip, c))
              for c in srv["prefill_buckets"]]
@@ -300,12 +324,32 @@ def _rehearse_config(args):
     return _report(todo, progs, args.hlo)
 
 
-def _report(todo, progs, hlo):
+def slot_state_faults(name, row, progs):
+    """What a compiled executable of a model with state under the slot
+    table must not do: ``copy`` a whole pool array as large as one layer's
+    state of every slot or larger (the state is updated in place, whoever
+    the executable; the tails, 1% of it, may be re-laid), or — the step —
+    reserve scratch of that size."""
+    layer_state = progs.S * progs.slot_state_bytes() \
+        // max(1, len(progs.eng.ssm))
+    faults = [f"{name}: {copy} copies a whole pool array of {n} bytes"
+              for copy, n in row["copy_bytes"].items() if n >= layer_state]
+    if name == "serve.step" and row["temp_bytes"] >= layer_state:
+        faults.append(f"{name}: temp_bytes {row['temp_bytes']} is not under "
+                      f"one layer's state of every slot ({layer_state})")
+    return faults
+
+
+def _report(todo, progs, hlo, check=None):
+    faults = []
     for name, build in todo:
         t0 = time.time()
         compiled = build()
         row = {"executable": name, **pool_report(compiled, progs),
                "compile_s": round(time.time() - t0, 1)}
+        if check is not None:
+            row["faults"] = check(name, row, progs)
+            faults += row["faults"]
         if hlo:
             os.makedirs(hlo, exist_ok=True)
             path = os.path.join(hlo, re.sub(r"\W+", "_", name) + ".hlo")
@@ -313,7 +357,7 @@ def _report(todo, progs, hlo):
                 fh.write(compiled.as_text())
             row["hlo"] = path
         print(json.dumps(row), flush=True)
-    return 0
+    return 1 if faults else 0
 
 
 if __name__ == "__main__":

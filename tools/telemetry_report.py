@@ -540,8 +540,10 @@ def check_serve(events):
             continue   # sync mode / torn-down pool: nothing resident
         # a model with windowed layers keeps a second pool, priced by
         # its own page size (absent from older recordings: 0)
+        # and one with state under the slot table its bytes a slot
         priced = total * page_bytes + (st.get("window_pages_total") or 0) \
-            * (st.get("window_page_bytes") or 0)
+            * (st.get("window_page_bytes") or 0) \
+            + slots * (st.get("state_bytes_per_slot") or 0)
         if schema is not None:
             # the slot-state layout declaration is on hand: the scalar
             # state must price to EXACTLY slots * slot_state_bytes()
