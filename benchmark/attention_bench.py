@@ -12,11 +12,16 @@ table; ``tests/test_attention.py`` asserts the dispatch matches it.
     python benchmark/attention_bench.py --seqs 512,2048
     python benchmark/attention_bench.py --batch 8 --heads 16 --seqs 1024
     python benchmark/attention_bench.py --seqs 1024 --blocks 128,256,512
+    python benchmark/attention_bench.py --batch 8 --heads 16 --seqs 1024 --packed
 
 ``--batch`` / ``--heads`` set the shape (the table's is B4 H8, the train
 cell's B8 H16); ``--blocks`` times each Pallas kernel alone (forward, dq,
 dk/dv) over every pair of the listed block sizes instead — the sweep behind
-``_block_for`` / ``_block_dkv_for``.
+``_block_for`` / ``_block_dkv_for``.  ``--packed`` starts from the fused
+``(B, L, 3U)`` projection instead and times the kernels that read it where
+it lies (``flash_attention_qkv``'s packed path) against what a model paid
+before them: the split into ``(B, H, L, D)`` heads, the same kernels on
+those, and the transpose back.
 
 Every timed region ends in a device->host readback (as in bench.py), and
 dispatch is amortized by looping the op inside one jit via lax.scan.
@@ -46,6 +51,9 @@ def main():
     ap.add_argument("--blocks", default="",
                     help="comma-separated block sizes: time each Pallas "
                          "kernel alone over every (resident, streamed) pair")
+    ap.add_argument("--packed", action="store_true",
+                    help="time the kernels over the packed (B, L, 3U) "
+                         "projection against split + kernels + transpose")
     args = ap.parse_args()
 
     import jax
@@ -73,9 +81,14 @@ def main():
                     # left unused (dk, dv) is dead code, and XLA drops
                     # the kernel that made it
                     outs = out if isinstance(out, tuple) else (out,)
-                    nxt = sum(o.astype(jnp.float32) for o in outs
-                              if o is not None and o.shape == q0.shape)
-                    return nxt.astype(q0.dtype), None
+                    same = [o.astype(jnp.float32) for o in outs
+                            if o is not None and o.shape == q0.shape]
+                    if same:
+                        return sum(same).astype(q0.dtype), None
+                    # --packed, forward: (B, L, U) out of (B, L, 3U) goes
+                    # back into the carry's leading lanes, in place
+                    return lax.dynamic_update_slice(
+                        c, outs[0].astype(q0.dtype), (0,) * c.ndim), None
                 c, _ = lax.scan(body, q0, None, length=inner)
                 return jnp.sum(c.astype(jnp.float32))
             return looped
@@ -133,6 +146,29 @@ def main():
         rng = onp.random.RandomState(0)
         q, k, v = (jnp.asarray(rng.randn(B, H, seq, D), dtype)
                    for _ in range(3))
+
+        if args.packed:
+            # one (B, L, 3U) array in, (B, L, U) out, a (B, L, 3U)
+            # gradient back: what MultiHeadAttention holds between its two
+            # projections.  Both arms run the Pallas kernels; "apart" pays
+            # the copies around them
+            force_pallas(True)
+            qkv = jnp.asarray(rng.randn(B, seq, 3 * H * D), dtype)
+            seed = jnp.uint32(0)
+
+            def packed(qkv):
+                return attn._flash_qkv(qkv, None, seed, H, scale, True, 0.0)
+
+            def apart(qkv):
+                out = attn._flash(*attn._split_heads(qkv, H), None, seed,
+                                  scale, True, 0.0, "pallas")
+                return out.transpose(0, 2, 1, 3).reshape(B, seq, H * D)
+
+            for impl, fn in (("packed", packed), ("apart", apart)):
+                emit(seq, impl, "fwd", lambda: bench(fn, qkv))
+                emit(seq, impl, "fwd+bwd", lambda: bench(jax.grad(
+                    lambda x: jnp.sum(fn(x).astype(jnp.float32))), qkv))
+            continue
 
         if blocks:
             # each kernel alone; "resident" is the block that stays while

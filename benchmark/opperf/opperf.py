@@ -66,6 +66,8 @@ def _default_inputs(name, rng, large):
         "argsort": lambda: [mk(sq)],
         "flash_attention": lambda: [mk((4, 8, 256, 64)), mk((4, 8, 256, 64)),
                                     mk((4, 8, 256, 64))],
+        "flash_attention_qkv": lambda: ([mk((4, 256, 3 * 8 * 64))],
+                                        {"num_heads": 8}),
     }
     if name in specials:
         out = specials[name]()

@@ -13,7 +13,7 @@ TARGET_DTYPE_OPS = [
     "dot", "batch_dot", "matmul", "linalg_gemm2",
     "_contrib_interleaved_matmul_selfatt_qk",
     "_contrib_interleaved_matmul_selfatt_valatt",
-    "flash_attention", "fused_rnn",
+    "flash_attention", "flash_attention_qkv", "fused_rnn",
 ]
 
 # numerically-sensitive ops pinned to fp32

@@ -4,9 +4,10 @@ Reference counterpart: GluonNLP's BERT/Transformer blocks built on the
 contrib interleaved self-attention ops
 (``_contrib_interleaved_matmul_selfatt_qk``, SURVEY.md §3.1) which fuse the
 QKV projections into one matmul.  Here the same fusion holds (one
-Dense(3·units) projection — one big MXU GEMM) and the O(L²) score
+Dense(3·units) projection — one big MXU GEMM), the O(L²) score
 materialization is replaced by the flash kernel (O(L) memory,
-SURVEY.md §5.7).
+SURVEY.md §5.7), and the kernel reads the heads out of that projection
+where it lies (``ops.attention.flash_attention_qkv``).
 """
 from __future__ import annotations
 
@@ -46,17 +47,12 @@ class MultiHeadAttention(HybridBlock):
             self.drop = Dropout(dropout) if dropout else None
 
     def hybrid_forward(self, F, x, mask=None):
-        B, L, U = x.shape
-        H, D = self._heads, self._units // self._heads
-        qkv = self.qkv(x)                                     # (B, L, 3U)
-        qkv = F.reshape(qkv, shape=(B, L, 3, H, D))
-        qkv = F.transpose(qkv, axes=(2, 0, 3, 1, 4))          # (3,B,H,L,D)
-        q = F.reshape(F.slice_axis(qkv, axis=0, begin=0, end=1), shape=(B, H, L, D))
-        k = F.reshape(F.slice_axis(qkv, axis=0, begin=1, end=2), shape=(B, H, L, D))
-        v = F.reshape(F.slice_axis(qkv, axis=0, begin=2, end=3), shape=(B, H, L, D))
-        out = F.flash_attention(q, k, v, mask, causal=self._causal,
-                                dropout=self._attn_dropout)
-        out = F.reshape(F.transpose(out, axes=(0, 2, 1, 3)), shape=(B, L, U))
+        # q, k and v stay where the projection put them: the op reads the
+        # (B, L, 3U) array by head and hands (B, L, U) back
+        out = F.flash_attention_qkv(self.qkv(x), mask,
+                                    num_heads=self._heads,
+                                    causal=self._causal,
+                                    dropout=self._attn_dropout)
         out = self.proj(out)
         if self.drop is not None:
             out = self.drop(out)

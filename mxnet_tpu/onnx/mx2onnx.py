@@ -334,6 +334,42 @@ def _conv_flash(name, ins, out, attrs):
     return nodes
 
 
+@register_converter("flash_attention_qkv")
+def _conv_flash_qkv(name, ins, out, attrs):
+    """The op over the packed (B, L, 3U) projection exports as what
+    ``MultiHeadAttention`` held there before it: reshape / transpose /
+    slice into (B, H, L, D) heads, ``flash_attention``'s decomposition,
+    transpose and reshape back — an exported graph is what it was."""
+    shp = (attrs.get("_in_shapes") or [None])[0]
+    if not shp:
+        raise MXNetError(
+            "onnx: flash_attention_qkv export needs input_shapes (to "
+            "split the packed projection into heads)")
+    B, L, U3 = (int(d) for d in shp)
+    H = int(attrs["num_heads"])
+    D = U3 // 3 // H
+    nodes = _conv_reshape(f"{name}_split", ins[:1], f"{name}_split",
+                          {"shape": (B, L, 3, H, D)})
+    nodes += _conv_transpose(f"{name}_heads", [f"{name}_split"],
+                             f"{name}_heads", {"axes": (2, 0, 3, 1, 4)})
+    qkv = []
+    for i, role in enumerate("qkv"):
+        nodes += _conv_slice_axis(f"{name}_{role}5", [f"{name}_heads"],
+                                  f"{name}_{role}5",
+                                  {"axis": 0, "begin": i, "end": i + 1})
+        nodes += _conv_reshape(f"{name}_{role}", [f"{name}_{role}5"],
+                               f"{name}_{role}", {"shape": (B, H, L, D)})
+        qkv.append(f"{name}_{role}")
+    nodes += _conv_flash(f"{name}_attn", qkv + list(ins[1:]),
+                         f"{name}_attn",
+                         {"causal": attrs.get("causal"),
+                          "_in_shapes": [(B, H, L, D)]})
+    nodes += _conv_transpose(f"{name}_rows", [f"{name}_attn"],
+                             f"{name}_rows", {"axes": (0, 2, 1, 3)})
+    return nodes + _conv_reshape(name, [f"{name}_rows"], out,
+                                 {"shape": (B, L, U3 // 3)})
+
+
 for _mx, _onnx in [("broadcast_add", "Add"), ("broadcast_sub", "Sub"),
                    ("broadcast_mul", "Mul"), ("broadcast_div", "Div"),
                    ("broadcast_maximum", "Max"), ("broadcast_minimum", "Min"),

@@ -17,6 +17,14 @@ The TPU-native answer (SURVEY.md §5.7 — NEW capability, not parity) is:
   general dense biases (e.g. ALiBi tables) take the XLA blockwise path.
   Backward recomputes blockwise from the saved log-sum-exp (the
   flash-attention-2 scheme) — no O(L²) residuals on any path.
+- ``flash_attention_qkv``: the same attention straight off a model's fused
+  (B, L, 3U) projection, giving (B, L, U).  Where ``flash_attention``
+  would take the Pallas kernels and the heads fill whole lane tiles, the
+  same three kernel bodies address q, k, v, the output and the gradients
+  as 128-lane blocks of that array where it lies (two 64-wide heads a
+  block), so no transpose, slice or concatenate stands between the
+  projections and the kernels; everywhere else it splits the heads and
+  calls ``flash_attention``.
 - ``ring_attention``: sequence-parallel attention over a mesh axis; K/V
   shards rotate around the ICI ring via ``ppermute`` while each device
   accumulates online-softmax partials for its local Q shard.  This is the
@@ -27,22 +35,23 @@ Dropout determinism: the keep-mask is a pure position hash of
 kernels and the XLA paths, so a forward on one path and a backward
 recompute on another still see the same mask.
 
-Shapes follow (batch, heads, seq, head_dim) throughout.
+Shapes follow (batch, heads, seq, head_dim) but for the packed projection.
 """
 from __future__ import annotations
 
 import functools
 import inspect
 import os
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .registry import op
+from .registry import get_op, op
 
-__all__ = ["flash_attention", "ring_attention", "rope"]
+__all__ = ["flash_attention", "flash_attention_qkv", "ring_attention",
+           "rope"]
 
 _NEG_INF = -1e30
 _BLOCK = 128  # MXU-native q/k tile
@@ -203,6 +212,8 @@ def _kmask_arrays(bias, B):
 # A kernel that wants one as a column turns a block of it once per q (or
 # k) block; in VMEM the running statistics stay lane-replicated.
 _LANES = 128
+# what a kernel over the packed projection adds to its name
+_PACKED_NAME = "_qkv"
 
 
 def _col_to_row(x):
@@ -303,17 +314,141 @@ def _causal_tiles(qi, kj, block_q, block_k, causal, split_along, tile):
             lambda: tile(*whole, True))
 
 
-def _streamed_k_at(block_q, block_k, causal):
-    """Index map of the k and v blocks of a (BH, q blocks, k blocks) grid:
-    a causal block the mask removes re-names the last one its q block
-    reads, so a skipped step fetches nothing."""
+def _streamed_k_block(block_q, block_k, causal):
+    """``(i, j) ->`` the k (and v) block that step ``j`` of q block ``i``
+    reads: a causal block the mask removes re-names the last one its q
+    block reads, so a skipped step fetches nothing."""
     if not causal:
-        return lambda b, i, j: (b, j, 0)
-    return lambda b, i, j: (
-        b, jnp.minimum(j, ((i + 1) * block_q - 1) // block_k), 0)
+        return lambda i, j: j
+    return lambda i, j: jnp.minimum(j, ((i + 1) * block_q - 1) // block_k)
 
 
-def _lead_operands(seed, kmask, H, block_k, k_axis):
+class _HeadBlocks(NamedTuple):
+    """How a kernel's grid ``(n0, pairs, row blocks, streamed blocks)``
+    finds its heads, in either layout of the operands.
+
+    Apart, ``(B, H, L, D)``: q, k, v (and ``do``, the output, every
+    gradient) are ``(B*H, L, D)`` arrays, a block is one head at its own
+    width, ``n0 = B*H`` and ``pairs = 1``.
+
+    Packed, the ``(B, L, 3U)`` projection as it lies: ONE array handed in
+    once a role, a block is ``width`` = 128 lanes of it holding
+    ``per_block`` = two 64-wide heads side by side (or one head's D where
+    that is whole lane tiles: ``_packed_heads``) — q at lane block ``p``, k at ``U/width + p``, v at
+    ``2U/width + p`` — and ``do``, the output and the gradients are
+    ``(B, L, U)`` with a head pair at lane block ``p``; ``n0 = B`` and
+    ``pairs = H / per_block``.
+
+    Per-row statistics are ``(B*H, 1, L)`` rows in both, ``per_block`` rows
+    a grid step."""
+    B: int
+    H: int
+    D: int
+    n0: int
+    pairs: int
+    per_block: int
+    width: int
+    k_at: int
+    v_at: int
+
+    def spec(self, rows, row_block, at=0):
+        """BlockSpec of ``rows`` rows by ``width`` lanes; ``row_block``
+        maps the grid's last two indices to the row block, ``at`` is the
+        role's first lane block."""
+        from jax.experimental import pallas as pl
+        from jax.experimental.pallas import tpu as pltpu
+        return pl.BlockSpec(
+            (1, rows, self.width),
+            lambda n, p, a, b: (n, row_block(a, b), at + p),
+            memory_space=pltpu.VMEM)
+
+    def row_spec(self, cols, col_block):
+        """BlockSpec of the heads' ``(1, cols)`` statistics rows."""
+        from jax.experimental import pallas as pl
+        from jax.experimental.pallas import tpu as pltpu
+        return pl.BlockSpec(
+            (self.per_block, 1, cols),
+            lambda n, p, a, b: (n * self.pairs + p, 0, col_block(a, b)),
+            memory_space=pltpu.VMEM)
+
+    def first_head(self):
+        """``batch * H + head`` of a grid step's first head: what the
+        dropout hash is keyed on, the same in both layouts."""
+        from jax.experimental import pallas as pl
+        return (pl.program_id(0) * self.pairs + pl.program_id(1)) \
+            * self.per_block
+
+    def array(self, L):
+        """Shape of ``do``, the output or a gradient of ``L`` rows."""
+        if self.n0 == self.B:
+            return (self.B, L, self.H * self.D)
+        return (self.n0, L, self.D)
+
+
+def _packed_heads(U, heads):
+    """(heads a lane block, its width) of a packed projection of ``heads``
+    heads: one head of whole lane tiles, or an even count of 64-wide heads
+    in pairs.  None for every other width — no whole lane tiles, or four
+    and more heads a block, whose score-sized temporaries (a set a head)
+    overran the 16 MiB of VMEM a kernel may take in the compile for a
+    described v5e (D 32 at L 2,048, D 16 at 1,024: the forward)."""
+    D = U // heads
+    if D % _LANES == 0:
+        return 1, D
+    if 2 * D == _LANES and heads % 2 == 0:
+        return 2, _LANES
+    return None
+
+
+def _head_blocks(q, k, v, heads):
+    """(the kernel's q, k, v operands, their ``_HeadBlocks``): ``heads`` is
+    None for q, k, v apart and the head count where ``q`` is the packed
+    projection (``k`` and ``v`` are then None)."""
+    if heads is None:
+        B, H, L, D = q.shape
+        return [x.reshape(B * H, x.shape[2], D) for x in (q, k, v)], \
+            _HeadBlocks(B, H, D, B * H, 1, 1, D, 0, 0)
+    B, L, U3 = q.shape
+    U = U3 // 3
+    per_block, width = _packed_heads(U, heads)
+    return [q, q, q], _HeadBlocks(
+        B, heads, U // heads, B, heads // per_block, per_block, width,
+        U // width, 2 * U // width)
+
+
+# A lane block of the packed layout holds ``per_block`` heads side by side
+# and each is an attention of its own.  A 64-deep contraction half-fills
+# the v5e's 128 x 128 MXU already, so head ``h`` is taken with lane masks
+# and no lane slicing at no extra MXU pass: ``s_h = (q * m_h) @ k.T`` over
+# all 128 lanes, and a product that ends in the head's lanes is made 128
+# wide and its other lanes dropped.
+
+def _lanes_of(h, hb, shape):
+    lane = lax.broadcasted_iota(jnp.int32, shape, 1)
+    return (lane >= h * hb.D) & (lane < (h + 1) * hb.D)
+
+
+def _head_rows(x, h, hb):
+    """``x`` with every lane but head ``h``'s zeroed: against it a
+    contraction over all the lanes of the other operand is head ``h``'s
+    alone."""
+    if hb.per_block == 1:
+        return x
+    return jnp.where(_lanes_of(h, hb, x.shape), x, jnp.zeros_like(x))
+
+
+def _by_head(parts, hb):
+    """One ``(n, width)`` array whose lanes of head ``h`` are
+    ``parts[h]``'s: of products made 128 lanes wide, or of lane-replicated
+    ``(n, 128)`` statistics (a head apart takes its column as it is
+    broadcast)."""
+    if hb.per_block == 1:
+        return parts[0] if parts[0].shape[1] == hb.width else parts[0][:, :1]
+    first, second = parts                   # ``_packed_heads``: pairs only
+    return jnp.where(_lanes_of(0, hb, first.shape), first, second)
+
+
+def _lead_operands(seed, kmask, hb, block_k, k_axis):
     """What every kernel takes first: the dropout seed in SMEM and, where
     there is one, the (Nb, 1, Lk) key mask by k block (``k_axis`` is the
     grid axis that walks k)."""
@@ -327,7 +462,7 @@ def _lead_operands(seed, kmask, H, block_k, k_axis):
         if kmask.shape[0] == 1:
             km_idx = lambda *g: (0, 0, g[k_axis])
         else:
-            km_idx = lambda *g: (g[0] // H, 0, g[k_axis])
+            km_idx = lambda *g: (g[0] // (hb.n0 // hb.B), 0, g[k_axis])
         specs.append(pl.BlockSpec((1, 1, block_k), km_idx,
                                   memory_space=pltpu.VMEM))
         args.append(kmask)
@@ -342,7 +477,7 @@ def _kernel_jit(fn):
     described v5e).  ``interpret`` is read here, outside the cache, and is
     part of its key."""
     static = {"scale", "causal", "dropout", "need_dbias", "block_q",
-              "block_k", "interpret"}
+              "block_k", "heads", "interpret"}
     jitted = jax.jit(fn, static_argnames=sorted(
         static & set(inspect.signature(fn).parameters)))
 
@@ -354,8 +489,9 @@ def _kernel_jit(fn):
 
 @_kernel_jit
 def _pallas_fwd(q, k, v, scale, causal, kmask=None, seed=None, dropout=0.0,
-                block_q=None, block_k=None, interpret=False):
-    """Flash forward on TPU.  Grid (batch·heads, q_blocks, k_blocks) with
+                block_q=None, block_k=None, heads=None, interpret=False):
+    """Flash forward on TPU.  Grid (batch·heads, 1, q_blocks, k_blocks) —
+    packed, (batch, head pairs, q_blocks, k_blocks): ``_HeadBlocks`` — with
     the k axis innermost: VMEM holds one q/k/v block at a time (O(block·D)
     VMEM — long sequences stream from HBM) while running max / sum / output
     accumulators live in VMEM scratch across the k sweep.  The products
@@ -363,28 +499,31 @@ def _pallas_fwd(q, k, v, scale, causal, kmask=None, seed=None, dropout=0.0,
     float32.  A causal block above the diagonal is neither computed nor
     fetched.  ``kmask`` is an optional (Nb, 1, Lk) additive bias (key
     padding mask); ``dropout``/``seed`` apply in-kernel attention dropout
-    via the shared position hash.  Returns (out, lse) with lse (B, H, L)."""
+    via the shared position hash.  Returns (out, lse): (B, H, L, D) and
+    (B, H, L) for q, k, v apart; with ``heads``, ``q`` the packed
+    (B, L, 3U) projection, (B, L, U) and the (B·H, 1, L) rows."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    B, H, L, D = q.shape
-    Lk = k.shape[2]
+    (q3, k3, v3), hb = _head_blocks(q, k, v, heads)
+    L, Lk = q3.shape[1], k3.shape[1]
     if block_q is None:
         block_q = _block_for(L, q.dtype)
     if block_k is None:
-        block_k = _block_for(Lk, k.dtype)
+        block_k = _block_for(Lk, q.dtype)
     nq = L // block_q
     nk = Lk // block_k
     inv_keep = 1.0 / (1.0 - dropout) if dropout > 0.0 else 1.0
+    heads_here = range(hb.per_block)
 
     def kernel(seed_ref, *refs):
         if kmask is not None:
             km_ref = refs[0]
             refs = refs[1:]
         q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s = refs
-        bhi = pl.program_id(0)
-        qi = pl.program_id(1)
-        kj = pl.program_id(2)
+        head0 = hb.first_head()
+        qi = pl.program_id(2)
+        kj = pl.program_id(3)
 
         @pl.when(kj == 0)
         def _init():
@@ -398,72 +537,75 @@ def _pallas_fwd(q, k, v, scale, causal, kmask=None, seed=None, dropout=0.0,
                 jnp.int32, (nq_, 1), 0)
             kpos = kj * block_k + ks.start + lax.broadcasted_iota(
                 jnp.int32, (1, nk_), 1)
-            s = _block_scores(
-                q_ref[0, qs], k_ref[0, ks], scale, qpos, kpos, masked,
-                km_ref[0, :, ks] if kmask is not None else None)
-            m_prev = m_s[qs]
-            m_new = jnp.maximum(
-                m_prev, jnp.broadcast_to(
-                    jnp.max(s, axis=-1, keepdims=True), (nq_, _LANES)))
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new[:, :1])
-            if kmask is not None:
-                # a row whose keys so far are all masked:
-                # exp(-1e30 - (-1e30)) == 1 poison.  (Causal alone never
-                # has one: key 0 is open to every row in the first block.)
-                p = jnp.where(s <= _NEG_INF * 0.5, 0.0, p)
-            m_s[qs] = m_new
-            l_s[qs] = l_s[qs] * alpha + jnp.broadcast_to(
-                jnp.sum(p, axis=-1, keepdims=True), (nq_, _LANES))
-            if dropout > 0.0:
-                keep = _keep(seed_ref[0, 0], bhi, qpos, kpos, dropout)
-                p = jnp.where(keep, p, 0.0) * inv_keep
-            acc_s[qs] = acc_s[qs] * alpha[:, :1] + _mxu(
-                p.astype(v_ref.dtype), v_ref[0, ks], _NN)
+            alphas, pvs = [], []
+            for h in heads_here:
+                s = _block_scores(
+                    _head_rows(q_ref[0, qs], h, hb),
+                    k_ref[0, ks], scale,
+                    qpos, kpos, masked,
+                    km_ref[0, :, ks] if kmask is not None else None)
+                m_prev = m_s[h, qs]
+                m_new = jnp.maximum(
+                    m_prev, jnp.broadcast_to(
+                        jnp.max(s, axis=-1, keepdims=True), (nq_, _LANES)))
+                alpha = jnp.exp(m_prev - m_new)
+                p = jnp.exp(s - m_new[:, :1])
+                if kmask is not None:
+                    # a row whose keys so far are all masked:
+                    # exp(-1e30 - (-1e30)) == 1 poison.  (Causal alone
+                    # never has one: key 0 is open to every row in the
+                    # first block.)
+                    p = jnp.where(s <= _NEG_INF * 0.5, 0.0, p)
+                m_s[h, qs] = m_new
+                l_s[h, qs] = l_s[h, qs] * alpha + jnp.broadcast_to(
+                    jnp.sum(p, axis=-1, keepdims=True), (nq_, _LANES))
+                if dropout > 0.0:
+                    keep = _keep(seed_ref[0, 0], head0 + h, qpos, kpos,
+                                 dropout)
+                    p = jnp.where(keep, p, 0.0) * inv_keep
+                alphas.append(alpha)
+                pvs.append(_mxu(p.astype(v_ref.dtype),
+                                v_ref[0, ks], _NN))
+            acc_s[qs] = acc_s[qs] * _by_head(alphas, hb) + _by_head(pvs, hb)
 
         _causal_tiles(qi, kj, block_q, block_k, causal, None, tile)
 
         @pl.when(kj == nk - 1)
         def _finalize():
-            l = jnp.maximum(l_s[:], 1e-30)
-            o_ref[0] = (acc_s[:] / l[:, :1]).astype(o_ref.dtype)
-            lse_ref[0] = _col_to_row(m_s[:] + jnp.log(l))
+            l = [jnp.maximum(l_s[h], 1e-30) for h in heads_here]
+            o_ref[0] = (acc_s[:] / _by_head(l, hb)).astype(o_ref.dtype)
+            for h in heads_here:
+                lse_ref[h] = _col_to_row(m_s[h] + jnp.log(l[h]))
 
-    kv_at = _streamed_k_at(block_q, block_k, causal)
-    in_specs, args = _lead_operands(seed, kmask, H, block_k, 2)
-    in_specs += [
-        pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_k, D), kv_at, memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_k, D), kv_at, memory_space=pltpu.VMEM),
-    ]
-    args += [q.reshape(B * H, L, D), k.reshape(B * H, Lk, D),
-             v.reshape(B * H, Lk, D)]
+    k_block = _streamed_k_block(block_q, block_k, causal)
+    q_block = lambda i, j: i
+    in_specs, args = _lead_operands(seed, kmask, hb, block_k, 3)
+    in_specs += [hb.spec(block_q, q_block),
+                 hb.spec(block_k, k_block, hb.k_at),
+                 hb.spec(block_k, k_block, hb.v_at)]
     out, lse = pl.pallas_call(
         kernel,
-        grid=(B * H, nq, nk),
+        grid=(hb.n0, hb.pairs, nq, nk),
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i),
-                         memory_space=pltpu.VMEM),
-        ],
+        out_specs=[hb.spec(block_q, q_block),
+                   hb.row_spec(block_q, q_block)],
         out_shape=[
-            jax.ShapeDtypeStruct((B * H, L, D), q.dtype),
-            jax.ShapeDtypeStruct((B * H, 1, L), jnp.float32),
+            jax.ShapeDtypeStruct(hb.array(L), q.dtype),
+            jax.ShapeDtypeStruct((hb.B * hb.H, 1, L), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, D), jnp.float32),
+            pltpu.VMEM((hb.per_block, block_q, _LANES), jnp.float32),
+            pltpu.VMEM((hb.per_block, block_q, _LANES), jnp.float32),
+            pltpu.VMEM((block_q, hb.width), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel",) * 3 + ("arbitrary",)),
         interpret=interpret,
-        name="mx_flash_fwd",
-    )(*args)
-    return out.reshape(B, H, L, D), lse.reshape(B, H, L)
+        name="mx_flash_fwd" + _PACKED_NAME * (heads is not None),
+    )(*args, q3, k3, v3)
+    if heads is not None:
+        return out, lse
+    return out.reshape(q.shape), lse.reshape(hb.B, hb.H, L)
 
 
 # --------------------------------------------------------------------------- #
@@ -473,56 +615,89 @@ def _pallas_fwd(q, k, v, scale, causal, kmask=None, seed=None, dropout=0.0,
 @_kernel_jit
 def _pallas_bwd_dq(q, k, v, g, lse, delta, scale, causal, kmask=None,
                    seed=None, dropout=0.0, block_q=None, block_k=None,
-                   interpret=False):
-    """dq kernel: grid (BH, nq, nk), k innermost; dq accumulates in VMEM.
+                   heads=None, out=None, interpret=False):
+    """dq kernel: the forward's grid, k innermost; dq accumulates in VMEM.
     ``lse``/``delta`` are the (BH, 1, L) rows, turned to columns once per
-    q block.  Operands as in the forward: ``ds`` is cast to k's dtype."""
+    q block.  Operands as in the forward: ``ds`` is cast to k's dtype.
+
+    With ``heads``, ``q`` is the packed projection and ``g`` (B, L, U);
+    ``delta`` is None and the forward's ``out`` (B, L, U) comes instead:
+    the kernel makes ``delta = rowsum(do * o)`` of its q block itself, a
+    head at a time (``do`` is resident anyway, and over (B, L, H·D) XLA
+    re-lays the float32 product out before it can sum 64 lanes of 128).
+    Returns (the (B, L, 3U) gradient of the projection with dq's lane
+    blocks written and the rest left to ``_pallas_bwd_dkv``, the delta
+    rows)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    B, H, L, D = q.shape
-    Lk = k.shape[2]
+    (q3, k3, v3), hb = _head_blocks(q, k, v, heads)
+    L, Lk = q3.shape[1], k3.shape[1]
     if block_q is None:
         block_q = _block_for(L, q.dtype)
     if block_k is None:
-        block_k = _block_for(Lk, k.dtype)
+        block_k = _block_for(Lk, q.dtype)
     nq, nk = L // block_q, Lk // block_k
     inv_keep = 1.0 / (1.0 - dropout) if dropout > 0.0 else 1.0
+    heads_here = range(hb.per_block)
+    packed = heads is not None
 
     def kernel(seed_ref, *refs):
         if kmask is not None:
             km_ref = refs[0]
             refs = refs[1:]
-        (q_ref, k_ref, v_ref, g_ref, lse_ref, dlt_ref, dq_ref,
-         dq_s, lse_s, dlt_s) = refs
-        bhi = pl.program_id(0)
-        qi = pl.program_id(1)
-        kj = pl.program_id(2)
+        # the sixth operand: the delta rows or, packed, the forward's
+        # output, with the delta rows made here a second output
+        q_ref, k_ref, v_ref, g_ref, lse_ref, dlt_ref, dq_ref, *refs = refs
+        if packed:
+            o_ref = dlt_ref
+            dlt_out_ref, *refs = refs
+        dq_s, lse_s, dlt_s = refs
+        head0 = hb.first_head()
+        qi = pl.program_id(2)
+        kj = pl.program_id(3)
 
         @pl.when(kj == 0)
         def _init():
             dq_s[:] = jnp.zeros_like(dq_s)
-            lse_s[:] = _row_to_col(lse_ref[0])
-            dlt_s[:] = _row_to_col(dlt_ref[0])
+            if packed:
+                og = o_ref[0].astype(jnp.float32) * \
+                    g_ref[0].astype(jnp.float32)
+            for h in heads_here:
+                lse_s[h] = _row_to_col(lse_ref[h])
+                if not packed:
+                    dlt_s[h] = _row_to_col(dlt_ref[h])
+                    continue
+                dlt_s[h] = jnp.broadcast_to(
+                    jnp.sum(_head_rows(og, h, hb), axis=1, keepdims=True),
+                    (block_q, _LANES))
+                dlt_out_ref[h] = _col_to_row(dlt_s[h])
 
         def tile(qs, ks, masked):
             qpos = qi * block_q + qs.start + lax.broadcasted_iota(
                 jnp.int32, (qs.stop - qs.start, 1), 0)
             kpos = kj * block_k + ks.start + lax.broadcasted_iota(
                 jnp.int32, (1, ks.stop - ks.start), 1)
-            s = _block_scores(
-                q_ref[0, qs], k_ref[0, ks], scale, qpos, kpos, masked,
-                km_ref[0, :, ks] if kmask is not None else None)
-            p = jnp.exp(s - lse_s[qs, :1])              # (q rows, k rows)
-            if kmask is not None:
-                p = jnp.where(s <= _NEG_INF * 0.5, 0.0, p)
-            dp = _mxu(g_ref[0, qs], v_ref[0, ks], _NT)
-            if dropout > 0.0:
-                keep = _keep(seed_ref[0, 0], bhi, qpos, kpos, dropout)
-                dp = jnp.where(keep, dp, 0.0) * inv_keep
-            ds = p * (dp - dlt_s[qs, :1])
-            dq_s[qs] = dq_s[qs] + _mxu(ds.astype(k_ref.dtype),
-                                       k_ref[0, ks], _NN)
+            dqs = []
+            for h in heads_here:
+                s = _block_scores(
+                    _head_rows(q_ref[0, qs], h, hb),
+                    k_ref[0, ks], scale,
+                    qpos, kpos, masked,
+                    km_ref[0, :, ks] if kmask is not None else None)
+                p = jnp.exp(s - lse_s[h, qs, :1])       # (q rows, k rows)
+                if kmask is not None:
+                    p = jnp.where(s <= _NEG_INF * 0.5, 0.0, p)
+                dp = _mxu(_head_rows(g_ref[0, qs], h, hb),
+                          v_ref[0, ks], _NT)
+                if dropout > 0.0:
+                    keep = _keep(seed_ref[0, 0], head0 + h, qpos, kpos,
+                                 dropout)
+                    dp = jnp.where(keep, dp, 0.0) * inv_keep
+                ds = p * (dp - dlt_s[h, qs, :1])
+                dqs.append(_mxu(ds.astype(k_ref.dtype),
+                                k_ref[0, ks], _NN))
+            dq_s[qs] = dq_s[qs] + _by_head(dqs, hb)
 
         _causal_tiles(qi, kj, block_q, block_k, causal, "q", tile)
 
@@ -530,59 +705,75 @@ def _pallas_bwd_dq(q, k, v, g, lse, delta, scale, causal, kmask=None,
         def _finalize():
             dq_ref[0] = (dq_s[:] * scale).astype(dq_ref.dtype)
 
-    kv_at = _streamed_k_at(block_q, block_k, causal)
-    q_at = lambda b, i, j: (b, i, 0)
-    row_at = lambda b, i, j: (b, 0, i)
-    in_specs, args = _lead_operands(seed, kmask, H, block_k, 2)
-    in_specs += [
-        pl.BlockSpec((1, block_q, D), q_at, memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_k, D), kv_at, memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_k, D), kv_at, memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_q, D), q_at, memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, 1, block_q), row_at, memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, 1, block_q), row_at, memory_space=pltpu.VMEM),
-    ]
-    args += [q.reshape(B * H, L, D), k.reshape(B * H, Lk, D),
-             v.reshape(B * H, Lk, D), g.reshape(B * H, L, D), lse, delta]
-    dq = pl.pallas_call(
+    k_block = _streamed_k_block(block_q, block_k, causal)
+    q_block = lambda i, j: i
+    in_specs, args = _lead_operands(seed, kmask, hb, block_k, 3)
+    in_specs += [hb.spec(block_q, q_block),
+                 hb.spec(block_k, k_block, hb.k_at),
+                 hb.spec(block_k, k_block, hb.v_at),
+                 hb.spec(block_q, q_block),
+                 hb.row_spec(block_q, q_block),
+                 hb.row_spec(block_q, q_block) if not packed
+                 else hb.spec(block_q, q_block)]
+    out_specs = [hb.spec(block_q, q_block)]
+    out_shape = [jax.ShapeDtypeStruct(
+        q.shape if packed else hb.array(L), q.dtype)]
+    if packed:
+        out_specs.append(hb.row_spec(block_q, q_block))
+        out_shape.append(jax.ShapeDtypeStruct(lse.shape, jnp.float32))
+    dq, *made = pl.pallas_call(
         kernel,
-        grid=(B * H, nq, nk),
+        grid=(hb.n0, hb.pairs, nq, nk),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, block_q, D), q_at,
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((B * H, L, D), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32),
-                        pltpu.VMEM((block_q, _LANES), jnp.float32),
-                        pltpu.VMEM((block_q, _LANES), jnp.float32)],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=[
+            pltpu.VMEM((block_q, hb.width), jnp.float32),
+            pltpu.VMEM((hb.per_block, block_q, _LANES), jnp.float32),
+            pltpu.VMEM((hb.per_block, block_q, _LANES), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel",) * 3 + ("arbitrary",)),
         interpret=interpret,
-        name="mx_flash_bwd_dq",
-    )(*args)
-    return dq.reshape(B, H, L, D)
+        name="mx_flash_bwd_dq" + _PACKED_NAME * packed,
+    )(*args, q3, k3, v3, g.reshape(hb.array(L)), lse,
+      out if packed else delta)
+    return (dq, made[0]) if packed else dq.reshape(q.shape)
 
 
 @_kernel_jit
 def _pallas_bwd_dkv(q, k, v, g, lse, delta, scale, causal, kmask=None,
                     seed=None, dropout=0.0, need_dbias=False,
-                    block_q=None, block_k=None, interpret=False):
-    """dk/dv kernel: grid (BH, nk, nq), q innermost.  Scores are computed
-    k-row by q-column ((block_k, block_q)), so the (BH, 1, L) ``lse`` /
-    ``delta`` rows broadcast as they are and dk/dv are plain products
-    ``p.T @ g`` / ``ds.T @ q`` with nothing transposed.  A causal q block
-    before the diagonal is neither computed nor fetched.  Optionally also
-    emits the q-summed dbias for the k-mask layout as (BH, 1, Lk)."""
+                    block_q=None, block_k=None, heads=None, into=None,
+                    interpret=False):
+    """dk/dv kernel: grid (BH, 1, nk, nq) — packed, (B, head pairs, nk,
+    nq) — q innermost.  Scores are computed k-row by q-column ((block_k,
+    block_q)), so the (BH, 1, L) ``lse`` / ``delta`` rows broadcast as they
+    are and dk/dv are plain products ``p.T @ g`` / ``ds.T @ q`` with
+    nothing transposed.  A causal q block before the diagonal is neither
+    computed nor fetched.  Optionally also emits the q-summed dbias for
+    the k-mask layout as (BH, 1, Lk).
+
+    With ``heads``, ``q`` is the packed projection, ``g`` (B, L, U) and
+    ``into`` the (B, L, 3U) gradient that ``_pallas_bwd_dq`` began: it is
+    this call's output too (aliased), and the kernel copies each finished
+    dk and dv block into its lane block of it itself — a ``pallas_call``
+    writes one block an output a grid step, and here two go to one array.
+    The copies of a grid step are waited for when the next ones are due,
+    so the grid runs in order (every axis ``arbitrary``: the v5e has one
+    core to run it on).  Returns (that gradient, the dbias rows)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    B, H, L, D = q.shape
-    Lk = k.shape[2]
+    (q3, k3, v3), hb = _head_blocks(q, k, v, heads)
+    L, Lk = q3.shape[1], k3.shape[1]
     if block_k is None:
-        block_k = _block_dkv_for(Lk, k.dtype)
+        block_k = _block_dkv_for(Lk, q.dtype)
     if block_q is None:
         block_q = _block_dkv_for(L, q.dtype)
     nq, nk = L // block_q, Lk // block_k
     inv_keep = 1.0 / (1.0 - dropout) if dropout > 0.0 else 1.0
+    heads_here = range(hb.per_block)
+    packed = heads is not None
 
     def kernel(seed_ref, *refs):
         if kmask is not None:
@@ -591,15 +782,22 @@ def _pallas_bwd_dkv(q, k, v, g, lse, delta, scale, causal, kmask=None,
         (q_ref, k_ref, v_ref, g_ref, lse_ref, dlt_ref) = refs[:6]
         refs = refs[6:]
         km_s = db_ref = db_s = None
-        if need_dbias:
-            dk_ref, dv_ref, db_ref, dk_s, dv_s, db_s, *refs = refs
+        if packed:
+            # (the aliased input), the buffer, ..., staging and semaphores
+            _, buf_ref, *refs, stage, sems = refs
+            dk_ref = dv_ref = None
         else:
-            dk_ref, dv_ref, dk_s, dv_s, *refs = refs
+            dk_ref, dv_ref, *refs = refs
+        if need_dbias:
+            db_ref, dk_s, dv_s, db_s, *refs = refs
+        else:
+            dk_s, dv_s, *refs = refs
         if kmask is not None:
             km_s, = refs
-        bhi = pl.program_id(0)
-        kj = pl.program_id(1)
-        qi = pl.program_id(2)
+        head0 = hb.first_head()
+        n, p = pl.program_id(0), pl.program_id(1)
+        kj = pl.program_id(2)
+        qi = pl.program_id(3)
 
         @pl.when(qi == 0)
         def _init():
@@ -616,91 +814,130 @@ def _pallas_bwd_dkv(q, k, v, g, lse, delta, scale, causal, kmask=None,
                 jnp.int32, (1, qs.stop - qs.start), 1)
             kpos = kj * block_k + ks.start + lax.broadcasted_iota(
                 jnp.int32, (nk_, 1), 0)
-            s = _block_scores(
-                k_ref[0, ks], q_ref[0, qs], scale, qpos, kpos, masked,
-                km_s[ks, :1] if kmask is not None else None)
-            p = jnp.exp(s - lse_ref[0, :, qs])          # (k rows, q rows)
-            if kmask is not None:
-                p = jnp.where(s <= _NEG_INF * 0.5, 0.0, p)
-            dp = _mxu(v_ref[0, ks], g_ref[0, qs], _NT)
-            p_drop = p
-            if dropout > 0.0:
-                keep = _keep(seed_ref[0, 0], bhi, qpos, kpos, dropout)
-                dp = jnp.where(keep, dp, 0.0) * inv_keep
-                p_drop = jnp.where(keep, p, 0.0) * inv_keep
-            ds = p * (dp - dlt_ref[0, :, qs])
-            dv_s[ks] = dv_s[ks] + _mxu(p_drop.astype(g_ref.dtype),
-                                       g_ref[0, qs], _NN)
-            dk_s[ks] = dk_s[ks] + _mxu(ds.astype(q_ref.dtype),
-                                       q_ref[0, qs], _NN)
-            if need_dbias:
-                db_s[ks] = db_s[ks] + jnp.broadcast_to(
-                    jnp.sum(ds, axis=1, keepdims=True), (nk_, _LANES))
+            dks, dvs = [], []
+            for h in heads_here:
+                s = _block_scores(
+                    _head_rows(k_ref[0, ks], h, hb),
+                    q_ref[0, qs], scale,
+                    qpos, kpos, masked,
+                    km_s[ks, :1] if kmask is not None else None)
+                p = jnp.exp(s - lse_ref[h, :, qs])      # (k rows, q rows)
+                if kmask is not None:
+                    p = jnp.where(s <= _NEG_INF * 0.5, 0.0, p)
+                dp = _mxu(_head_rows(v_ref[0, ks], h, hb),
+                          g_ref[0, qs], _NT)
+                p_drop = p
+                if dropout > 0.0:
+                    keep = _keep(seed_ref[0, 0], head0 + h, qpos, kpos,
+                                 dropout)
+                    dp = jnp.where(keep, dp, 0.0) * inv_keep
+                    p_drop = jnp.where(keep, p, 0.0) * inv_keep
+                ds = p * (dp - dlt_ref[h, :, qs])
+                dvs.append(_mxu(p_drop.astype(g_ref.dtype),
+                                g_ref[0, qs], _NN))
+                dks.append(_mxu(ds.astype(q_ref.dtype),
+                                q_ref[0, qs], _NN))
+                if need_dbias:
+                    db_s[h, ks] = db_s[h, ks] + jnp.broadcast_to(
+                        jnp.sum(ds, axis=1, keepdims=True), (nk_, _LANES))
+            dv_s[ks] = dv_s[ks] + _by_head(dvs, hb)
+            dk_s[ks] = dk_s[ks] + _by_head(dks, hb)
 
         _causal_tiles(qi, kj, block_q, block_k, causal, "k", tile)
 
+        def copies():
+            """dk and dv of this grid step: staging -> their lane blocks
+            of the one (B, L, 3U) gradient."""
+            rows = pl.ds(pl.multiple_of(kj * block_k, block_k), block_k)
+            return [pltpu.make_async_copy(
+                stage.at[r],
+                buf_ref.at[n, rows, pl.ds(pl.multiple_of(
+                    (at + p) * hb.width, hb.width), hb.width)],
+                sems.at[r]) for r, at in enumerate((hb.k_at, hb.v_at))]
+
         @pl.when(qi == nq - 1)
         def _finalize():
-            dk_ref[0] = (dk_s[:] * scale).astype(dk_ref.dtype)
-            dv_ref[0] = dv_s[:].astype(dv_ref.dtype)
+            if packed:
+                first = (n == 0) & (p == 0) & (kj == 0)
+                last = (n == hb.n0 - 1) & (p == hb.pairs - 1) & \
+                    (kj == nk - 1)
+
+                @pl.when(jnp.logical_not(first))
+                def _previous_landed():
+                    for c in copies():
+                        c.wait()
+                stage[0] = (dk_s[:] * scale).astype(stage.dtype)
+                stage[1] = dv_s[:].astype(stage.dtype)
+                for c in copies():
+                    c.start()
+
+                @pl.when(last)
+                def _all_landed():
+                    for c in copies():
+                        c.wait()
+            else:
+                dk_ref[0] = (dk_s[:] * scale).astype(dk_ref.dtype)
+                dv_ref[0] = dv_s[:].astype(dv_ref.dtype)
             if need_dbias:
-                db_ref[0] = _col_to_row(db_s[:])
+                for h in heads_here:
+                    db_ref[h] = _col_to_row(db_s[h])
 
     if causal:
         # q blocks before the diagonal re-name the first one read
-        live = lambda j, i: jnp.minimum(
+        q_block = lambda j, i: jnp.minimum(
             jnp.maximum(i, j * block_k // block_q), nq - 1)
     else:
-        live = lambda j, i: i
-    q_at = lambda b, j, i: (b, live(j, i), 0)
-    row_at = lambda b, j, i: (b, 0, live(j, i))
-    kv_at = lambda b, j, i: (b, j, 0)
-    in_specs, args = _lead_operands(seed, kmask, H, block_k, 1)
-    in_specs += [
-        pl.BlockSpec((1, block_q, D), q_at, memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_k, D), kv_at, memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_k, D), kv_at, memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_q, D), q_at, memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, 1, block_q), row_at, memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, 1, block_q), row_at, memory_space=pltpu.VMEM),
-    ]
-    args += [q.reshape(B * H, L, D), k.reshape(B * H, Lk, D),
-             v.reshape(B * H, Lk, D), g.reshape(B * H, L, D), lse, delta]
-    out_specs = [
-        pl.BlockSpec((1, block_k, D), kv_at, memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_k, D), kv_at, memory_space=pltpu.VMEM),
-    ]
-    out_shape = [
-        jax.ShapeDtypeStruct((B * H, Lk, D), k.dtype),
-        jax.ShapeDtypeStruct((B * H, Lk, D), v.dtype),
-    ]
-    scratch = [pltpu.VMEM((block_k, D), jnp.float32),
-               pltpu.VMEM((block_k, D), jnp.float32)]
+        q_block = lambda j, i: i
+    k_block = lambda j, i: j
+    in_specs, args = _lead_operands(seed, kmask, hb, block_k, 2)
+    in_specs += [hb.spec(block_q, q_block),
+                 hb.spec(block_k, k_block, hb.k_at),
+                 hb.spec(block_k, k_block, hb.v_at),
+                 hb.spec(block_q, q_block),
+                 hb.row_spec(block_q, q_block),
+                 hb.row_spec(block_q, q_block)]
+    out_specs = [hb.spec(block_k, k_block), hb.spec(block_k, k_block)]
+    out_shape = [jax.ShapeDtypeStruct(hb.array(Lk), q.dtype)] * 2
+    operands = [q3, k3, v3, g.reshape(hb.array(L)), lse, delta]
+    aliases = {}
+    if packed:
+        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
+        aliases = {len(args) + len(operands): 0}
+        operands.append(into)
+        out_specs = [pl.BlockSpec(memory_space=pl.ANY)]
+        out_shape = [jax.ShapeDtypeStruct(into.shape, into.dtype)]
+    scratch = [pltpu.VMEM((block_k, hb.width), jnp.float32),
+               pltpu.VMEM((block_k, hb.width), jnp.float32)]
     if need_dbias:
-        out_specs.append(
-            pl.BlockSpec((1, 1, block_k), lambda b, j, i: (b, 0, j),
-                         memory_space=pltpu.VMEM))
+        out_specs.append(hb.row_spec(block_k, k_block))
         out_shape.append(
-            jax.ShapeDtypeStruct((B * H, 1, Lk), jnp.float32))
-        scratch.append(pltpu.VMEM((block_k, _LANES), jnp.float32))
+            jax.ShapeDtypeStruct((hb.B * hb.H, 1, Lk), jnp.float32))
+        scratch.append(
+            pltpu.VMEM((hb.per_block, block_k, _LANES), jnp.float32))
     if kmask is not None:
         scratch.append(pltpu.VMEM((block_k, _LANES), jnp.float32))
+    if packed:
+        scratch += [pltpu.VMEM((2, block_k, hb.width), into.dtype),
+                    pltpu.SemaphoreType.DMA((2,))]
     res = pl.pallas_call(
         kernel,
-        grid=(B * H, nk, nq),
+        grid=(hb.n0, hb.pairs, nk, nq),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch,
+        input_output_aliases=aliases,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",) * 4 if packed
+            else ("parallel",) * 3 + ("arbitrary",)),
         interpret=interpret,
-        name="mx_flash_bwd_dkv",
-    )(*args)
-    dk = res[0].reshape(B, H, Lk, D)
-    dv = res[1].reshape(B, H, Lk, D)
-    dbias = res[2].reshape(B, H, Lk) if need_dbias else None
-    return dk, dv, dbias
+        name="mx_flash_bwd_dkv" + _PACKED_NAME * packed,
+    )(*args, *operands)
+    if packed:
+        return res[0], res[1] if need_dbias else None
+    dk, dv, *db = res
+    return dk.reshape(k.shape), dv.reshape(v.shape), \
+        db[0].reshape(hb.B, hb.H, Lk) if need_dbias else None
 
 
 # --------------------------------------------------------------------------- #
@@ -733,6 +970,17 @@ def _flash_fwd(q, k, v, bias, seed, scale, causal, dropout=0.0,
     return out, (q, k, v, bias, seed, out, lse)
 
 
+def _kmask_grad(dbias_bh, bias, B, H):
+    """The dk/dv kernel's per-head (B·H, 1, Lk) sums -> the key mask's
+    gradient, in its (B|1, 1, 1, Lk) layout."""
+    if bias is None:
+        return None
+    db = dbias_bh.reshape(B, H, -1).sum(axis=1)         # (B, Lk): sum heads
+    if bias.shape[0] == 1:
+        db = db.sum(axis=0, keepdims=True)
+    return db.reshape(bias.shape).astype(bias.dtype)
+
+
 def _flash_bwd(scale, causal, dropout, impl, res, g):
     q, k, v, bias, seed, out, lse = res
     B, H, Lq, D = q.shape
@@ -750,14 +998,7 @@ def _flash_bwd(scale, causal, dropout, impl, res, g):
         dk, dv, dbias_bh = _pallas_bwd_dkv(
             q, k, v, g, lse_row, dlt_row, scale, causal, kmask=kmask,
             seed=seed, dropout=dropout, need_dbias=bias is not None)
-        if bias is None:
-            dbias = None
-        else:
-            db = dbias_bh.sum(axis=1)                   # (B, Lk): sum heads
-            if bias.shape[0] == 1:
-                db = db.sum(axis=0, keepdims=True)
-            dbias = db.reshape(bias.shape).astype(bias.dtype)
-        return dq, dk, dv, dbias, None
+        return dq, dk, dv, _kmask_grad(dbias_bh, bias, B, H), None
 
     # the five products below take q, k, v, g as they arrive and p / ds
     # cast to match (float32 inputs: float32 products, as before);
@@ -847,6 +1088,47 @@ def _flash_bwd(scale, causal, dropout, impl, res, g):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+# --------------------------------------------------------------------------- #
+# the same kernels over the packed (B, L, 3U) projection
+# --------------------------------------------------------------------------- #
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_qkv(qkv, bias, seed, heads, scale, causal, dropout):
+    """Self-attention straight off the fused projection: ``qkv`` (B, L, 3U)
+    in, (B, L, U) out, one (B, L, 3U) gradient back, and no transpose,
+    slice or copy of q, k, v, ``do`` or the output between the projections
+    and the kernels.  ``bias`` is None or a (B|1, 1, 1, L) key mask.
+    Pallas path only (``_packed_heads`` and ``L % 128 == 0`` are the
+    caller's to check)."""
+    return _flash_qkv_fwd(qkv, bias, seed, heads, scale, causal, dropout)[0]
+
+
+def _flash_qkv_fwd(qkv, bias, seed, heads, scale, causal, dropout):
+    kmask = _kmask_arrays(bias, qkv.shape[0]) if bias is not None else None
+    out, lse = _pallas_fwd(qkv, None, None, scale, causal, kmask=kmask,
+                           seed=seed, dropout=dropout, heads=heads)
+    return out, (qkv, bias, seed, out, lse)
+
+
+def _flash_qkv_bwd(heads, scale, causal, dropout, res, g):
+    qkv, bias, seed, out, lse = res
+    B = out.shape[0]
+    kmask = _kmask_arrays(bias, B) if bias is not None else None
+    # one (B, L, 3U) buffer: the dq kernel writes its third and makes
+    # delta, the dk/dv kernel fills in the rest — no concatenate
+    dq, delta = _pallas_bwd_dq(
+        qkv, None, None, g, lse, None, scale, causal, kmask=kmask, seed=seed,
+        dropout=dropout, heads=heads, out=out)
+    dqkv, dbias_bh = _pallas_bwd_dkv(
+        qkv, None, None, g, lse, delta, scale, causal, kmask=kmask,
+        seed=seed, dropout=dropout, need_dbias=bias is not None,
+        heads=heads, into=dq)
+    return dqkv, _kmask_grad(dbias_bh, bias, B, heads), None
+
+
+_flash_qkv.defvjp(_flash_qkv_fwd, _flash_qkv_bwd)
+
+
 # below this many score elements per head, materializing the full (Lq, Lk)
 # attention matrix is cheap and XLA's fused softmax beats the blockwise
 # kernel's scan overhead (measured on v5e: 12 layers of L=128 attention run
@@ -888,6 +1170,15 @@ _PATH_TABLE = {
     # and, train, 0.184 / 0.108 / 0.122 | 0.756 / 0.788 / 1.618: plain
     # holds at the small batch and trails at the large one, and the choice
     # does not see the batch.
+    # From the packed (B, L, 3U) projection (``--packed``, 2026-10-04, PR 34;
+    # both arms the Pallas kernels, "apart" pays the split into heads and
+    # the transposes back), ms at B4 H8 | B8 H16, packed / apart:
+    #   fwd:   1,024 0.110 / 0.150 | 0.440 / 0.759    2,048 - | 1.667 / 2.520
+    #   train: 1,024 0.359 / 0.421 | 1.438 / 1.893    2,048 - | 5.361 / 7.031
+    # (a head out of a 128-lane pair by lane masks; by 64-lane slices the
+    # packed arm read 0.497 and 1.565 at 1,024, B8 H16; with three gradient
+    # arrays and a concatenate in place of the one buffer, 1.446 and 5.398.)
+    # The rows below hold for it as they stand: it asks them the same way.
     "fwd": ((768, "xla"), (None, "pallas")),
     "train": ((None, "pallas"),),
 }
@@ -961,6 +1252,15 @@ def _plain_attn(q, k, v, bias, scale, causal, dropout=0.0, seed=None):
     return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v)
 
 
+def _dropout_seed(rate):
+    """The call's seed of the position hash: fresh bits off the framework's
+    key where anything is dropped, a constant where not."""
+    if rate > 0.0:
+        from .. import random as mxrandom
+        return jax.random.bits(mxrandom.next_key(), dtype=jnp.uint32)
+    return jnp.uint32(0)
+
+
 @op("flash_attention")
 @jax.named_scope("mx.attn")
 def flash_attention(q, k, v, bias=None, *, scale: Optional[float] = None,
@@ -992,11 +1292,7 @@ def flash_attention(q, k, v, bias=None, *, scale: Optional[float] = None,
         from .. import autograd
         training = autograd.is_training()
     rate = float(dropout) if training else 0.0
-    if rate > 0.0:
-        from .. import random as mxrandom
-        seed = jax.random.bits(mxrandom.next_key(), dtype=jnp.uint32)
-    else:
-        seed = jnp.uint32(0)
+    seed = _dropout_seed(rate)
     path = _choose_path(q.shape[2], k.shape[2], bias, bool(training))
     if path == "plain":
         return _plain_attn(q, k, v, bias, float(scale), bool(causal),
@@ -1008,6 +1304,59 @@ def flash_attention(q, k, v, bias=None, *, scale: Optional[float] = None,
         return out[:, :, :Lq] if out.shape[2] != Lq else out
     return _flash(q, k, v, bias, seed, float(scale), bool(causal), rate,
                   "xla")
+
+
+def _split_heads(qkv, heads):
+    """(B, L, 3U) -> q, k, v as (B, H, L, D): the reshape / transpose /
+    slice ``MultiHeadAttention`` did itself before the packed kernels."""
+    B, L, U3 = qkv.shape
+    qkv = qkv.reshape(B, L, 3, heads, U3 // 3 // heads)
+    qkv = qkv.transpose(2, 0, 3, 1, 4)                   # (3, B, H, L, D)
+    return qkv[0], qkv[1], qkv[2]
+
+
+@op("flash_attention_qkv")
+def flash_attention_qkv(qkv, bias=None, *, num_heads: int,
+                        causal: bool = False, dropout: float = 0.0,
+                        training: Optional[bool] = None):
+    """Self-attention over the fused projection ``qkv`` (B, L, 3U) — q, k
+    and v side by side, heads within each — giving (B, L, U): what
+    ``MultiHeadAttention`` puts between its two projections.  ``bias``,
+    ``causal``, ``dropout`` and ``training`` are ``flash_attention``'s.
+
+    It asks the same measured table as ``flash_attention``.  Where the
+    answer is the Pallas kernels, L is whole 128-row blocks and the heads
+    fill whole lane tiles (``_packed_heads``: D 64 with an even H, or
+    ``D % 128 == 0``), the kernels read q, k and v as
+    128-lane blocks of ``qkv`` where it lies and write (B, L, U) — no
+    transpose or slice around them, forward or backward, and the same
+    dropout positions as the call apart.  Everywhere else (plain and XLA
+    paths, a dense bias, other widths, no TPU) it splits the heads as the
+    model did and calls ``flash_attention``, where XLA folds the transposes
+    into its einsums.  A ``telemetry`` event ``attention_path`` a traced
+    call names the path taken."""
+    from .. import telemetry
+    B, L, U3 = qkv.shape
+    U = U3 // 3
+    if training is None:
+        from .. import autograd
+        training = autograd.is_training()
+    path = _choose_path(L, L, bias, bool(training))
+    if path == "pallas" and L % _BLOCK == 0 and _packed_heads(U, num_heads):
+        path = "packed"
+    telemetry.emit("attention_path", op="flash_attention_qkv", path=path,
+                   seq=L, heads=num_heads, head_dim=U // num_heads)
+    if path != "packed":
+        # outside ``mx.attn``: the split is the enclosing region's, as it
+        # was the model's
+        q, k, v = _split_heads(qkv, num_heads)
+        out = get_op("flash_attention").fn(
+            q, k, v, bias, causal=causal, dropout=dropout, training=training)
+        return out.transpose(0, 2, 1, 3).reshape(B, L, U)
+    with jax.named_scope("mx.attn"):
+        rate = float(dropout) if training else 0.0
+        return _flash_qkv(qkv, bias, _dropout_seed(rate), num_heads,
+                          1.0 / (U // num_heads) ** 0.5, bool(causal), rate)
 
 
 # ---------------------------------------------------------------------------

@@ -739,3 +739,197 @@ class TestQ8MatvecTiling:
         from mxnet_tpu.ops import q8_matvec as q8
         assert q8._pick_tiles(1, 256, 1000) == (0, 0)
         assert q8._pick_tiles(1, 64, 192) == (0, 0)
+
+
+# --------------------------------------------------------------------------- #
+# the kernels over the packed (B, L, 3U) projection (PR 34)
+# --------------------------------------------------------------------------- #
+
+def _mha_apart(qkv, bias, heads, **kw):
+    """What ``MultiHeadAttention`` did between its projections before the
+    packed op: reshape / transpose / slice, ``flash_attention``, transpose
+    back."""
+    from mxnet_tpu.ops.registry import get_op
+    B, L, U3 = qkv.shape
+    x = qkv.reshape(B, L, 3, heads, U3 // 3 // heads).transpose(2, 0, 3, 1, 4)
+    out = get_op("flash_attention").fn(x[0], x[1], x[2], bias, **kw)
+    return out.transpose(0, 2, 1, 3).reshape(B, L, U3 // 3)
+
+
+def _packed_inputs(B, H, D, L, dtype, key_mask=False):
+    import jax
+    import jax.numpy as jnp
+    ks = jax.random.split(jax.random.PRNGKey(H * D + L), 2)
+    qkv = jax.random.normal(ks[0], (B, L, 3 * H * D), jnp.float32)
+    g = jax.random.normal(ks[1], (B, L, H * D), jnp.float32)
+    bias = None
+    if key_mask:
+        bias = onp.zeros((B, 1, 1, L), "float32")
+        bias[0, :, :, L - 40:] = -1e30
+        bias = jnp.asarray(bias)
+    return qkv.astype(dtype), g.astype(dtype), bias
+
+
+def _assert_same(got, want, what):
+    """Bit for bit: a head of a pair is the same sums in the same order as
+    the head apart (the lanes masked out add exact zeros)."""
+    for name, a, b in zip(("out", "dqkv", "dbias"), got, want):
+        if b is None:
+            assert a is None, (what, name)
+            continue
+        assert a.dtype == b.dtype and a.shape == b.shape, (what, name)
+        onp.testing.assert_array_equal(
+            onp.asarray(a.astype("float32")), onp.asarray(b.astype("float32")),
+            err_msg=f"{what}: {name}")
+
+
+def _packed_and_apart(qkv, g, bias, H, causal, rate):
+    """(out, dqkv, dbias) of the packed kernels and of the same kernels on
+    heads split apart, one dropout seed."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import attention as attn
+    B, L, U = g.shape
+    scale = 1.0 / (U // H) ** 0.5
+    seed = jnp.uint32(5)
+
+    def packed(qkv, bias):
+        return attn._flash_qkv(qkv, bias, seed, H, scale, causal, rate)
+
+    def apart(qkv, bias):
+        out = attn._flash(*attn._split_heads(qkv, H), bias, seed, scale,
+                          causal, rate, "pallas")
+        return out.transpose(0, 2, 1, 3).reshape(B, L, U)
+
+    res = []
+    for fn in (packed, apart):
+        out, vjp = jax.vjp(fn, qkv, bias)
+        res.append((out,) + vjp(g))
+    return res
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("L", [256, 1024])
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("D", [64, 128])
+def test_packed_kernels_match_kernels_apart(monkeypatch, D, causal, L, dtype):
+    """Forward and the one (B, L, 3U) gradient of the three kernels reading
+    the projection where it lies, against the same kernels on (B, H, L, D)
+    copies: heads in pairs at D 64 (two pairs), one a block at D 128."""
+    monkeypatch.setenv("MXNET_FLASH_INTERPRET", "1")
+    H = 4 if D == 64 else 2
+    qkv, g, _ = _packed_inputs(1, H, D, L, dtype)
+    got, want = _packed_and_apart(qkv, g, None, H, causal, 0.0)
+    assert got[0].shape == (1, L, H * D) and got[1].shape == qkv.shape
+    _assert_same(got, want, f"D{D} causal={causal} L{L} {dtype}")
+
+
+@pytest.mark.parametrize("rate", [0.0, 0.2], ids=["keep_all", "dropout"])
+@pytest.mark.parametrize("key_mask", [False, True], ids=["open", "key_mask"])
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("D", [64, 128])
+def test_packed_kernels_key_mask_and_dropout(monkeypatch, D, causal,
+                                             key_mask, rate):
+    """A key mask (its gradient too) and dropout under one seed: the hash
+    is keyed on batch * H + head in both layouts, so a packed call and a
+    call apart drop the same positions — two batch rows, so the head's
+    index is not the grid's."""
+    monkeypatch.setenv("MXNET_FLASH_INTERPRET", "1")
+    H = 4 if D == 64 else 2
+    qkv, g, bias = _packed_inputs(2, H, D, 256, "float32", key_mask)
+    got, want = _packed_and_apart(qkv, g, bias, H, causal, rate)
+    if rate:
+        # some probability dropped, not all: the outputs differ from the
+        # call that keeps every position
+        kept, _ = _packed_and_apart(qkv, g, bias, H, causal, 0.0)
+        assert not onp.allclose(onp.asarray(got[0]), onp.asarray(kept[0]))
+    _assert_same(got, want, f"D{D} causal={causal} mask={key_mask} p={rate}")
+
+
+def _paths(fn, *args):
+    """(result, jaxpr text, ``path`` fields of the op's telemetry events)
+    of one traced call."""
+    import jax
+    from mxnet_tpu import telemetry
+    telemetry.clear_events()
+    jaxpr = str(jax.make_jaxpr(fn)(*args))
+    paths = [e["path"] for e in telemetry.events("attention_path")]
+    return fn(*args), jaxpr, paths
+
+
+FALLBACKS = {
+    # name: (H, D, L, bias, path on a TPU)
+    "L128_plain": (4, 64, 128, None, "plain"),
+    "dense_bias": (2, 64, 1024, "dense", "xla"),
+    "D80": (2, 80, 1024, None, "pallas"),
+    "H_odd_D64": (3, 64, 1024, None, "pallas"),
+    # four heads a lane block: their temporaries do not fit the chip's VMEM
+    "D32": (4, 32, 1024, None, "pallas"),
+}
+
+
+@pytest.mark.parametrize("case", list(FALLBACKS))
+def test_packed_op_falls_back_to_the_split(monkeypatch, case):
+    """Every caller that does not reach the packed kernels gets what
+    ``MultiHeadAttention`` computed before: the split, ``flash_attention``,
+    the transpose back — no ``pallas_call`` off the TPU, and on one (the
+    interpreter stands in) only the kernels over heads apart."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops.registry import get_op
+    op_fn = get_op("flash_attention_qkv").fn
+    H, D, L, bias, tpu_path = FALLBACKS[case]
+    qkv, g, _ = _packed_inputs(1, H, D, L, "float32")
+    if bias == "dense":
+        bias = jnp.asarray(_rand(1, 1, L, L))
+    kw = dict(causal=True, dropout=0.0, training=True)
+
+    def loss(fn):
+        return lambda qkv: jnp.sum(fn(qkv) * g)
+
+    new = lambda qkv: op_fn(qkv, bias, num_heads=H, **kw)
+    old = lambda qkv: _mha_apart(qkv, bias, H, **kw)
+    out, jaxpr, paths = _paths(new, qkv)
+    assert "pallas_call" not in jaxpr
+    assert paths == ["plain" if tpu_path == "plain" else "xla"]
+    onp.testing.assert_array_equal(onp.asarray(out), onp.asarray(old(qkv)))
+    onp.testing.assert_array_equal(
+        onp.asarray(jax.grad(loss(new))(qkv)),
+        onp.asarray(jax.grad(loss(old))(qkv)))
+    assert "pallas_call" not in str(jax.make_jaxpr(jax.grad(loss(new)))(qkv))
+
+    monkeypatch.setenv("MXNET_FLASH_INTERPRET", "1")
+    _, jaxpr, paths = _paths(jax.grad(loss(new)), qkv)
+    assert paths == [tpu_path]
+    assert "_qkv" not in jaxpr
+    assert ("mx_flash_bwd_dkv" in jaxpr) == (tpu_path == "pallas")
+
+
+@pytest.mark.parametrize("D, H", [(64, 4), (128, 2)])
+def test_packed_op_takes_the_packed_kernels(monkeypatch, D, H):
+    """Training at L 1,024 on a TPU (the interpreter stands in): the op's
+    three kernels are the packed ones, by name, no transpose of a
+    (B, H, L, D) array is left in the program, and the result is the
+    split's."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops.registry import get_op
+    monkeypatch.setenv("MXNET_FLASH_INTERPRET", "1")
+    op_fn = get_op("flash_attention_qkv").fn
+    qkv, g, _ = _packed_inputs(1, H, D, 1024, "float32")
+    kw = dict(causal=True, dropout=0.0, training=True)
+    new = lambda qkv: jnp.sum(op_fn(qkv, num_heads=H, **kw) * g)
+    old = lambda qkv: jnp.sum(_mha_apart(qkv, None, H, **kw) * g)
+    grad, jaxpr, paths = _paths(jax.grad(new), qkv)
+    assert paths == ["packed"]
+    for kernel in ("mx_flash_fwd_qkv", "mx_flash_bwd_dq_qkv",
+                   "mx_flash_bwd_dkv_qkv"):
+        assert kernel in jaxpr, kernel
+    assert "transpose[" not in jaxpr.split("pallas_call")[0]
+    onp.testing.assert_array_equal(onp.asarray(grad),
+                                   onp.asarray(jax.grad(old)(qkv)))
+    # inference at this length stays with the measured table's XLA row
+    _, _, paths = _paths(
+        lambda qkv: op_fn(qkv, num_heads=H, causal=True, training=False),
+        qkv[:, :768])
+    assert paths == ["xla"]

@@ -76,3 +76,44 @@ def test_kernel_compiles_for_v5e(one_chip, shape, kernel):
                  lambda q, k, v, g, lse, delta: fn(
                      q, k, v, g, lse, delta, scale, True),
                  qkv, qkv, qkv, qkv, row, row)
+
+
+PACKED = {
+    # the train cell's projection: B 8, L 1,024, 3 x (H 16 x D 64): heads
+    # in pairs, a 128-lane block of the (B, L, 3U) array a grid step
+    "gpt2m_train": (8, 16, 64, 1024),
+    # one head a lane block
+    "L2048_D128": (2, 8, 128, 2048),
+}
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "bwd_dq", "bwd_dkv"])
+@pytest.mark.parametrize("shape", list(PACKED))
+def test_packed_kernel_compiles_for_v5e(one_chip, shape, kernel):
+    """The same three kernels reading q, k and v as lane blocks of the
+    packed projection and writing (B, L, U) and one (B, L, 3U) gradient:
+    the lane masks that take a head out of a pair, two heads' score-sized
+    temporaries in the 16 MiB a kernel may take, the dq kernel's own delta,
+    the dk/dv kernel's copies into the buffer it shares with dq."""
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import attention as attn
+    B, H, D, L = PACKED[shape]
+    scale = 1.0 / D ** 0.5
+    qkv = ((B, L, 3 * H * D), jnp.bfloat16)
+    g = ((B, L, H * D), jnp.bfloat16)
+    row = ((B * H, 1, L), jnp.float32)
+    if kernel == "fwd":
+        compiled = _compile(
+            one_chip, lambda qkv: attn._pallas_fwd(
+                qkv, None, None, scale, True, heads=H), qkv)
+    elif kernel == "bwd_dq":        # makes delta itself, of out and do
+        compiled = _compile(
+            one_chip, lambda qkv, g, lse, out: attn._pallas_bwd_dq(
+                qkv, None, None, g, lse, None, scale, True, heads=H,
+                out=out), qkv, g, row, g)
+    else:       # copies dk and dv into dq's buffer itself
+        compiled = _compile(
+            one_chip, lambda qkv, g, lse, delta, into: attn._pallas_bwd_dkv(
+                qkv, None, None, g, lse, delta, scale, True, heads=H,
+                into=into), qkv, g, row, row, qkv)
+    assert f"mx_flash_{kernel}_qkv" in compiled.as_text()

@@ -31,6 +31,90 @@ def test_mha_shapes_and_grad():
     assert onp.isfinite(x.grad.asnumpy()).all()
 
 
+# MultiHeadAttention blocks in the shapes its callers use, with what the
+# block gave at commit b701dcd — before the attention core moved from
+# reshape / transpose / slice + ``flash_attention`` in the model to one
+# ``flash_attention_qkv`` call (PR 34): loss = sum(y * y), then the sums of
+# |y|, |d loss / dx| and |d loss / d qkv weight|
+MHA_BLOCKS = {
+    "bert": (dict(units=64, heads=4, L=24, causal=False, key_mask=True),
+             (8086.43359375, 3897.45654296875, 65034.08203125,
+              666493.125)),
+    "gpt2": (dict(units=128, heads=2, L=32, causal=True, key_mask=False),
+             (149703.609375, 27731.111328125, 1635559.5, 20310120.0)),
+    "seq2seq_encoder": (
+        dict(units=32, heads=4, L=12, causal=False, key_mask=True),
+        (495.9189147949219, 482.3891296386719, 2889.72119140625,
+         27618.37109375)),
+    "seq2seq_decoder": (
+        dict(units=32, heads=4, L=12, causal=True, key_mask=False),
+        (682.932861328125, 540.8837890625, 3234.278564453125,
+         29343.84765625)),
+}
+
+
+def _plain_mha(x, mask, wqkv, bqkv, wout, bout, heads, causal):
+    """The block's arithmetic written out, float32, no framework op."""
+    import jax
+    import jax.numpy as jnp
+    B, L, U = x.shape
+    D = U // heads
+    q, k, v = jnp.split(x @ wqkv.T + bqkv, 3, axis=-1)
+    q, k, v = (t.reshape(B, L, heads, D).transpose(0, 2, 1, 3)
+               for t in (q, k, v))
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / D ** 0.5
+    if mask is not None:
+        s = s + mask
+    if causal:
+        s = jnp.where(jnp.tril(jnp.ones((L, L), bool)), s, -1e30)
+    o = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), v)
+    return o.transpose(0, 2, 1, 3).reshape(B, L, U) @ wout.T + bout
+
+
+@pytest.mark.parametrize("block", list(MHA_BLOCKS))
+def test_mha_unchanged_against_plain_reference(block):
+    """Forward and every gradient of a BERT-, GPT-2- and seq2seq-shaped
+    ``MultiHeadAttention`` against the arithmetic written out, and against
+    the numbers the block gave before PR 34."""
+    import jax
+    import jax.numpy as jnp
+    c, stored = MHA_BLOCKS[block]
+    mx.random.seed(0)
+    mha = MultiHeadAttention(c["units"], c["heads"], causal=c["causal"])
+    mha.initialize(mx.init.Normal(0.2))
+    rng = onp.random.RandomState(1)
+    x = mx.nd.array(rng.randn(2, c["L"], c["units"]).astype("float32"))
+    mask = None
+    if c["key_mask"]:
+        mask = onp.zeros((2, 1, 1, c["L"]), "float32")
+        mask[1, :, :, c["L"] - 5:] = -1e9
+        mask = mx.nd.array(mask)
+    x.attach_grad()
+    with autograd.record():
+        y = mha(x, mask) if mask is not None else mha(x)
+        loss = (y * y).sum()
+    loss.backward()
+    params = [mha.qkv.weight, mha.qkv.bias, mha.proj.weight, mha.proj.bias]
+    got = [y.asnumpy(), x.grad.asnumpy()] + \
+        [p.grad().asnumpy() for p in params]
+
+    def plain(x, *w):
+        y = _plain_mha(x, None if mask is None else mask._data, *w,
+                       c["heads"], c["causal"])
+        return jnp.sum(y * y), y
+    grads, ref_y = jax.grad(plain, argnums=(0, 1, 2, 3, 4), has_aux=True)(
+        x._data, *[p.data()._data for p in params])
+    for name, a, b in zip(("y", "dx", "dwqkv", "dbqkv", "dwout", "dbout"),
+                          got, [ref_y] + list(grads)):
+        b = onp.asarray(b)
+        onp.testing.assert_allclose(a, b, rtol=2e-4,
+                                    atol=2e-5 * onp.abs(b).max(),
+                                    err_msg=f"{block}: {name}")
+    onp.testing.assert_allclose(
+        [float(loss.asnumpy().sum()), onp.abs(got[0]).sum(),
+         onp.abs(got[1]).sum(), onp.abs(got[2]).sum()], stored, rtol=1e-5)
+
+
 def test_gpt_forward_and_causality():
     mx.random.seed(0)
     net = _tiny_gpt()

@@ -331,6 +331,7 @@ SPECIALS = {
     # ---- attention / rnn / rope -------------------------------------- #
     "flash_attention": spec(FN(2, 2, 8, 16), FN(2, 2, 8, 16),
                             FN(2, 2, 8, 16)),
+    "flash_attention_qkv": spec(FN(2, 8, 96), num_heads=2),
     "rope": spec(FN(2, 2, 8, 16)),
     "_contrib_interleaved_matmul_selfatt_qk": spec(FN(4, 2, 24), heads=2),
     "_contrib_interleaved_matmul_selfatt_valatt": spec(
@@ -1048,6 +1049,8 @@ VALUE_EXEMPT = {
     "UpSampling": "golden: tests/test_legacy_ops.py",
     # attention / rnn: parity vs naive implementations
     "flash_attention": "parity vs naive attention: tests/test_attention.py",
+    "flash_attention_qkv":
+        "parity vs the split + flash_attention: tests/test_attention.py",
     "rope": "rotation identities: tests/test_llama.py",
     "fused_rnn": "parity vs unrolled cells: tests/test_rnn.py",
     "_contrib_interleaved_matmul_selfatt_qk":

@@ -258,6 +258,41 @@ class TestONNXImport:
         onp.testing.assert_allclose(outs[-1].asnumpy(), ref.asnumpy(),
                                     rtol=2e-4, atol=2e-4)
 
+    def test_mha_exports_the_graph_it_did(self, tmp_path):
+        """``MultiHeadAttention`` calls ``flash_attention_qkv`` on the
+        packed projection (PR 34); its converter emits what the block held
+        there before — reshape / transpose / slice into heads,
+        ``flash_attention``'s decomposition, transpose and reshape back —
+        so the exported node sequence is the one commit b701dcd gave."""
+        from mxnet_tpu.models import MultiHeadAttention
+        mx.random.seed(0)
+        mha = MultiHeadAttention(32, 4)
+        mha.initialize()
+        x = mx.nd.array(onp.random.RandomState(0).randn(2, 8, 32)
+                        .astype(onp.float32))
+        ref = mha(x)
+        mha.hybridize()
+        mha(x)
+        prefix = str(tmp_path / "mha")
+        mha.export(prefix)
+        path = mx.onnx.export_model(
+            prefix + "-symbol.json", prefix + "-0000.params",
+            input_shapes=[("data", (2, 8, 32))],
+            onnx_file_path=str(tmp_path / "mha.onnx"))
+        if path.endswith(".json"):
+            nodes = json.load(open(path))["graph"]["nodes"]
+            assert [n["op_type"] for n in nodes] == [
+                "Gemm", "Reshape", "Transpose", "Slice", "Reshape", "Slice",
+                "Reshape", "Slice", "Reshape", "Transpose", "MatMul", "Mul",
+                "Softmax", "MatMul", "Transpose", "Reshape", "Gemm"]
+            assert [n["attrs"]["perm"] for n in nodes
+                    if n["op_type"] == "Transpose"] == [
+                [2, 0, 3, 1, 4], [0, 1, 3, 2], [0, 2, 1, 3]]
+        sym, arg_params, aux_params = mx.onnx.import_model(path)
+        outs = sym.bind(args={**arg_params, "data": x}).forward()
+        onp.testing.assert_allclose(outs[-1].asnumpy(), ref.asnumpy(),
+                                    rtol=2e-4, atol=2e-5)
+
     def test_unknown_op_raises(self, tmp_path):
         bad = {"opset": 13, "graph": {
             "nodes": [{"op_type": "NoSuchOp", "inputs": ["x"],

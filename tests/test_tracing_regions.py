@@ -120,13 +120,20 @@ def test_region_is_the_innermost_mx_component(path, region):
     ("mx_flash_bwd_dkv.24", "", "mx.attn"),
     ("jvp_mx_flash_fwd_.1", "", "mx.attn"),
     ("transpose_jvp_mx_flash_bwd_dq__.7", "", "mx.attn"),
+    ("mx_flash_fwd_qkv.2", "", "mx.attn"),
+    ("mx_flash_bwd_dq_qkv", "", "mx.attn"),
+    ("mx_flash_bwd_dkv_qkv.23", "", "mx.attn"),
+    ("jvp_mx_flash_fwd_qkv_.1", "", "mx.attn"),
+    ("transpose_jvp_mx_flash_bwd_dkv_qkv__.5", "", "mx.attn"),
     ("mx_paged_attention.8",
      "jit(step)/mx.dense/while/body/mx.attn/mx_paged_attention/pallas_call",
      "mx.attn"),
     ("fusion.170", "", "unscoped"),
 ], ids=["grouped_product", "paged_attention", "flash_fwd", "flash_bwd_dq",
         "flash_bwd_dkv", "flash_fwd_differentiated",
-        "flash_bwd_dq_differentiated", "provenance_wins", "other"])
+        "flash_bwd_dq_differentiated", "packed_fwd", "packed_bwd_dq",
+        "packed_bwd_dkv", "packed_fwd_differentiated",
+        "packed_bwd_dkv_differentiated", "provenance_wins", "other"])
 def test_region_of_a_kernel_known_by_name(name, provenance, region):
     """A custom kernel whose device events carry no provenance is known by
     a part of its operation's name (a differentiated program wraps a
