@@ -1,0 +1,36 @@
+"""The serving entry of the trinity_large configuration: the model behind
+``serve.DecodeServer`` on the scheduler's own thread, driven by sessions over
+each client's own cached document (``generators/doc_sessions.py``), short and
+long documents side by side in one queue.
+
+The run IS ``entries/serve_dots3.py``'s: the same set-up (every client's
+document submitted bare with one new token, so that the server's chunked
+prefill builds its cache, the prefix index registers its end and keeps the
+window's tail; then a few whole sessions, so that every executable the window
+can reach has run), the same driver, window, counters and ``correct`` (the
+served-token gap to the plain reference over a seeded sample of the finished
+requests, the longest among them; limit from chip readings of the program and
+of the int8 control, the configuration's ``limits_why``).  That run names its
+model family by two modules, ``dots3`` (``reference_config``, ``build``,
+``shapes``, ``load_seeded``, ``seeded_weights``) and ``reference_dots3``
+(``served_gaps``); ``chipbench/trinity.py`` and ``reference_trinity.py`` have
+the same functions, so this entry runs it with them in those places and adds
+the one reading that run lacks, the first-token tail.
+"""
+from unittest import mock
+
+from chipbench import harness, reference_trinity, trinity
+from chipbench.entries import serve_dots3
+
+
+def run(ctx):
+    with mock.patch.multiple(serve_dots3, dots3=trinity,
+                             reference_dots3=reference_trinity):
+        out = serve_dots3.run(ctx)
+    w = out["window"]
+    ttft = [((r["times"][0] if r["times"] else w["t_end"]) - r["submit"])
+            * 1e3 for r in out["records"]
+            if w["t_open"] <= r["submit"] < w["t_close"]]
+    out["end_to_end"]["ttft_p95_ms"] = \
+        harness.percentile(ttft, 95) if ttft else None
+    return out

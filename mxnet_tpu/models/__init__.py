@@ -17,6 +17,7 @@ from .bert import BERTModel, BERTConfig, bert_base, bert_large
 from .llama import (Llama, LlamaConfig, llama_tp_rules, llama_tiny,
                     llama_7b)
 from .dots3 import Dots3, Dots3Config, dots3_tiny
+from .trinity import Trinity, TrinityConfig, trinity_tiny
 from .granite_hybrid import (GraniteHybrid, GraniteHybridConfig,
                              granite_hybrid_tiny)
 from .seq2seq import (CrossAttention, Seq2SeqEncoder, Seq2SeqDecoder,
@@ -31,6 +32,7 @@ __all__ = [
     "Seq2SeqDecoderCell", "TransformerSeq2Seq",
     "Llama", "LlamaConfig", "llama_tp_rules", "llama_tiny", "llama_7b",
     "Dots3", "Dots3Config", "dots3_tiny",
+    "Trinity", "TrinityConfig", "trinity_tiny",
     "GraniteHybrid", "GraniteHybridConfig", "granite_hybrid_tiny",
     "kv_generate", "decode_mode", "decode_step_program",
 ]

@@ -20,11 +20,24 @@ Kinds implemented here:
   ``ssm_state`` under the SLOT table: the recurrent state and the
   convolution's tail, one entry a slot addressed by the slot's own index,
   updated in place at every token, never shared, never paged;
-- attention ``gqa``: grouped-query attention without positions at the
-  description's own ``scale``; cache kind ``kv``: a K row and a V row under
-  the MAIN page table (the single-query step walks the pages in
-  ``ops.paged_attention``);
+- attention ``gqa``: grouped-query attention at the description's own
+  ``scale``, by the description's optional keys: ``theta`` (RoPE on q and k;
+  none without it: no positions at all), ``rope`` ``"halves"`` (the halves
+  of a head rotated together, not consecutive pairs), ``qk_norm`` (an
+  RMSNorm over each head of q and of k), ``gate`` (a sigmoid gate on the
+  output, elementwise), ``window`` (keys ``t - window < s <= t``).  Cache
+  kind ``kv``: a K row and a V row under the MAIN page table; with
+  ``window``, cache kind ``kv_window``: the same two rows under the WINDOW
+  table's ring.  The single-query step walks the pages in
+  ``ops.paged_attention`` — the whole table row, or the ring from the
+  window's first page —, a prefill reads the rows its queries can reach
+  through a masked dense form;
 - feed-forward ``swiglu`` and ``routed`` (``ops.moe``).
+
+A layer of the stacked-runs body may also declare ``post_norms`` (an RMSNorm
+AFTER each sub-block, before it joins the stream: four norms a layer) and
+``residual`` (a multiplier on what joins); the model an untied ``head`` and
+an ``embedding_multiplier``.
 
 A model whose ``weights()`` come as ``runs`` — the parameters of each maximal
 run of like layers stacked along a leading axis — has each run SCANNED
@@ -56,7 +69,10 @@ from ..serve.schema import pool_rows, row_lanes
 
 __all__ = ["LayeredEngine", "top_mask", "mask_positions", "top_positions"]
 
-# the indexer's scores of one head block may take this many bytes
+# a routed layer's parameters that a scan over a stacked run does not slice
+_EXPERT_WEIGHTS = ("egu_weight", "edown_weight")
+# the indexer's scores of one head block, and a prefill's attention scores
+# of one block of K/V heads, may take this many bytes
 _INDEX_BLOCK_BYTES = 512 << 20
 
 
@@ -75,9 +91,10 @@ def _layer_norm(x, gamma, beta, eps):
     return (y * gamma + beta).astype(x.dtype)
 
 
-def _rope(x, pos, theta):
-    """Rotate consecutive (even, odd) pairs of the last axis of ``x``
-    ``(B, C, [heads,] d)`` by the angles of positions ``pos`` ``(B, C)``."""
+def _rope(x, pos, theta, halves=False):
+    """Rotate pairs of the last axis of ``x`` ``(B, C, [heads,] d)`` by the
+    angles of positions ``pos`` ``(B, C)``: consecutive (even, odd) pairs,
+    or with ``halves`` the pairs ``(j, j + d / 2)`` (``rotate_half``)."""
     d = x.shape[-1]
     inv = theta ** (-jnp.arange(d // 2, dtype=jnp.float32) * 2.0 / d)
     ang = pos.astype(jnp.float32)[..., None] * inv
@@ -85,9 +102,12 @@ def _rope(x, pos, theta):
         ang = ang[:, :, None, :]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     x32 = x.astype(jnp.float32)
-    x1, x2 = x32[..., 0::2], x32[..., 1::2]
-    out = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
-    return out.reshape(x.shape).astype(x.dtype)
+    x1, x2 = (x32[..., :d // 2], x32[..., d // 2:]) if halves \
+        else (x32[..., 0::2], x32[..., 1::2])
+    pair = (x1 * cos - x2 * sin, x1 * sin + x2 * cos)
+    out = jnp.concatenate(pair, axis=-1) if halves \
+        else jnp.stack(pair, axis=-1).reshape(x.shape)
+    return out.astype(x.dtype)
 
 
 def _dot(x, w):
@@ -249,6 +269,7 @@ class LayeredEngine:
         self.full, self.win = of_kind("latent_index"), \
             of_kind("latent_window")
         self.kv, self.ssm = of_kind("kv"), of_kind("ssm_state")
+        self.kvw = of_kind("kv_window")
         # the cache kinds whose arrays are addressed by the slot's own
         # index (``serve.schema.POOL_ROWS``: table "slot")
         self.slot_kinds = sorted({d["cache"] for d in self.desc
@@ -269,22 +290,33 @@ class LayeredEngine:
             a = self.desc[self.full[0]]["attn"]
             self.rows["latent"] = row_lanes(a["kv_rank"] + a["rope"])
             self.rows["index_key"] = row_lanes(a["index_dim"])
+        self.window = None
         if self.win:
             a = self.desc[self.win[0]]["attn"]
             self.rows["window_latent"] = row_lanes(a["kv_rank"]
                                                    + a["rope"])
             self.window = int(a["window"])
-        else:
-            self.window = None
         # a model of stacked runs with no layer of a kind keeps that kind's
         # arrays with no layers in them
         self.state_shape = (0, 0, 0)
-        if self.kv or self.ssm:
+        if self.stacked:
             self.rows.update(k=0, v=0, conv_tail=0)
         if self.kv:
             a = self.desc[self.kv[0]]["attn"]
             self.rows["k"] = self.rows["v"] = row_lanes(
                 a["kv_heads"] * a["head_dim"])
+        if self.kvw:
+            if self.ssm:
+                # ``vp`` carries the window table's arrays or the slot
+                # table's, not both
+                from ..base import MXNetError
+                raise MXNetError(
+                    "the layered decode engine keeps no window table "
+                    "beside state under the slot table")
+            a = self.desc[self.kvw[0]]["attn"]
+            self.rows["window_k"] = self.rows["window_v"] = row_lanes(
+                a["kv_heads"] * a["head_dim"])
+            self.window = int(a["window"])
         if self.ssm:
             a = self.desc[self.ssm[0]]["attn"]
             g = ssd.heads_per_row(a["heads"], a["head_dim"])
@@ -332,18 +364,27 @@ class LayeredEngine:
             + self.rows["conv_tail"] * self.cdtype.itemsize)
 
     def window_page_bytes(self, page):
-        return len(self.win) * page * self.rows.get("window_latent", 0) \
-            * self.cdtype.itemsize
+        """Bytes of one window-table page over every layer that has one."""
+        w = len(self.win) * self.rows.get("window_latent", 0) \
+            + len(self.kvw) * (self.rows.get("window_k", 0)
+                               + self.rows.get("window_v", 0))
+        return w * page * self.cdtype.itemsize
 
     def pool_zeros(self, num_pages, window_pages, page, slots=0):
         """``(kp, vp)``: ``kp`` the main-table pools (``(latent, index
         key)`` or ``(k, v)``), each ``(layers, pages, page, lanes)``;
-        ``vp`` the window-table pool, or — a model with state under the
-        SLOT table — ``(state, conv tail)``, each ``(layers, slots, ...)``."""
+        ``vp`` the window-table pools (the latent one, or ``(k, v)``), or —
+        a model with state under the SLOT table — ``(state, conv tail)``,
+        each ``(layers, slots, ...)``."""
         z = lambda n, p, w: jnp.zeros((n, p, page, w), self.cdtype)
-        if self.ssm or self.kv:
+        if self.stacked:
             kp = (z(len(self.kv), num_pages, self.rows["k"]),
                   z(len(self.kv), num_pages, self.rows["v"]))
+            if self.kvw:
+                return kp, (z(len(self.kvw), window_pages,
+                              self.rows["window_k"]),
+                            z(len(self.kvw), window_pages,
+                              self.rows["window_v"]))
             vp = (jnp.zeros((len(self.ssm), slots) + self.state_shape,
                             ssd.STATE_DTYPE),
                   jnp.zeros((len(self.ssm), slots, self.rows["conv_tail"]),
@@ -532,11 +573,13 @@ class LayeredEngine:
         of like layers: each run is ONE ``lax.scan`` over its layers.
 
         ``C == 1`` is the step: every slot one token, the pools a run
-        writes — ``(k, v)`` under the main table ``tables``, ``(state, conv
-        tail)`` under the slot table — carried whole and updated in place at
-        ``(layer, ...)``; ``live`` masks the slot table's update.
+        writes — ``(k, v)`` under the main table, ``(k, v)`` under the
+        window table's ring, ``(state, conv tail)`` under the slot table —
+        carried whole and updated in place at ``(layer, ...)``; ``live``
+        masks the slot table's update.  ``tables`` is the main table, or
+        the pair ``(main, window ring)`` where the model keeps a window.
 
-        ``C > 1`` is a prefill: the K and V rows go through the table as in
+        ``C > 1`` is a prefill: the K and V rows go through the tables as in
         the step, but the slot table's entries of the rows' ``slots`` are
         read out BEFORE the scans (zero for a row at offset 0, whatever its
         slot held), ride each scan as its ``xs``, come back as its ``ys``
@@ -544,68 +587,93 @@ class LayeredEngine:
         carry the whole state: carried, the chip's compiler gave it a
         layout that suits the prefill's transposes and converted the WHOLE
         array to it and back, every dispatch.  A row leaves the state of its
-        last TRUE token (column ``last[b]``)."""
+        last TRUE token (column ``last[b]``).
+
+        A routed layer's expert ids ride out as its scan's ``ys``: ``aux``
+        holds them for every routed layer, as the layer loop's does."""
         cfg = self.cfg
-        kv_pools, slot_pools = pools
+        eps = cfg.rms_norm_eps
+        main, other = pools
+        held = {"kv": main, "kv_window": other if self.kvw else (),
+                "ssm_state": () if self.kvw else other}
+        ptm, ptw = tables if isinstance(tables, tuple) else (tables, None)
         B, C = toks.shape
         pos = off[:, None] + jnp.arange(C, dtype=jnp.int32)[None]
         count = jnp.full((B,), C, jnp.int32) if last is None else last + 1
         x = (w["wte"][toks].astype(jnp.float32)
              * getattr(cfg, "embedding_multiplier", 1.0)).astype(self.cdtype)
-        ctx = {"pos": pos, "count": count, "page": page, "table": tables,
-               "key_pages": key_pages, "live": live}
+        ctx = {"pos": pos, "count": count, "page": page, "table": ptm,
+               "ring": ptw, "key_pages": key_pages, "live": live}
         step = C == 1
         mem = None
         if not step and self.ssm:
             fresh = off == 0
             mem = tuple(jnp.where(
                 fresh.reshape((1, B) + (1,) * (a.ndim - 2)), 0,
-                _slot_rows(a, slots)) for a in slot_pools)
-        seen, after = {"ssm": 0, "gqa": 0}, []
+                _slot_rows(a, slots)) for a in held["ssm_state"])
+        seen, after, experts = dict.fromkeys(held, 0), [], []
         for rw, (first, n) in zip(w["runs"], self.runs):
             d = self.desc[first]
-            kind = d["attn"]["kind"]
+            kind, cache = d["attn"]["kind"], d["cache"]
             mixer = getattr(self, self._KINDS[kind])
-            carried = slot_pools if kind == "ssm" and step else \
-                kv_pools if kind == "gqa" else ()
-            lo = seen[kind]
+            carried = () if kind == "ssm" and not step else held[cache]
+            lo = seen[cache]
             rows = tuple(a[lo:lo + n] for a in mem) \
                 if kind == "ssm" and mem is not None else ()
+            # the run's routed experts stay whole, closed over: the
+            # grouped product takes the layer's by index (``ops.moe``)
+            whole = {k: v for k, v in rw.items() if k in _EXPERT_WEIGHTS}
+            rw = {k: v for k, v in rw.items() if k not in whole}
 
-            def layer(carry, xs, d=d, mixer=mixer):
-                x, held = carry
-                lw, li, rows = xs
-                h = _rms(x, lw["norm1_gamma"], cfg.rms_norm_eps)
-                o, held, rows = mixer(lw, d["attn"], h, held, li, ctx, rows)
-                x = x + (o * d["residual"]).astype(x.dtype)
-                h = _rms(x, lw["norm2_gamma"], cfg.rms_norm_eps)
-                y, _ = self._ffn(lw, d["ffn"], h.reshape(B * C, -1))
-                x = x + (y.reshape(B, C, -1) * d["residual"]).astype(x.dtype)
-                return (x, held), rows
+            def layer(carry, xs, d=d, mixer=mixer, lo=lo, whole=whole):
+                x, pools = carry
+                lw, j, rows = xs
+                li = lo + j         # the layer's place among its cache kind
+                res, post = d.get("residual", 1.0), d.get("post_norms")
+                h = _rms(x, lw["norm1_gamma"], eps)
+                o, pools, rows = mixer(lw, d["attn"], h, pools, li, ctx,
+                                       rows)
+                if post:
+                    o = _rms(o, lw["post1_gamma"], eps)
+                x = x + (o * res).astype(x.dtype)
+                h = _rms(x, lw["norm2_gamma"], eps)
+                y, eidx = self._ffn(dict(lw, **whole), d["ffn"],
+                                    h.reshape(B * C, -1), j if whole else None)
+                y = y.reshape(B, C, -1)
+                if post:
+                    y = _rms(y, lw["post2_gamma"], eps)
+                x = x + (y * res).astype(x.dtype)
+                return (x, pools), (rows, () if eidx is None else eidx)
 
-            ids = lo + jnp.arange(n, dtype=jnp.int32)
-            (x, carried), rows = jax.lax.scan(layer, (x, carried),
-                                              (rw, ids, rows))
-            seen[kind] += n
-            if kind == "gqa":
-                kv_pools = carried
-            elif step:
-                slot_pools = carried
+            (x, carried), (rows, eidx) = jax.lax.scan(
+                layer, (x, carried),
+                (rw, jnp.arange(n, dtype=jnp.int32), rows))
+            seen[cache] += n
+            if kind != "ssm" or step:
+                held[cache] = carried
             else:
                 after.append(rows)
+            if d["ffn"]["kind"] == "routed":
+                experts.append(eidx)
         if after:
-            slot_pools = tuple(
+            held["ssm_state"] = tuple(
                 _slot_rows_set(a, slots, jnp.concatenate(parts))
-                for a, parts in zip(slot_pools, zip(*after)))
+                for a, parts in zip(held["ssm_state"], zip(*after)))
         with jax.named_scope("mx.head"):
             if last is not None:
                 x = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
-            # the tied head: the embedding's rows contracted as they lie
-            logits = jnp.einsum(
-                "...h,vh->...v", _rms(x, w["normf"], cfg.rms_norm_eps),
-                w["wte"], preferred_element_type=jnp.float32) \
-                / getattr(cfg, "logits_scaling", 1.0)
-        return logits, kv_pools, slot_pools, {}
+            xn = _rms(x, w["normf"], eps)
+            if "head" in w:
+                logits = jnp.dot(xn, w["head"],
+                                 preferred_element_type=jnp.float32)
+            else:
+                # the tied head: the embedding's rows contracted as they lie
+                logits = jnp.einsum("...h,vh->...v", xn, w["wte"],
+                                    preferred_element_type=jnp.float32)
+            logits = logits / getattr(cfg, "logits_scaling", 1.0)
+        aux = {"expert": jnp.concatenate(experts)} if experts else {}
+        return logits, held["kv"], \
+            held["kv_window" if self.kvw else "ssm_state"], aux
 
     def _ssm_mixer(self, lw, a, h, held, li, ctx, rows):
         """One state-space layer over ``h`` ``(B, C, H)``.  The step (``C ==
@@ -668,75 +736,148 @@ class LayeredEngine:
         return (o, (state, tail), ()) if step else (o, held, (s1, t1))
 
     def _gqa_mixer(self, lw, a, h, held, li, ctx, mem=()):
-        """One grouped-query attention layer without positions: the new K
-        and V rows go through the main table into layer ``li``'s pages;
-        the single-query step walks the slot's pages, a prefill reads the
-        rows its queries can reach back through the table."""
+        """One grouped-query attention layer by the description's keys:
+        ``qk_norm`` (an RMSNorm over each head of q and k), ``theta`` (RoPE
+        on q and k, ``rope`` ``"halves"`` or consecutive pairs; without it
+        no positions), ``window``, ``gate`` (a sigmoid gate on the output).
+        The new K and V rows go through the table into layer ``li``'s pages:
+        the main table, or with ``window`` the window table's ring, where a
+        position's page is entry ``(position // page) % ring``.  The
+        single-query step walks the slot's pages (the table row from 0, or
+        the ring from the window's first page); a prefill reads the rows
+        its queries can reach — the first ``key_pages`` of the row, or the
+        ``window_span_pages`` of the ring — back through the table into a
+        masked dense form."""
         from .decoding import _flat_attention
 
         kpool, vpool = held
         B, C, _ = h.shape
         hq, kvh, D = a["heads"], a["kv_heads"], a["head_dim"]
-        page, pos, pt = ctx["page"], ctx["pos"], ctx["table"]
+        page, pos, window = ctx["page"], ctx["pos"], a.get("window")
+        pt = ctx["table"] if window is None else ctx["ring"]
+        npages, lanes = kpool.shape[1], kpool.shape[-1]
         q = _dot(h, lw["q_weight"]).reshape(B, C, hq, D)
         kv = _dot(h, lw["kv_weight"])
         k, v = kv[..., :kvh * D], kv[..., kvh * D:]
-        lanes = kpool.shape[-1]
+        if a.get("qk_norm") or a.get("theta") is not None:
+            with jax.named_scope("mx.qk_norm_rope"):
+                k = k.reshape(B, C, kvh, D)
+                if a.get("qk_norm"):
+                    q = _rms(q, lw["qnorm_gamma"], self.cfg.rms_norm_eps)
+                    k = _rms(k, lw["knorm_gamma"], self.cfg.rms_norm_eps)
+                if a.get("theta") is not None:
+                    halves = a.get("rope") == "halves"
+                    q = _rope(q, pos, a["theta"], halves)
+                    k = _rope(k, pos, a["theta"], halves)
+                k = k.reshape(B, C, kvh * D)
+
+        def pages_of(lp):
+            """Page ids of logical pages ``lp`` ``(B, n)``."""
+            if window is None:
+                return _table_pages(pt, lp, npages)
+            pg = jnp.take_along_axis(pt, lp % pt.shape[1], axis=1)
+            return jnp.where(lp >= 0, pg, npages)
 
         def write(kpool, vpool):
             with jax.named_scope("mx.kv_write"):
-                pg = _table_pages(pt, pos // page, kpool.shape[1])
+                pg = pages_of(pos // page)
                 return (kpool.at[li, pg, pos % page].set(
                             _pad_last(k, lanes), mode="drop"),
                         vpool.at[li, pg, pos % page].set(
                             _pad_last(v, lanes), mode="drop"))
 
-        def rows(pool, n):
-            reach = jnp.minimum(pt[:, :n], pool.shape[1] - 1)
+        # the pages a row of queries reads, the position of their first
+        # column and which columns each query may see
+        if window is None:
+            n = pt.shape[1] if C == 1 or ctx["key_pages"] is None \
+                else ctx["key_pages"]
+            reach, base = pt[:, :n], 0
+            kpos = jnp.arange(n * page, dtype=jnp.int32)[None, None]
+            ok = kpos <= pos[..., None]
+        else:
+            n = self.window_span_pages(page, C)
+            lps = (pos[:, :1] // page - self.window_back_pages(page)) \
+                + jnp.arange(n, dtype=jnp.int32)[None]
+            reach, base = pages_of(lps), lps[:, 0] * page
+            kpos = (lps[:, :, None] * page
+                    + jnp.arange(page, dtype=jnp.int32)[None, None]
+                    ).reshape(B, 1, n * page)
+            p = pos[..., None]
+            ok = (kpos <= p) & (kpos > p - window) & (kpos >= 0)
+        reach = jnp.minimum(reach, npages - 1)
+
+        def rows(pool):
             return pool.at[li, reach].get(mode="promise_in_bounds").reshape(
                 B, n * page, lanes)
 
+        region = "mx.attn" if window is None else "mx.window_attn"
         if C == 1:
             def view():
-                n = pt.shape[1]
-                iB, p0 = jnp.arange(B), jnp.minimum(pos[:, 0], n * page - 1)
-                kc = rows(kpool, n).at[iB, p0].set(_pad_last(k[:, 0], lanes))
-                vc = rows(vpool, n).at[iB, p0].set(_pad_last(v[:, 0], lanes))
-                ok = jnp.arange(n * page)[None, None] <= pos[..., None]
+                iB = jnp.arange(B)
+                p0 = jnp.clip(pos[:, 0] - base, 0, n * page - 1)
+                kc = rows(kpool).at[iB, p0].set(_pad_last(k[:, 0], lanes))
+                vc = rows(vpool).at[iB, p0].set(_pad_last(v[:, 0], lanes))
                 return _flat_attention(q, kc[..., :kvh * D],
                                        vc[..., :kvh * D], ok, a["scale"],
                                        self.cdtype)[:, 0]
 
-            with jax.named_scope("mx.attn"):
+            with jax.named_scope(region):
                 if _paged.supports(lanes, self.cdtype, page, hq, D) \
                         and lanes == kvh * D:
+                    if window is None:
+                        ends, starts = _paged.walk_lengths(
+                            pt, pos[:, 0], page, npages), None
+                    else:
+                        ends, starts = _paged.walk_span(
+                            pt, pos[:, 0], page, npages, window)
                     o = _paged.paged_attention(
                         q[:, 0], k[:, 0], v[:, 0], kpool, vpool, li, pt,
-                        _paged.walk_lengths(pt, pos[:, 0], page,
-                                            kpool.shape[1]),
-                        a["scale"], view)
+                        ends, a["scale"], view, starts)
                 else:
                     o = view()
             kpool, vpool = write(kpool, vpool)
             o = o[:, None]
         else:
             kpool, vpool = write(kpool, vpool)
-            n = pt.shape[1] if ctx["key_pages"] is None else ctx["key_pages"]
-            with jax.named_scope("mx.attn"):
-                kc = rows(kpool, n)[..., :kvh * D].reshape(B, -1, kvh, D)
-                vc = rows(vpool, n)[..., :kvh * D].reshape(B, -1, kvh, D)
-                s = jnp.einsum("bckgd,btkd->bkgct",
-                               q.reshape(B, C, kvh, hq // kvh, D), kc,
+            G, T = hq // kvh, n * page
+
+            def attend(qg, kc, vc):
+                """``qg`` ``(B, C, kb, G, D)`` over ``kc`` / ``vc`` ``(B, T,
+                kb, D)``: a block of ``kb`` K/V heads."""
+                s = jnp.einsum("bckgd,btkd->bkgct", qg, kc,
                                preferred_element_type=jnp.float32) \
                     * a["scale"]
-                ok = jnp.arange(n * page)[None, None] <= pos[..., None]
                 s = jnp.where(ok[:, None, None], s, -1e30)
                 p = jax.nn.softmax(s, axis=-1).astype(self.cdtype)
-                o = jnp.einsum("bkgct,btkd->bckgd", p, vc,
-                               preferred_element_type=jnp.float32
-                               ).astype(self.cdtype).reshape(B, C, hq * D)
-        return _dot(o.reshape(B, C, hq * D), lw["o_weight"]
-                    ).astype(jnp.float32), (kpool, vpool), mem
+                return jnp.einsum("bkgct,btkd->bckgd", p, vc,
+                                  preferred_element_type=jnp.float32
+                                  ).astype(self.cdtype)
+
+            with jax.named_scope(region):
+                qg = q.reshape(B, C, kvh, G, D)
+                kc = rows(kpool)[..., :kvh * D].reshape(B, T, kvh, D)
+                vc = rows(vpool)[..., :kvh * D].reshape(B, T, kvh, D)
+                # K/V heads a block, so that a block's scores fit
+                kb = max(1, min(kvh, _INDEX_BLOCK_BYTES
+                                // max(1, B * G * C * T * 4)))
+                while kvh % kb:
+                    kb -= 1
+                if kb == kvh:
+                    o = attend(qg, kc, vc)
+                else:
+                    blocks = lambda x, axis: jnp.moveaxis(
+                        x.reshape(x.shape[:axis] + (kvh // kb, kb)
+                                  + x.shape[axis + 1:]), axis, 0)
+                    o = jnp.moveaxis(jax.lax.map(
+                        lambda xs: attend(*xs),
+                        (blocks(qg, 2), blocks(kc, 2), blocks(vc, 2))), 0, 2)
+        o = o.reshape(B, C, hq * D)
+        if a.get("gate"):
+            gate = jax.nn.sigmoid(jnp.dot(
+                h, lw["gate_weight"], preferred_element_type=jnp.float32))
+            o = (o.astype(jnp.float32) * gate).astype(self.cdtype)
+        return _dot(o, lw["o_weight"]).astype(jnp.float32), \
+            (kpool, vpool), mem
 
     # -- attention ------------------------------------------------------ #
     def _latent_qkv(self, lw, a, h, pos):
@@ -871,9 +1012,11 @@ class LayeredEngine:
                           preferred_element_type=jnp.float32)
 
     # -- feed-forward --------------------------------------------------- #
-    def _ffn(self, lw, f, h):
+    def _ffn(self, lw, f, h, layer=None):
         """``(y (N, H), local expert ids (N, top_k) or None)``; an id
-        outside ``[0, held)`` is an expert another chip holds."""
+        outside ``[0, held)`` is an expert another chip holds.  With
+        ``layer`` the routed experts' two arrays are a stacked run's and
+        ``layer`` the index into it."""
         if f["kind"] == "swiglu":
             return moe.swiglu(h, lw["gu_weight"], lw["down_weight"]), None
         lo, n = f["held"]
@@ -882,7 +1025,7 @@ class LayeredEngine:
                                  lw["router_bias"], f["top_k"], f["scale"])
         with jax.named_scope("mx.moe_experts"):
             y, _ = moe.routed_experts(h, idx, wts, lw["egu_weight"],
-                                      lw["edown_weight"], lo)
+                                      lw["edown_weight"], lo, layer)
         with jax.named_scope("mx.moe_shared"):
             y = y + moe.swiglu(h, lw["sgu_weight"], lw["sdown_weight"])
         local = idx - lo

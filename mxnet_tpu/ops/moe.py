@@ -41,13 +41,21 @@ def route(x, w_router, bias, top_k, scale=1.0):
     return idx.astype(jnp.int32), w
 
 
-def routed_experts(x, idx, weights, w_gu, w_down, lo):
+def routed_experts(x, idx, weights, w_gu, w_down, lo, layer=None):
     """The held experts' part of the routed sum for ``x`` ``(N, H)``:
     ``w_gu`` ``(n, H, 2I)`` and ``w_down`` ``(n, I, H)`` are experts ``lo
     .. lo + n - 1`` of the layer.  Returns ``(y (N, H), load (n,) int32)``,
-    ``load`` the tokens each held expert got."""
+    ``load`` the tokens each held expert got.
+
+    With ``layer`` (a traced index) the two arrays hold a RUN of ``L``
+    layers' experts stacked, ``(L, n, ...)``, as a scan over the run closes
+    over them: the grouped products then see all ``L * n`` groups, every
+    other layer's empty.  No layer's experts are sliced out of the run: the
+    grouped product is a kernel of its own on the chip, takes no fused
+    slice, and a slice of its operands was a copy of 1.8 GB a layer a step
+    (tools/rehearse_serve.py, PERF.md PR 35)."""
     N, K = idx.shape
-    n = w_gu.shape[0]
+    n = w_gu.shape[-3]
     local = idx - lo
     mine = (local >= 0) & (local < n)
     group = jnp.where(mine, local, n).reshape(N * K)
@@ -59,11 +67,18 @@ def routed_experts(x, idx, weights, w_gu, w_down, lo):
     # no-op for a bfloat16 XLA dot, but the chip's grouped-product kernel
     # refuses bfloat16 operands under it, so they ask for what they are
     prec = None if x.dtype == jnp.float32 else jax.lax.Precision.DEFAULT
-    gu = jax.lax.ragged_dot(xs, w_gu, load, precision=prec,
+    sizes = load
+    if layer is not None:
+        groups = w_gu.shape[0] * n
+        sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((groups,), jnp.int32), load, (layer * n,))
+        w_gu = w_gu.reshape((groups,) + w_gu.shape[2:])
+        w_down = w_down.reshape((groups,) + w_down.shape[2:])
+    gu = jax.lax.ragged_dot(xs, w_gu, sizes, precision=prec,
                             preferred_element_type=jnp.float32)
     g, u = jnp.split(gu, 2, axis=-1)
     a = (jax.nn.silu(g) * u).astype(x.dtype)
-    ys = jax.lax.ragged_dot(a, w_down, load, precision=prec,
+    ys = jax.lax.ragged_dot(a, w_down, sizes, precision=prec,
                             preferred_element_type=jnp.float32)
     w = jnp.where(mine, weights, 0.0).reshape(N * K)[order]
     # rows behind the last group belong to no expert here: whatever the

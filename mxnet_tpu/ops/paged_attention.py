@@ -1,17 +1,25 @@
 """Single-query attention straight out of the paged K/V pool (Pallas TPU).
 
 The serving step's cache-to-output leg for ONE new token a slot
-(``models/decoding.py::_scan_token``, ``pages=`` branch): slot ``b`` owns
-the pages its table row ``pt[b]`` names, of which only the first
-``ceil(pos[b] / page)`` hold tokens.  The view path gathers all ``MAXP``
-pages of every slot into a ``(B, T, KV·D)`` view and contracts all ``T``
-columns; this kernel walks each slot's row only as far as its length and
-never builds the view.
+(``models/decoding.py::_scan_token``, ``pages=`` branch; ``models/layered.py::
+_gqa_mixer``): slot ``b`` owns the pages its table row ``pt[b]`` names, of
+which only the first ``ceil(pos[b] / page)`` hold tokens.  The view path
+gathers all ``MAXP`` pages of every slot into a ``(B, T, KV·D)`` view and
+contracts all ``T`` columns; this kernel walks each slot's row only as far
+as its length and never builds the view.
+
+A walk has an END and, for a layer that keeps a window, a START
+(``walk_span``): the table row is then a RING (logical page ``lp`` lies at
+entry ``lp % width``), the walk begins at the entry of the page that holds
+the first visible position ``start[b]`` and runs in ring order to the page
+of ``pos[b] - 1``, and the columns before ``start[b]`` in that first page
+are masked.  A layer without a window passes no start: that is position 0,
+entry 0, no wrap, and the kernel is what it was without one.
 
 - The pools ``(NL, NPAGES, page, KV·D)`` stay in HBM, whole
   (``memory_space=pl.ANY``): no BlockSpec copy, no slice of the layer.
-  ``layer``, the flattened table and each slot's walk length ride in SMEM
-  (scalar prefetch).
+  ``layer``, the flattened table and each slot's walk end and start ride in
+  SMEM (scalar prefetch).
 - A page is one contiguous ``(page, KV·D)`` block; it is fetched by an
   async copy into VMEM, a GROUP of ``_ROWS // page`` pages (one compute
   block of ``_ROWS`` rows) at a time, K and V alike, double-buffered: while
@@ -23,14 +31,16 @@ never builds the view.
   (``_spread_queries``), one MXU contraction over the whole row, float32
   accumulation; an online softmax in float32 over the groups; ``p`` cast to
   the pool's dtype before ``p·V`` (as the view path does), accumulated in
-  float32.  Columns at and past the walk length are masked.
+  float32.  Columns at and past the walk's end, and before its start, are
+  masked.
 - The NEW token's own K and V are operands, not pool rows: they enter as the
   first key of the online softmax, so nothing is written to the pool here
   and the step's post-scan scatter stays as it is.
-- No page id reaches a copy unchecked: ``walk_lengths`` cuts a slot's walk
-  at its first sentinel entry (a retired slot's row is all sentinel: it
-  walks nothing) and the kernel clamps what it reads from the table — an
-  out-of-range DMA takes the chip down, where a gather only clamps.
+- No page id reaches a copy unchecked: ``walk_lengths`` / ``walk_span`` cut
+  a slot's walk at the first sentinel entry on its way (a retired slot's row
+  is all sentinel: it walks nothing) and the kernel clamps what it reads
+  from the table — an out-of-range DMA takes the chip down, where a gather
+  only clamps.
 
 The grid runs the slots in turn (v5e has one TensorCore), so unequal
 lengths cost no balance.
@@ -56,11 +66,14 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .attention import _interpret
 
-__all__ = ["paged_attention", "supports", "walk_lengths"]
+__all__ = ["paged_attention", "supports", "walk_lengths", "walk_span"]
 
 _NEG_INF = -1e30
 _ROWS = 256      # token rows a compute block (a group of pages) holds
-_NAME = "mx_paged_attention"    # profiler_xla._KERNEL_REGIONS knows it
+# profiler_xla._KERNEL_REGIONS knows both: the walk from position 0, and the
+# same kernel called with a start (a window layer's ring)
+_NAME = "mx_paged_attention"
+_NAME_WINDOW = "mx_paged_attention_window"
 
 
 def supports(lanes, dtype, page, num_heads, head_dim):
@@ -84,6 +97,25 @@ def walk_lengths(pt, pos, page, num_pages):
     at the table's width.  ``(B,)`` int32; the same for every layer."""
     owned = jnp.cumprod((pt < num_pages).astype(jnp.int32), axis=1)
     return jnp.minimum(pos.astype(jnp.int32), owned.sum(axis=1) * page)
+
+
+def walk_span(pt, pos, page, num_pages, window):
+    """A window layer's walk over its RING ``pt`` ``(B, width)``: ``(ends,
+    starts)``, both ``(B,)`` int32 positions.  The new token at ``pos[b]``
+    sees the cached positions ``starts[b] = max(pos[b] - window + 1, 0)`` to
+    ``pos[b] - 1``; the walk runs from the ring entry of the start's page in
+    ring order and is cut at the first sentinel entry on its way, so a
+    retired slot (all sentinel) has ``ends[b] <= starts[b]`` and walks
+    nothing."""
+    pos = pos.astype(jnp.int32)
+    width = pt.shape[1]
+    starts = jnp.maximum(pos - (window - 1), 0)
+    first = starts // page
+    entries = (first[:, None] + jnp.arange(width, dtype=jnp.int32)[None]) \
+        % width
+    owned = jnp.cumprod((jnp.take_along_axis(pt, entries, axis=1)
+                         < num_pages).astype(jnp.int32), axis=1)
+    return jnp.minimum(pos, (first + owned.sum(axis=1)) * page), starts
 
 
 def _spread_queries(q, kv, kvp, rows, dtype):
@@ -113,19 +145,23 @@ def _spread_queries(q, kv, kvp, rows, dtype):
     return jnp.where(own[None], spread, 0).astype(dtype)
 
 
-def _kernel(layer_ref, pt_ref, len_ref,                  # SMEM (prefetch)
+def _kernel(layer_ref, pt_ref, len_ref, start_ref,       # SMEM (prefetch)
             qb_ref, kn_ref, vn_ref, kp_ref, vp_ref,      # inputs
             out_ref,                                     # output
             kbuf, vbuf, acc, m_ref, l_ref, state, sems,  # scratch
-            *, scale, page, maxp, num_pages, kv, kvp, groups):
+            *, scale, page, maxp, num_pages, kv, kvp, groups, ring):
     b = pl.program_id(0)
     nslots = pl.num_programs(0)
     per = _ROWS // page                 # pages a group
     lanes = kbuf.shape[-1]
     D = lanes // kv
     layer = layer_ref[0]
-    length = len_ref[b]
-    ngroups = pl.cdiv(length, _ROWS)
+    # ``ring`` is static: without it a walk starts at position 0, entry 0
+    first_page = (lambda slot_b: start_ref[slot_b] // page) if ring \
+        else (lambda slot_b: 0)
+    length = len_ref[b]                 # the walk's end, a position
+    origin = first_page(b) * page       # position of the walk's column 0
+    ngroups = pl.cdiv(length - origin, _ROWS)
     prec = lax.Precision.HIGHEST if kbuf.dtype == jnp.float32 \
         else lax.Precision.DEFAULT
 
@@ -133,12 +169,17 @@ def _kernel(layer_ref, pt_ref, len_ref,                  # SMEM (prefetch)
         """``act`` on the K and the V copy of every page of group ``g`` of
         slot ``slot_b`` into buffer ``buf`` (``start`` them, later ``wait``
         for the same ones)."""
-        count = jnp.minimum(pl.cdiv(len_ref[slot_b], page) - g * per, per)
+        first = first_page(slot_b)
+        count = jnp.minimum(
+            pl.cdiv(len_ref[slot_b], page) - first - g * per, per)
 
         def body(j, carry):
+            entry = first + g * per + j
+            if ring:
+                entry = lax.rem(entry, maxp)
             # checked against NPAGES though ``walk_lengths`` walks owned
             # pages only: an id out of range must never reach a DMA
-            pid = jnp.clip(pt_ref[slot_b * maxp + g * per + j], 0,
+            pid = jnp.clip(pt_ref[slot_b * maxp + entry], 0,
                            num_pages - 1)
             dst = pl.ds(pl.multiple_of(j * page, page), page)
             for i, (pool, rows) in enumerate(((kp_ref, kbuf),
@@ -180,8 +221,9 @@ def _kernel(layer_ref, pt_ref, len_ref,                  # SMEM (prefetch)
     acc[...] = jnp.broadcast_to(vn_ref[0].astype(jnp.float32), acc.shape)
 
     nxt = jnp.minimum(b + 1, nslots - 1)
-    next_groups = jnp.where(b + 1 < nslots,
-                            pl.cdiv(len_ref[nxt], _ROWS), 0)
+    next_groups = jnp.where(
+        b + 1 < nslots,
+        pl.cdiv(len_ref[nxt] - first_page(nxt) * page, _ROWS), 0)
 
     def group(g, carry):
         buf = (first + g) % 2
@@ -198,8 +240,12 @@ def _kernel(layer_ref, pt_ref, len_ref,                  # SMEM (prefetch)
         s = lax.dot_general(qb, kbuf[buf], (((1,), (1,)), ((), ())),
                             precision=prec,
                             preferred_element_type=jnp.float32) * scale
-        col = g * _ROWS + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(col < length, s, _NEG_INF)
+        col = origin + g * _ROWS + lax.broadcasted_iota(jnp.int32, s.shape,
+                                                        1)
+        seen = col < length
+        if ring:
+            seen = seen & (col >= start_ref[b])
+        s = jnp.where(seen, s, _NEG_INF)
         m_old = m_ref[...]
         m_new = jnp.maximum(m_old, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_old - m_new)
@@ -228,7 +274,7 @@ def _kernel(layer_ref, pt_ref, len_ref,                  # SMEM (prefetch)
 
 
 def _kernel_call(q, k_new, v_new, kpool, vpool, layer, pt, lengths, scale,
-                 interpret):
+                 interpret, starts=None):
     B, H, D = q.shape
     _, num_pages, page, lanes = kpool.shape
     kv = lanes // D
@@ -237,9 +283,12 @@ def _kernel_call(q, k_new, v_new, kpool, vpool, layer, pt, lengths, scale,
     kvp = -(-kv // 8) * 8
     rows = -(-G * kvp // 16) * 16
     dtype = kpool.dtype
+    ring = starts is not None       # static: a walk with a start
+    if starts is None:
+        starts = jnp.zeros_like(lengths)
     qb = _spread_queries(q, kv, kvp, rows, dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(B,),
         in_specs=[
             pl.BlockSpec((1, rows, lanes), lambda b, *_: (b, 0, 0)),
@@ -260,40 +309,48 @@ def _kernel_call(q, k_new, v_new, kpool, vpool, layer, pt, lengths, scale,
         ])
     out = pl.pallas_call(
         functools.partial(_kernel, scale=scale, page=page, maxp=maxp,
-                          num_pages=num_pages, kv=kv, kvp=kvp, groups=G),
+                          num_pages=num_pages, kv=kv, kvp=kvp, groups=G,
+                          ring=ring),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, G, lanes), dtype),
         # the slots run in turn: a slot starts the next one's first fetch
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
-        name=_NAME,
+        name=_NAME_WINDOW if ring else _NAME,
         interpret=interpret,
     )(jnp.reshape(layer, (1,)).astype(jnp.int32),
       pt.reshape(-1).astype(jnp.int32), lengths.astype(jnp.int32),
-      qb, k_new.astype(dtype)[:, None, :], v_new.astype(dtype)[:, None, :],
+      starts.astype(jnp.int32), qb, k_new.astype(dtype)[:, None, :], v_new.astype(dtype)[:, None, :],
       kpool, vpool)
     # (B, G, KV·D) -> heads in order k * G + g
     return out.reshape(B, G, kv, D).transpose(0, 2, 1, 3).reshape(B, H * D)
 
 
 def paged_attention(q, k_new, v_new, kpool, vpool, layer, pt, lengths,
-                    scale, fallback):
+                    scale, fallback, starts=None):
     """Attention of one new token a slot over its cached pages and itself.
 
     ``q`` ``(B, H, D)``; ``k_new`` / ``v_new`` ``(B, KV·D)``, the new
     token's rows; ``kpool`` / ``vpool`` the whole pools; ``layer`` a traced
-    scalar; ``pt`` ``(B, MAXP)``; ``lengths`` ``walk_lengths(...)``.  Returns
-    ``(B, H·D)`` in the pool's dtype.
+    scalar; ``pt`` ``(B, MAXP)``; ``lengths`` ``walk_lengths(...)``.  With
+    ``starts`` the table is a window layer's ring and ``(lengths, starts)``
+    are ``walk_span(...)``'s; without, every walk starts at position 0.
+    Returns ``(B, H·D)`` in the pool's dtype.
 
     The kernel is what a TPU lowering gets; every other platform lowers
     ``fallback()``, the view path, which is also the kernel's reference.
     ``MXNET_FLASH_INTERPRET=1`` runs the kernel interpreted wherever it is
     (CPU numerics)."""
-    kernel = functools.partial(_kernel_call, scale=scale)
+    span = (lengths,) if starts is None else (lengths, starts)
+
+    def kernel(q, k_new, v_new, kpool, vpool, layer, pt, lengths, *starts,
+               interpret=False):
+        return _kernel_call(q, k_new, v_new, kpool, vpool, layer, pt,
+                            lengths, scale, interpret, *starts)
+
     if _interpret():
-        return kernel(q, k_new, v_new, kpool, vpool, layer, pt, lengths,
+        return kernel(q, k_new, v_new, kpool, vpool, layer, pt, *span,
                       interpret=True)
     return lax.platform_dependent(
-        q, k_new, v_new, kpool, vpool, layer, pt, lengths,
-        tpu=functools.partial(kernel, interpret=False),
-        default=lambda *_: fallback())
+        q, k_new, v_new, kpool, vpool, layer, pt, *span,
+        tpu=kernel, default=lambda *_: fallback())

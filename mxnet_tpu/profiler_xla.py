@@ -226,6 +226,9 @@ def _device_planes(data):
 # differentiated program names ``jvp_mx_flash_fwd_[.n]`` and
 # ``transpose_jvp_mx_flash_bwd_dq__[.n]`` — hence a part, not the start.
 _KERNEL_REGIONS = (("ragged-dot", "mx.moe_experts"),
+                   # the same kernel walking a window layer's ring: its
+                   # name holds the shorter one, so it comes first
+                   ("mx_paged_attention_window", "mx.window_attn"),
                    ("mx_paged_attention", "mx.attn"),
                    ("mx_flash", "mx.attn"),
                    ("mx_ssm_update", "mx.ssm_state"))

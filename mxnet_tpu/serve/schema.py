@@ -143,9 +143,12 @@ KV_PAGE_INT8 = {"codes": "int8", "scales": "float32"}
 # ``rows`` are the stored row kinds, each one array ``(layers of the kind,
 # pages, page, lanes)`` — under the slot table ``(layers of the kind,
 # slots, ...)``; the state tuple's ``kp`` holds the main-table arrays and
-# ``vp`` the window-table ones or, where a model has them, the slot-table
-# ones (a uniform K/V model: ``kp`` = K, ``vp`` = V, both main), so that
-# every executable donates them by the same two NAMES.  A row's lanes are
+# ``vp`` the window-table ones (one latent array, or a K and a V array for
+# ``kv_window``: a grouped-query layer with a window keeps its two rows
+# under the ring, as ``kv`` keeps them under the main table) or, where a
+# model has them, the slot-table ones (a uniform K/V model: ``kp`` = K,
+# ``vp`` = V, both main), so that every executable donates them by the same
+# two NAMES.  A row's lanes are
 # its width rounded up to whole 128-lane tiles (``row_lanes``): a 64-wide
 # minor dimension made the chip keep the pool page-minor and re-lay it out
 # for every consumer (PERF.md, PR 27).
@@ -153,6 +156,7 @@ POOL_ROWS = {
     "kv": {"table": "main", "rows": ("k", "v")},
     "latent_index": {"table": "main", "rows": ("latent", "index_key")},
     "latent_window": {"table": "window", "rows": ("latent",)},
+    "kv_window": {"table": "window", "rows": ("k", "v")},
     "ssm_state": {"table": "slot", "rows": ("state", "conv_tail")},
 }
 POOL_TABLES = ("main", "window", "slot")

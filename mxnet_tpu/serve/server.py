@@ -2059,10 +2059,13 @@ class DecodeServer:
         out = onp.full((len(slots) if rows is None else rows, ring),
                        progs.window_pages, onp.int32)
         for i, slot in enumerate(slots):
-            if slot is None:
-                continue
-            for lp, pg in self._slot_wpages[slot].items():
-                out[i, lp % ring] = pg
+            held = None if slot is None else self._slot_wpages[slot]
+            if held:
+                # one assignment a slot: a window of K/V rows is some
+                # hundreds of pages, and this runs before every step
+                lps = onp.fromiter(held.keys(), onp.int64, len(held))
+                out[i, lps % ring] = onp.fromiter(held.values(), onp.int32,
+                                                  len(held))
         return out
 
     def _drop_chunk_record(self, slot):

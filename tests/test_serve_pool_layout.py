@@ -307,3 +307,109 @@ def test_slot_pools_keep_the_declared_layout(hybrid_reports, which):
     for lay in hybrid_reports[which]["pool_entry_layouts"]:
         dims = lay.split("{")[1]
         assert dims.startswith(("4,3,2,1,0", "3,2,1,0", "2,1,0")), lay
+
+
+# --------------------------------------------------------------------------- #
+# K and V rows under the window table's ring (ISSUE 35)
+# --------------------------------------------------------------------------- #
+
+W_SLOTS, W_TOTAL, W_PAGES, W_WPAGES = 4, 2048, 16384, 4096
+
+
+@pytest.fixture(scope="module")
+def window_progs():
+    """Gated grouped-query attention, three sliding layers (a window of 65)
+    in four, K/V rows of one whole lane tile (2 heads of 64), routed layers
+    in a run of three: the stacked-runs body with both tables."""
+    from mxnet_tpu.models import trinity
+    net, _ = trinity.trinity_tiny(
+        dtype="bfloat16", hidden_size=256, intermediate_size=384,
+        num_attention_heads=8, num_key_value_heads=2, head_dim=64,
+        sliding_window=65, moe_intermediate_size=512, vocab_size=512,
+        vocab_slice=(0, 512), num_hidden_layers=5,
+        layer_types=("sliding_attention",) * 4 + ("full_attention",),
+        max_length=W_TOTAL)
+    net.collect_params().setattr("grad_req", "null")
+    net.initialize(mx.init.Zero())
+    return PoolPrograms(net, W_SLOTS, W_TOTAL, page_size=PAGE,
+                        num_pages=W_PAGES, window_pages=W_WPAGES,
+                        max_chunk=64)
+
+
+@pytest.fixture(scope="module")
+def window_reports(chip, window_progs):
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from tools import rehearse_serve as rs
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        p = window_progs
+        return {
+            "step": rs.pool_report(rs.compile_step(p, chip), p),
+            "chunk": rs.pool_report(rs.compile_chunk(p, chip, 64), p),
+            "admit_hit": rs.pool_report(rs.compile_hit(p, chip, 2), p)}
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+def test_window_pools_are_priced_as_allocated(window_progs):
+    import jax
+
+    from mxnet_tpu.serve.engine import pool_state_bytes, pool_state_init
+    p = window_progs
+    kp, vp = jax.eval_shape(lambda: pool_state_init(p))[:2]
+    shapes = [a.shape for a in jax.tree.leaves((kp, vp))]
+    assert shapes == [(1, W_PAGES, PAGE, 128)] * 2 \
+        + [(4, W_WPAGES, PAGE, 128)] * 2
+    # a ring: the window's pages back, the one written, a chunk's, a spare
+    assert p.window == 65 and p.ring == 4 + 1 + 4 + 1
+    want = sum(onp.prod(s) * 2 for s in shapes) + W_SLOTS * 29
+    assert pool_state_bytes(p, num_pages=W_PAGES) == want
+
+
+@pytest.mark.parametrize("which", ["step", "chunk", "admit_hit"])
+def test_window_no_pass_over_a_whole_pool(window_reports, which):
+    """Every pool-sized result is an in-place scatter into a donated pool:
+    a K and a V row a layer, four of them through the ring; a hit copies
+    main-table pages only."""
+    sized = window_reports[which]["pool_sized"]
+    assert not window_reports[which]["copy_bytes"]
+    allowed = {"fusion:scatter", "scatter", "fusion:dynamic-update-slice"}
+    assert set(sized) <= allowed, sized
+    layouts = window_reports[which]["pool_entry_layouts"]
+    assert len(layouts) == 2, window_reports[which]
+    for lay in layouts:
+        assert lay.split("{")[1].startswith("3,2,1,0"), lay
+
+
+def test_window_step_walks_both_tables_in_the_kernel(window_reports):
+    """One kernel body, two names: the run of three sliding layers and the
+    dense one walk their rings (``mx_paged_attention_window``), the full
+    layer its table row from 0; the runs' grouped products beside them."""
+    kernels = window_reports["step"]["kernels"]
+    walks = [k for k in kernels if k.startswith("mx_paged_attention")]
+    ring = [k for k in walks if k.startswith("mx_paged_attention_window")]
+    # three runs: dense sliding, routed sliding x 3 (one scan), full
+    assert len(walks) == 3 and len(ring) == 2, kernels
+    assert any(k.startswith("ragged-dot") for k in kernels)
+    assert not any(k.startswith("mx_paged_attention")
+                   for k in window_reports["chunk"]["kernels"])
+
+
+@pytest.mark.parametrize("which", ["step", "chunk", "admit_hit"])
+def test_window_scratch_is_no_second_pool(window_reports, window_progs,
+                                          which):
+    """The scratch stays under a quarter of the smaller pool, and the
+    step's under ONE layer's gate-and-up experts (16 x 256 x 1,024): a scan
+    over the routed run slices no layer's experts out for the grouped
+    product (``ops.moe.routed_experts``)."""
+    smaller = W_PAGES * PAGE * 128 * 2
+    temp = window_reports[which]["temp_bytes"]
+    assert temp < smaller // 4, window_reports[which]
+    if which == "step":
+        assert temp < 16 * 256 * 1024 * 2 // 2, window_reports[which]

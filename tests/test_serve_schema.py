@@ -162,7 +162,9 @@ class TestPoolTables:
         assert schema.POOL_TABLES == ("main", "window", "slot")
         assert {k: v["table"] for k, v in schema.POOL_ROWS.items()} == {
             "kv": "main", "latent_index": "main",
-            "latent_window": "window", "ssm_state": "slot"}
+            "latent_window": "window", "kv_window": "window",
+            "ssm_state": "slot"}
+        assert schema.pool_rows("kv_window") == ("window", ("k", "v"))
         assert schema.pool_rows("ssm_state") == (
             "slot", ("state", "conv_tail"))
         assert all(v["table"] in schema.POOL_TABLES
