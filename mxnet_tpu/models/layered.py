@@ -9,8 +9,10 @@ Kinds implemented here:
 
 - attention ``latent_sparse``: latent attention in the absorbed form over
   the ``topk`` positions an indexer selects (indexer scores over the paged
-  index keys, an exact top-k WITHOUT a sort — ``top_positions`` —, a gather of
-  the selected latent rows through the page table); cache kind ``latent_index``: a latent row
+  index keys — the single-query step walks them where they lie,
+  ``ops.index_scores``; a prefill gathers their view —, an exact top-k
+  WITHOUT a sort and its positions without a gather — ``top_positions``
+  —, a gather of the selected latent rows through the page table); cache kind ``latent_index``: a latent row
   ``[c_kv | k_rope]`` and an index-key row under the MAIN page table;
 - attention ``latent_window``: latent attention over the last ``window``
   positions; cache kind ``latent_window``: one latent row under the WINDOW
@@ -63,6 +65,7 @@ import math
 import jax
 import jax.numpy as jnp
 
+from ..ops import index_scores as _index
 from ..ops import moe, ssd
 from ..ops import paged_attention as _paged
 from ..serve.schema import pool_rows, row_lanes
@@ -102,8 +105,10 @@ def _rope(x, pos, theta, halves=False):
         ang = ang[:, :, None, :]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     x32 = x.astype(jnp.float32)
+    # a strided slice, not ``x32[..., 0::2]``: that index lowers to a gather
     x1, x2 = (x32[..., :d // 2], x32[..., d // 2:]) if halves \
-        else (x32[..., 0::2], x32[..., 1::2])
+        else (jax.lax.slice_in_dim(x32, 0, d, 2, axis=x32.ndim - 1),
+              jax.lax.slice_in_dim(x32, 1, d, 2, axis=x32.ndim - 1))
     pair = (x1 * cos - x2 * sin, x1 * sin + x2 * cos)
     out = jnp.concatenate(pair, axis=-1) if halves \
         else jnp.stack(pair, axis=-1).reshape(x.shape)
@@ -181,36 +186,86 @@ def top_mask(score, valid, k):
 
 def mask_positions(chosen, k):
     """The positions of the ``k`` set entries of every row of ``chosen``
-    ``(N, T)``, ascending, by blocks of 128: the block of the j-th from the
-    blocks' running counts, then its place inside that block from the
-    block's own mask (one gather of ``(N, k)`` mask rows; the running count
-    inside a block is a product with a triangle of ones)."""
+    ``(N, T)``, ascending, by blocks of 128, WITHOUT a gather (the chip
+    walks a gather's indices one by one: three of them were 1.76 ms a layer
+    of the ``dots3`` step, PERF.md PR 37):
+
+    1. every set entry's rank inside its block, 1 .. 128, from a product
+       with a triangle of ones (0 where the entry is not set), and with it
+       the blocks' counts;
+    2. the block of the j-th from the blocks' running counts — the blocks
+       that end at or before it are a prefix, so their number is its block
+       and their counts' sum what it leaves to find inside: two reductions
+       of one ``(N, k, blocks)`` comparison;
+    3. that block's ranks by a one-hot product over the blocks (the MXU
+       takes the one-hot as it is compared; no ``(N, k, 128)`` rows are
+       fetched), and the place whose rank is the one sought.
+
+    0 / 1 and ranks up to 128 are exact in bfloat16, and a one-hot row sums
+    one term.  Where a row has fewer than ``k`` set, the rest read the last
+    block's first position."""
     N, T = chosen.shape
     W = 128
     nb = -(-T // W)
     blocks = jnp.pad(chosen, ((0, 0), (0, nb * W - T))).reshape(N, nb, W)
-    count = jnp.sum(blocks, axis=-1, dtype=jnp.int32)           # (N, nb)
-    before = jnp.cumsum(count, axis=-1) - count
-    j = jnp.arange(k, dtype=jnp.int32)
-    blk = jnp.sum(before[:, None, :] + count[:, None, :] <= j[None, :, None],
-                  axis=-1, dtype=jnp.int32)                     # (N, k)
-    blk = jnp.minimum(blk, nb - 1)
-    nth = j[None] - jnp.take_along_axis(before, blk, axis=1)    # (N, k)
-    rows = jnp.take_along_axis(blocks, blk[..., None], axis=1)  # (N, k, W)
     tri = jnp.triu(jnp.ones((W, W), jnp.bfloat16))
-    # counts up to 128 are exact in bfloat16
-    running = jnp.einsum("nkw,wv->nkv", rows.astype(jnp.bfloat16), tri,
-                         preferred_element_type=jnp.bfloat16)
-    here = rows & (running == (nth + 1)[..., None].astype(jnp.bfloat16))
+    rank = jnp.einsum("nbw,wv->nbv", blocks.astype(jnp.bfloat16), tri,
+                      preferred_element_type=jnp.float32)
+    count = rank[:, :, W - 1].astype(jnp.int32)                 # (N, nb)
+    rank = jnp.where(blocks, rank, 0).astype(jnp.bfloat16)
+    # the last block is never passed: a short row's rest stay in it
+    upto = jnp.cumsum(count, axis=-1)[:, :nb - 1]
+    j = jnp.arange(k, dtype=jnp.int32)
+    passed = upto[:, None, :] <= j[None, :, None]               # (N, k, nb-1)
+    blk = jnp.sum(passed, axis=-1, dtype=jnp.int32)             # (N, k)
+    nth = j[None] - jnp.sum(
+        jnp.where(passed, count[:, None, :nb - 1], 0), axis=-1)
+    onehot = jnp.arange(nb, dtype=jnp.int32) == blk[..., None]
+    picked = jnp.einsum("nkb,nbw->nkw", onehot.astype(jnp.bfloat16), rank,
+                        preferred_element_type=jnp.float32)     # (N, k, W)
+    here = picked == (nth + 1)[..., None].astype(jnp.float32)
     return blk * W + jnp.argmax(here, axis=-1).astype(jnp.int32)
+
+
+def _index_scores_view(iq, iw, ikp, fi, table, page):
+    """The indexer's scores ``(B, C, T)`` float32 of queries ``iq`` ``(B, C,
+    J, d)`` weighted ``iw`` ``(B, C, J)`` over the ``T`` positions the table
+    rows ``table`` ``(B, n)`` reach in layer ``fi`` of the index-key pool
+    ``ikp``: ``sum_j iw[j] * relu(iq[j] . key[t])``.  The keys are gathered
+    into a ``(B, T, d)`` view (a sentinel entry reads the last page) and
+    contracted a block of heads at a time, so that a block's ``(B, C, jb,
+    T)`` scores fit ``_INDEX_BLOCK_BYTES``."""
+    B, C, J, dI = iq.shape
+    T = table.shape[1] * page
+    keys = ikp.at[fi, jnp.minimum(table, ikp.shape[1] - 1)].get(
+        mode="promise_in_bounds")
+    keys = keys.reshape(B, T, -1)[..., :dI]
+
+    def block(q, wj):
+        s = jnp.einsum("bcjd,btd->bcjt", q, keys,
+                       preferred_element_type=jnp.float32)
+        return jnp.einsum("bcj,bcjt->bct", wj, jax.nn.relu(s))
+
+    jb = max(1, min(J, _INDEX_BLOCK_BYTES // max(1, B * C * T * 4)))
+    while J % jb:
+        jb -= 1
+    if jb == J:
+        return block(iq, iw)
+    nb = J // jb
+    qs = jnp.moveaxis(iq.reshape(B, C, nb, jb, dI), 2, 0)
+    ws = jnp.moveaxis(iw.reshape(B, C, nb, jb), 2, 0)
+    score, _ = jax.lax.scan(
+        lambda acc, xs: (acc + block(*xs), None),
+        jnp.zeros((B, C, T), jnp.float32), (qs, ws))
+    return score
 
 
 def top_positions(score, valid, k):
     """``mask_positions`` of ``top_mask``: the positions of the ``k``
     largest, ascending.  (The chip's ``lax.top_k`` is a full sort of every
-    row; at a few dozen rows the two cost the same, 1.3-1.5 ms for 32 rows
-    of 33,152, but this one's set comes first and alone where nothing needs
-    the positions: PERF.md, PR 29.)"""
+    row, 1.3 ms for 32 rows of 33,152 — PERF.md, PR 29; the bisection is
+    0.10 ms and the positions 0.12 at 32 rows, 0.33 at 128 — PR 37 —, and
+    the set comes first and alone where nothing needs the positions.)"""
     return mask_positions(top_mask(score, valid, k), k)
 
 
@@ -401,7 +456,8 @@ class LayeredEngine:
     def step_counters(self, aux, active):
         """A step's counters reduced over the live slots: tokens each held
         expert of each routed layer got, keys the indexer selected and the
-        queries that selected them."""
+        queries that selected them, and what the index-score kernel's page
+        walks counted (nothing where the step gathers the key view)."""
         out = {}
         if "expert" in aux:
             n = next(d["ffn"]["held"][1] for d in self.desc
@@ -413,6 +469,10 @@ class LayeredEngine:
             sel = aux["selected"][:, :, 0]
             out["selected"] = jnp.sum(jnp.where(active[None], sel, 0))
             out["queries"] = jnp.sum(active) * sel.shape[0]
+        if "index_walk" in aux:
+            # (full layers, slots, [pages walked, copies, table width])
+            out["index_walk"] = jnp.sum(jnp.where(
+                active[None, :, None], aux["index_walk"], 0), axis=(0, 1))
         return out
 
     # -- the programs' bodies ------------------------------------------- #
@@ -489,7 +549,7 @@ class LayeredEngine:
         pos = off[:, None] + jnp.arange(C, dtype=jnp.int32)[None]
         lp, row = pos // page, pos % page
         x = w["wte"][toks]
-        aux = {"expert": [], "selected": []}
+        aux = {"expert": [], "selected": [], "index_walk": []}
         fi = wi = 0
         for i, d in enumerate(self.desc):
             lw, a = w["layers"][i], d["attn"]
@@ -507,10 +567,13 @@ class LayeredEngine:
                 kp_n = ptm.shape[1] if key_pages is None else key_pages
                 reach = jnp.minimum(ptm[:, :kp_n], lat.shape[1] - 1)
                 with jax.named_scope("mx.index"):
-                    full, seen = self._select(a, iq, iw, ikp, fi, reach,
-                                              pos, page)    # (B, C, T)
+                    full, seen, walk = self._select(
+                        a, iq, iw, ikp, fi, ptm[:, :kp_n], pos,
+                        page)                               # (B, C, T)
                     chosen = full & seen
                 aux["selected"].append(jnp.sum(chosen, axis=-1))
+                if walk is not None:
+                    aux["index_walk"].append(walk)
                 if C >= self.dense_chunk:
                     with jax.named_scope("mx.latent_gather"):
                         rows = lat.at[fi, reach].get(
@@ -525,7 +588,7 @@ class LayeredEngine:
                         sel = mask_positions(full.reshape(B * C, -1),
                                              K).reshape(B, C, K)
                         # fewer than K seen: the rest point past ``pos``
-                        ok = jnp.take_along_axis(seen, sel, axis=2)
+                        ok = sel <= pos[..., None]
                     with jax.named_scope("mx.latent_gather"):
                         pgs = jnp.take_along_axis(reach[:, None, :],
                                                   sel // page, axis=2)
@@ -914,38 +977,42 @@ class LayeredEngine:
                      preferred_element_type=jnp.float32)
         return iq, ik, iw
 
-    def _select(self, a, iq, iw, ikp, fi, ptm, pos, page):
+    def _select(self, a, iq, iw, ikp, fi, table, pos, page):
         """Which positions every query attends to: the ``topk`` of largest
         indexer score among ``s <= pos``, all of them while there are
         fewer — ``(B, C, T)`` bool over the ``T`` positions the table rows
-        ``ptm`` reach, with exactly ``topk`` set a query (the earliest
-        unseen positions fill up a short one), and ``seen`` itself."""
-        B, C, J, dI = iq.shape
-        keys = ikp.at[fi, ptm].get(mode="promise_in_bounds")
-        T = ptm.shape[1] * page
-        keys = keys.reshape(B, T, -1)[..., :dI]
+        ``table`` (sentinels and all) reach, with exactly ``topk`` set a
+        query (the earliest unseen positions fill up a short one), ``seen``
+        itself, and what the page walk counted (``ops.index_scores``;
+        ``None`` where the scores come from the gathered view).
 
-        def block(q, wj):
-            s = jnp.einsum("bcjd,btd->bcjt", q, keys,
-                           preferred_element_type=jnp.float32)
-            return jnp.einsum("bcj,bcjt->bct", wj, jax.nn.relu(s))
-
-        jb = max(1, min(J, _INDEX_BLOCK_BYTES // max(1, B * C * T * 4)))
-        while J % jb:
-            jb -= 1
-        if jb == J:
-            score = block(iq, iw)
+        One query a row (the step) over a pool the kernel takes: the
+        scores come out of ``ops.index_scores``, which walks each row's
+        pages where they lie as far as ``pos + 1`` and leaves 0 past it.
+        ``top_mask`` keys every column that is not ``seen`` to 0 whatever
+        it holds, so such a column is never chosen ahead of a seen one.
+        Every other caller — a chunk's or a wave's ``C`` queries a row —
+        gathers the key view and contracts it; that form is also what the
+        step lowers to off the TPU."""
+        B, C = iq.shape[:2]
+        npages = ikp.shape[1]
+        T = table.shape[1] * page
+        view = lambda: _index_scores_view(iq, iw, ikp, fi, table, page)
+        walk = None
+        if C == 1 and _index.supports(ikp.shape[-1], ikp.dtype, page,
+                                      npages):
+            # the new token's key is in the pool already: the walk ends
+            # AFTER its position
+            ends = _paged.walk_lengths(table, pos[:, 0] + 1, page, npages)
+            score, walk = _index.index_scores(
+                iq[:, 0], iw[:, 0], ikp, fi, table, ends,
+                lambda: view()[:, 0])
         else:
-            nb = J // jb
-            qs = jnp.moveaxis(iq.reshape(B, C, nb, jb, dI), 2, 0)
-            ws = jnp.moveaxis(iw.reshape(B, C, nb, jb), 2, 0)
-            score, _ = jax.lax.scan(
-                lambda acc, xs: (acc + block(*xs), None),
-                jnp.zeros((B, C, T), jnp.float32), (qs, ws))
+            score = view()
         seen = jnp.arange(T, dtype=jnp.int32)[None, None] <= pos[..., None]
         chosen = top_mask(score.reshape(B * C, T), seen.reshape(B * C, T),
                           min(int(a["topk"]), T)).reshape(B, C, T)
-        return chosen, seen
+        return chosen, seen, walk
 
     def _window_rows(self, a, wlat, wi, ptw, off, pos, page, C):
         """The window pool's rows a row of queries can reach, ``(B, T',

@@ -221,7 +221,9 @@ def _device_planes(data):
 # The paged-attention kernel (``ops/paged_attention.py``) is a
 # ``pallas_call`` named for this table: its custom call keeps its provenance
 # in the HLO, and is known by name should an event of it come without; so
-# are the flash kernels of ``ops/attention.py`` (``mx_flash_fwd``,
+# is the index-score kernel of ``ops/index_scores.py`` (``mx_index_scores``,
+# the page walk of a selecting layer's decode step, under ``mx.index``), and
+# so are the flash kernels of ``ops/attention.py`` (``mx_flash_fwd``,
 # ``mx_flash_bwd_dq``, ``mx_flash_bwd_dkv``), whose operations a
 # differentiated program names ``jvp_mx_flash_fwd_[.n]`` and
 # ``transpose_jvp_mx_flash_bwd_dq__[.n]`` — hence a part, not the start.
@@ -231,7 +233,8 @@ _KERNEL_REGIONS = (("ragged-dot", "mx.moe_experts"),
                    ("mx_paged_attention_window", "mx.window_attn"),
                    ("mx_paged_attention", "mx.attn"),
                    ("mx_flash", "mx.attn"),
-                   ("mx_ssm_update", "mx.ssm_state"))
+                   ("mx_ssm_update", "mx.ssm_state"),
+                   ("mx_index_scores", "mx.index"))
 
 
 def region_of(provenance, name=None):

@@ -672,6 +672,12 @@ class _Request:
         self.span = {}
 
 
+# ``stats()`` keys of the step's ``index_walk`` readback, in its order
+# (``ops.index_scores``: pages walked, copies started, table width)
+_INDEX_WALK_KEYS = ("index_pages_walked", "index_copies",
+                    "index_pages_table")
+
+
 class DecodeServer:
     """Continuous-batching decode server over a slot-pool KV cache.
 
@@ -1247,7 +1253,8 @@ class DecodeServer:
         experts or a selecting attention), summed over the steps routed so
         far: mean over routed layers and steps of the busiest held expert's
         tokens over the mean, share of (layer, expert) cells a step
-        touched, tokens a held expert a step, keys selected a query."""
+        touched, tokens a held expert a step, keys selected a query, the
+        pages the index scores walked."""
         t = self._step_sums
         out = {}
         if t.get("cells"):
@@ -1257,6 +1264,11 @@ class DecodeServer:
                 t["ratio_sum"] / t["ratio_n"] if t["ratio_n"] else None
         if t.get("queries"):
             out["selected_keys_per_query"] = t["selected"] / t["queries"]
+        # the index-score kernel's own count over live slots and selecting
+        # layers: pages its walks read, copies they took, the tables' width
+        # (0 / 0 where no step ran it)
+        for k in _INDEX_WALK_KEYS:
+            out[k] = t.get(k, 0)
         return out
 
     def _add_step_counters(self, c):
@@ -1273,6 +1285,9 @@ class DecodeServer:
         if "selected" in c:
             t["selected"] = t.get("selected", 0) + int(c["selected"])
             t["queries"] = t.get("queries", 0) + int(c["queries"])
+        if "index_walk" in c:
+            for k, v in zip(_INDEX_WALK_KEYS, onp.asarray(c["index_walk"])):
+                t[k] = t.get(k, 0) + int(v)
 
     def close(self, drain=True, timeout=60.0):
         """Stop the scheduler.  ``drain=True`` serves everything already

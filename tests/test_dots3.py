@@ -14,6 +14,7 @@ gate, rope on the wrong half, one expert left out) moves them by 1e-2 or
 more.
 """
 import functools
+import os
 
 import jax
 import jax.numpy as jnp
@@ -132,21 +133,40 @@ def test_reference_tail_equals_its_full_pass(tiny):
     assert ref.tail_rows(rcfg, 48, 6) == [48, 30, 22, 14, 6]
 
 
+@pytest.fixture(params=[(4, False), (8, False), (8, True)],
+                ids=["page4_view", "page8_view", "page8_kernel"])
+def lowering(request, monkeypatch):
+    """The page size and what scores the decode step's index keys: pages of
+    4 float32 rows are no whole sublane tile, so the step gathers the key
+    view; pages of 8 the index-score kernel takes — interpreted
+    (``page8_kernel``), or as a CPU lowers the step, the view behind
+    ``platform_dependent`` (``page8_view``).  Other test modules switch the
+    interpreter on process-wide as they are imported."""
+    page, interpreted = request.param
+    if interpreted:
+        monkeypatch.setenv("MXNET_FLASH_INTERPRET", "1")
+    else:
+        monkeypatch.delenv("MXNET_FLASH_INTERPRET", raising=False)
+    return page
+
+
 @pytest.mark.parametrize("chunk", [8, 5], ids=["aligned", "ragged"])
-def test_paged_prefill_then_decode_logits(tiny, chunk):
-    """Prefill in chunks, then one token at a time to 48 positions, through
-    scattered pages and a ring of 6 window pages (the window needs 4): the
+def test_paged_prefill_then_decode_logits(tiny, chunk, lowering):
+    """Prefill in chunks, then one token at a time to 48 positions — past
+    the window and, at top-8, past the indexer's budget — through scattered
+    pages and a ring of window pages one more than the window needs: the
     logits of every position against the reference's full pass."""
     net, cfg, w, rcfg = tiny
-    page, T = 4, 48
+    page, T = lowering, 48
     eng = layered.LayeredEngine(net, 1, 1, T)
     weights = net.weights()
     toks = _tokens(T, seed=5)
     want = _ref_logits(w, rcfg, toks)
     ring = eng.window_span_pages(page, chunk) + 1
-    perm = np.random.default_rng(1).permutation(16)[:T // page]
+    # 256 pages: a pool of at least one of the kernel's compute blocks
+    perm = np.random.default_rng(1).permutation(256)[:T // page]
     ptm = jnp.asarray(perm[None].astype(np.int32))
-    pools = eng.pool_zeros(16, ring, page)
+    pools = eng.pool_zeros(256, ring, page)
     ptw = jnp.asarray(np.arange(ring, dtype=np.int32)[None])
     run = jax.jit(lambda tk, off, pools, last: eng.tokens_paged(
         weights, tk, off, (ptm, ptw), pools, page, last)[:3])
@@ -212,18 +232,22 @@ def test_served_streams_match_reference(tiny, form):
 
 @pytest.mark.parametrize("extra", [0, 7, 1], ids=["same", "question",
                                                   "one_more"])
-def test_hit_and_miss_streams_identical(tiny, extra):
+def test_hit_and_miss_streams_identical(tiny, extra, lowering):
     """A cached 50-token document, then the document (+ a question): the
     prefix pages are mapped read-only, the window enters from the tail the
     index kept, only the rest is chunked — and the stream is the miss's."""
     net, _, w, rcfg = tiny
+    page = lowering
     doc = _tokens(50, seed=50)
     prompt = np.concatenate([doc, _tokens(extra, seed=9)])
-    miss = _server(net, prefix_cache=False)
+    server = functools.partial(_server, page_size=page,
+                               num_pages=2048 // page,
+                               num_window_pages=256 // page)
+    miss = server(net, prefix_cache=False)
     want, = _drain(miss, [miss.submit(prompt, max_new_tokens=10)])
     miss.close()
     assert len(want) == 10 and _is_ref_stream(w, rcfg, prompt, want)
-    srv = _server(net)
+    srv = server(net)
     _drain(srv, [srv.submit(doc, max_new_tokens=1)])
     srv.reset_counters()
     st0 = srv.stats()
@@ -234,8 +258,67 @@ def test_hit_and_miss_streams_identical(tiny, extra):
     assert st["counters"]["admit_dispatches"] == 0
     cached = st["prompt_tokens_cached"] - st0["prompt_tokens_cached"]
     # whole pages short of the whole prompt: a window page is never copied
-    assert cached == min(50 // 4, (prompt.size - 1) // 4) * 4
+    assert cached == min(50 // page, (prompt.size - 1) // page) * page
+    walked = st["index_pages_walked"], st["index_pages_table"]
+    if os.environ.get("MXNET_FLASH_INTERPRET") == "1":
+        # the chunk gives the first token, nine steps the rest; a step of
+        # one slot over two selecting layers walks the pages that hold
+        # positions 0 .. pos, of a table of 128 / page entries
+        n = prompt.size
+        assert walked == (2 * sum(-(-(n + j + 1) // page)
+                                  for j in range(9)),
+                          2 * 9 * (128 // page))
+        assert 0 < st["index_copies"] <= walked[0]
+    else:
+        assert walked == (0, 0) and st["index_copies"] == 0
     srv.close()
+
+
+@pytest.mark.parametrize("pos", [[5, 40, 100, 127], [0, 8, 64, 77]],
+                         ids=["ragged", "edges"])
+def test_both_lowerings_select_the_same_set(tiny, pos, monkeypatch):
+    """``_select`` of one query a slot over the same pool, scores from the
+    interpreted kernel and from the gathered view: the same ``topk`` set
+    wherever no two scores around the cut tie within rounding (random
+    float32 keys: they do not), the same ``seen``, and only the kernel leg
+    counts a walk."""
+    net, _, _, _ = tiny
+    eng = layered.LayeredEngine(net, 4, 1, 128)
+    a = eng.desc[0]["attn"]
+    page, npages, B = 8, 256, 4
+    rng = np.random.default_rng(4)
+    ikp = jnp.asarray(rng.normal(size=(2, npages, page, 128)), jnp.float32)
+    ikp = ikp.at[..., a["index_dim"]:].set(0.0)
+    iq = jnp.asarray(rng.normal(size=(B, 1, a["index_heads"],
+                                      a["index_dim"])), jnp.float32)
+    iw = jnp.asarray(rng.random(size=(B, 1, a["index_heads"])), jnp.float32)
+    table = np.full((B, 16), npages, np.int32)
+    ids = list(rng.permutation(npages))
+    posj = jnp.asarray(pos, jnp.int32)[:, None]
+    for b in range(B - 1):                  # the last slot is retired
+        for j in range(pos[b] // page + 1):
+            table[b, j] = ids.pop()
+    table = jnp.asarray(table)
+    got = {}
+    for leg in ("view", "kernel"):
+        if leg == "kernel":
+            monkeypatch.setenv("MXNET_FLASH_INTERPRET", "1")
+        else:
+            monkeypatch.delenv("MXNET_FLASH_INTERPRET", raising=False)
+        got[leg] = jax.jit(lambda: eng._select(a, iq, iw, ikp, 1, table,
+                                               posj, page))()
+    (cv, sv, wv), (ck, sk, wk) = got["view"], got["kernel"]
+    live = np.arange(B) < B - 1
+    np.testing.assert_array_equal(np.asarray(sv), np.asarray(sk))
+    np.testing.assert_array_equal(np.asarray(cv & sv)[live],
+                                  np.asarray(ck & sk)[live])
+    picked = np.asarray(jnp.sum(ck & sk, axis=-1))[:, 0]
+    assert picked[live].tolist() == [min(p + 1, a["topk"])
+                                     for p in pos[:B - 1]]
+    assert np.asarray(wv).tolist() == [[0, 0, 0]] * B
+    assert np.asarray(wk)[:, 0].tolist() == [p // page + 1
+                                             for p in pos[:B - 1]] + [0]
+    assert (np.asarray(wk)[:, 2] == 16).all()
 
 
 def test_match_is_cut_back_where_no_tail_was_kept(tiny):
@@ -418,6 +501,81 @@ def test_router_bias_chooses_and_does_not_weigh():
     np.testing.assert_allclose(wts, chosen / chosen.sum(1, keepdims=True),
                                atol=1e-6)
     np.testing.assert_allclose(wts.sum(1), 1.0, atol=1e-6)
+
+
+def _positions(chosen, k):
+    """``mask_positions`` in NumPy: a row's set positions in order; where
+    it has fewer than ``k`` the rest read the last block's first position
+    (what the gather form of PR 29 returned there, pinned on its code
+    before it went: PERF.md, PR 37)."""
+    N, T = chosen.shape
+    out = np.full((N, k), (-(-T // 128) - 1) * 128, np.int32)
+    for n in range(N):
+        at = np.flatnonzero(chosen[n])[:k]
+        out[n, :at.size] = at
+    return out
+
+
+def _chosen(case, N, T, k):
+    """A ``(N, T)`` mask for a ``mask_positions`` case, ``k`` set a row
+    unless the case says otherwise."""
+    rng = np.random.default_rng(N * 7919 + T)
+    chosen = np.zeros((N, T), bool)
+    for n in range(N):
+        if case == "one_block":         # all of them inside one block
+            lo = 128 * int(rng.integers(0, T // 128))
+            at = lo + rng.choice(min(128, T - lo), k, replace=False)
+        elif case == "last_block":      # only in the last, padded block
+            lo = (T - 1) // 128 * 128
+            at = lo + rng.choice(T - lo, k, replace=False)
+        elif case == "short":           # fewer than k: 0, 1, k - 1, ...
+            at = rng.choice(T, (0, 1, k - 1, k // 2)[n % 4], replace=False)
+        else:
+            at = rng.choice(T, k, replace=False)
+        chosen[n, at] = True
+    return chosen
+
+
+@pytest.mark.parametrize("case,N,T,k", [
+    ("spread", 1, 1000, 100), ("spread", 32, 1000, 100),
+    ("spread", 128, 700, 64), ("spread", 3, 33152, 2048),
+    ("all", 2, 300, 300), ("all", 4, 128, 128), ("one", 2, 257, 1),
+    ("one", 32, 100, 1), ("one_block", 5, 1000, 100),
+    ("one_block", 3, 640, 128), ("last_block", 4, 1000, 60),
+    ("last_block", 2, 257, 1), ("short", 8, 1000, 100),
+    ("short", 4, 128, 40), ("short", 4, 33152, 2048)],
+    ids=lambda v: str(v))
+def test_mask_positions_are_the_set_positions_in_order(case, N, T, k):
+    """The gather-free ``mask_positions`` against ``np.flatnonzero``: the
+    same int32 positions, ascending, for one row, a step's 32 and a
+    chunk's 128, ``T`` off the blocks' grid, every position chosen, one
+    chosen, all inside one block, all inside the padded last block, and
+    rows with fewer than ``k`` set."""
+    chosen = _chosen(case, N, T, k)
+    got = jax.jit(layered.mask_positions, static_argnums=1)(
+        jnp.asarray(chosen), k)
+    assert got.dtype == jnp.int32 and got.shape == (N, k)
+    np.testing.assert_array_equal(np.asarray(got), _positions(chosen, k))
+
+
+@pytest.mark.parametrize("B,C,T,k", [(4, 1, 300, 64), (1, 16, 200, 200),
+                                     (3, 2, 1000, 128)])
+def test_positions_past_a_query_are_not_ok(B, C, T, k):
+    """``ok`` as ``tokens_paged`` takes it — a selected position is one the
+    query has seen iff it is at or before the query's own — equals ``seen``
+    read at the positions, also where a short query's fill points past
+    it."""
+    rng = np.random.default_rng(B)
+    pos = rng.integers(0, T, size=(B, C)).astype(np.int32)
+    pos[0, 0], pos[-1, -1] = 0, T - 1
+    seen = np.arange(T)[None, None] <= pos[..., None]
+    score = rng.normal(size=(B * C, T)).astype(np.float32)
+    sel = np.asarray(jax.jit(layered.top_positions, static_argnums=2)(
+        jnp.asarray(score), jnp.asarray(seen.reshape(B * C, T)),
+        k)).reshape(B, C, k)
+    np.testing.assert_array_equal(
+        sel <= pos[..., None], np.take_along_axis(seen, sel, axis=2))
+    assert (~(sel <= pos[..., None])).any()
 
 
 @pytest.mark.parametrize("N,T,k,ties", [

@@ -367,6 +367,8 @@ def test_served_streams_match_reference(tiny):
     assert stats["state_resets"] == 5 and stats["prefix_cache"] is False
     assert stats["slot_kinds"] == ["ssm_state"]
     assert stats["counters"]["chunk_dispatches"] >= 4
+    # no selecting layer: the index-score walk's counters read 0 / 0
+    assert stats["index_pages_walked"] == stats["index_pages_table"] == 0
     eng = srv._progs.eng if srv._progs else None
     assert stats["state_bytes_per_slot"] == eng.slot_state_bytes() > 0
 

@@ -213,6 +213,86 @@ def test_layered_pools_keep_the_declared_layout(layered_reports, which):
         assert lay.split("{")[1].startswith("3,2,1,0"), lay
 
 
+def test_layered_step_scores_its_keys_where_they_lie(layered_reports,
+                                                     layered_progs):
+    """The TPU lowering of the step holds the index-score kernel, one
+    custom call a selecting layer named ``mx_index_scores`` (a Mosaic call:
+    the view form the CPU lowers cannot pass), and neither the gathered
+    ``(S, T, 128)`` key view nor the ``(S, J, T)`` float32 score block; a
+    chunk of ``C`` queries keeps the view form and holds no such kernel."""
+    step, chunk = layered_reports["step"], layered_reports["chunk"]
+    scoring = [k for k in step["kernels"] if k.startswith("mx_index_scores")]
+    assert len(scoring) == len(layered_progs.eng.full) == 2, step["kernels"]
+    assert step["view_sized"] == []
+    assert not [k for k in chunk["kernels"] if k.startswith("mx_index")]
+
+
+@pytest.mark.parametrize("which,rows", [("step", "slots"), ("chunk", 64)])
+def test_layered_selection_gathers_nothing(layered_reports, layered_progs,
+                                           which, rows):
+    """The selection gathers nothing.  Before PR 37 ``mask_positions`` and
+    its caller fetched, index by index, the chosen blocks' mask rows out of
+    ``(N, blocks, 128)``, the blocks' counts out of ``(N, blocks)`` and
+    ``seen`` ``(N, T)`` at the positions (the chip's compiler rewrites such
+    a gather and drops its provenance, so they are told by what they
+    gather FROM, in any region): none of them is left, for the step's one
+    query a slot or a chunk's 64.  Under ``mx.index`` the step's TPU
+    lowering holds no ``gather`` at all — the rotation's even and odd
+    lanes are strided slices — and a chunk's only one is the key view's,
+    out of the index-key pool.  The latent rows of the selected positions
+    are still gathered, under their own region."""
+    report = layered_reports[which]
+    N = L_SLOTS if rows == "slots" else rows
+    T = layered_progs.maxp * PAGE
+    gone = {f"pred[{N},{T}]", f"pred[{N},1,{T}]", f"pred[1,{N},{T}]",
+            f"pred[{N},{T // 128},128]", f"s32[{N},{T // 128}]"}
+    sources = {t for found in report["gathers"].values() for t in found}
+    assert not gone & sources, report["gathers"]
+    assert report["gathers"]["mx.latent_gather"]
+    pool = f"bf16[2,{L_PAGES},{PAGE},128]"
+    assert set(report["gathers"].get("mx.index", [])) == (
+        set() if which == "step" else {pool}), report["gathers"]
+
+
+def test_index_key_pool_enters_the_kernel_as_declared(chip, layered_progs):
+    """The index-key pool is an operand of the custom call whole, in the
+    layout the program declares (minor to major ``{3,2,1,0}``): what the
+    in-place scatter of the new keys hands on, no copy of it between."""
+    import re
+
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from tools import rehearse_serve as rs
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = rs.compile_step(layered_progs, chip).as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    pool = f"bf16[2,{L_PAGES},{PAGE},128]"
+    calls = [line for line in text.splitlines()
+             if " custom-call(" in line and "mx_index_scores" in
+             line.split(" = ")[0]]
+    assert len(calls) == 2
+    by_name = {m.group(1): m.group(2) for m in re.finditer(
+        r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = \S+ ([a-z\-]+)\(", text, re.M)}
+    for line in calls:
+        constraints = line.split("operand_layout_constraints=")[1].split(
+            "backend_config")[0]
+        assert pool + "{3,2,1,0}" in constraints, constraints
+        operands = re.search(r"custom-call\((.*?)\), custom_call_target",
+                             line).group(1)
+        last = operands.split(",")[-1].strip().lstrip("%")
+        # the pool operand is the scatter that wrote the new keys (or the
+        # parameter itself), never a copy
+        assert by_name.get(last) in ("fusion", "scatter", "parameter",
+                                     "get-tuple-element"), (last, by_name.get(last))
+
+
 @pytest.mark.parametrize("which", ["step", "chunk", "admit_hit"])
 def test_layered_scratch_is_no_second_pool(layered_reports, which):
     """The scratch stays under a quarter of the SMALLEST pool (the index

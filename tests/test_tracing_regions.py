@@ -128,17 +128,33 @@ def test_region_is_the_innermost_mx_component(path, region):
     ("mx_paged_attention.8",
      "jit(step)/mx.dense/while/body/mx.attn/mx_paged_attention/pallas_call",
      "mx.attn"),
+    ("mx_index_scores.3", "", "mx.index"),
+    ("mx_index_scores",
+     "jit(step)/mx.dense/mx.index/cond/branch_0_fun/mx_index_scores/"
+     "pallas_call", "mx.index"),
+    ("mx_paged_attention_window.2", "", "mx.window_attn"),
+    ("mx_ssm_update.4", "", "mx.ssm_state"),
     ("fusion.170", "", "unscoped"),
 ], ids=["grouped_product", "paged_attention", "flash_fwd", "flash_bwd_dq",
         "flash_bwd_dkv", "flash_fwd_differentiated",
         "flash_bwd_dq_differentiated", "packed_fwd", "packed_bwd_dq",
         "packed_bwd_dkv", "packed_fwd_differentiated",
-        "packed_bwd_dkv_differentiated", "provenance_wins", "other"])
+        "packed_bwd_dkv_differentiated", "provenance_wins", "index_scores",
+        "index_scores_provenance", "ring_walk", "ssm_update", "other"])
 def test_region_of_a_kernel_known_by_name(name, provenance, region):
     """A custom kernel whose device events carry no provenance is known by
     a part of its operation's name (a differentiated program wraps a
     ``pallas_call``'s name: ``jvp_mx_flash_fwd_``)."""
     assert profiler_xla.region_of(provenance, name) == region
+
+
+def test_no_kernel_name_hides_a_later_one():
+    """``region_of`` takes the FIRST listed part a name holds: a part that
+    another listed part holds must come after it."""
+    parts = [part for part, _ in profiler_xla._KERNEL_REGIONS]
+    for i, early in enumerate(parts):
+        for late in parts[i + 1:]:
+            assert early not in late, (early, late)
 
 
 def _two_executables(make_xspace):

@@ -402,6 +402,9 @@ def test_served_streams_match_reference(tiny):
     c = st["counters"]
     assert c["admit_dispatches"] >= 1 and c["chunk_dispatches"] >= 2
     assert c["step_dispatches"] == st["steps"]
+    # no selecting layer: the index-score walk's counters read 0 / 0 / 0
+    assert (st["index_pages_walked"], st["index_copies"],
+            st["index_pages_table"]) == (0, 0, 0)
     # the pools' bytes follow the declaration: both tables' pages and the
     # slots' scalar columns
     progs = srv._progs

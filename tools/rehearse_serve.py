@@ -174,7 +174,20 @@ def pool_report(compiled, progs):
     custom calls (the paged-attention kernel of a step that walks its
     pages) and ``view_sized`` every instruction, fused ones too, whose
     result is one layer's view of every slot: ``(S, T, KV·D)``, or ``(S,
-    MAXP, page, KV·D)`` as the gather hands it over."""
+    MAXP, page, KV·D)`` as the gather hands it over.  For a model with a
+    selecting attention the views are its indexer's: the gathered key view
+    ``(S, T, lanes)`` (or ``(S·MAXP, page, lanes)``) and the float32 score
+    block ``(S, [1,] J, T)`` of one query a slot, which the step's
+    index-score kernel leaves out (a chunk's block has its ``C`` queries
+    in its shape and is not one).  ``gathers`` lists every ``gather``,
+    fused ones too, as ``{region: [type of what it gathers from]}``, the
+    region the innermost ``mx.*`` scope of its provenance
+    (``profiler_xla.region_of``; ``unscoped`` where the chip's compiler
+    rewrote a small one and dropped its provenance, so tell those by what
+    they gather from): the chip walks a gather's indices one by one, so a
+    wide one inside a step is a finding."""
+    from mxnet_tpu.profiler_xla import region_of
+
     shapes = pool_shapes(progs)
     dims = ["[" + ",".join(str(d) for d in shape) + "]" for shape in shapes]
     n_pools = {math.prod(shape) for shape in shapes}
@@ -183,6 +196,14 @@ def pool_report(compiled, progs):
     views = () if progs.layered else (
         f"[{progs.S},{progs.Tp},{shapes[0][-1]}]",
         f"[{progs.S},{progs.maxp},{progs.page},{shapes[0][-1]}]")
+    if progs.layered and progs.eng.full:
+        S, T = progs.S, progs.maxp * progs.page
+        lanes = progs.eng.rows["index_key"]
+        J = progs.eng.desc[progs.eng.full[0]]["attn"]["index_heads"]
+        views = (f"[{S},{T},{lanes}]",
+                 f"[{S},{progs.maxp},{progs.page},{lanes}]",
+                 f"[{S * progs.maxp},{progs.page},{lanes}]",
+                 f"f32[{S},1,{J},{T}]", f"f32[{S},{J},{T}]")
     text = compiled.as_text()
     entry = re.search(r"entry_computation_layout=\{\((.*?)\)->", text)
     layouts = sorted({m for d in dims for m in re.findall(
@@ -190,6 +211,7 @@ def pool_report(compiled, progs):
         entry.group(1) if entry else "")})
     roots, found, comp = {}, [], None
     kernels, view_sized, copy_bytes = [], [], {}
+    types, gathered = {}, []
     for line in text.splitlines():
         c = _COMPUTATION.match(line)
         if c is not None:
@@ -202,6 +224,12 @@ def pool_report(compiled, progs):
             roots[comp] = m.group("op")
         sizes = [math.prod(int(d) for d in a.split(",") if d)
                  for a in _ARRAY.findall(m.group("type"))]
+        types[m.group("name")] = m.group("type")
+        if m.group("op") == "gather":
+            source = re.search(r" gather\(%?([\w.\-]+)", line)
+            where = re.search(r'op_name="([^"]*)"', line)
+            gathered.append((region_of(where.group(1) if where else ""),
+                             source.group(1)))
         if 'custom_call_target="tpu_custom_call"' in line:
             kernels.append(m.group("name"))
         if any(v in m.group("type") for v in views):
@@ -222,6 +250,10 @@ def pool_report(compiled, progs):
         if op == "fusion":
             op = f"fusion:{roots.get(called, '?')}"
         sized.setdefault(op, []).append(name)
+    gathers = {}
+    for region, source in gathered:
+        gathers.setdefault(region, []).append(
+            types.get(source, source).split("{")[0])
     ma = compiled.memory_analysis()
     return {"temp_bytes": ma.temp_size_in_bytes,
             "argument_bytes": ma.argument_size_in_bytes,
@@ -232,7 +264,8 @@ def pool_report(compiled, progs):
             "pool_sized": sized,
             "copy_bytes": copy_bytes,
             "kernels": kernels,
-            "view_sized": view_sized}
+            "view_sized": view_sized,
+            "gathers": gathers}
 
 
 def main(argv=None):
