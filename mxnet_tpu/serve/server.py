@@ -118,7 +118,8 @@ serve_counters = {"step_dispatches": 0, "admit_dispatches": 0,
                   "prefix_hits": 0, "cow_copies": 0,
                   "chunk_dispatches": 0, "verify_dispatches": 0,
                   "draft_proposed": 0, "draft_accepted": 0,
-                  "draft_rejected": 0}
+                  "draft_rejected": 0, "hit_dispatches": 0,
+                  "admit_rows": 0, "admit_tokens": 0}
 _counters_lock = threading.Lock()
 _server_seq = itertools.count()
 
@@ -140,12 +141,23 @@ class _CounterView(MutableMapping):
     so benchmarks/tests keep reading ``srv.counters["step_dispatches"]``
     while exporters see the same numbers in ``telemetry.snapshot()`` /
     ``render_prometheus()``.  Assignment (the reset path) writes the
-    backing counter; iteration order is the historical key order."""
+    backing counter; iteration order is the historical key order.
+
+    Admission's own: ``hit_dispatches`` (prefix-hit admission dispatches;
+    ``prefix_hits`` counts hit ROWS and partial hits), ``admit_rows``
+    (token positions the admission dispatches compute: ``A x P`` a wave,
+    ``C`` a chunk, none a hit) and ``admit_tokens`` (the real prompt
+    tokens among them); ``compiles`` / ``compile_ms`` (this server's pool
+    executables compiled, and their wall milliseconds: the compile watch
+    counts them by the site's ``server`` field, whatever the event ring
+    still holds)."""
 
     _KEYS = ("step_dispatches", "admit_dispatches", "sync_requests",
              "pool_grows", "prefix_hits", "cow_copies",
              "chunk_dispatches", "verify_dispatches",
-             "draft_proposed", "draft_accepted", "draft_rejected")
+             "draft_proposed", "draft_accepted", "draft_rejected",
+             "hit_dispatches", "admit_rows", "admit_tokens",
+             "compiles", "compile_ms")
 
     def __init__(self, server_label):
         self._c = {k: telemetry.counter(f"serve_{k}_total",
@@ -1943,8 +1955,8 @@ class DecodeServer:
             k = min(npb, len(row))
             pages[i, :k] = row[:k]
             zmain[i, :len(row)] = row
-        # request-span admission fields + one serve_admit event per
-        # dispatch (waves are step-boundary-rare, not per-token)
+        # request-span admission fields (waves are step-boundary-rare,
+        # not per-token)
         now = time.perf_counter()
         S = len(self._slots)
         busy = sum(r is not None for r in self._slots)
@@ -1956,12 +1968,10 @@ class DecodeServer:
                             a_bucket=A, p_bucket=P,
                             occupancy_at_admit=occ, admit_seq=seq)
             self._tele["wait"].observe(wait)
-        telemetry.emit("serve_admit", server=self.telemetry_label,
-                       wave=len(wave), a_bucket=A, p_bucket=P,
-                       pool=S, occupancy=round(occ, 4))
+        ntok = sum(int(req.prompt.size) for _, req in wave)
         param_vals, q8, sw = self._progs.operands
         self._phase("mx:serve:admit", seq=seq, wave=len(wave),
-                    a_bucket=A, p_bucket=P,
+                    a_bucket=A, p_bucket=P, rows=A * P, tokens=ntok,
                     requests=[r.stream.request_id for _, r in wave])
         new_state, (first, done) = fn(param_vals, prompts, meta, dls,
                                       pages, zpages, *self._state)
@@ -1973,6 +1983,8 @@ class DecodeServer:
             self._state = None
             return
         self._count("admit_dispatches")
+        self._count("admit_rows", A * P)
+        self._count("admit_tokens", ntok)
         if self._progs.slot_kinds:
             self._state_resets += len(wave)
         self._inflight.append(("admit", (first, done), list(wave), seq))
@@ -2186,11 +2198,6 @@ class DecodeServer:
         self._slot_pages[slot] = list(shared) + owned
         if m:
             self._count("prefix_hits")
-            telemetry.emit("prefix_cache_hit",
-                           server=self.telemetry_label,
-                           request_id=req.stream.request_id,
-                           shared_pages=m, cow_copy=False,
-                           partial=True)
         return {"mode": "chunk", "req": req, "slot": slot,
                 "off": m * PG, "zero": owned}
 
@@ -2268,18 +2275,16 @@ class DecodeServer:
                             a_bucket=A, p_bucket=0,
                             occupancy_at_admit=occ, admit_seq=seq)
             self._tele["wait"].observe(wait)
-            telemetry.emit("prefix_cache_hit",
-                           server=self.telemetry_label,
-                           request_id=req.stream.request_id,
-                           shared_pages=plan["shared"],
-                           cow_copy=plan["src"] >= 0, partial=False)
+        # no model forward: a hit computes no token positions
         self._phase("mx:serve:admit_hit", seq=seq, wave=len(hits),
-                    a_bucket=A, p_bucket=0,
+                    a_bucket=A, p_bucket=0, rows=0, tokens=0,
                     requests=[p["req"].stream.request_id for p in hits])
         new_state = fn(meta, dls, srcs, dsts, zpages, *self._state)
         self._state = new_state
         if self._torn:
             self._state = None
+            return
+        self._count("hit_dispatches")
 
     def _pump_chunks(self):
         """Advance every mid-prefill request by ONE chunk dispatch per
@@ -2360,8 +2365,8 @@ class DecodeServer:
             zrow[:len(zero)] = zero
         param_vals, q8, sw = self._progs.operands
         seq = self._next_seq()
-        self._phase("mx:serve:chunk", seq=seq, c_bucket=C,
-                    requests=[req.stream.request_id])
+        self._phase("mx:serve:chunk", seq=seq, c_bucket=C, rows=C,
+                    tokens=ntok, requests=[req.stream.request_id])
         new_state, (first, done) = fn(param_vals, q8, sw, toks, meta, dl,
                                       ptrow, zrow, *self._state)
         self._state = new_state
@@ -2369,6 +2374,8 @@ class DecodeServer:
             self._state = None
             return True
         self._count("chunk_dispatches")
+        self._count("admit_rows", C)
+        self._count("admit_tokens", ntok)
         if self._progs.slot_kinds and off == 0:
             self._state_resets += 1
         rec["off"] = off + ntok

@@ -10,7 +10,11 @@ compile + first dispatch) and the cache size after (``cache_size > 1``
 is a RETRACE — the regression class tests/test_fused_step.py and
 tests/test_serve.py pin, now visible in production streams).  The
 registry mirrors the stream: ``compiles_total{site=}`` and
-``retraces_total{site=}``.
+``retraces_total{site=}``.  A site whose ``fields`` name a ``server`` (the
+serve pool programs) also counts into that server's
+``serve_compiles_total{server=}`` and ``serve_compile_ms_total{server=}``
+(``DecodeServer.counters["compiles"]`` / ``["compile_ms"]``): exact however
+many events the ring has let go since.
 
 Steady-state cost per dispatch: two ``_cache_size()`` calls (a C++
 attribute read) + one ``perf_counter`` pair — noise against even a
@@ -110,6 +114,11 @@ class _CompileWatch:
                     if ma is not None:
                         ev.update((f"mem_{k}", v) for k, v in ma.items())
         REGISTRY.counter("compiles_total", site=self._site).inc()
+        server = self._fields.get("server")
+        if server is not None:
+            REGISTRY.counter("serve_compiles_total", server=server).inc()
+            REGISTRY.counter("serve_compile_ms_total",
+                             server=server).inc(wall * 1e3)
         if retrace:
             REGISTRY.counter("retraces_total", site=self._site).inc()
         events.emit("compile", **ev)

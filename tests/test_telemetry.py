@@ -438,7 +438,9 @@ class TestServeCounterView:
                           "sync_requests", "pool_grows", "prefix_hits",
                           "cow_copies", "chunk_dispatches",
                           "verify_dispatches", "draft_proposed",
-                          "draft_accepted", "draft_rejected"}
+                          "draft_accepted", "draft_rejected",
+                          "hit_dispatches", "admit_rows", "admit_tokens",
+                          "compiles", "compile_ms"}
         v.inc("step_dispatches")
         v["step_dispatches"] += 2        # MutableMapping read-modify
         assert v["step_dispatches"] == 3
@@ -498,8 +500,6 @@ def _serve_stream(step_dispatches=10, steps=10, retrace=False):
         {"ts": 1.2, "kind": "compile", "site": "serve.admit",
          "server": "s0", "pool": 2, "a_bucket": 1, "p_bucket": 8,
          "wall_s": 0.4, "cache_size": 1},
-        {"ts": 1.3, "kind": "serve_admit", "server": "s0", "wave": 1,
-         "a_bucket": 1, "p_bucket": 8, "pool": 2, "occupancy": 0.5},
         {"ts": 1.4, "kind": "serve_request", "server": "s0",
          "request_id": 0, "reason": "max_len", "tokens": 5,
          "ttft_s": 0.01, "queue_wait_s": 0.001, "wave": 1,
