@@ -460,11 +460,7 @@ class LayeredEngine:
         walks counted (nothing where the step gathers the key view)."""
         out = {}
         if "expert" in aux:
-            n = next(d["ffn"]["held"][1] for d in self.desc
-                     if d["ffn"]["kind"] == "routed")
-            hit = (aux["expert"][..., None] == jnp.arange(n)) \
-                & active[None, :, None, None]
-            out["expert_load"] = jnp.sum(hit, axis=(1, 2)).astype(jnp.int32)
+            out["expert_load"] = self._expert_load(aux["expert"], active)
         if "selected" in aux:
             sel = aux["selected"][:, :, 0]
             out["selected"] = jnp.sum(jnp.where(active[None], sel, 0))
@@ -474,6 +470,16 @@ class LayeredEngine:
             out["index_walk"] = jnp.sum(jnp.where(
                 active[None, :, None], aux["index_walk"], 0), axis=(0, 1))
         return out
+
+    def _expert_load(self, expert, rows):
+        """``(routed layers, held)`` int32: the tokens each held expert of
+        each routed layer got from the rows ``rows`` ``(R,)`` of ``expert``
+        ``(routed layers, R, top_k)`` (local ids, -1 where another chip's)."""
+        n = next(d["ffn"]["held"][1] for d in self.desc
+                 if d["ffn"]["kind"] == "routed")
+        hit = (expert[..., None] == jnp.arange(n)) \
+            & rows[None, :, None, None]
+        return jnp.sum(hit, axis=(1, 2)).astype(jnp.int32)
 
     # -- the programs' bodies ------------------------------------------- #
     def pool_token_paged(self, x_tok, pos, kp, vp, pt, page, sw=None,
@@ -496,11 +502,17 @@ class LayeredEngine:
         offset 0 and from what the last chunk stored after it."""
         tables = tuple(t[None] for t in ptrow) \
             if isinstance(ptrow, tuple) else ptrow[None]
-        logits, kp, vp, _ = self.tokens_paged(
+        logits, kp, vp, aux = self.tokens_paged(
             self.model.weights(), toks[None], off[None], tables, (kp, vp),
             page, nlast[None], key_pages=key_pages,
             slots=None if slot is None else slot[None])
-        return logits, kp, vp
+        if "expert" not in aux:
+            return logits, kp, vp
+        # the experts' load over EVERY row the chunk computes: the rows
+        # past the prompt route and run through the experts as well
+        every = jnp.ones((toks.shape[0],), jnp.bool_)
+        return logits, kp, vp, {
+            "expert_load": self._expert_load(aux["expert"], every)}
 
     def admit_tokens(self, prompts, last, tables, page, kp, vp, slots=None):
         """An admission wave: ``(A, P)`` right-padded prompts from offset

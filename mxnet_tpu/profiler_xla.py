@@ -227,7 +227,11 @@ def _device_planes(data):
 # ``mx_flash_bwd_dq``, ``mx_flash_bwd_dkv``), whose operations a
 # differentiated program names ``jvp_mx_flash_fwd_[.n]`` and
 # ``transpose_jvp_mx_flash_bwd_dq__[.n]`` — hence a part, not the start.
-_KERNEL_REGIONS = (("ragged-dot", "mx.moe_experts"),
+# Since PR 39 a TPU lowering of the routed experts' two products is ONE
+# ``pallas_call``, ``mx_moe_gmm`` (``ops/grouped_matmul.py``), under
+# ``mx.moe_experts`` too; shapes it takes no row tile for keep ragged-dot.
+_KERNEL_REGIONS = (("mx_moe_gmm", "mx.moe_experts"),
+                   ("ragged-dot", "mx.moe_experts"),
                    # the same kernel walking a window layer's ring: its
                    # name holds the shorter one, so it comes first
                    ("mx_paged_attention_window", "mx.window_attn"),

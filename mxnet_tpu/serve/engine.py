@@ -821,7 +821,7 @@ class PoolPrograms:
             # final one)
             where = {"slot": slot} if self.slot_kinds else {}
             with _TRACE_LOCK, params_swapped(deng.params, param_vals):
-                logits, kp, vp = deng.chunk_tokens(
+                logits, kp, vp, *counted = deng.chunk_tokens(
                     toks, off, nlast, ptrow, page, kp, vp, sw, q8, **bound,
                     **where)
                 first = self._sample_slots(key1[None], logits,
@@ -841,7 +841,9 @@ class PoolPrograms:
             dl = dl.at[tgt].set(dls, mode="drop")
             spec = spec.at[tgt].set(spec_d, mode="drop")
             new_state = (kp, vp, pos, tok, active, stop, keys, dl, spec)
-            return new_state, (first, done)
+            # an engine that counts what a chunk routed (experts' load)
+            # hands it back beside the first token: a third readback
+            return new_state, (first, done) + tuple(counted)
 
         fn = telemetry.instrument_jit(
             jax.jit(chunk,

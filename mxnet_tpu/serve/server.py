@@ -119,7 +119,8 @@ serve_counters = {"step_dispatches": 0, "admit_dispatches": 0,
                   "chunk_dispatches": 0, "verify_dispatches": 0,
                   "draft_proposed": 0, "draft_accepted": 0,
                   "draft_rejected": 0, "hit_dispatches": 0,
-                  "admit_rows": 0, "admit_tokens": 0}
+                  "admit_rows": 0, "admit_tokens": 0,
+                  "chunk_expert_tokens": 0, "chunk_experts_touched": 0}
 _counters_lock = threading.Lock()
 _server_seq = itertools.count()
 
@@ -150,14 +151,19 @@ class _CounterView(MutableMapping):
     tokens among them); ``compiles`` / ``compile_ms`` (this server's pool
     executables compiled, and their wall milliseconds: the compile watch
     counts them by the site's ``server`` field, whatever the event ring
-    still holds)."""
+    still holds).  A model with routed experts counts what its chunks
+    routed: ``chunk_expert_tokens`` ((row, held expert) pairs over every
+    row a chunk computes, padding included) and ``chunk_experts_touched``
+    ((routed layer, held expert) cells that got a row: the experts'
+    weights a chunk has to read)."""
 
     _KEYS = ("step_dispatches", "admit_dispatches", "sync_requests",
              "pool_grows", "prefix_hits", "cow_copies",
              "chunk_dispatches", "verify_dispatches",
              "draft_proposed", "draft_accepted", "draft_rejected",
              "hit_dispatches", "admit_rows", "admit_tokens",
-             "compiles", "compile_ms")
+             "compiles", "compile_ms", "chunk_expert_tokens",
+             "chunk_experts_touched")
 
     def __init__(self, server_label):
         self._c = {k: telemetry.counter(f"serve_{k}_total",
@@ -965,6 +971,10 @@ class DecodeServer:
         self._prompt_tokens = self._prompt_cached = 0
         self._state_resets = 0  # slots started from zero per-slot state
         self._step_sums = {}    # the step's own counters, added up
+        # a chunk's counters (its readback, in seq order) until a later
+        # dispatch's readback says the device is past it; then added up
+        self._chunk_pending, self._chunk_sums = [], {}
+        self._chunk_lock = threading.Lock()
         self._prefix = _PrefixIndex(
             self._progs.page, self._pages, self._wpages,
             self._wback + 1 if windowed else 0) \
@@ -1169,6 +1179,7 @@ class DecodeServer:
         (``benchmark/serve_bench.py`` warms the whole admission-bucket
         ladder) reports the window's own occupancy, undiluted by the
         warm-up's idle lanes."""
+        self._fold_chunk_counters()
         for k in self.counters:
             self.counters[k] = 0
         self._steps = 0
@@ -1254,6 +1265,7 @@ class DecodeServer:
             "prompt_tokens": self._prompt_tokens,
             "prompt_tokens_cached": self._prompt_cached,
             **self._step_stats(),
+            **self._chunk_stats(),
             "counters": dict(self.counters),
             "ttft": self._tele["ttft"].summary(),
             "token_gap": self._tele["gap"].summary(),
@@ -1282,6 +1294,42 @@ class DecodeServer:
         for k in _INDEX_WALK_KEYS:
             out[k] = t.get(k, 0)
         return out
+
+    def _chunk_stats(self):
+        """What the chunks counted themselves (a model with routed
+        experts), summed over every chunk dispatched so far: (row, held
+        expert) pairs a (routed layer, held expert) cell, and the share of
+        those cells a chunk touched.  Folds in the chunks still pending
+        (their readback waits at most for the newest chunk)."""
+        self._fold_chunk_counters()
+        t = self._chunk_sums
+        if not t.get("cells"):
+            return {}
+        return {"chunk_moe_tokens_per_expert": t["tokens"] / t["cells"],
+                "chunk_moe_experts_touched_share": t["touched"] / t["cells"]}
+
+    def _fold_chunk_counters(self, before=None):
+        """Add up the pending chunk counters dispatched before the dispatch
+        ``before`` (a seq; every one where ``None``): the device runs the
+        dispatches in order, so once a later one's readback is in hand
+        theirs is too."""
+        with self._chunk_lock:
+            due = [c for seq, c in self._chunk_pending
+                   if before is None or seq < before]
+            self._chunk_pending = [(seq, c) for seq, c in self._chunk_pending
+                                   if before is not None and seq >= before]
+        # read back outside the lock: a ``stats()`` from another thread may
+        # wait here for the newest chunk, the scheduler's next append not
+        loads = [onp.asarray(c["expert_load"]) for c in due]
+        with self._chunk_lock:
+            t = self._chunk_sums
+            for load in loads:
+                tokens, touched = int(load.sum()), int((load > 0).sum())
+                self._count("chunk_expert_tokens", tokens)
+                self._count("chunk_experts_touched", touched)
+                for k, v in (("tokens", tokens), ("touched", touched),
+                             ("cells", load.size)):
+                    t[k] = t.get(k, 0) + v
 
     def _add_step_counters(self, c):
         t = self._step_sums
@@ -2367,9 +2415,12 @@ class DecodeServer:
         seq = self._next_seq()
         self._phase("mx:serve:chunk", seq=seq, c_bucket=C, rows=C,
                     tokens=ntok, requests=[req.stream.request_id])
-        new_state, (first, done) = fn(param_vals, q8, sw, toks, meta, dl,
-                                      ptrow, zrow, *self._state)
+        new_state, (first, done, *counted) = fn(
+            param_vals, q8, sw, toks, meta, dl, ptrow, zrow, *self._state)
         self._state = new_state
+        if counted:
+            with self._chunk_lock:
+                self._chunk_pending.append((seq, counted[0]))
         if self._torn:
             self._state = None
             return True
@@ -2598,6 +2649,9 @@ class DecodeServer:
                                 freed = True
                         if freed:
                             self._free_slot_pages(slot)
+            if self._chunk_pending:
+                # this readback is in hand: every chunk before it is done
+                self._fold_chunk_counters(seq)
         return worked
 
     def _route_verify(self, arrays, meta, seq):

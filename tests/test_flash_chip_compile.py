@@ -117,3 +117,32 @@ def test_packed_kernel_compiles_for_v5e(one_chip, shape, kernel):
                 qkv, None, None, g, lse, delta, scale, True, heads=H,
                 into=into), qkv, g, row, row, qkv)
     assert f"mx_flash_{kernel}_qkv" in compiled.as_text()
+
+
+# the routed experts' grouped-product kernel (ISSUE 39) at the serve cells'
+# four shapes: (rows M = N x top_k, H, I, groups): a question chunk's and a
+# decode step's, ``trinity``'s over a stacked run of two layers
+MOE = {
+    "trinity_step": (96, 3072, 3072, 64),
+    "dots3_step": (256, 5120, 1536, 32),
+    "trinity_chunk": (512, 3072, 3072, 64),
+    "dots3_chunk": (1024, 5120, 1536, 32),
+}
+
+
+@pytest.mark.parametrize("shape", list(MOE))
+def test_moe_kernel_compiles_for_v5e(one_chip, shape):
+    """``mx_moe_gmm``: weight blocks of about 4 MB double-buffered beside a
+    row tile, its float32 output tile and three scratches, under the VMEM
+    limit the call asks for; bfloat16 products at ``DEFAULT`` precision
+    (the framework's ``highest`` default would fail with "Bad lhs
+    type")."""
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import grouped_matmul as gm
+    M, H, I, G = MOE[shape]
+    compiled = _compile(
+        one_chip, lambda xs, wgu, wd, sizes, base: gm.grouped_swiglu(
+            xs, wgu, wd, sizes, base),
+        ((M, H), jnp.bfloat16), ((G, H, 2 * I), jnp.bfloat16),
+        ((G, I, H), jnp.bfloat16), ((32,), jnp.int32), ((), jnp.int32))
+    assert gm._NAME in compiled.as_text()

@@ -476,7 +476,8 @@ def test_window_step_walks_both_tables_in_the_kernel(window_reports):
     ring = [k for k in walks if k.startswith("mx_paged_attention_window")]
     # three runs: dense sliding, routed sliding x 3 (one scan), full
     assert len(walks) == 3 and len(ring) == 2, kernels
-    assert any(k.startswith("ragged-dot") for k in kernels)
+    # the routed experts' two products: one kernel a run (PR 39)
+    assert any(k.startswith("mx_moe_gmm") for k in kernels)
     assert not any(k.startswith("mx_paged_attention")
                    for k in window_reports["chunk"]["kernels"])
 

@@ -440,7 +440,8 @@ class TestServeCounterView:
                           "verify_dispatches", "draft_proposed",
                           "draft_accepted", "draft_rejected",
                           "hit_dispatches", "admit_rows", "admit_tokens",
-                          "compiles", "compile_ms"}
+                          "compiles", "compile_ms", "chunk_expert_tokens",
+                          "chunk_experts_touched"}
         v.inc("step_dispatches")
         v["step_dispatches"] += 2        # MutableMapping read-modify
         assert v["step_dispatches"] == 3
