@@ -20,6 +20,7 @@ from .dots3 import Dots3, Dots3Config, dots3_tiny
 from .trinity import Trinity, TrinityConfig, trinity_tiny
 from .granite_hybrid import (GraniteHybrid, GraniteHybridConfig,
                              granite_hybrid_tiny)
+from .pangu_moe import PanguUltraMoE, PanguUltraMoEConfig, pangu_tiny
 from .seq2seq import (CrossAttention, Seq2SeqEncoder, Seq2SeqDecoder,
                       Seq2SeqDecoderCell, TransformerSeq2Seq)
 
@@ -34,5 +35,6 @@ __all__ = [
     "Dots3", "Dots3Config", "dots3_tiny",
     "Trinity", "TrinityConfig", "trinity_tiny",
     "GraniteHybrid", "GraniteHybridConfig", "granite_hybrid_tiny",
+    "PanguUltraMoE", "PanguUltraMoEConfig", "pangu_tiny",
     "kv_generate", "decode_mode", "decode_step_program",
 ]

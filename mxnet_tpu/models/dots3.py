@@ -122,7 +122,7 @@ class Dots3Config:
                     "v": self.v_head_dim, "theta": float(self.rope_theta),
                     "index_heads": self.index_n_heads,
                     "index_dim": self.index_head_dim,
-                    "topk": self.index_topk}
+                    "topk": self.index_topk, "gate": True}
         return {"kind": "latent_window",
                 "heads": self.swa_num_attention_heads,
                 "q_rank": self.swa_q_lora_rank,
@@ -131,7 +131,7 @@ class Dots3Config:
                 "rope": self.swa_qk_rope_head_dim,
                 "v": self.swa_v_head_dim,
                 "theta": float(self.swa_rope_theta),
-                "window": self.sliding_window_size}
+                "window": self.sliding_window_size, "gate": True}
 
     def ffn(self, layer):
         if layer < self.first_k_dense_replace:

@@ -18,6 +18,13 @@ Kinds implemented here:
   positions; cache kind ``latent_window``: one latent row under the WINDOW
   page table, a ring of ``ring`` entries a slot indexed by ``(position //
   page) % ring``, so a slot holds pages for its window only;
+- attention ``latent``: latent attention in the absorbed form over EVERY
+  position ``s <= t`` (no indexer, no window); cache kind ``latent``: the
+  latent row alone under the MAIN page table.  The single-query step walks
+  each slot's pages in place (``ops.latent_attention``: one read of a row
+  serves every head, as key and as value); a prefill reads the rows its
+  queries can reach back through the table into the masked dense form
+  (``_attend_dense``);
 - attention ``ssm``: a Mamba-2 state-space mixer (``ops.ssd``); cache kind
   ``ssm_state`` under the SLOT table: the recurrent state and the
   convolution's tail, one entry a slot addressed by the slot's own index,
@@ -36,10 +43,11 @@ Kinds implemented here:
   through a masked dense form;
 - feed-forward ``swiglu`` and ``routed`` (``ops.moe``).
 
-A layer of the stacked-runs body may also declare ``post_norms`` (an RMSNorm
-AFTER each sub-block, before it joins the stream: four norms a layer) and
-``residual`` (a multiplier on what joins); the model an untied ``head`` and
-an ``embedding_multiplier``.
+A layer of either body may declare ``post_norms`` (an RMSNorm AFTER each
+sub-block, before it joins the stream: four norms a layer); a latent layer
+``gate`` (a head-wise sigmoid gate on its output); a layer of the
+stacked-runs body ``residual`` (a multiplier on what joins); the model an
+untied ``head`` and an ``embedding_multiplier``.
 
 A model whose ``weights()`` come as ``runs`` — the parameters of each maximal
 run of like layers stacked along a leading axis — has each run SCANNED
@@ -66,6 +74,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import index_scores as _index
+from ..ops import latent_attention as _latent
 from ..ops import moe, ssd
 from ..ops import paged_attention as _paged
 from ..serve.schema import pool_rows, row_lanes
@@ -131,6 +140,15 @@ def _table_pages(table, lp, sentinel):
     W = table.shape[1]
     pg = jnp.take_along_axis(table, jnp.clip(lp, 0, W - 1), axis=1)
     return jnp.where((lp >= 0) & (lp < W), pg, sentinel)
+
+
+def _reach_rows(lat, fi, reach):
+    """Layer ``fi``'s rows of the pages ``reach`` ``(B, n)`` (ids in the
+    pool) of the latent pool, one view a row: ``(B, n * page, lanes)``."""
+    B, n = reach.shape
+    with jax.named_scope("mx.latent_gather"):
+        return lat.at[fi, reach].get(mode="promise_in_bounds").reshape(
+            B, n * lat.shape[2], lat.shape[3])
 
 
 def _slot_rows(arr, slots):
@@ -289,7 +307,7 @@ class LayeredEngine:
     # two latent branches).  A model is served by one body, so it names
     # kinds of one body only; a kind the table lacks raises at build
     _KINDS = {"ssm": "_ssm_mixer", "gqa": "_gqa_mixer",
-              "latent_sparse": None, "latent_window": None}
+              "latent_sparse": None, "latent_window": None, "latent": None}
 
     def __init__(self, model, B, P, total, temperature=0.0, top_k=0,
                  prefill="batched", weights="native"):
@@ -321,7 +339,10 @@ class LayeredEngine:
         self.stacked = all(self._KINDS[k] for k in kinds)
         of_kind = lambda kind: [i for i, d in enumerate(self.desc)
                                 if d["cache"] == kind]
-        self.full, self.win = of_kind("latent_index"), \
+        # every layer with a latent row under the main table; of those the
+        # selecting ones keep an index-key row beside it
+        self.full = sorted(of_kind("latent_index") + of_kind("latent"))
+        self.idx, self.win = of_kind("latent_index"), \
             of_kind("latent_window")
         self.kv, self.ssm = of_kind("kv"), of_kind("ssm_state")
         self.kvw = of_kind("kv_window")
@@ -337,14 +358,17 @@ class LayeredEngine:
             self.runs.append((i, n))
             i += n
         if not self.full:
-            # no selecting attention: no dense form, no key-page bound
+            # no latent attention over the main table: no dense form, no
+            # key-page bound
             self.dense_chunk = None
         # stored row widths, in whole lane tiles
         self.rows = {}
         if self.full:
             a = self.desc[self.full[0]]["attn"]
             self.rows["latent"] = row_lanes(a["kv_rank"] + a["rope"])
-            self.rows["index_key"] = row_lanes(a["index_dim"])
+            self.rows["index_key"] = row_lanes(
+                self.desc[self.idx[0]]["attn"]["index_dim"]) \
+                if self.idx else 0
         self.window = None
         if self.win:
             a = self.desc[self.win[0]]["attn"]
@@ -404,10 +428,10 @@ class LayeredEngine:
 
     def main_page_bytes(self, page):
         """Bytes of one main-table page over every layer that has one."""
-        w = self.rows.get("latent", 0) + self.rows.get("index_key", 0)
         kv = self.rows.get("k", 0) + self.rows.get("v", 0)
-        return (len(self.full) * w + len(self.kv) * kv) * page \
-            * self.cdtype.itemsize
+        return (len(self.full) * self.rows.get("latent", 0)
+                + len(self.idx) * self.rows.get("index_key", 0)
+                + len(self.kv) * kv) * page * self.cdtype.itemsize
 
     def slot_state_bytes(self):
         """Bytes ONE slot keeps under the slot table over every layer that
@@ -446,8 +470,9 @@ class LayeredEngine:
                             self.cdtype))
             return kp, vp
         kp = (z(len(self.full), num_pages, self.rows["latent"]),
-              z(len(self.full), num_pages, self.rows["index_key"]))
-        vp = z(len(self.win), window_pages, self.rows["window_latent"])
+              z(len(self.idx), num_pages, self.rows["index_key"]))
+        vp = z(len(self.win), window_pages,
+               self.rows.get("window_latent", 0))
         return kp, vp
 
     def cache_bytes(self):
@@ -456,8 +481,9 @@ class LayeredEngine:
     def step_counters(self, aux, active):
         """A step's counters reduced over the live slots: tokens each held
         expert of each routed layer got, keys the indexer selected and the
-        queries that selected them, and what the index-score kernel's page
-        walks counted (nothing where the step gathers the key view)."""
+        queries that selected them, and what the index-score kernel's and
+        the latent attention kernel's page walks counted (nothing where the
+        step gathers the view)."""
         out = {}
         if "expert" in aux:
             out["expert_load"] = self._expert_load(aux["expert"], active)
@@ -469,6 +495,10 @@ class LayeredEngine:
             # (full layers, slots, [pages walked, copies, table width])
             out["index_walk"] = jnp.sum(jnp.where(
                 active[None, :, None], aux["index_walk"], 0), axis=(0, 1))
+        if "latent_walk" in aux:
+            # (latent layers, slots, [rows walked, copies])
+            out["latent_walk"] = jnp.sum(jnp.where(
+                active[None, :, None], aux["latent_walk"], 0), axis=(0, 1))
         return out
 
     def _expert_load(self, expert, rows):
@@ -556,62 +586,22 @@ class LayeredEngine:
                                      last, key_pages, slots, live)
         cfg = self.cfg
         (lat, ikp), wlat = pools
-        ptm, ptw = tables
+        ptm, ptw = tables if isinstance(tables, tuple) else (tables, None)
         B, C = toks.shape
         pos = off[:, None] + jnp.arange(C, dtype=jnp.int32)[None]
         lp, row = pos // page, pos % page
         x = w["wte"][toks]
-        aux = {"expert": [], "selected": [], "index_walk": []}
-        fi = wi = 0
+        eps = cfg.rms_norm_eps
+        aux = {"expert": [], "selected": [], "index_walk": [],
+               "latent_walk": []}
+        # a layer's place among the main table's latent layers, the index
+        # keys' layers and the window's
+        fi = ii = wi = 0
         for i, d in enumerate(self.desc):
             lw, a = w["layers"][i], d["attn"]
-            h = _rms(x, lw["norm1_gamma"], cfg.rms_norm_eps)
+            h = _rms(x, lw["norm1_gamma"], eps)
             q_nope, q_rope, new_row, cq = self._latent_qkv(lw, a, h, pos)
-            if a["kind"] == "latent_sparse":
-                with jax.named_scope("mx.index"):
-                    iq, ik_row, iw = self._index_qkw(lw, a, h, cq, pos)
-                with jax.named_scope("mx.latent_write"):
-                    pg = _table_pages(ptm, lp, lat.shape[1])
-                    lat = lat.at[fi, pg, row].set(
-                        _pad_last(new_row, lat.shape[-1]), mode="drop")
-                    ikp = ikp.at[fi, pg, row].set(
-                        _pad_last(ik_row, ikp.shape[-1]), mode="drop")
-                kp_n = ptm.shape[1] if key_pages is None else key_pages
-                reach = jnp.minimum(ptm[:, :kp_n], lat.shape[1] - 1)
-                with jax.named_scope("mx.index"):
-                    full, seen, walk = self._select(
-                        a, iq, iw, ikp, fi, ptm[:, :kp_n], pos,
-                        page)                               # (B, C, T)
-                    chosen = full & seen
-                aux["selected"].append(jnp.sum(chosen, axis=-1))
-                if walk is not None:
-                    aux["index_walk"].append(walk)
-                if C >= self.dense_chunk:
-                    with jax.named_scope("mx.latent_gather"):
-                        rows = lat.at[fi, reach].get(
-                            mode="promise_in_bounds").reshape(
-                                B, kp_n * page, -1)         # (B, T, W)
-                    with jax.named_scope("mx.latent_attn"):
-                        o = self._attend_dense(lw, a, q_nope, q_rope, rows,
-                                               chosen)
-                else:
-                    K = min(int(a["topk"]), kp_n * page)
-                    with jax.named_scope("mx.index"):
-                        sel = mask_positions(full.reshape(B * C, -1),
-                                             K).reshape(B, C, K)
-                        # fewer than K seen: the rest point past ``pos``
-                        ok = sel <= pos[..., None]
-                    with jax.named_scope("mx.latent_gather"):
-                        pgs = jnp.take_along_axis(reach[:, None, :],
-                                                  sel // page, axis=2)
-                        rows = lat.at[fi, pgs, sel % page].get(
-                            mode="promise_in_bounds")       # (B, C, K, W)
-                    with jax.named_scope("mx.latent_attn"):
-                        o = self._attend(lw, a, q_nope, q_rope, rows, ok,
-                                         "bchf,bckf->bchk",
-                                         "bchk,bckr->bchr")
-                fi += 1
-            else:
+            if a["kind"] == "latent_window":
                 with jax.named_scope("mx.latent_write"):
                     ring = ptw.shape[1]
                     pg = jnp.take_along_axis(ptw, lp % ring, axis=1)
@@ -623,19 +613,52 @@ class LayeredEngine:
                     o = self._attend(lw, a, q_nope, q_rope, rows, ok,
                                      "bchf,btf->bcht", "bcht,btr->bchr")
                 wi += 1
-            gate = jax.nn.sigmoid(jnp.dot(
-                h, lw["gate_weight"], preferred_element_type=jnp.float32))
-            o = (o.astype(jnp.float32) * gate[..., None]).astype(x.dtype)
-            x = x + _dot(o.reshape(B, C, -1), lw["o_weight"])
-            h = _rms(x, lw["norm2_gamma"], cfg.rms_norm_eps)
+            else:
+                sparse = a["kind"] == "latent_sparse"
+                if sparse:
+                    with jax.named_scope("mx.index"):
+                        iq, ik_row, iw = self._index_qkw(lw, a, h, cq, pos)
+                with jax.named_scope("mx.latent_write"):
+                    pg = _table_pages(ptm, lp, lat.shape[1])
+                    lat = lat.at[fi, pg, row].set(
+                        _pad_last(new_row, lat.shape[-1]), mode="drop")
+                    if sparse:
+                        ikp = ikp.at[ii, pg, row].set(
+                            _pad_last(ik_row, ikp.shape[-1]), mode="drop")
+                kp_n = ptm.shape[1] if key_pages is None else key_pages
+                reach = jnp.minimum(ptm[:, :kp_n], lat.shape[1] - 1)
+                if sparse:
+                    o = self._sparse_attend(lw, a, q_nope, q_rope, lat, fi,
+                                            iq, iw, ikp, ii, ptm[:, :kp_n],
+                                            reach, pos, page, aux)
+                    ii += 1
+                else:
+                    o, walk = self._every_attend(lw, a, q_nope, q_rope, lat,
+                                                 fi, ptm, reach, pos, page)
+                    if walk is not None:
+                        aux["latent_walk"].append(walk)
+                fi += 1
+            if a.get("gate"):
+                gate = jax.nn.sigmoid(jnp.dot(
+                    h, lw["gate_weight"], preferred_element_type=jnp.float32))
+                o = o.astype(jnp.float32) * gate[..., None]
+            post = d.get("post_norms")
+            y = _dot(o.astype(x.dtype).reshape(B, C, -1), lw["o_weight"])
+            if post:
+                y = _rms(y, lw["post1_gamma"], eps)
+            x = x + y
+            h = _rms(x, lw["norm2_gamma"], eps)
             y, eidx = self._ffn(lw, d["ffn"], h.reshape(B * C, -1))
             if eidx is not None:
                 aux["expert"].append(eidx)
-            x = x + y.reshape(B, C, -1)
+            y = y.reshape(B, C, -1)
+            if post:
+                y = _rms(y, lw["post2_gamma"], eps)
+            x = x + y
         with jax.named_scope("mx.head"):
             if last is not None:
                 x = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
-            logits = jnp.dot(_rms(x, w["normf"], cfg.rms_norm_eps),
+            logits = jnp.dot(_rms(x, w["normf"], eps),
                              w["head"],
                              preferred_element_type=jnp.float32)
         aux = {k: jnp.stack(v) for k, v in aux.items() if v}
@@ -962,7 +985,7 @@ class LayeredEngine:
         cfg = self.cfg
         B, C, H = h.shape
         rq, r = a["q_rank"], a["kv_rank"]
-        rescale = cfg.apply_mla_qkv_lora_rescale
+        rescale = getattr(cfg, "apply_mla_qkv_lora_rescale", False)
         cq = _rms(_dot(h, lw["qa_weight"]), lw["qnorm_gamma"],
                   cfg.rms_norm_eps, (H / rq) ** 0.5 if rescale else 1.0)
         q = _dot(cq, lw["qb_weight"]).reshape(
@@ -989,7 +1012,7 @@ class LayeredEngine:
                      preferred_element_type=jnp.float32)
         return iq, ik, iw
 
-    def _select(self, a, iq, iw, ikp, fi, table, pos, page):
+    def _select(self, a, iq, iw, ikp, ii, table, pos, page):
         """Which positions every query attends to: the ``topk`` of largest
         indexer score among ``s <= pos``, all of them while there are
         fewer — ``(B, C, T)`` bool over the ``T`` positions the table rows
@@ -1009,7 +1032,7 @@ class LayeredEngine:
         B, C = iq.shape[:2]
         npages = ikp.shape[1]
         T = table.shape[1] * page
-        view = lambda: _index_scores_view(iq, iw, ikp, fi, table, page)
+        view = lambda: _index_scores_view(iq, iw, ikp, ii, table, page)
         walk = None
         if C == 1 and _index.supports(ikp.shape[-1], ikp.dtype, page,
                                       npages):
@@ -1017,7 +1040,7 @@ class LayeredEngine:
             # AFTER its position
             ends = _paged.walk_lengths(table, pos[:, 0] + 1, page, npages)
             score, walk = _index.index_scores(
-                iq[:, 0], iw[:, 0], ikp, fi, table, ends,
+                iq[:, 0], iw[:, 0], ikp, ii, table, ends,
                 lambda: view()[:, 0])
         else:
             score = view()
@@ -1025,6 +1048,76 @@ class LayeredEngine:
         chosen = top_mask(score.reshape(B * C, T), seen.reshape(B * C, T),
                           min(int(a["topk"]), T)).reshape(B, C, T)
         return chosen, seen, walk
+
+    def _sparse_attend(self, lw, a, q_nope, q_rope, lat, fi, iq, iw, ikp, ii,
+                       table, reach, pos, page, aux):
+        """A ``latent_sparse`` layer's attention over the ``topk`` positions
+        its indexer selects (``_select``): the dense form masked to the set
+        from ``dense_chunk`` queries a row, else the selected rows gathered
+        through the page table.  ``aux`` gains what the selection
+        counted."""
+        B, C = pos.shape
+        kp_n = table.shape[1]
+        with jax.named_scope("mx.index"):
+            full, seen, walk = self._select(a, iq, iw, ikp, ii, table, pos,
+                                            page)           # (B, C, T)
+            chosen = full & seen
+        aux["selected"].append(jnp.sum(chosen, axis=-1))
+        if walk is not None:
+            aux["index_walk"].append(walk)
+        if C >= self.dense_chunk:
+            rows = _reach_rows(lat, fi, reach)
+            with jax.named_scope("mx.latent_attn"):
+                return self._attend_dense(lw, a, q_nope, q_rope, rows,
+                                          chosen)
+        K = min(int(a["topk"]), kp_n * page)
+        with jax.named_scope("mx.index"):
+            sel = mask_positions(full.reshape(B * C, -1),
+                                 K).reshape(B, C, K)
+            # fewer than K seen: the rest point past ``pos``
+            ok = sel <= pos[..., None]
+        with jax.named_scope("mx.latent_gather"):
+            pgs = jnp.take_along_axis(reach[:, None, :], sel // page, axis=2)
+            rows = lat.at[fi, pgs, sel % page].get(
+                mode="promise_in_bounds")                   # (B, C, K, W)
+        with jax.named_scope("mx.latent_attn"):
+            return self._attend(lw, a, q_nope, q_rope, rows, ok,
+                                "bchf,bckf->bchk", "bchk,bckr->bchr")
+
+    def _every_attend(self, lw, a, q_nope, q_rope, lat, fi, table, reach,
+                      pos, page):
+        """A ``latent`` layer's attention over every position ``s <= pos``
+        (the new rows are in the pool already).  The single-query step of a
+        pool the kernel takes walks each slot's pages in place
+        (``ops.latent_attention``) and hands back what the walk counted;
+        every other caller — a chunk, a wave, the CPU — gathers the rows
+        its queries can reach (``reach``: the table's first pages) and
+        takes the masked dense form, which is also the walk's reference.
+        Returns ``(o (B, C, heads, v) float32, walk counts or None)``."""
+        B, C = pos.shape
+        T = reach.shape[1] * page
+        npages, lanes = lat.shape[1], lat.shape[-1]
+
+        def dense(expand=True):
+            seen = jnp.arange(T, dtype=jnp.int32)[None, None] \
+                <= pos[..., None]
+            return self._attend_dense(lw, a, q_nope, q_rope,
+                                      _reach_rows(lat, fi, reach), seen,
+                                      expand)
+
+        with jax.named_scope("mx.latent_attn"):
+            if C > 1 or not _latent.supports(lanes, a["kv_rank"], lat.dtype,
+                                             page, npages):
+                return dense(), None
+            wkv = self._wkv(lw, a)
+            qf = self._absorbed(a, q_nope[:, 0], q_rope[:, 0], wkv, lanes)
+            ends = _paged.walk_lengths(table, pos[:, 0] + 1, page, npages)
+            ctx, walk = _latent.latent_paged_attention(
+                qf, lat, fi, table, ends, self._scale(a), a["kv_rank"],
+                lambda: dense(expand=False)[:, 0])
+            o = jnp.einsum("bhr,rhv->bhv", ctx, wkv[..., a["nope"]:],
+                           preferred_element_type=jnp.float32)
+        return o[:, None], walk
 
     def _window_rows(self, a, wlat, wi, ptw, off, pos, page, C):
         """The window pool's rows a row of queries can reach, ``(B, T',
@@ -1044,7 +1137,8 @@ class LayeredEngine:
         ok = (kpos <= p) & (kpos > p - a["window"]) & (kpos >= 0)
         return rows, ok
 
-    def _attend_dense(self, lw, a, q_nope, q_rope, rows, chosen):
+    def _attend_dense(self, lw, a, q_nope, q_rope, rows, chosen,
+                      expand=True):
         """``_attend`` of every query against ALL the slot's rows ``(B, T,
         W)``, masked to ``chosen`` ``(B, C, T)``, a block of heads at a
         time so that the scores fit."""
@@ -1053,40 +1147,58 @@ class LayeredEngine:
         hb = max(1, min(hh, _INDEX_BLOCK_BYTES // max(1, B * C * T * 4)))
         while hh % hb:
             hb -= 1
-        wkv = lw["kvb_weight"].reshape(a["kv_rank"], hh // hb, hb,
-                                       a["nope"] + a["v"])
+        wkv = self._wkv(lw, a).reshape(a["kv_rank"], hh // hb, hb, -1)
         blocks = lambda q: jnp.moveaxis(
             q.reshape(B, C, hh // hb, hb, q.shape[-1]), 2, 0)
         out = jax.lax.map(
             lambda xs: self._attend(lw, a, xs[0], xs[1], rows, chosen,
                                     "bchf,btf->bcht", "bcht,btr->bchr",
-                                    wkv=xs[2]),
+                                    wkv=xs[2], expand=expand),
             (blocks(q_nope), blocks(q_rope), jnp.moveaxis(wkv, 1, 0)))
         return jnp.moveaxis(out, 0, 2).reshape(B, C, hh, -1)
 
+    @staticmethod
+    def _wkv(lw, a):
+        """``W_kvb`` by head: ``(kv_rank, heads, nope + v)``."""
+        return lw["kvb_weight"].reshape(a["kv_rank"], a["heads"],
+                                        a["nope"] + a["v"])
+
+    @staticmethod
+    def _scale(a):
+        return 1.0 / (a["nope"] + a["rope"]) ** 0.5
+
+    @staticmethod
+    def _absorbed(a, q_nope, q_rope, wkv, lanes):
+        """The queries taken into the latent space (``W_kvb``'s key half)
+        beside their rotary part, padded to the stored rows' ``lanes``:
+        ``[q_nope W_kvb,k | q_rope | 0]`` in the queries' dtype."""
+        qabs = jnp.einsum("...hd,rhd->...hr", q_nope, wkv[..., :a["nope"]],
+                          preferred_element_type=jnp.float32
+                          ).astype(q_nope.dtype)
+        return _pad_last(jnp.concatenate([qabs, q_rope], axis=-1), lanes)
+
     def _attend(self, lw, a, q_nope, q_rope, rows, ok, scores, context,
-                wkv=None):
+                wkv=None, expand=True):
         """Latent attention in the absorbed form: the queries are taken
         into the latent space (``W_kvb``'s key half), scored against the
         stored rows ``[c_kv | k_rope | 0]`` in one contraction, and the
         context comes back through ``W_kvb``'s value half (``wkv``: that
-        matrix for the heads given, all of them by default)."""
-        r, hh = a["kv_rank"], a["heads"]
+        matrix for the heads given, all of them by default) — or, without
+        ``expand``, is returned as it is, ``(..., heads, kv_rank)``."""
+        r = a["kv_rank"]
         if wkv is None:
-            wkv = lw["kvb_weight"].reshape(r, hh, a["nope"] + a["v"])
-        qabs = jnp.einsum("bchd,rhd->bchr", q_nope, wkv[..., :a["nope"]],
-                          preferred_element_type=jnp.float32
-                          ).astype(q_nope.dtype)
-        qf = _pad_last(jnp.concatenate([qabs, q_rope], axis=-1),
-                       rows.shape[-1])
+            wkv = self._wkv(lw, a)
+        qf = self._absorbed(a, q_nope, q_rope, wkv, rows.shape[-1])
         s = jnp.einsum(scores, qf, rows,
                        preferred_element_type=jnp.float32)
-        s = s * (1.0 / (a["nope"] + a["rope"]) ** 0.5)
+        s = s * self._scale(a)
         s = jnp.where(ok[:, :, None, :], s, -1e30)
         p = jax.nn.softmax(s, axis=-1).astype(rows.dtype)
         ctx = jnp.einsum(context, p, rows[..., :r],
                          preferred_element_type=jnp.float32
                          ).astype(rows.dtype)
+        if not expand:
+            return ctx
         return jnp.einsum("bchr,rhv->bchv", ctx, wkv[..., a["nope"]:],
                           preferred_element_type=jnp.float32)
 

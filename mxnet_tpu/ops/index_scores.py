@@ -135,6 +135,60 @@ def group_runs(pt, ends, page, per, sub, num_pages):
     return jnp.where(runs(per), 1 << nsub, bits)
 
 
+def each_copy(act, pool_ref, kbuf, sems, pt_ref, end_ref, run_ref, layer,
+              slot_b, g, buf, *, page, maxp, num_pages, per, sub, groups,
+              counted=None):
+    """``act`` on every copy of group ``g`` of slot ``slot_b`` into buffer
+    ``buf`` of ``kbuf`` ``(2, per, page, lanes)`` (``start`` them, later
+    ``wait`` for the same ones): one copy where the whole group is a run
+    (``group_runs``' code); else, block by block of ``sub`` pages, one copy
+    where the block is a run and one a held page where it is not.
+    ``counted(n)`` is told the copies as they are made.  The page walks of
+    ``ops.latent_attention`` fetch their groups the same way."""
+    nsub = per // sub
+    entry = slot_b * maxp + g * per
+    code = run_ref[slot_b * groups + g]
+    whole = code == (1 << nsub)
+    counted = counted or (lambda n: None)
+
+    def stretch(first, n, dst):
+        # checked against the pool though ``group_runs`` has: an id out of
+        # range must never reach a DMA
+        pid = jnp.clip(pt_ref[first], 0, num_pages - n)
+        act(pltpu.make_async_copy(pool_ref.at[layer, pl.ds(pid, n)], dst,
+                                  sems.at[buf]))
+
+    @pl.when(whole)
+    def _():
+        stretch(entry, per, kbuf.at[buf])
+        counted(1)
+
+    @pl.when(jnp.logical_not(whole))
+    def _():
+        held = jnp.minimum(pl.cdiv(end_ref[slot_b], page) - g * per, per)
+        for k in range(nsub):
+            has = jnp.clip(held - k * sub, 0, sub)
+            is_run = (code >> k) & 1 == 1
+
+            @pl.when(is_run & (has > 0))
+            def _():
+                stretch(entry + k * sub, sub,
+                        kbuf.at[buf, pl.ds(k * sub, sub)])
+                counted(1)
+
+            @pl.when(jnp.logical_not(is_run))
+            def _():
+                def body(j, carry):
+                    at = k * sub + j
+                    pid = jnp.clip(pt_ref[entry + at], 0, num_pages - 1)
+                    act(pltpu.make_async_copy(pool_ref.at[layer, pid],
+                                              kbuf.at[buf, at],
+                                              sems.at[buf]))
+                    return carry
+                lax.fori_loop(0, has, body, 0)
+                counted(has)
+
+
 def _kernel(layer_ref, pt_ref, end_ref, run_ref,        # SMEM (prefetch)
             q_ref, w_ref, pool_ref,                     # inputs
             out_ref, cnt_ref,                           # outputs
@@ -143,72 +197,25 @@ def _kernel(layer_ref, pt_ref, end_ref, run_ref,        # SMEM (prefetch)
     b = pl.program_id(0)
     nslots = pl.num_programs(0)
     rows = per * page
-    nsub = per // sub
     lanes = kbuf.shape[-1]
     layer = layer_ref[0]
     end = end_ref[b]                    # the walk's end, a position
     ngroups = pl.cdiv(end, rows)
     prec = lax.Precision.HIGHEST if kbuf.dtype == jnp.float32 \
         else lax.Precision.DEFAULT
-
-    def each_copy(slot_b, g, buf, act, count):
-        """``act`` on every copy of group ``g`` of slot ``slot_b`` into
-        buffer ``buf`` (``start`` them, later ``wait`` for the same ones):
-        one copy where the whole group is a run; else, block by block of
-        ``sub`` pages, one copy where the block is a run and one a held
-        page where it is not.  With ``count`` the slot's copies add up."""
-        entry = slot_b * maxp + g * per
-        code = run_ref[slot_b * groups + g]
-        whole = code == (1 << nsub)
-
-        def stretch(first, n, dst):
-            # checked against the pool though ``group_runs`` has: an id
-            # out of range must never reach a DMA
-            pid = jnp.clip(pt_ref[first], 0, num_pages - n)
-            act(pltpu.make_async_copy(pool_ref.at[layer, pl.ds(pid, n)],
-                                      dst, sems.at[buf]))
-
-        def counted(n):
-            if count:
-                cnt_ref[slot_b, 1] = cnt_ref[slot_b, 1] + n
-
-        @pl.when(whole)
-        def _():
-            stretch(entry, per, kbuf.at[buf])
-            counted(1)
-
-        @pl.when(jnp.logical_not(whole))
-        def _():
-            held = jnp.minimum(
-                pl.cdiv(end_ref[slot_b], page) - g * per, per)
-            for k in range(nsub):
-                has = jnp.clip(held - k * sub, 0, sub)
-                is_run = (code >> k) & 1 == 1
-
-                @pl.when(is_run & (has > 0))
-                def _():
-                    stretch(entry + k * sub, sub,
-                            kbuf.at[buf, pl.ds(k * sub, sub)])
-                    counted(1)
-
-                @pl.when(jnp.logical_not(is_run))
-                def _():
-                    def body(j, carry):
-                        at = k * sub + j
-                        pid = jnp.clip(pt_ref[entry + at], 0,
-                                       num_pages - 1)
-                        act(pltpu.make_async_copy(pool_ref.at[layer, pid],
-                                                  kbuf.at[buf, at],
-                                                  sems.at[buf]))
-                        return carry
-                    lax.fori_loop(0, has, body, 0)
-                    counted(has)
+    copies = functools.partial(
+        each_copy, pool_ref=pool_ref, kbuf=kbuf, sems=sems, pt_ref=pt_ref,
+        end_ref=end_ref, run_ref=run_ref, layer=layer, page=page, maxp=maxp,
+        num_pages=num_pages, per=per, sub=sub, groups=groups)
 
     def start(slot_b, g, buf):
-        each_copy(slot_b, g, buf, lambda c: c.start(), True)
+        def counted(n):
+            cnt_ref[slot_b, 1] = cnt_ref[slot_b, 1] + n
+        copies(lambda c: c.start(), slot_b=slot_b, g=g, buf=buf,
+               counted=counted)
 
     def wait(slot_b, g, buf):
-        each_copy(slot_b, g, buf, lambda c: c.wait(), False)
+        copies(lambda c: c.wait(), slot_b=slot_b, g=g, buf=buf)
 
     @pl.when(b == 0)
     def _():

@@ -238,7 +238,8 @@ _KERNEL_REGIONS = (("mx_moe_gmm", "mx.moe_experts"),
                    ("mx_paged_attention", "mx.attn"),
                    ("mx_flash", "mx.attn"),
                    ("mx_ssm_update", "mx.ssm_state"),
-                   ("mx_index_scores", "mx.index"))
+                   ("mx_index_scores", "mx.index"),
+                   ("mx_latent_paged_attention", "mx.latent_attn"))
 
 
 def region_of(provenance, name=None):

@@ -155,6 +155,7 @@ KV_PAGE_INT8 = {"codes": "int8", "scales": "float32"}
 POOL_ROWS = {
     "kv": {"table": "main", "rows": ("k", "v")},
     "latent_index": {"table": "main", "rows": ("latent", "index_key")},
+    "latent": {"table": "main", "rows": ("latent",)},
     "latent_window": {"table": "window", "rows": ("latent",)},
     "kv_window": {"table": "window", "rows": ("k", "v")},
     "ssm_state": {"table": "slot", "rows": ("state", "conv_tail")},

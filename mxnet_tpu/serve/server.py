@@ -120,7 +120,8 @@ serve_counters = {"step_dispatches": 0, "admit_dispatches": 0,
                   "draft_proposed": 0, "draft_accepted": 0,
                   "draft_rejected": 0, "hit_dispatches": 0,
                   "admit_rows": 0, "admit_tokens": 0,
-                  "chunk_expert_tokens": 0, "chunk_experts_touched": 0}
+                  "chunk_expert_tokens": 0, "chunk_experts_touched": 0,
+                  "latent_rows_walked": 0}
 _counters_lock = threading.Lock()
 _server_seq = itertools.count()
 
@@ -155,7 +156,10 @@ class _CounterView(MutableMapping):
     routed: ``chunk_expert_tokens`` ((row, held expert) pairs over every
     row a chunk computes, padding included) and ``chunk_experts_touched``
     ((routed layer, held expert) cells that got a row: the experts'
-    weights a chunk has to read)."""
+    weights a chunk has to read).  A model with latent attention over every
+    position counts what its decode steps' page walks read:
+    ``latent_rows_walked`` (cached rows, summed over the live slots and the
+    latent layers)."""
 
     _KEYS = ("step_dispatches", "admit_dispatches", "sync_requests",
              "pool_grows", "prefix_hits", "cow_copies",
@@ -163,7 +167,7 @@ class _CounterView(MutableMapping):
              "draft_proposed", "draft_accepted", "draft_rejected",
              "hit_dispatches", "admit_rows", "admit_tokens",
              "compiles", "compile_ms", "chunk_expert_tokens",
-             "chunk_experts_touched")
+             "chunk_experts_touched", "latent_rows_walked")
 
     def __init__(self, server_label):
         self._c = {k: telemetry.counter(f"serve_{k}_total",
@@ -1278,7 +1282,8 @@ class DecodeServer:
         far: mean over routed layers and steps of the busiest held expert's
         tokens over the mean, share of (layer, expert) cells a step
         touched, tokens a held expert a step, keys selected a query, the
-        pages the index scores walked."""
+        pages the index scores walked, the rows the latent attention's
+        walks read a step."""
         t = self._step_sums
         out = {}
         if t.get("cells"):
@@ -1293,6 +1298,13 @@ class DecodeServer:
         # (0 / 0 where no step ran it)
         for k in _INDEX_WALK_KEYS:
             out[k] = t.get(k, 0)
+        # the latent attention kernel's: rows its walks read over live slots
+        # and latent layers, and the copies they took, a step dispatched
+        # (absent where no step walked)
+        if t.get("latent_rows") and self._steps:
+            out["latent_rows_walked_per_step"] = \
+                t["latent_rows"] / self._steps
+            out["latent_copies_per_step"] = t["latent_copies"] / self._steps
         return out
 
     def _chunk_stats(self):
@@ -1348,6 +1360,11 @@ class DecodeServer:
         if "index_walk" in c:
             for k, v in zip(_INDEX_WALK_KEYS, onp.asarray(c["index_walk"])):
                 t[k] = t.get(k, 0) + int(v)
+        if "latent_walk" in c:
+            rows, copies = (int(v) for v in onp.asarray(c["latent_walk"]))
+            self._count("latent_rows_walked", rows)
+            for k, v in (("latent_rows", rows), ("latent_copies", copies)):
+                t[k] = t.get(k, 0) + v
 
     def close(self, drain=True, timeout=60.0):
         """Stop the scheduler.  ``drain=True`` serves everything already

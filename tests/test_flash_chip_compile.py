@@ -146,3 +146,34 @@ def test_moe_kernel_compiles_for_v5e(one_chip, shape):
         ((M, H), jnp.bfloat16), ((G, H, 2 * I), jnp.bfloat16),
         ((G, I, H), jnp.bfloat16), ((32,), jnp.int32), ((), jnp.int32))
     assert gm._NAME in compiled.as_text()
+
+
+# the page walks of a latent layer's decode step at the cells' shapes: the
+# latent attention kernel at ``pangu_ultra_serve_sessions24``'s (24 slots,
+# 128 heads of 640 lanes, five layers of 40,960 pages of 16, a table of
+# 2,072 entries) and the index-score kernel at ``dots3``'s, which fetches its
+# groups by the same copies
+@pytest.mark.parametrize("kernel", ["latent_walk", "index_scores"])
+def test_page_walk_kernel_compiles_for_v5e(one_chip, kernel):
+    """Two row buffers of a group, the scores and the float32 accumulator
+    under the VMEM a kernel may take; run copies of a whole group and of a
+    block, page copies; bfloat16 products at ``DEFAULT`` precision."""
+    import jax.numpy as jnp
+    if kernel == "latent_walk":
+        from mxnet_tpu.ops import latent_attention as la
+        compiled = _compile(
+            one_chip, lambda q, pool, pt, ends: la._kernel_call(
+                q, pool, 2, pt, ends, 192 ** -0.5, 512, False),
+            ((24, 128, 640), jnp.bfloat16),
+            ((5, 40960, 16, 640), jnp.bfloat16), ((24, 2072), jnp.int32),
+            ((24,), jnp.int32))
+        assert la._NAME in compiled.as_text()
+    else:
+        from mxnet_tpu.ops import index_scores as ix
+        compiled = _compile(
+            one_chip, lambda q, w, pool, pt, ends: ix._kernel_call(
+                q, w, pool, 1, pt, ends, False),
+            ((32, 64, 128), jnp.bfloat16), ((32, 64), jnp.float32),
+            ((2, 65536, 16, 128), jnp.bfloat16), ((32, 2072), jnp.int32),
+            ((32,), jnp.int32))
+        assert ix._NAME in compiled.as_text()

@@ -161,7 +161,7 @@ class TestPoolTables:
     def test_declared_kinds_and_tables(self):
         assert schema.POOL_TABLES == ("main", "window", "slot")
         assert {k: v["table"] for k, v in schema.POOL_ROWS.items()} == {
-            "kv": "main", "latent_index": "main",
+            "kv": "main", "latent_index": "main", "latent": "main",
             "latent_window": "window", "kv_window": "window",
             "ssm_state": "slot"}
         assert schema.pool_rows("kv_window") == ("window", ("k", "v"))

@@ -190,20 +190,28 @@ def pool_report(compiled, progs):
 
     shapes = pool_shapes(progs)
     dims = ["[" + ",".join(str(d) for d in shape) + "]" for shape in shapes]
-    n_pools = {math.prod(shape) for shape in shapes}
+    # a kind the model has no layer of keeps an empty array: no pool
+    n_pools = {math.prod(shape) for shape in shapes} - {0}
     # one layer's T-wide view of every slot, which the view path builds
     # and a step that walks its pages does not
     views = () if progs.layered else (
         f"[{progs.S},{progs.Tp},{shapes[0][-1]}]",
         f"[{progs.S},{progs.maxp},{progs.page},{shapes[0][-1]}]")
-    if progs.layered and progs.eng.full:
-        S, T = progs.S, progs.maxp * progs.page
+    S, T = progs.S, progs.maxp * progs.page
+    if progs.layered and progs.eng.idx:
         lanes = progs.eng.rows["index_key"]
-        J = progs.eng.desc[progs.eng.full[0]]["attn"]["index_heads"]
+        J = progs.eng.desc[progs.eng.idx[0]]["attn"]["index_heads"]
         views = (f"[{S},{T},{lanes}]",
                  f"[{S},{progs.maxp},{progs.page},{lanes}]",
                  f"[{S * progs.maxp},{progs.page},{lanes}]",
                  f"f32[{S},1,{J},{T}]", f"f32[{S},{J},{T}]")
+    elif progs.layered and progs.eng.full:
+        # latent attention over every position: the latent rows' view of
+        # every slot, which the step's walk leaves out
+        lanes = progs.eng.rows["latent"]
+        views = (f"[{S},{T},{lanes}]",
+                 f"[{S},{progs.maxp},{progs.page},{lanes}]",
+                 f"[{S * progs.maxp},{progs.page},{lanes}]")
     text = compiled.as_text()
     entry = re.search(r"entry_computation_layout=\{\((.*?)\)->", text)
     layouts = sorted({m for d in dims for m in re.findall(
