@@ -529,20 +529,28 @@ class LayeredEngine:
         """``C`` tokens of slot ``slot`` at offset ``off``; ``key_pages``
         bounds the main-table pages the chunk can reach (its last
         position's).  State under the slot table starts from zero at
-        offset 0 and from what the last chunk stored after it."""
+        offset 0 and from what the last chunk stored after it.  What the
+        chunk counted itself — its experts' load, its latent walks' rows
+        and copies summed over the layers — comes back as a fourth
+        value where there is any."""
         tables = tuple(t[None] for t in ptrow) \
             if isinstance(ptrow, tuple) else ptrow[None]
         logits, kp, vp, aux = self.tokens_paged(
             self.model.weights(), toks[None], off[None], tables, (kp, vp),
             page, nlast[None], key_pages=key_pages,
             slots=None if slot is None else slot[None])
-        if "expert" not in aux:
+        counted = {}
+        if "expert" in aux:
+            # the experts' load over EVERY row the chunk computes: the rows
+            # past the prompt route and run through the experts as well
+            every = jnp.ones((toks.shape[0],), jnp.bool_)
+            counted["expert_load"] = self._expert_load(aux["expert"], every)
+        if "latent_walk" in aux:
+            # (latent layers, 1, [rows walked, copies])
+            counted["latent_walk"] = jnp.sum(aux["latent_walk"], axis=(0, 1))
+        if not counted:
             return logits, kp, vp
-        # the experts' load over EVERY row the chunk computes: the rows
-        # past the prompt route and run through the experts as well
-        every = jnp.ones((toks.shape[0],), jnp.bool_)
-        return logits, kp, vp, {
-            "expert_load": self._expert_load(aux["expert"], every)}
+        return logits, kp, vp, counted
 
     def admit_tokens(self, prompts, last, tables, page, kp, vp, slots=None):
         """An admission wave: ``(A, P)`` right-padded prompts from offset
@@ -1087,13 +1095,16 @@ class LayeredEngine:
     def _every_attend(self, lw, a, q_nope, q_rope, lat, fi, table, reach,
                       pos, page):
         """A ``latent`` layer's attention over every position ``s <= pos``
-        (the new rows are in the pool already).  The single-query step of a
-        pool the kernel takes walks each slot's pages in place
-        (``ops.latent_attention``) and hands back what the walk counted;
-        every other caller — a chunk, a wave, the CPU — gathers the rows
-        its queries can reach (``reach``: the table's first pages) and
-        takes the masked dense form, which is also the walk's reference.
-        Returns ``(o (B, C, heads, v) float32, walk counts or None)``."""
+        (the new rows are in the pool already).  Over a pool the kernels
+        take, the single-query step walks each slot's pages in place
+        (``ops.latent_attention``), and a chunk's or a wave's ``C`` queries
+        a row walk the WHOLE table row (``table``) as far as each tile's
+        last query reaches, whatever bound ``reach`` carries; both hand
+        back what the walk counted.  Every other caller — the CPU, a pool
+        the kernels refuse — gathers the rows its queries can reach
+        (``reach``: the table's first pages) and takes the masked dense
+        form, which is also the walks' reference.  Returns ``(o (B, C,
+        heads, v) float32, walk counts or None)``."""
         B, C = pos.shape
         T = reach.shape[1] * page
         npages, lanes = lat.shape[1], lat.shape[-1]
@@ -1106,10 +1117,22 @@ class LayeredEngine:
                                       expand)
 
         with jax.named_scope("mx.latent_attn"):
-            if C > 1 or not _latent.supports(lanes, a["kv_rank"], lat.dtype,
-                                             page, npages):
+            if not _latent.supports(lanes, a["kv_rank"], lat.dtype, page,
+                                    npages):
                 return dense(), None
             wkv = self._wkv(lw, a)
+            if C > 1:
+                qf = self._absorbed(a, q_nope, q_rope, wkv, lanes)
+                held = _paged.walk_lengths(
+                    table, jnp.full((B,), table.shape[1] * page, jnp.int32),
+                    page, npages)
+                ctx, walk = _latent.latent_chunk_attention(
+                    qf, lat, fi, table, jnp.minimum(pos + 1, held[:, None]),
+                    self._scale(a), a["kv_rank"],
+                    lambda: dense(expand=False))
+                return jnp.einsum("bchr,rhv->bchv", ctx,
+                                  wkv[..., a["nope"]:],
+                                  preferred_element_type=jnp.float32), walk
             qf = self._absorbed(a, q_nope[:, 0], q_rope[:, 0], wkv, lanes)
             ends = _paged.walk_lengths(table, pos[:, 0] + 1, page, npages)
             ctx, walk = _latent.latent_paged_attention(

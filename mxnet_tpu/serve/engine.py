@@ -841,8 +841,9 @@ class PoolPrograms:
             dl = dl.at[tgt].set(dls, mode="drop")
             spec = spec.at[tgt].set(spec_d, mode="drop")
             new_state = (kp, vp, pos, tok, active, stop, keys, dl, spec)
-            # an engine that counts what a chunk routed (experts' load)
-            # hands it back beside the first token: a third readback
+            # an engine that counts what a chunk did (experts' load,
+            # latent rows walked) hands it back beside the first token: a
+            # third readback
             return new_state, (first, done) + tuple(counted)
 
         fn = telemetry.instrument_jit(

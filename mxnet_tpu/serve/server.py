@@ -121,7 +121,7 @@ serve_counters = {"step_dispatches": 0, "admit_dispatches": 0,
                   "draft_rejected": 0, "hit_dispatches": 0,
                   "admit_rows": 0, "admit_tokens": 0,
                   "chunk_expert_tokens": 0, "chunk_experts_touched": 0,
-                  "latent_rows_walked": 0}
+                  "latent_rows_walked": 0, "chunk_latent_rows_walked": 0}
 _counters_lock = threading.Lock()
 _server_seq = itertools.count()
 
@@ -157,9 +157,14 @@ class _CounterView(MutableMapping):
     row a chunk computes, padding included) and ``chunk_experts_touched``
     ((routed layer, held expert) cells that got a row: the experts'
     weights a chunk has to read).  A model with latent attention over every
-    position counts what its decode steps' page walks read:
-    ``latent_rows_walked`` (cached rows, summed over the live slots and the
-    latent layers)."""
+    position counts what its page walks read, the decode steps' and the
+    chunks' apart: ``latent_rows_walked`` (cached rows the steps' walks
+    read, summed over the live slots and the latent layers: what
+    ``latent_walk_roofline_pct.pangu``, ``step_hbm_roofline_pct.pangu`` and
+    ``serve_mfu_pct.pangu`` divide by the step's time and dispatches) and
+    ``chunk_latent_rows_walked`` (rows the chunks' walks reached, each
+    chunk's last query's end summed over the latent layers, however many
+    of its tiles re-read them; no reader divides it by a step)."""
 
     _KEYS = ("step_dispatches", "admit_dispatches", "sync_requests",
              "pool_grows", "prefix_hits", "cow_copies",
@@ -167,7 +172,8 @@ class _CounterView(MutableMapping):
              "draft_proposed", "draft_accepted", "draft_rejected",
              "hit_dispatches", "admit_rows", "admit_tokens",
              "compiles", "compile_ms", "chunk_expert_tokens",
-             "chunk_experts_touched", "latent_rows_walked")
+             "chunk_experts_touched", "latent_rows_walked",
+             "chunk_latent_rows_walked")
 
     def __init__(self, server_label):
         self._c = {k: telemetry.counter(f"serve_{k}_total",
@@ -1332,10 +1338,16 @@ class DecodeServer:
                                    if before is not None and seq >= before]
         # read back outside the lock: a ``stats()`` from another thread may
         # wait here for the newest chunk, the scheduler's next append not
-        loads = [onp.asarray(c["expert_load"]) for c in due]
+        due = [{k: onp.asarray(v) for k, v in c.items()} for c in due]
         with self._chunk_lock:
             t = self._chunk_sums
-            for load in loads:
+            for c in due:
+                if "latent_walk" in c:
+                    self._count("chunk_latent_rows_walked",
+                                int(c["latent_walk"][0]))
+                if "expert_load" not in c:
+                    continue
+                load = c["expert_load"]
                 tokens, touched = int(load.sum()), int((load > 0).sum())
                 self._count("chunk_expert_tokens", tokens)
                 self._count("chunk_experts_touched", touched)

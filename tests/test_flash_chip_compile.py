@@ -177,3 +177,23 @@ def test_page_walk_kernel_compiles_for_v5e(one_chip, kernel):
             ((2, 65536, 16, 128), jnp.bfloat16), ((32, 2072), jnp.int32),
             ((32,), jnp.int32))
         assert ix._NAME in compiled.as_text()
+
+
+# the page walk of a latent layer's CHUNK at ``pangu_ultra_serve_sessions24``'s
+# shapes: a 128-token question and a 512-token document chunk of one slot,
+# 128 heads of 640 lanes, five layers of 40,960 pages of 16, a table of 2,072
+@pytest.mark.parametrize("C", [128, 512])
+def test_latent_chunk_kernel_compiles_for_v5e(one_chip, C):
+    """Tiles of 2,048 query rows and groups of 512 rows: the scores and
+    weights of a group beside the float32 accumulator and two row buffers
+    under the VMEM limit the call asks for; the masked and the unmasked
+    update; bfloat16 products at ``DEFAULT`` precision."""
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import latent_attention as la
+    compiled = _compile(
+        one_chip, lambda q, pool, pt, ends: la._chunk_call(
+            q, pool, 2, pt, ends, 192 ** -0.5, 512, False),
+        ((1, C, 128, 640), jnp.bfloat16),
+        ((5, 40960, 16, 640), jnp.bfloat16), ((1, 2072), jnp.int32),
+        ((1, C), jnp.int32))
+    assert la._CHUNK_NAME in compiled.as_text()

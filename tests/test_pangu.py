@@ -346,6 +346,82 @@ def test_kernel_equals_the_gathered_form(runs, monkeypatch):
             + [0]
 
 
+# a chunk of C queries a slot: (C, heads, dtype, table rows in runs, query
+# rows a tile — None: the served tile).  The last query of each live slot
+# ends mid-page; 40 heads (48 padded) make 42 queries a tile, so a 512 chunk
+# ends in a partial tile; tiles of 2 queries walk eight tiles a slot, each to
+# its own end, prefetching across tiles and slots
+_CHUNKS = {
+    "C2_float32": (2, 4, "float32", True, None),
+    "C16_float32_scattered": (16, 4, "float32", False, None),
+    "C16_tiles_of_2_queries": (16, 4, "float32", True, 32),
+    "C128_bfloat16": (128, 4, "bfloat16", True, None),
+    "C512_partial_tile_bfloat16_scattered": (512, 40, "bfloat16", False,
+                                             None),
+}
+
+
+@pytest.mark.parametrize("case", list(_CHUNKS))
+def test_chunk_kernel_equals_the_dense_form(case, monkeypatch):
+    """``latent_chunk_attention`` interpreted against the masked dense form
+    over the same pool: a wave of two live slots at different offsets
+    (each query sees its own position and what lies before it; the held
+    pages past the chunk are other rows and masked) and a retired slot,
+    which reads 0 and walks nothing.  Counts: each live slot's last query's
+    end (once, however many tiles re-read the rows) and at least one copy."""
+    C, H, dtype, runs, tile = _CHUNKS[case]
+    dtype = jnp.dtype(dtype)
+    lanes, rank = 256, 128
+    page = 8 * 4 // dtype.itemsize
+    npages = 512
+    offs = [1003 - C, 501]
+    rng = np.random.default_rng(C + H)
+    pool = jnp.asarray(rng.normal(size=(2, npages, page, lanes)), dtype)
+    held = [-(-(o + C) // page) + 2 for o in offs]
+    maxp = max(held) + 3
+    B = len(offs) + 1
+    table = np.full((B, maxp), npages, np.int32)
+    ids = iter(rng.permutation(npages))
+    nxt = 0
+    for b, n in enumerate(held):
+        table[b, :n] = nxt + np.arange(n) if runs \
+            else [next(ids) for _ in range(n)]
+        nxt += n
+    pos = np.asarray(offs + [0])[:, None] + np.arange(C)[None]
+    ends = np.minimum(pos + 1, np.asarray(held + [0])[:, None] * page)
+    q = jnp.asarray(rng.normal(size=(B, C, H, lanes)) * 0.1, dtype)
+    scale = 1.0 / 12 ** 0.5
+
+    rows = np.asarray(pool[1].astype(jnp.float32))[
+        np.minimum(table, npages - 1)].reshape(B, maxp * page, lanes)
+    s = np.einsum("bchf,btf->bcht", np.asarray(q.astype(jnp.float32)),
+                  rows) * scale
+    s = np.where(np.arange(maxp * page)[None, None, None]
+                 < ends[:, :, None, None], s, -1e30)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    want = np.einsum("bcht,btr->bchr", p / p.sum(-1, keepdims=True),
+                     rows[..., :rank])
+
+    monkeypatch.setenv("MXNET_FLASH_INTERPRET", "1")
+    got, counts = jax.jit(lambda q, pool, table, ends: la._chunk_call(
+        q, pool, 1, table, ends, scale, rank, True, tile=tile))(
+            q, pool, jnp.asarray(table), jnp.asarray(ends, jnp.int32))
+    got = np.asarray(got.astype(jnp.float32))
+    assert got.shape == (B, C, H, rank)
+    live = slice(0, B - 1)
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(got[live], want[live], atol=1e-5, rtol=0)
+    else:
+        # bfloat16 rows and weights: the two sum in another order and round
+        # p apart (the chip's bench holds them to the same 2e-2)
+        assert np.abs(got[live] - want[live]).max() \
+            <= 2e-2 * np.abs(want[live]).max()
+    assert not got[B - 1].any()
+    counts = np.asarray(counts)
+    assert counts[:, 0].tolist() == [int(e[-1]) for e in ends[:-1]] + [0]
+    assert (counts[live, 1] >= 1).all() and counts[B - 1, 1] == 0
+
+
 # --------------------------------------------------------------------------- #
 # through DecodeServer
 # --------------------------------------------------------------------------- #
@@ -414,15 +490,23 @@ def test_prefix_hit_and_one_chunk_serve_the_cold_stream(tiny):
 
 
 def test_walk_counter_reaches_stats(monkeypatch):
-    """With the kernel interpreted the step's walks count every cached row
+    """With the kernels interpreted the step's walks count every cached row
     and the new one of every live slot in every latent layer: the server's
-    counter and ``stats()``'s mean a step."""
-    monkeypatch.setenv("MXNET_FLASH_INTERPRET", "1")
+    counter and ``stats()``'s mean a step.  The chunks' walks count apart,
+    each its last query's end a layer; the streams are the dense form's."""
+    monkeypatch.delenv("MXNET_FLASH_INTERPRET", raising=False)
     net, _, _, _ = _kernel_model()
-    srv = _server(net, page_size=8, num_pages=128, prefill_buckets=(16,),
-                  admit_sizes=(1,))
+    kw = dict(page_size=8, num_pages=128, prefill_buckets=(16,),
+              admit_sizes=(1,))
     prompt = _tokens(20, seed=1)
-    _drain(srv, [srv.submit(prompt, max_new_tokens=5)])
+    dense = _server(net, **kw)
+    want, = _drain(dense, [dense.submit(prompt, max_new_tokens=5)])
+    assert dense.stats()["counters"]["chunk_latent_rows_walked"] == 0
+    dense.close()
+    monkeypatch.setenv("MXNET_FLASH_INTERPRET", "1")
+    srv = _server(net, **kw)
+    got, = _drain(srv, [srv.submit(prompt, max_new_tokens=5)])
+    assert got == want
     for _ in range(3):      # the readbacks of steps still in flight
         srv.pump()
     st = srv.stats()
@@ -432,6 +516,10 @@ def test_walk_counter_reaches_stats(monkeypatch):
     assert st["latent_rows_walked_per_step"] == pytest.approx(
         rows / st["steps"])
     assert st["latent_copies_per_step"] * st["steps"] >= 4 * 4
+    # two chunks of 16 in four layers: positions 0-15, then 16-31 of the
+    # four pages the prompt and its answer hold (its padding rows too)
+    assert st["counters"]["chunk_dispatches"] == 2
+    assert st["counters"]["chunk_latent_rows_walked"] == 4 * (16 + 32)
     srv.close()
 
 

@@ -441,7 +441,8 @@ class TestServeCounterView:
                           "draft_accepted", "draft_rejected",
                           "hit_dispatches", "admit_rows", "admit_tokens",
                           "compiles", "compile_ms", "chunk_expert_tokens",
-                          "chunk_experts_touched", "latent_rows_walked"}
+                          "chunk_experts_touched", "latent_rows_walked",
+                          "chunk_latent_rows_walked"}
         v.inc("step_dispatches")
         v["step_dispatches"] += 2        # MutableMapping read-modify
         assert v["step_dispatches"] == 3
