@@ -21,6 +21,7 @@ from .trinity import Trinity, TrinityConfig, trinity_tiny
 from .granite_hybrid import (GraniteHybrid, GraniteHybridConfig,
                              granite_hybrid_tiny)
 from .pangu_moe import PanguUltraMoE, PanguUltraMoEConfig, pangu_tiny
+from .brumby import Brumby, BrumbyConfig, brumby_tiny
 from .seq2seq import (CrossAttention, Seq2SeqEncoder, Seq2SeqDecoder,
                       Seq2SeqDecoderCell, TransformerSeq2Seq)
 
@@ -36,5 +37,6 @@ __all__ = [
     "Trinity", "TrinityConfig", "trinity_tiny",
     "GraniteHybrid", "GraniteHybridConfig", "granite_hybrid_tiny",
     "PanguUltraMoE", "PanguUltraMoEConfig", "pangu_tiny",
+    "Brumby", "BrumbyConfig", "brumby_tiny",
     "kv_generate", "decode_mode", "decode_step_program",
 ]

@@ -29,6 +29,13 @@ Kinds implemented here:
   ``ssm_state`` under the SLOT table: the recurrent state and the
   convolution's tail, one entry a slot addressed by the slot's own index,
   updated in place at every token, never shared, never paged;
+- attention ``retention``: degree-2 power retention (``ops.power_retention``:
+  grouped-query projections with ``qk_norm`` and RoPE, a decay a token a KV
+  head, the state of each KV head read by its group's query heads); cache
+  kind ``retention_state`` under the SLOT table: the state ``S`` and its
+  normaliser ``z``, one entry a slot, updated in place at every token by the
+  kernel ``mx_retention_update``.  A model of such layers alone holds no
+  pages;
 - attention ``gqa``: grouped-query attention at the description's own
   ``scale``, by the description's optional keys: ``theta`` (RoPE on q and k;
   none without it: no positions at all), ``rope`` ``"halves"`` (the halves
@@ -77,6 +84,7 @@ from ..ops import index_scores as _index
 from ..ops import latent_attention as _latent
 from ..ops import moe, ssd
 from ..ops import paged_attention as _paged
+from ..ops import power_retention as _ret
 from ..serve.schema import pool_rows, row_lanes
 
 __all__ = ["LayeredEngine", "top_mask", "mask_positions", "top_positions"]
@@ -307,6 +315,7 @@ class LayeredEngine:
     # two latent branches).  A model is served by one body, so it names
     # kinds of one body only; a kind the table lacks raises at build
     _KINDS = {"ssm": "_ssm_mixer", "gqa": "_gqa_mixer",
+              "retention": "_retention_mixer",
               "latent_sparse": None, "latent_window": None, "latent": None}
 
     def __init__(self, model, B, P, total, temperature=0.0, top_k=0,
@@ -346,10 +355,20 @@ class LayeredEngine:
             of_kind("latent_window")
         self.kv, self.ssm = of_kind("kv"), of_kind("ssm_state")
         self.kvw = of_kind("kv_window")
+        self.ret = of_kind("retention_state")
         # the cache kinds whose arrays are addressed by the slot's own
         # index (``serve.schema.POOL_ROWS``: table "slot")
         self.slot_kinds = sorted({d["cache"] for d in self.desc
                                   if pool_rows(d["cache"])[0] == "slot"})
+        if len(self.slot_kinds) > 1:
+            from ..base import MXNetError
+            raise MXNetError(
+                "the layered decode engine keeps one kind of state under "
+                f"the slot table, not {self.slot_kinds}")
+        # what ``vp`` carries besides the main table's arrays: the window
+        # table's, or the slot table's (``ssm_state`` where neither)
+        self.other_kind = "kv_window" if self.kvw else \
+            (self.slot_kinds or ["ssm_state"])[0]
         # maximal runs of like layers ``(first layer, layers)``: what a
         # model that stacks its weights hands a scan each
         self.runs, i = [], 0
@@ -385,7 +404,7 @@ class LayeredEngine:
             self.rows["k"] = self.rows["v"] = row_lanes(
                 a["kv_heads"] * a["head_dim"])
         if self.kvw:
-            if self.ssm:
+            if self.slot_kinds:
                 # ``vp`` carries the window table's arrays or the slot
                 # table's, not both
                 from ..base import MXNetError
@@ -406,6 +425,13 @@ class LayeredEngine:
             self.conv_width = a["heads"] * a["head_dim"] + 2 * a["state"]
             self.rows["conv_tail"] = (a["conv"] - 1) * row_lanes(
                 self.conv_width)
+        if self.ret:
+            a = self.desc[self.ret[0]]["attn"]
+            # a slot's state as stored (``ops.power_retention``: ``v``'s
+            # coordinates on the sublanes, the expansion on the lanes) and
+            # its normaliser
+            self.state_shape = (a["kv_heads"], a["head_dim"],
+                                _ret.expanded_rows(a["head_dim"]))
 
     # -- what serve.engine.PoolPrograms reads --------------------------- #
     def take_operands(self):
@@ -436,6 +462,10 @@ class LayeredEngine:
     def slot_state_bytes(self):
         """Bytes ONE slot keeps under the slot table over every layer that
         has such state (0 where the model has none)."""
+        if self.ret:
+            G, _, E = self.state_shape
+            return len(self.ret) * (math.prod(self.state_shape) + G * E) \
+                * _ret.STATE_DTYPE.itemsize
         if not self.ssm:
             return 0
         return len(self.ssm) * (
@@ -453,8 +483,8 @@ class LayeredEngine:
         """``(kp, vp)``: ``kp`` the main-table pools (``(latent, index
         key)`` or ``(k, v)``), each ``(layers, pages, page, lanes)``;
         ``vp`` the window-table pools (the latent one, or ``(k, v)``), or —
-        a model with state under the SLOT table — ``(state, conv tail)``,
-        each ``(layers, slots, ...)``."""
+        a model with state under the SLOT table — ``(state, conv tail)`` or
+        ``(state, z)``, each ``(layers, slots, ...)``."""
         z = lambda n, p, w: jnp.zeros((n, p, page, w), self.cdtype)
         if self.stacked:
             kp = (z(len(self.kv), num_pages, self.rows["k"]),
@@ -464,6 +494,12 @@ class LayeredEngine:
                               self.rows["window_k"]),
                             z(len(self.kvw), window_pages,
                               self.rows["window_v"]))
+            if self.ret:
+                G, _, E = self.state_shape
+                n = len(self.ret)
+                return kp, (jnp.zeros((n, slots) + self.state_shape,
+                                      _ret.STATE_DTYPE),
+                            jnp.zeros((n, slots, G, E), _ret.STATE_DTYPE))
             vp = (jnp.zeros((len(self.ssm), slots) + self.state_shape,
                             ssd.STATE_DTYPE),
                   jnp.zeros((len(self.ssm), slots, self.rows["conv_tail"]),
@@ -700,8 +736,9 @@ class LayeredEngine:
         cfg = self.cfg
         eps = cfg.rms_norm_eps
         main, other = pools
-        held = {"kv": main, "kv_window": other if self.kvw else (),
-                "ssm_state": () if self.kvw else other}
+        slot = self.other_kind if self.slot_kinds else None
+        held = {"kv": main, "kv_window": (), "ssm_state": (),
+                self.other_kind: other}
         ptm, ptw = tables if isinstance(tables, tuple) else (tables, None)
         B, C = toks.shape
         pos = off[:, None] + jnp.arange(C, dtype=jnp.int32)[None]
@@ -712,20 +749,20 @@ class LayeredEngine:
                "ring": ptw, "key_pages": key_pages, "live": live}
         step = C == 1
         mem = None
-        if not step and self.ssm:
+        if not step and slot:
             fresh = off == 0
             mem = tuple(jnp.where(
                 fresh.reshape((1, B) + (1,) * (a.ndim - 2)), 0,
-                _slot_rows(a, slots)) for a in held["ssm_state"])
+                _slot_rows(a, slots)) for a in held[slot])
         seen, after, experts = dict.fromkeys(held, 0), [], []
         for rw, (first, n) in zip(w["runs"], self.runs):
             d = self.desc[first]
             kind, cache = d["attn"]["kind"], d["cache"]
             mixer = getattr(self, self._KINDS[kind])
-            carried = () if kind == "ssm" and not step else held[cache]
+            rowwise = cache == slot and not step
+            carried = () if rowwise else held[cache]
             lo = seen[cache]
-            rows = tuple(a[lo:lo + n] for a in mem) \
-                if kind == "ssm" and mem is not None else ()
+            rows = tuple(a[lo:lo + n] for a in mem) if rowwise else ()
             # the run's routed experts stay whole, closed over: the
             # grouped product takes the layer's by index (``ops.moe``)
             whole = {k: v for k, v in rw.items() if k in _EXPERT_WEIGHTS}
@@ -755,16 +792,16 @@ class LayeredEngine:
                 layer, (x, carried),
                 (rw, jnp.arange(n, dtype=jnp.int32), rows))
             seen[cache] += n
-            if kind != "ssm" or step:
-                held[cache] = carried
-            else:
+            if rowwise:
                 after.append(rows)
+            else:
+                held[cache] = carried
             if d["ffn"]["kind"] == "routed":
                 experts.append(eidx)
         if after:
-            held["ssm_state"] = tuple(
+            held[slot] = tuple(
                 _slot_rows_set(a, slots, jnp.concatenate(parts))
-                for a, parts in zip(held["ssm_state"], zip(*after)))
+                for a, parts in zip(held[slot], zip(*after)))
         with jax.named_scope("mx.head"):
             if last is not None:
                 x = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
@@ -778,8 +815,7 @@ class LayeredEngine:
                                     preferred_element_type=jnp.float32)
             logits = logits / getattr(cfg, "logits_scaling", 1.0)
         aux = {"expert": jnp.concatenate(experts)} if experts else {}
-        return logits, held["kv"], \
-            held["kv_window" if self.kvw else "ssm_state"], aux
+        return logits, held["kv"], held[self.other_kind], aux
 
     def _ssm_mixer(self, lw, a, h, held, li, ctx, rows):
         """One state-space layer over ``h`` ``(B, C, H)``.  The step (``C ==
@@ -840,6 +876,50 @@ class LayeredEngine:
                      ).astype(h.dtype)
         o = _dot(g, lw["out_weight"]).astype(f32)
         return (o, (state, tail), ()) if step else (o, held, (s1, t1))
+
+    def _retention_mixer(self, lw, a, h, held, li, ctx, rows):
+        """One power-retention layer over ``h`` ``(B, C, H)``
+        (``ops.power_retention``): q, k, v by grouped-query projections,
+        q and k through their RMSNorm and RoPE (``rope`` ``"halves"``), a
+        decay ``log a = logsigmoid(h W_a)`` a token a KV head.  The step
+        (``C == 1``) updates layer ``li`` of ``held = (state, z)`` in place
+        for the live slots; a prefill starts from ``rows = (state (B, ...),
+        z (B, ...))`` as stored and hands the rows' new entries back."""
+        B, C, _ = h.shape
+        hq, kvh, d = a["heads"], a["kv_heads"], a["head_dim"]
+        eps = self.cfg.rms_norm_eps
+        f32 = jnp.float32
+        q = _dot(h, lw["q_weight"]).reshape(B, C, hq, d)
+        k = _dot(h, lw["k_weight"]).reshape(B, C, kvh, d)
+        v = _dot(h, lw["v_weight"]).reshape(B, C, kvh, d)
+        with jax.named_scope("mx.qk_norm_rope"):
+            halves = a.get("rope") == "halves"
+            q = _rope(_rms(q, lw["qnorm_gamma"], eps), ctx["pos"],
+                      a["theta"], halves)
+            k = _rope(_rms(k, lw["knorm_gamma"], eps), ctx["pos"],
+                      a["theta"], halves)
+        log_a = jax.nn.log_sigmoid(jnp.dot(h, lw["gate_weight"],
+                                           preferred_element_type=f32))
+        step = C == 1
+        # the regions of a slot-table state: the step's in-place update, and
+        # prefill's chunked form
+        if step:
+            state, z = held
+            live = jnp.ones((B,), jnp.bool_) if ctx["live"] is None \
+                else ctx["live"]
+            with jax.named_scope("mx.ssm_state"):
+                y, state, z = _ret.state_update(
+                    state, z, li, q[:, 0], k[:, 0], v[:, 0], log_a[:, 0],
+                    live, a["eps"])
+            y = y[:, None]
+        else:
+            with jax.named_scope("mx.ssm_scan"):
+                y, s1, z1 = _ret.chunk_scan(q, k, v, log_a, *rows,
+                                            ctx["count"], a["chunk"],
+                                            a["eps"])
+        o = _dot(y.astype(h.dtype).reshape(B, C, hq * d),
+                 lw["o_weight"]).astype(f32)
+        return (o, (state, z), ()) if step else (o, held, (s1, z1))
 
     def _gqa_mixer(self, lw, a, h, held, li, ctx, mem=()):
         """One grouped-query attention layer by the description's keys:

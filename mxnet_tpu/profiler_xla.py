@@ -230,6 +230,11 @@ def _device_planes(data):
 # Since PR 39 a TPU lowering of the routed experts' two products is ONE
 # ``pallas_call``, ``mx_moe_gmm`` (``ops/grouped_matmul.py``), under
 # ``mx.moe_experts`` too; shapes it takes no row tile for keep ragged-dot.
+# A power-retention layer's step kernel, ``mx_retention_update``
+# (``ops/power_retention.py``), updates a slot-table state in place as
+# ``mx_ssm_update`` does, under the same region; its prefill's two
+# products with the expansion, ``mx_retention_read`` and
+# ``mx_retention_write``, are prefill's scan as ``ops.ssd.chunk_scan`` is.
 _KERNEL_REGIONS = (("mx_moe_gmm", "mx.moe_experts"),
                    ("ragged-dot", "mx.moe_experts"),
                    # the same kernel walking a window layer's ring: its
@@ -238,6 +243,9 @@ _KERNEL_REGIONS = (("mx_moe_gmm", "mx.moe_experts"),
                    ("mx_paged_attention", "mx.attn"),
                    ("mx_flash", "mx.attn"),
                    ("mx_ssm_update", "mx.ssm_state"),
+                   ("mx_retention_update", "mx.ssm_state"),
+                   ("mx_retention_read", "mx.ssm_scan"),
+                   ("mx_retention_write", "mx.ssm_scan"),
                    ("mx_index_scores", "mx.index"),
                    ("mx_latent_paged_attention", "mx.latent_attn"))
 

@@ -326,18 +326,24 @@ class PoolPrograms:
         # span and every table row cover MAXP pages
         self.Tp = -(-self.T // self.page) * self.page
         self.maxp = self.Tp // self.page
-        self.num_pages = self.S * self.maxp if num_pages is None \
-            else int(num_pages)
-        if self.num_pages < 1:
-            raise MXNetError(f"num_pages must be >= 1, "
-                             f"got {self.num_pages}")
-        # one-past-the-end page id: gathers clamp it, scatters drop
-        self.sentinel = self.num_pages
         self.temperature, self.top_k = float(temperature), int(top_k)
         self.eos_id = None if eos_id is None else int(eos_id)
         self.weights = weights
         self.eng = decode_engine(model, self.S, 1, self.Tp, temperature,
                                  top_k, "batched", weights, "auto")
+        # a layered model with no layer under the main table (every layer's
+        # memory under the slot table) holds no pages: none is reserved,
+        # and its page pool is empty
+        self.paged = not (self.eng.mode == "layered"
+                          and getattr(self.eng, "window", None) is None
+                          and self.eng.main_page_bytes(self.page) == 0)
+        self.num_pages = (self.S * self.maxp if self.paged else 0) \
+            if num_pages is None else int(num_pages)
+        if self.paged and self.num_pages < 1:
+            raise MXNetError(f"num_pages must be >= 1, "
+                             f"got {self.num_pages}")
+        # one-past-the-end page id: gathers clamp it, scatters drop
+        self.sentinel = self.num_pages
         # a layered engine (per-layer kinds) brings its own row kinds and,
         # where a kind keeps a window, a second page table: a ring of
         # ``ring`` entries a slot, wide enough for the window plus the
@@ -407,8 +413,9 @@ class PoolPrograms:
         return self.eng.slot_state_bytes() if self.slot_kinds else 0
 
     def pages_for(self, total_len):
-        """Pages a sequence of ``total_len`` cached positions needs."""
-        return -(-int(total_len) // self.page)
+        """Pages a sequence of ``total_len`` cached positions needs (none
+        where the model holds no pages)."""
+        return -(-int(total_len) // self.page) if self.paged else 0
 
     def key_pages_for(self, c_bucket, reach):
         """How many of a slot's table pages a ``c_bucket``-token chunk

@@ -159,6 +159,7 @@ POOL_ROWS = {
     "latent_window": {"table": "window", "rows": ("latent",)},
     "kv_window": {"table": "window", "rows": ("k", "v")},
     "ssm_state": {"table": "slot", "rows": ("state", "conv_tail")},
+    "retention_state": {"table": "slot", "rows": ("state", "z")},
 }
 POOL_TABLES = ("main", "window", "slot")
 LANE_TILE = 128

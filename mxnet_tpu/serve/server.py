@@ -121,7 +121,8 @@ serve_counters = {"step_dispatches": 0, "admit_dispatches": 0,
                   "draft_rejected": 0, "hit_dispatches": 0,
                   "admit_rows": 0, "admit_tokens": 0,
                   "chunk_expert_tokens": 0, "chunk_experts_touched": 0,
-                  "latent_rows_walked": 0, "chunk_latent_rows_walked": 0}
+                  "latent_rows_walked": 0, "chunk_latent_rows_walked": 0,
+                  "chunk_carried_tokens": 0}
 _counters_lock = threading.Lock()
 _server_seq = itertools.count()
 
@@ -164,7 +165,11 @@ class _CounterView(MutableMapping):
     ``serve_mfu_pct.pangu`` divide by the step's time and dispatches) and
     ``chunk_latent_rows_walked`` (rows the chunks' walks reached, each
     chunk's last query's end summed over the latent layers, however many
-    of its tiles re-read them; no reader divides it by a step)."""
+    of its tiles re-read them; no reader divides it by a step).
+    ``chunk_carried_tokens`` counts the prompt tokens of the chunks that
+    continue a prompt (offset past 0): their queries read what the earlier
+    chunks left — the prompt's own pages, or a slot-table state (a power
+    retention layer's ``phi(Q) S``)."""
 
     _KEYS = ("step_dispatches", "admit_dispatches", "sync_requests",
              "pool_grows", "prefix_hits", "cow_copies",
@@ -173,7 +178,7 @@ class _CounterView(MutableMapping):
              "hit_dispatches", "admit_rows", "admit_tokens",
              "compiles", "compile_ms", "chunk_expert_tokens",
              "chunk_experts_touched", "latent_rows_walked",
-             "chunk_latent_rows_walked")
+             "chunk_latent_rows_walked", "chunk_carried_tokens")
 
     def __init__(self, server_label):
         self._c = {k: telemetry.counter(f"serve_{k}_total",
@@ -977,6 +982,9 @@ class DecodeServer:
             self._progs.page) if windowed else 0
         self._slot_wpages = [{} for _ in range(self.pool_sizes[0])]
         self._slot_pos = [0] * self.pool_sizes[0]
+        # the request each slot last admitted (its entries under the slot
+        # table hold that request's state until the next admission)
+        self._tenant = [None] * self.pool_sizes[0]
         self._wheld_max = 0     # most window pages a stepping slot held
         self._prompt_tokens = self._prompt_cached = 0
         self._state_resets = 0  # slots started from zero per-slot state
@@ -1424,6 +1432,23 @@ class DecodeServer:
         self._emit_stats()
         self._teardown(MXNetError("server closed"), reason="closed")
 
+    def slot_state(self, slot):
+        """``(request id, entries)``: slot ``slot``'s entries under the slot
+        table — a host copy of every layer's, one array a row kind its model
+        declares (``stats()["slot_kinds"]``), in the stored layout — and the
+        request they last took tokens from (``None`` before any).  A check
+        reads it: only while the server is idle (nothing queued, in a slot
+        or in flight), so that no dispatch holds the arrays."""
+        if self.sync_mode or not self._progs.slot_kinds:
+            raise MXNetError("this server keeps no state under the slot "
+                             "table")
+        if self._state is None or self._pending or self._inflight \
+                or self._chunking or any(r is not None for r in self._slots):
+            raise MXNetError("slot_state reads only an idle server: wait "
+                             "until every request has retired")
+        return self._tenant[slot], tuple(
+            onp.asarray(a[:, slot]) for a in self._state[1])
+
     def _emit_stats(self):
         """One ``serve_stats`` event per server lifetime (at close):
         the final counters + occupancy + latency summaries, so a
@@ -1800,7 +1825,7 @@ class DecodeServer:
         # an explicitly pinned page count stays pinned across growth;
         # the dense-equivalent default rescales with the slot count
         new_pages = self._pages.num_pages if self._num_pages_fixed \
-            else new_s * self._progs.maxp
+            else new_s * self._progs.maxp if self._progs.paged else 0
         self._check_budget(new_s, scratch=self._pool_bytes,
                            what=f"pool growth {S} -> {new_s} slots",
                            num_pages=new_pages)
@@ -1830,6 +1855,7 @@ class DecodeServer:
         self._slot_pages.extend([] for _ in range(new_s - S))
         self._slot_wpages.extend({} for _ in range(new_s - S))
         self._slot_pos.extend([0] * (new_s - S))
+        self._tenant.extend([None] * (new_s - S))
         self._count("pool_grows")
 
     def _admit_pending(self):
@@ -2215,6 +2241,7 @@ class DecodeServer:
         plan = self._plan_pages(req, slot, L, need, m, shared)
         if plan is None:
             return None
+        self._tenant[slot] = req.stream.request_id
         if windowed:
             # the window pages the admission dispatch itself reads and
             # writes: an admit's whole prompt, a hit's tail; a chunk takes
@@ -2456,6 +2483,8 @@ class DecodeServer:
         self._count("chunk_dispatches")
         self._count("admit_rows", C)
         self._count("admit_tokens", ntok)
+        if off:
+            self._count("chunk_carried_tokens", ntok)
         if self._progs.slot_kinds and off == 0:
             self._state_resets += 1
         rec["off"] = off + ntok

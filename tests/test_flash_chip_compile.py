@@ -197,3 +197,40 @@ def test_latent_chunk_kernel_compiles_for_v5e(one_chip, C):
         ((5, 40960, 16, 640), jnp.bfloat16), ((1, 2072), jnp.int32),
         ((1, C), jnp.int32))
     assert la._CHUNK_NAME in compiled.as_text()
+
+
+def test_retention_update_kernel_compiles_for_v5e(one_chip):
+    """The cell's step: 24 slots, 8 KV heads of 128 lanes, the expansion's
+    8,704 rows of a 128-wide head (the floor counts 8,256), five query heads
+    a group; the state's blocks of 4.5 MB in and out under the VMEM limit
+    the call asks for, the slot map and the group's row picked by masks."""
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import power_retention as pr
+    E = pr.expanded_rows(128)
+    compiled = _compile(
+        one_chip, lambda s, z, lv, pk, pq, vd: pr._kernel_call(
+            s, z, jnp.int32(3), lv, pk, pq, vd, False),
+        ((8, 24, 8, 128, E), jnp.float32), ((8, 24, 8, E), jnp.float32),
+        ((24,), jnp.bool_), ((24, 8, E), jnp.float32),
+        ((24, 8, 5, E), jnp.float32), ((24, 8, 136, 128), jnp.float32))
+    assert pr._NAME in compiled.as_text()
+
+
+@pytest.mark.parametrize("T", [128, 1024])
+def test_retention_prefill_kernels_compile_for_v5e(one_chip, T):
+    """The cell's narrowest and widest admission buckets: ``T`` tokens of 40 query
+    heads read the carried state of its 8 KV heads (``S`` with ``z`` below
+    it, 144 rows a group), and its 8 KV heads write into the state — ``phi``
+    built in VMEM a group of 512 rows at a time."""
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import power_retention as pr
+    E = pr.expanded_rows(128)
+    read = _compile(
+        one_chip, lambda x, s: pr._read_call(x, s, False),
+        ((8, 5 * T, 128), jnp.bfloat16), ((8, 144, E), jnp.bfloat16))
+    assert pr._READ_NAME in read.as_text()
+    write = _compile(
+        one_chip, lambda x, c, v: pr._write_call(x, c, v, E, False),
+        ((8, T, 128), jnp.bfloat16), ((8, T), jnp.float32),
+        ((8, T, 128), jnp.bfloat16))
+    assert pr._WRITE_NAME in write.as_text()

@@ -163,10 +163,12 @@ class TestPoolTables:
         assert {k: v["table"] for k, v in schema.POOL_ROWS.items()} == {
             "kv": "main", "latent_index": "main", "latent": "main",
             "latent_window": "window", "kv_window": "window",
-            "ssm_state": "slot"}
+            "ssm_state": "slot", "retention_state": "slot"}
         assert schema.pool_rows("kv_window") == ("window", ("k", "v"))
         assert schema.pool_rows("ssm_state") == (
             "slot", ("state", "conv_tail"))
+        assert schema.pool_rows("retention_state") == (
+            "slot", ("state", "z"))
         assert all(v["table"] in schema.POOL_TABLES
                    for v in schema.POOL_ROWS.values())
 

@@ -442,7 +442,7 @@ class TestServeCounterView:
                           "hit_dispatches", "admit_rows", "admit_tokens",
                           "compiles", "compile_ms", "chunk_expert_tokens",
                           "chunk_experts_touched", "latent_rows_walked",
-                          "chunk_latent_rows_walked"}
+                          "chunk_latent_rows_walked", "chunk_carried_tokens"}
         v.inc("step_dispatches")
         v["step_dispatches"] += 2        # MutableMapping read-modify
         assert v["step_dispatches"] == 3

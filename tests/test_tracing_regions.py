@@ -134,13 +134,17 @@ def test_region_is_the_innermost_mx_component(path, region):
      "pallas_call", "mx.index"),
     ("mx_paged_attention_window.2", "", "mx.window_attn"),
     ("mx_ssm_update.4", "", "mx.ssm_state"),
+    ("mx_retention_update.2", "", "mx.ssm_state"),
+    ("mx_retention_read.1", "", "mx.ssm_scan"),
+    ("mx_retention_write.8", "", "mx.ssm_scan"),
     ("fusion.170", "", "unscoped"),
 ], ids=["grouped_product", "paged_attention", "flash_fwd", "flash_bwd_dq",
         "flash_bwd_dkv", "flash_fwd_differentiated",
         "flash_bwd_dq_differentiated", "packed_fwd", "packed_bwd_dq",
         "packed_bwd_dkv", "packed_fwd_differentiated",
         "packed_bwd_dkv_differentiated", "provenance_wins", "index_scores",
-        "index_scores_provenance", "ring_walk", "ssm_update", "other"])
+        "index_scores_provenance", "ring_walk", "ssm_update",
+        "retention_update", "retention_read", "retention_write", "other"])
 def test_region_of_a_kernel_known_by_name(name, provenance, region):
     """A custom kernel whose device events carry no provenance is known by
     a part of its operation's name (a differentiated program wraps a

@@ -157,7 +157,7 @@ def compile_admit(progs, chip, a_bucket, p_bucket):
     args = (progs.operands[0], i32(A, P),
             i32(A, schema.meta_width("admit")),
             jax.ShapeDtypeStruct((A,), jnp.float32),
-            i32(A, progs.pages_for(P)), _tables(progs, A), *state)
+            i32(A, -(-P // progs.page)), _tables(progs, A), *state)
     return progs.admit_fn(A, P).lower(*_structs(args, chip)).compile()
 
 
@@ -345,7 +345,7 @@ def _rehearse_config(args):
     srv = config["server"]
     progs = PoolPrograms(net, srv["pool_sizes"][0], srv["max_total_len"],
                          page_size=srv.get("page_size", 16),
-                         num_pages=srv["num_pages"],
+                         num_pages=srv.get("num_pages"),
                          window_pages=srv.get("num_window_pages"),
                          max_chunk=srv["prefill_buckets"][-1])
     chip = v5e_chip()
@@ -372,7 +372,7 @@ def slot_state_faults(name, row, progs):
     the executable; the tails, 1% of it, may be re-laid), or — the step —
     reserve scratch of that size."""
     layer_state = progs.S * progs.slot_state_bytes() \
-        // max(1, len(progs.eng.ssm))
+        // max(1, len(progs.eng.ssm) + len(progs.eng.ret))
     faults = [f"{name}: {copy} copies a whole pool array of {n} bytes"
               for copy, n in row["copy_bytes"].items() if n >= layer_state]
     if name == "serve.step" and row["temp_bytes"] >= layer_state:
