@@ -12,8 +12,10 @@ Kinds implemented here:
   index keys — the single-query step walks them where they lie,
   ``ops.index_scores``; a prefill gathers their view —, an exact top-k
   WITHOUT a sort and its positions without a gather — ``top_positions``
-  —, a gather of the selected latent rows through the page table); cache kind ``latent_index``: a latent row
-  ``[c_kv | k_rope]`` and an index-key row under the MAIN page table;
+  —, the selected positions' page ids by a one-hot product, not a gather
+  — ``block_pages`` —, a gather of the selected latent rows); cache kind
+  ``latent_index``: a latent row ``[c_kv | k_rope]`` and an index-key row
+  under the MAIN page table;
 - attention ``latent_window``: latent attention over the last ``window``
   positions; cache kind ``latent_window``: one latent row under the WINDOW
   page table, a ring of ``ring`` entries a slot indexed by ``(position //
@@ -87,7 +89,8 @@ from ..ops import paged_attention as _paged
 from ..ops import power_retention as _ret
 from ..serve.schema import pool_rows, row_lanes
 
-__all__ = ["LayeredEngine", "top_mask", "mask_positions", "top_positions"]
+__all__ = ["LayeredEngine", "top_mask", "mask_positions", "block_positions",
+           "block_pages", "top_positions"]
 
 # a routed layer's parameters that a scan over a stacked run does not slice
 _EXPERT_WEIGHTS = ("egu_weight", "edown_weight")
@@ -212,9 +215,16 @@ def top_mask(score, valid, k):
 
 def mask_positions(chosen, k):
     """The positions of the ``k`` set entries of every row of ``chosen``
+    ``(N, T)``, ascending (``block_positions`` without its one-hot)."""
+    return block_positions(chosen, k)[0]
+
+
+def block_positions(chosen, k):
+    """The positions of the ``k`` set entries of every row of ``chosen``
     ``(N, T)``, ascending, by blocks of 128, WITHOUT a gather (the chip
     walks a gather's indices one by one: three of them were 1.76 ms a layer
-    of the ``dots3`` step, PERF.md PR 37):
+    of the ``dots3`` step), and the one-hot ``(N, k,
+    blocks)`` bfloat16 of the block each lies in, for ``block_pages``:
 
     1. every set entry's rank inside its block, 1 .. 128, from a product
        with a triangle of ones (0 where the entry is not set), and with it
@@ -246,11 +256,48 @@ def mask_positions(chosen, k):
     blk = jnp.sum(passed, axis=-1, dtype=jnp.int32)             # (N, k)
     nth = j[None] - jnp.sum(
         jnp.where(passed, count[:, None, :nb - 1], 0), axis=-1)
-    onehot = jnp.arange(nb, dtype=jnp.int32) == blk[..., None]
-    picked = jnp.einsum("nkb,nbw->nkw", onehot.astype(jnp.bfloat16), rank,
+    onehot = (jnp.arange(nb, dtype=jnp.int32) == blk[..., None]).astype(
+        jnp.bfloat16)
+    picked = jnp.einsum("nkb,nbw->nkw", onehot, rank,
                         preferred_element_type=jnp.float32)     # (N, k, W)
     here = picked == (nth + 1)[..., None].astype(jnp.float32)
-    return blk * W + jnp.argmax(here, axis=-1).astype(jnp.int32)
+    return blk * W + jnp.argmax(here, axis=-1).astype(jnp.int32), onehot
+
+
+def block_pages(onehot, sel, table, page, npages):
+    """``table[b, sel // page]`` — the page id of each selected position —
+    WITHOUT a gather (a ``take_along_axis`` over the table was 0.67 ms a
+    layer of the ``dots3`` step and 1.87 ms of a question chunk, PERF.md
+    section 5).  ``sel`` ``(B, C, k)`` and
+    its blocks' one-hot ``onehot`` ``(B, C, k, blocks)`` from
+    ``block_positions``; ``table`` ``(B, n)`` of ids below ``npages``, one
+    row for the ``C`` queries of a slot.
+
+    A stretch of ``L = lcm(page, 128)`` positions is ``L // 128`` blocks and
+    ``L // page`` pages.  Each block takes its stretch's page ids as columns,
+    split into bytes (exact in bfloat16); the one-hot product picks the
+    position's block's columns (a one-hot row sums one term: exact in
+    float32), a select the page inside the stretch, and the bytes join
+    again in int32."""
+    W = 128
+    nb = onehot.shape[-1]
+    B, n = table.shape
+    L = math.lcm(page, W)
+    per = L // page
+    spans = -(-nb * W // L)
+    parts = max(1, -(-(npages - 1).bit_length() // 8))
+    ids = jnp.pad(table, ((0, 0), (0, spans * per - n)))
+    cols = jnp.stack([(ids >> (8 * i)) & 0xFF for i in range(parts)], -1)
+    cols = jnp.repeat(cols.reshape(B, spans, per * parts), L // W,
+                      axis=1)[:, :nb].astype(jnp.bfloat16)
+    got = jnp.einsum("bckn,bnp->bckp", onehot, cols,
+                     preferred_element_type=jnp.float32)
+    got = got.reshape(*sel.shape, per, parts)
+    inner = (sel % L) // page
+    byte = jnp.sum(jnp.where(
+        (inner[..., None] == jnp.arange(per, dtype=jnp.int32))[..., None],
+        got, 0.0), axis=-2).astype(jnp.int32)                  # (.., parts)
+    return sum(byte[..., i] << (8 * i) for i in range(parts))
 
 
 def _index_scores_view(iq, iw, ikp, fi, table, page):
@@ -1142,8 +1189,8 @@ class LayeredEngine:
         """A ``latent_sparse`` layer's attention over the ``topk`` positions
         its indexer selects (``_select``): the dense form masked to the set
         from ``dense_chunk`` queries a row, else the selected rows gathered
-        through the page table.  ``aux`` gains what the selection
-        counted."""
+        from the pool at the page ids ``block_pages`` finds.  ``aux`` gains
+        what the selection counted."""
         B, C = pos.shape
         kp_n = table.shape[1]
         with jax.named_scope("mx.index"):
@@ -1160,12 +1207,12 @@ class LayeredEngine:
                                           chosen)
         K = min(int(a["topk"]), kp_n * page)
         with jax.named_scope("mx.index"):
-            sel = mask_positions(full.reshape(B * C, -1),
-                                 K).reshape(B, C, K)
+            sel, blocks = block_positions(full.reshape(B * C, -1), K)
+            sel, blocks = sel.reshape(B, C, K), blocks.reshape(B, C, K, -1)
             # fewer than K seen: the rest point past ``pos``
             ok = sel <= pos[..., None]
         with jax.named_scope("mx.latent_gather"):
-            pgs = jnp.take_along_axis(reach[:, None, :], sel // page, axis=2)
+            pgs = block_pages(blocks, sel, reach, page, lat.shape[1])
             rows = lat.at[fi, pgs, sel % page].get(
                 mode="promise_in_bounds")                   # (B, C, K, W)
         with jax.named_scope("mx.latent_attn"):

@@ -600,3 +600,96 @@ def test_top_positions_is_an_exact_top_k(N, T, k, ties):
         v = int(nvalid[n])
         want = np.lexsort((np.arange(v), -s[n, :v]))[:min(k, v)]
         assert set(got[n][got[n] < v].tolist()) == set(want.tolist())
+
+
+def _table(B, n, npages, seed):
+    """Random page ids below ``npages``, every fifth entry its largest
+    (``npages - 1``, where the program clamps a sentinel)."""
+    t = np.random.default_rng(seed).integers(0, npages, (B, n))
+    t[:, ::5] = npages - 1
+    return t.astype(np.int32)
+
+
+@pytest.mark.parametrize("case,B,C,n,page,k,npages", [
+    ("spread", 1, 1, 65, 16, 100, 65536),       # T 1,040: off the grid
+    ("spread", 32, 1, 2072, 16, 2048, 65536),   # the step's 32 rows
+    ("spread", 1, 128, 44, 16, 64, 65536),      # a chunk's 128 rows
+    ("spread", 3, 2, 250, 4, 100, 300),
+    ("spread", 2, 3, 21, 48, 100, 70000),       # pages across blocks
+    ("spread", 2, 1, 4, 256, 64, 9),            # blocks inside a page
+    ("all", 2, 1, 75, 4, 300, 65536),           # k = T
+    ("one", 2, 2, 33, 8, 1, 65536),
+    ("one_block", 5, 1, 125, 8, 100, 65536),
+    ("last_block", 2, 2, 125, 8, 60, 65536),    # the padded last block
+    ("short", 2, 4, 125, 8, 100, 65536)],       # fewer than k set
+    ids=lambda v: str(v))
+def test_block_pages_are_the_tables_ids(case, B, C, n, page, k, npages):
+    """``block_pages`` of ``block_positions``' one-hot against
+    ``np.take_along_axis`` over the table: the same int32 ids for one
+    query, a step's 32 slots and a chunk's 128 queries of one slot; ``T``
+    off the blocks' grid, every position chosen, one chosen, all in one
+    block, all in the padded last block, rows with fewer than ``k`` set
+    (their rest read the last block's first position); ids of one, two and
+    three bytes, the largest of them ``npages - 1``."""
+    T = n * page
+    chosen = _chosen(case, B * C, T, k)
+    table = _table(B, n, npages, seed=n)
+    table[0, _positions(chosen, k)[0, 0] // page] = npages - 1
+
+    def run(chosen, table):
+        sel, blocks = layered.block_positions(chosen, k)
+        sel = sel.reshape(B, C, k)
+        return sel, layered.block_pages(blocks.reshape(B, C, k, -1), sel,
+                                        table, page, npages)
+
+    sel, got = jax.jit(run)(jnp.asarray(chosen), jnp.asarray(table))
+    assert got.dtype == jnp.int32 and got.shape == (B, C, k)
+    np.testing.assert_array_equal(np.asarray(sel).reshape(B * C, k),
+                                  _positions(chosen, k))
+    want = np.take_along_axis(table[:, None, :], np.asarray(sel) // page,
+                              axis=2)
+    np.testing.assert_array_equal(np.asarray(got), want)
+    assert (want == npages - 1).any()
+
+
+def _take_along_pages(onehot, sel, table, page, npages):
+    """The page ids as a gather over the table finds them."""
+    return jnp.take_along_axis(table[:, None, :], sel // page, axis=2)
+
+
+@pytest.mark.parametrize("C", [1, 5], ids=["step", "chunk"])
+def test_gather_form_is_bit_identical_to_take_along_axis(tiny, C,
+                                                         monkeypatch):
+    """The selecting layers' gather form through ``block_pages`` against
+    the same code with the page ids of a ``take_along_axis`` over the table
+    (``_take_along_pages``): two slots, 40 cached positions each (past the
+    indexer's top-8) through scattered pages, then a step or a 5-token
+    chunk — the same logits and pools, bit for bit."""
+    net, _, _, _ = tiny
+    page, B, P = 4, 2, 40
+    eng = layered.LayeredEngine(net, B, 1, 64)
+    assert C < eng.dense_chunk
+    weights = net.weights()
+    toks = _tokens(P + C, seed=9, rows=B)
+    ring = eng.window_span_pages(page, 8) + 1
+    perm = np.random.default_rng(2).permutation(256)[:B * 16]
+    ptm = jnp.asarray(perm.reshape(B, 16).astype(np.int32))
+    ptw = jnp.asarray(np.arange(B * ring, dtype=np.int32).reshape(B, ring))
+    pools = eng.pool_zeros(256, B * ring, page)
+
+    def program():      # traced anew: it reads ``block_pages`` then
+        return jax.jit(lambda tk, off, pools: eng.tokens_paged(
+            weights, tk, off, (ptm, ptw), pools, page,
+            jnp.full((B,), tk.shape[1] - 1, jnp.int32))[:3])
+
+    fill = program()
+    for at in range(0, P, 8):
+        _, kp, vp = fill(jnp.asarray(toks[:, at:at + 8]),
+                         jnp.full((B,), at, jnp.int32), pools)
+        pools = (kp, vp)
+    last = (jnp.asarray(toks[:, P:]), jnp.full((B,), P, jnp.int32), pools)
+    got = program()(*last)
+    monkeypatch.setattr(layered, "block_pages", _take_along_pages)
+    want = program()(*last)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))

@@ -13,6 +13,8 @@ the int8 pool keeps the view path.
 Every test here uses the ``chip`` fixture, which describes the topology
 inside the test's own process and skips where the TPU compiler cannot.
 """
+import math
+
 import numpy as onp
 import pytest
 
@@ -236,19 +238,33 @@ def test_layered_selection_gathers_nothing(layered_reports, layered_progs,
     ``seen`` ``(N, T)`` at the positions (the chip's compiler rewrites such
     a gather and drops its provenance, so they are told by what they
     gather FROM, in any region): none of them is left, for the step's one
-    query a slot or a chunk's 64.  Under ``mx.index`` the step's TPU
-    lowering holds no ``gather`` at all — the rotation's even and odd
-    lanes are strided slices — and a chunk's only one is the key view's,
-    out of the index-key pool.  The latent rows of the selected positions
-    are still gathered, under their own region."""
+    query a slot or a chunk's 64.  Nor is the lookup of the
+    selected positions' page ids in the page table (``block_pages``: a
+    one-hot product): the only gathers from the table, in any region, are
+    the new rows' writes, one id a query, never one a selected position.
+    Under ``mx.index`` the step's TPU lowering holds no ``gather`` at all —
+    the rotation's even and odd lanes are strided slices — and a chunk's
+    only one is the key view's, out of the index-key pool.  Under
+    ``mx.latent_gather`` each selecting layer holds one gather, of the
+    selected latent rows out of the latent pool."""
     report = layered_reports[which]
     N = L_SLOTS if rows == "slots" else rows
-    T = layered_progs.maxp * PAGE
+    maxp = layered_progs.maxp
+    T = maxp * PAGE
     gone = {f"pred[{N},{T}]", f"pred[{N},1,{T}]", f"pred[1,{N},{T}]",
             f"pred[{N},{T // 128},128]", f"s32[{N},{T // 128}]"}
     sources = {t for found in report["gathers"].values() for t in found}
     assert not gone & sources, report["gathers"]
-    assert report["gathers"]["mx.latent_gather"]
+    # the step's rows of the table, or a chunk's one slot's row
+    table = {f"s32[{L_SLOTS},{maxp}]", f"s32[1,{maxp}]", f"s32[{maxp}]"}
+    for found in report["gather_results"].values():
+        for source, result in found:
+            if source in table:
+                assert math.prod(int(d) for d in result.split("[")[1]
+                                 .rstrip("]").split(",") if d) <= N, found
+    latent = f"bf16[2,{L_PAGES},{PAGE},256]"
+    assert report["gathers"]["mx.latent_gather"] == [latent] * len(
+        layered_progs.eng.idx), report["gathers"]
     pool = f"bf16[2,{L_PAGES},{PAGE},128]"
     assert set(report["gathers"].get("mx.index", [])) == (
         set() if which == "step" else {pool}), report["gathers"]

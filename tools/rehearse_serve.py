@@ -185,7 +185,8 @@ def pool_report(compiled, progs):
     (``profiler_xla.region_of``; ``unscoped`` where the chip's compiler
     rewrote a small one and dropped its provenance, so tell those by what
     they gather from): the chip walks a gather's indices one by one, so a
-    wide one inside a step is a finding."""
+    wide one inside a step is a finding.  ``gather_results`` pairs each of
+    those types with the type of what the gather hands back."""
     from mxnet_tpu.profiler_xla import region_of
 
     shapes = pool_shapes(progs)
@@ -237,7 +238,7 @@ def pool_report(compiled, progs):
             source = re.search(r" gather\(%?([\w.\-]+)", line)
             where = re.search(r'op_name="([^"]*)"', line)
             gathered.append((region_of(where.group(1) if where else ""),
-                             source.group(1)))
+                             source.group(1), m.group("type")))
         if 'custom_call_target="tpu_custom_call"' in line:
             kernels.append(m.group("name"))
         if any(v in m.group("type") for v in views):
@@ -258,10 +259,12 @@ def pool_report(compiled, progs):
         if op == "fusion":
             op = f"fusion:{roots.get(called, '?')}"
         sized.setdefault(op, []).append(name)
-    gathers = {}
-    for region, source in gathered:
-        gathers.setdefault(region, []).append(
-            types.get(source, source).split("{")[0])
+    gathers, gather_results = {}, {}
+    for region, source, result in gathered:
+        source = types.get(source, source).split("{")[0]
+        gathers.setdefault(region, []).append(source)
+        gather_results.setdefault(region, []).append(
+            (source, result.split("{")[0]))
     ma = compiled.memory_analysis()
     return {"temp_bytes": ma.temp_size_in_bytes,
             "argument_bytes": ma.argument_size_in_bytes,
@@ -273,7 +276,8 @@ def pool_report(compiled, progs):
             "copy_bytes": copy_bytes,
             "kernels": kernels,
             "view_sized": view_sized,
-            "gathers": gathers}
+            "gathers": gathers,
+            "gather_results": gather_results}
 
 
 def main(argv=None):
