@@ -29,8 +29,13 @@ a[g])`` in ``(0, 1)``; each query head ``h`` of the group reads it::
   (the serving step).  Each live slot's state is read once and written once,
   in place: on a TPU by the Pallas kernel ``mx_retention_update`` (the decay,
   the rank-one update, ``z`` and the group's readouts in one pass over a
-  group's block); elsewhere by the same arithmetic in ``jax.numpy``, which is
-  the kernel's reference.  A slot that is not live moves no bytes.
+  group's block), whose only operand besides ``S`` and ``z`` is a slot's
+  128-wide ``q``, ``k``, ``v`` and decay packed in one ``(128, 128)`` block:
+  at the slot's first group it builds ``phi`` of the slot's query heads and
+  keys in VMEM (``expand``'s rows to 2 ulp), and each group takes ``v``'s
+  column from the block transposed; elsewhere by the same arithmetic in
+  ``jax.numpy`` over ``expand``, which is the kernel's reference.  A slot
+  that is not live moves no bytes.
 - ``chunk_scan``: ``T`` tokens a row from a given state (prefill), in the
   chunked form: within a chunk the gated scores ``(q . k)^2 exp(G_t -
   G_s)``, across chunks ``phi(Q) S`` and ``phi(K)^T V``.  A right-padded
@@ -87,10 +92,10 @@ def exact_rows(d):
 
 
 def supports(d, dv):
-    """Whether the kernel takes a state of these widths: whole tiles of the
-    expansion, whole 128-lane chunks of it, whole sublane tiles of ``v``."""
-    return d % _TILE == 0 and expanded_rows(d) % _LANES == 0 \
-        and dv % 8 == 0
+    """Whether the step's kernel takes a state of these widths: ``phi``
+    built as prefill's kernels build it (``prefill_kernels``; the expansion
+    then whole 128-lane chunks too), whole sublane tiles of ``v``."""
+    return prefill_kernels(d) and dv % 8 == 0
 
 
 def expand(x):
@@ -104,10 +109,11 @@ def expand(x):
         raise ValueError(f"power retention needs a width of whole tiles of "
                          f"{_TILE}, not {d}")
     t, N = d // _TILE, math.prod(lead)
-    x = jnp.moveaxis(x.astype(jnp.float32).reshape(N, t, _TILE), 0, -1)
+    tiles = jnp.moveaxis(x.astype(jnp.float32).reshape(N, t, _TILE), 0, -1)
     offsets = t // 2 + 1
-    rolled = jnp.stack([jnp.roll(x, -o, axis=0) for o in range(offsets)])
-    mine = jnp.broadcast_to(x[None], rolled.shape)        # (o, t, 8, N)
+    rolled = jnp.stack([jnp.roll(tiles, -o, axis=0)
+                        for o in range(offsets)])
+    mine = jnp.broadcast_to(tiles[None], rolled.shape)    # (o, t, 8, N)
     # rows in groups of ``n`` tiles: an offset a group, or (t even) two,
     # the last offset's second half (its pairs again) left out
     n, groups = (t // 2, t + 1) if t % 2 == 0 else (t, offsets)
@@ -119,12 +125,68 @@ def expand(x):
 
 
 # -- the step ---------------------------------------------------------- #
+def _rows_per_group(hpg):
+    """Rows of a KV head group in the step's packed operand: its ``hpg``
+    query heads, ``k``, ``v`` and the decay, padded to a sublane tile."""
+    return -(-(hpg + 3) // 8) * 8
+
+
+def _packed(q, k, v, log_a):
+    """The step kernel's one operand besides the state: ``(S, R, W)``
+    float32, group ``g``'s rows ``P g`` on (``P = _rows_per_group``) its
+    query heads, ``k``, ``v`` and ``exp(log a)`` along the whole row, the
+    rest zero; ``R`` and ``W`` whole 128-lane blocks so that the kernel can
+    transpose a slot's block."""
+    S, H, d = q.shape
+    G, dv = k.shape[1], v.shape[-1]
+    hpg = H // G
+    P = _rows_per_group(hpg)
+    W = -(-max(d, dv) // _LANES) * _LANES
+    R = -(-G * P // _LANES) * _LANES
+    lanes = lambda x: jnp.pad(x.astype(jnp.float32), [(0, 0)] * (x.ndim - 1)
+                              + [(0, W - x.shape[-1])])
+    a = jnp.broadcast_to(jnp.exp(log_a)[..., None, None], (S, G, 1, W))
+    rows = jnp.concatenate([lanes(q.reshape(S, G, hpg, d)),
+                            lanes(k)[:, :, None], lanes(v)[:, :, None], a],
+                           axis=2)
+    rows = jnp.pad(rows, [(0, 0), (0, 0), (0, P - hpg - 3), (0, 0)])
+    return jnp.pad(rows.reshape(S, G * P, W), [(0, 0), (0, R - G * P),
+                                               (0, 0)])
+
+
+def _phi_block(x_ref, xt_ref, rows_ref, phi_ref, *, t):
+    """``phi`` of every vector of a slot's packed block ``x_ref`` ``(1, R,
+    W)`` (vectors of ``8 t`` coordinates) into ``phi_ref`` ``(G, P, E)``,
+    row ``P g + j`` of the block at ``[g, j]``: the block transposed once
+    into ``xt_ref`` (coordinates on the sublanes, vectors on the lanes),
+    ``_phi_rows`` a group of tile pairs at a time into ``rows_ref``, each
+    128-row block of that transposed back (the vectors on the sublanes, the
+    expansion's rows on the lanes).  The rows are ``expand``'s to 2 ulp:
+    ``sqrt(2)`` multiplies the partner tile, not the product."""
+    G, P = phi_ref.shape[:2]
+    n = t // 2
+    xt_ref[0] = x_ref[0].T.reshape(xt_ref.shape[1:])
+    span = 64 * n
+
+    def group(m, carry):
+        _phi_rows(xt_ref, rows_ref, m, t=t, n=n)
+        for b in range(span // _LANES):
+            blk = rows_ref[pl.ds(b * _LANES, _LANES), :].T          # (R, L)
+            lo = pl.multiple_of(m * span + b * _LANES, _LANES)
+            for g in range(G):
+                phi_ref[g, :, pl.ds(lo, _LANES)] = blk[g * P:(g + 1) * P]
+        return carry
+
+    lax.fori_loop(0, t + 1, group, 0)
+
+
 def _kernel(layer_ref, live_ref, map_ref, grp_ref, first_ref, s_ref, z_ref,
-            pk_ref, pq_ref, vd_ref, s_out, z_out, y_out, acc_ref, *, hpg,
-            dv, h8):
+            x_ref, s_out, z_out, y_out, acc_ref, xt_ref, rows_ref, phi_ref,
+            *, hpg, dv, h8, t):
     del layer_ref, map_ref, grp_ref
     s, g = pl.program_id(0), pl.program_id(1)
     live = live_ref[s] != 0
+    P = phi_ref.shape[1]
 
     @pl.when(jnp.logical_and(jnp.logical_not(live), first_ref[s] != 0))
     def _keep():
@@ -133,35 +195,44 @@ def _kernel(layer_ref, live_ref, map_ref, grp_ref, first_ref, s_ref, z_ref,
         s_out[...] = s_ref[...]
         z_out[...] = z_ref[...]
 
+    @pl.when(jnp.logical_and(live, g == 0))
+    def _build():
+        # the slot's q and k do not change across its groups: every
+        # group's phi once, at its first
+        _phi_block(x_ref, xt_ref, rows_ref, phi_ref, t=t)
+
     @pl.when(live)
     def _step():
-        a = vd_ref[0, 0, pl.ds(dv, 1), :]                # (1, 128)
-        vcol = vd_ref[0, 0, pl.ds(0, dv), :]             # (dv, 128)
+        # the group's rows of the block: a mask, not a dynamic sublane
+        # index (which the chip's compiler refuses)
+        x = x_ref[0]
+        at_row = lambda r: lax.broadcasted_iota(jnp.int32, x.shape, 0) == r
+        a = jnp.sum(jnp.where(at_row(g * P + hpg + 2), x, 0.0), axis=0,
+                    keepdims=True)[:, :_LANES]                    # (1, 128)
+        xt = x.T
+        at_v = lax.broadcasted_iota(jnp.int32, xt.shape, 1) == g * P + hpg + 1
+        vcol = jnp.broadcast_to(jnp.sum(jnp.where(at_v, xt, 0.0), axis=1,
+                                        keepdims=True)[:dv], (dv, _LANES))
         acc_ref[...] = jnp.zeros_like(acc_ref)
-        # the group's row of a (G, ...) block: a mask, not a dynamic
-        # sublane index (which the chip's compiler refuses)
-        mine = lambda rows: lax.broadcasted_iota(
-            jnp.int32, rows.shape, 0) == g
 
         def chunk(c, dacc):
             lo = pl.multiple_of(c * _LANES, _LANES)
-            pks = pk_ref[0, :, pl.ds(lo, _LANES)]                   # (G, L)
-            at = mine(pks)
-            pk = jnp.sum(jnp.where(at, pks, 0.0), axis=0, keepdims=True)
+            blk = phi_ref[g, :, pl.ds(lo, _LANES)]                  # (P, L)
+            pk, pqc = blk[hpg:hpg + 1], blk[:hpg]
             new = a * s_ref[0, 0, 0, :, pl.ds(lo, _LANES)] + vcol * pk
             s_out[0, 0, 0, :, pl.ds(lo, _LANES)] = new
-            pqc = pq_ref[0, 0, :, pl.ds(lo, _LANES)]                # (hpg, L)
             for h in range(hpg):
                 acc_ref[h] += new * pqc[h:h + 1]
             # z: this group's row updated; the rows before it are the
             # earlier steps' (in the output buffer), the rows after it as
             # read
             zin = z_ref[0, 0, :, pl.ds(lo, _LANES)]
-            before = lax.broadcasted_iota(jnp.int32, zin.shape, 0) < g
-            zall = jnp.where(before, z_out[0, 0, :, pl.ds(lo, _LANES)], zin)
-            zall = jnp.where(at, a * zin + pks, zall)
+            rows = lax.broadcasted_iota(jnp.int32, zin.shape, 0)
+            zall = jnp.where(rows < g, z_out[0, 0, :, pl.ds(lo, _LANES)], zin)
+            zall = jnp.where(rows == g, a * zin + pk, zall)
             z_out[0, 0, :, pl.ds(lo, _LANES)] = zall
-            zn = jnp.sum(jnp.where(at, zall, 0.0), axis=0, keepdims=True)
+            zn = jnp.sum(jnp.where(rows == g, zall, 0.0), axis=0,
+                         keepdims=True)
             return dacc + zn * pqc
 
         dacc = lax.fori_loop(0, s_ref.shape[-1] // _LANES, chunk,
@@ -192,10 +263,15 @@ def _routes(live):
     return slot, lead
 
 
-def _kernel_call(state, z, layer, live, pk, pq, vd, interpret):
+def _kernel_call(state, z, layer, live, q, k, v, log_a, interpret):
     _, S, G, dv, E = state.shape
-    hpg = pq.shape[2]
+    d = k.shape[-1]
+    hpg = q.shape[1] // G
     h8 = -(-hpg // 8) * 8
+    x = _packed(q, k, v, log_a)
+    R, W = x.shape[1:]
+    P = _rows_per_group(hpg)
+    t = d // _TILE
     slot, lead = _routes(live)
     grp = jnp.where(live, -1, jnp.where(lead, 0, G - 1)).astype(jnp.int32)
 
@@ -206,22 +282,22 @@ def _kernel_call(state, z, layer, live, pk, pq, vd, interpret):
         lr[0], m[s], own(g, gr, s), 0, 0))
     zs = pl.BlockSpec((1, 1, G, E),
                       lambda s, g, lr, lv, m, gr, f: (lr[0], m[s], 0, 0))
-    ks = pl.BlockSpec((1, G, E), lambda s, g, lr, lv, m, gr, f: (m[s], 0, 0))
-    qs = pl.BlockSpec((1, 1, hpg, E), lambda s, g, lr, lv, m, gr, f: (
-        m[s], own(g, gr, s), 0, 0))
-    vs = pl.BlockSpec((1, 1, dv + 8, _LANES), lambda s, g, lr, lv, m, gr, f: (
-        m[s], own(g, gr, s), 0, 0))
+    xs = pl.BlockSpec((1, R, W), lambda s, g, lr, lv, m, gr, f: (m[s], 0, 0))
     ys = pl.BlockSpec((1, 1, 2 * h8, dv), lambda s, g, lr, lv, m, gr, f: (
         m[s], own(g, gr, s), 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5, grid=(S, G),
-        in_specs=[st, zs, ks, qs, vs], out_specs=[st, zs, ys],
-        scratch_shapes=[pltpu.VMEM((hpg, dv, _LANES), jnp.float32)])
-    # two buffers of each block in and out, the state's dominating
-    need = 4 * (4 * dv * E + 2 * G * E + G * E + 8 * E) \
-        + 4 * hpg * dv * _LANES
+        in_specs=[st, zs, xs], out_specs=[st, zs, ys],
+        scratch_shapes=[pltpu.VMEM((hpg, dv, _LANES), jnp.float32),
+                        pltpu.VMEM((1, W // _TILE, _TILE, R), jnp.float32),
+                        pltpu.VMEM((32 * t, R), jnp.float32),
+                        pltpu.VMEM((G, P, E), jnp.float32)])
+    # two buffers of each block in and out, the state's dominating; the
+    # scratch, phi of the slot's vectors the largest of it
+    need = 4 * (4 * dv * E + 4 * G * E + 2 * R * W + 4 * h8 * dv
+                + hpg * dv * _LANES + W * R + 32 * t * R + G * P * E)
     new, zn, y = pl.pallas_call(
-        functools.partial(_kernel, hpg=hpg, dv=dv, h8=h8),
+        functools.partial(_kernel, hpg=hpg, dv=dv, h8=h8, t=t),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct(state.shape, state.dtype),
                    jax.ShapeDtypeStruct(z.shape, z.dtype),
@@ -235,16 +311,20 @@ def _kernel_call(state, z, layer, live, pk, pq, vd, interpret):
         name=_NAME,
         interpret=interpret,
     )(jnp.reshape(layer, (1,)).astype(jnp.int32), live.astype(jnp.int32),
-      slot, grp, lead.astype(jnp.int32), state, z, pk, pq, vd)
+      slot, grp, lead.astype(jnp.int32), state, z, x)
     return y[:, :, :hpg], y[:, :, h8:h8 + hpg, 0], new, zn
 
 
-def _plain_call(state, z, layer, live, pk, pq, vd):
-    dv = state.shape[3]
+def _plain_call(state, z, layer, live, q, k, v, log_a):
+    S, H, _ = q.shape
+    G = k.shape[1]
     old = lax.dynamic_index_in_dim(state, layer, 0, keepdims=False)
     zo = lax.dynamic_index_in_dim(z, layer, 0, keepdims=False)
-    a = vd[:, :, dv, :1]                                   # (S, G, 1)
-    new = a[..., None] * old + vd[:, :, :dv, :1] * pk[:, :, None, :]
+    pk = expand(k)                                        # (S, G, E)
+    pq = expand(q.reshape(S, G, H // G, -1))              # (S, G, hpg, E)
+    a = jnp.exp(log_a)[..., None]                         # (S, G, 1)
+    new = a[..., None] * old \
+        + v.astype(jnp.float32)[..., None] * pk[:, :, None, :]
     zn = a * zo + pk
     # the sums over E in the kernel's order: the 128-lane chunks added up
     # lane by lane, then the lanes
@@ -268,26 +348,17 @@ def state_update(state, z, layer, q, k, v, log_a, live, eps):
     ``v`` ``(S, G, dv)``; ``log_a`` ``(S, G)`` float32; ``live`` ``(S,)``
     bool — a slot that is not live keeps its state.  Returns ``(y (S, H, dv)
     float32, state, z)``; ``y`` of a slot that is not live is 0."""
-    S, H, _ = q.shape
-    G, dv = k.shape[1], v.shape[-1]
-    f32 = jnp.float32
-    pk = expand(k)                                        # (S, G, E)
-    pq = expand(q.reshape(S, G, H // G, -1))              # (S, G, hpg, E)
-    # a column of v spread over the lanes, and the decay below it
-    vd = jnp.concatenate(
-        [v.astype(f32)[..., None],
-         jnp.broadcast_to(jnp.exp(log_a)[..., None, None], (S, G, 8, 1))],
-        axis=2)
-    vd = jnp.broadcast_to(vd, (S, G, dv + 8, _LANES))
-    args = (state, z, layer, live, pk, pq, vd)
-    if _interpret():
+    S, H, d = q.shape
+    dv = v.shape[-1]
+    args = (state, z, layer, live, q, k, v, log_a)
+    if not supports(d, dv):
+        num, den, state, z = _plain_call(*args)
+    elif _interpret():
         num, den, state, z = _kernel_call(*args, interpret=True)
-    elif supports(k.shape[-1], dv):
+    else:
         num, den, state, z = lax.platform_dependent(
             *args, tpu=functools.partial(_kernel_call, interpret=False),
             default=_plain_call)
-    else:
-        num, den, state, z = _plain_call(*args)
     y = num / (den + eps)[..., None]
     y = jnp.where(live[:, None, None, None], y, 0.0)
     return y.reshape(S, H, dv), state, z

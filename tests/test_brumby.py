@@ -87,11 +87,25 @@ def test_expansion_inner_product_is_the_squared_dot(d):
     assert (pr.expanded_rows(128), pr.exact_rows(128)) == (8704, 8256)
 
 
-def _step_inputs(S=5, G=2, hpg=3, d=32, L=2, seed=0, dtype=jnp.float32):
+def _step_inputs(S=5, G=2, hpg=3, d=32, L=2, seed=0, dtype=jnp.float32,
+                 tokens=0):
+    """A state of random entries; or, with ``tokens``, the state that many
+    earlier tokens of a stream leave (``S = sum v phi(k)^T``, ``z = sum
+    phi(k)``), whose readout's denominator is a sum of squares: at the
+    expansion's 8,704 rows random entries would cancel in it, and two
+    float32 orders of one sum would part by more than rounding."""
     rng = np.random.default_rng(seed)
     E = pr.expanded_rows(d)
-    state = jnp.asarray(rng.normal(size=(L, S, G, d, E)), jnp.float32)
-    z = jnp.asarray(rng.uniform(size=(L, S, G, E)), jnp.float32) * 10
+    if tokens:
+        ks = jnp.asarray(rng.normal(size=(L, S, G, tokens, d)), jnp.float32)
+        vs = jnp.asarray(rng.normal(size=(L, S, G, tokens, d)), jnp.float32)
+        phi = pr.expand(ks)
+        state = jnp.einsum("lsgjv,lsgjE->lsgvE", vs, phi,
+                           precision=jax.lax.Precision.HIGHEST)
+        z = jnp.sum(phi, axis=3)
+    else:
+        state = jnp.asarray(rng.normal(size=(L, S, G, d, E)), jnp.float32)
+        z = jnp.asarray(rng.uniform(size=(L, S, G, E)), jnp.float32) * 10
     q = jnp.asarray(rng.normal(size=(S, G * hpg, d)), dtype)
     k = jnp.asarray(rng.normal(size=(S, G, d)), dtype)
     v = jnp.asarray(rng.normal(size=(S, G, d)), dtype)
@@ -99,16 +113,25 @@ def _step_inputs(S=5, G=2, hpg=3, d=32, L=2, seed=0, dtype=jnp.float32):
     return state, z, q, k, v, la
 
 
-@pytest.mark.parametrize("live", [[1, 0, 1, 1, 0], [0, 0, 1, 0, 1],
-                                  [0, 0, 0, 0, 0], [1, 1, 1, 1, 1]],
-                         ids=["mixed", "leading-retired", "none", "all"])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
-                         ids=["f32", "bf16"])
-def test_step_kernel_is_its_plain_form(monkeypatch, live, dtype):
-    """The Pallas kernel (interpreted) against the ``jax.numpy`` form at
+_LIVE = {"mixed": [1, 0, 1, 1, 0], "leading-retired": [0, 0, 1, 0, 1],
+         "none": [0, 0, 0, 0, 0], "all": [1, 1, 1, 1, 1]}
+_DTYPES = {"f32": jnp.float32, "bf16": jnp.bfloat16}
+# (live slots, dtype, widths): the small cases, and the cell's widths (heads
+# of 128, 8 KV heads of five query heads) over three slots, one not live
+_STEP_CASES = {f"{dn}-{ln}": (live, dt, {})
+               for dn, dt in _DTYPES.items() for ln, live in _LIVE.items()}
+_STEP_CASES["published-bf16"] = ([1, 0, 1], jnp.bfloat16,
+                                 dict(S=3, G=8, hpg=5, d=128, tokens=16))
+
+
+@pytest.mark.parametrize("live,dtype,widths", list(_STEP_CASES.values()),
+                         ids=list(_STEP_CASES))
+def test_step_kernel_is_its_plain_form(monkeypatch, live, dtype, widths):
+    """The Pallas kernel (interpreted), which builds ``phi`` of the slot's
+    vectors in VMEM, against the ``jax.numpy`` form over ``expand`` at
     layer 1 of two: the same readouts and the same new state and ``z`` for
     the live slots; a retired slot, and the other layer, keep theirs."""
-    state, z, q, k, v, la = _step_inputs(dtype=dtype)
+    state, z, q, k, v, la = _step_inputs(dtype=dtype, **widths)
     live = jnp.asarray(live, bool)
     call = jax.jit(lambda *a: pr.state_update(*a, 1e-6))
     y0, s0, z0 = call(state, z, jnp.int32(1), q, k, v, la, live)
@@ -124,6 +147,36 @@ def test_step_kernel_is_its_plain_form(monkeypatch, live, dtype):
     assert np.all(np.asarray(y1)[~live] == 0)
     if live.any():
         assert np.abs(np.asarray(s1[1][live] - state[1][live])).max() > 1e-3
+
+
+@pytest.mark.parametrize("d", [32, 128])
+def test_step_kernel_phi_is_expand(d):
+    """The step kernel's ``phi`` of a slot's query heads and keys, built in
+    VMEM from the packed block (``_phi_block``, interpreted): ``expand``'s
+    rows, each vector at its group's row.  ``expand`` multiplies the product
+    by ``sqrt(2)``, the kernel one factor: each order rounds twice, so a row
+    may differ by 2 ulp (about one product in 250 of normal entries)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    G, hpg = 8, 5
+    rng = np.random.default_rng(d)
+    q = jnp.asarray(rng.normal(size=(1, G * hpg, d)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(1, G, d)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(1, G, d)), jnp.float32)
+    la = jnp.asarray(-rng.uniform(size=(1, G)), jnp.float32)
+    x = pr._packed(q, k, v, la)
+    R, W = x.shape[1:]
+    P, t, E = pr._rows_per_group(hpg), d // 8, pr.expanded_rows(d)
+    phi = pl.pallas_call(
+        lambda x_ref, o_ref, xt_ref, rows_ref: pr._phi_block(
+            x_ref, xt_ref, rows_ref, o_ref, t=t),
+        out_shape=jax.ShapeDtypeStruct((G, P, E), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((1, W // 8, 8, R), jnp.float32),
+                        pltpu.VMEM((32 * t, R), jnp.float32)],
+        interpret=True)(x)
+    np.testing.assert_array_max_ulp(phi[:, :hpg],
+                                    pr.expand(q.reshape(G, hpg, d)), 2)
+    np.testing.assert_array_max_ulp(phi[:, hpg], pr.expand(k[0]), 2)
 
 
 def _attention(q, k, v, la, s0=None, z0=None, eps=1e-6):

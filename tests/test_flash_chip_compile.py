@@ -202,18 +202,45 @@ def test_latent_chunk_kernel_compiles_for_v5e(one_chip, C):
 def test_retention_update_kernel_compiles_for_v5e(one_chip):
     """The cell's step: 24 slots, 8 KV heads of 128 lanes, the expansion's
     8,704 rows of a 128-wide head (the floor counts 8,256), five query heads
-    a group; the state's blocks of 4.5 MB in and out under the VMEM limit
-    the call asks for, the slot map and the group's row picked by masks."""
+    a group, ``q``, ``k`` and ``v`` in bfloat16 as the projections give
+    them; the state donated, its blocks of 4.5 MB in and out under the VMEM
+    limit the call asks for, the slot map and the group's row picked by
+    masks.  The kernel builds ``phi(q)``, ``phi(k)`` and ``v``'s lane
+    spread in VMEM: ``S`` and ``z`` are its only operands of the
+    expansion's width, both left in HBM (no memory space ``S(1)``), and no
+    buffer of the compiled update but they holds as many floats as one
+    layer's ``phi(k)``."""
+    import math
+    import re
+
+    import jax
     import jax.numpy as jnp
     from mxnet_tpu.ops import power_retention as pr
     E = pr.expanded_rows(128)
-    compiled = _compile(
-        one_chip, lambda s, z, lv, pk, pq, vd: pr._kernel_call(
-            s, z, jnp.int32(3), lv, pk, pq, vd, False),
-        ((8, 24, 8, 128, E), jnp.float32), ((8, 24, 8, E), jnp.float32),
-        ((24,), jnp.bool_), ((24, 8, E), jnp.float32),
-        ((24, 8, 5, E), jnp.float32), ((24, 8, 136, 128), jnp.float32))
-    assert pr._NAME in compiled.as_text()
+    s_shape, z_shape = (8, 24, 8, 128, E), (8, 24, 8, E)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        (s_shape, jnp.float32), (z_shape, jnp.float32),
+        ((24, 40, 128), jnp.bfloat16), ((24, 8, 128), jnp.bfloat16),
+        ((24, 8, 128), jnp.bfloat16), ((24, 8), jnp.float32),
+        ((24,), jnp.bool_))]
+    step = jax.jit(lambda s, z, q, k, v, la, lv: pr.state_update(
+        s, z, jnp.int32(3), q, k, v, la, lv, 1e-6), donate_argnums=(0, 1))
+    text = step.lower(*args).compile().as_text()
+    call = next(line for line in text.splitlines()
+                if "tpu_custom_call" in line and pr._NAME in line)
+    f32 = lambda txt: [tuple(int(x) for x in m.split(","))
+                       for m in re.findall(r"f32\[([\d,]+)\]", txt)]
+    operands = re.search(r"operand_layout_constraints=(.*?)"
+                         r"output_to_operand_aliasing", call).group(1)
+    assert [sh for sh in f32(operands) if sh[-1] == E] == [s_shape, z_shape]
+    results = call.split(" custom-call(")[0]
+    stored = re.findall(r"f32\[[\d,]+,%d\]\{([^}]*)\}" % E, results)
+    assert len(stored) == 2 and not any("S(" in lay for lay in stored)
+    limit = re.search(r'"scoped_memory_configs":\[\{[^}]*"size":"(\d+)"',
+                      call)
+    assert int(limit.group(1)) <= 100 << 20
+    wide = {sh for sh in f32(text) if math.prod(sh) >= 24 * 8 * E}
+    assert wide == {s_shape, z_shape}
 
 
 @pytest.mark.parametrize("T", [128, 1024])
