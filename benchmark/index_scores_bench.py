@@ -53,7 +53,7 @@ def main():
     from jax import lax
 
     from mxnet_tpu.ops import index_scores as ix
-    from mxnet_tpu.ops import paged_attention as pa
+    from mxnet_tpu.ops import page_walk
 
     dev = jax.devices()[0]
     on_tpu = dev.platform == "tpu"
@@ -91,7 +91,7 @@ def main():
 
     def timed(fn, pt):
         """ms a call of ``fn(q, w, pool, pt, ends) -> (scores, counts)``."""
-        ends = pa.walk_lengths(pt, posj + 1, page, NP)
+        ends = page_walk.walk_lengths(pt, posj + 1, page, NP)
 
         @jax.jit
         def looped(q, w, pool, pt, ends):

@@ -159,6 +159,29 @@ def main():
     T, k, page, npages = args.positions, args.topk, args.page, args.npages
     n = T // page
     rng = onp.random.RandomState(0)
+
+    # the page-id leg: B slots of C queries, one table row ``(B, n)`` a slot
+    def take_along(chosen, table):
+        B, C = table.shape[0], chosen.shape[0] // table.shape[0]
+        sel = layered.mask_positions(chosen, k)
+        return sel, jnp.take_along_axis(
+            table[:, None, :], sel.reshape(B, C, k) // page, axis=2)
+
+    def one_hot(chosen, table):
+        B, C = table.shape[0], chosen.shape[0] // table.shape[0]
+        sel, blocks = layered.block_positions(chosen, k)
+        sel = sel.reshape(B, C, k)
+        return sel, layered.block_pages(blocks.reshape(B, C, k, -1),
+                                        sel, table, page, npages)
+
+    def joined(chosen, table):
+        return joined_form(chosen, k, table, page, npages)
+
+    page_forms = (("take_along_axis", take_along), ("block_pages", one_hot),
+                  ("joined", joined))
+    jitted = {f: jax.jit(f, static_argnums=1)
+              for f in (gather_form, tree_form)} | {
+        form: jax.jit(form) for _, form in page_forms}
     for N in (int(v) for v in args.rows.split(",")):
         head = {"device": dev.device_kind,
                 "measured_on_chip": dev.platform == "tpu", "rows": N,
@@ -169,8 +192,8 @@ def main():
         for r in range(N):
             chosen[r, rng.choice(pos[r] + 1, k, replace=False)] = True
         c, p = jnp.asarray(chosen), jnp.asarray(pos)
-        old = jax.jit(gather_form, static_argnums=1)(c, k, p)
-        new = jax.jit(tree_form, static_argnums=1)(c, k, p)
+        old = jitted[gather_form](c, k, p)
+        new = jitted[tree_form](c, k, p)
         agree = bool(jnp.all(old[0] == new[0]) & jnp.all(old[1] == new[1]))
         exact = bool((onp.asarray(new[0]) == onp.stack(
             [onp.flatnonzero(r) for r in chosen])).all())
@@ -182,32 +205,15 @@ def main():
                 head, form=name, ms_a_call=best, ms_a_call_median=median,
                 forms_agree=agree, equals_flatnonzero=exact)), flush=True)
 
-        # the page-id leg: B slots of C queries, one table row a slot
         B, C = (N, 1) if N <= args.slots else (1, N)
         table = rng.randint(0, npages, (B, n)).astype(onp.int32)
         table[:, ::7] = npages - 1
         t = jnp.asarray(table)
-
-        def take_along(chosen, table):
-            sel = layered.mask_positions(chosen, k)
-            return sel, jnp.take_along_axis(
-                table[:, None, :], sel.reshape(B, C, k) // page, axis=2)
-
-        def one_hot(chosen, table):
-            sel, blocks = layered.block_positions(chosen, k)
-            sel = sel.reshape(B, C, k)
-            return sel, layered.block_pages(blocks.reshape(B, C, k, -1),
-                                            sel, table, page, npages)
-
-        def joined(chosen, table):
-            return joined_form(chosen, k, table, page, npages)
-
         want = onp.take_along_axis(
             table[:, None, :],
             onp.asarray(new[0]).reshape(B, C, k) // page, axis=2)
-        for name, form in (("take_along_axis", take_along),
-                           ("block_pages", one_hot), ("joined", joined)):
-            ids = onp.asarray(jax.jit(form)(c, t)[1]).reshape(B, C, k)
+        for name, form in page_forms:
+            ids = onp.asarray(jitted[form](c, t)[1]).reshape(B, C, k)
             best, median = timed(looped(form), c, t)
             print(json.dumps(dict(
                 head, leg="page_ids", slots=B, queries_a_slot=C, pages=n,

@@ -68,6 +68,7 @@ __all__ = ["kv_generate", "decode_mode", "decode_step_program"]
 # ``params_swapped`` site (kv_generate, the serving loop, _CachedOp);
 # defined next to the swap it guards.
 from ..gluon.parameter import _TRACE_LOCK
+from ..ops import page_walk
 from ..ops import paged_attention as _paged
 
 
@@ -979,7 +980,7 @@ class _DecodeEngine:
             # platform) builds the T-wide view
             walk = self.walks_pages(page, quant)
             if walk:
-                lengths = _paged.walk_lengths(pt, pos, page, ck.shape[1])
+                lengths = page_walk.walk_lengths(pt, pos, page, ck.shape[1])
 
             def _view_attention(q, k, v, l):
                 """The view path: gather the slot's whole table row, land

@@ -84,7 +84,7 @@ import jax.numpy as jnp
 
 from ..ops import index_scores as _index
 from ..ops import latent_attention as _latent
-from ..ops import moe, ssd
+from ..ops import moe, page_walk, ssd
 from ..ops import paged_attention as _paged
 from ..ops import power_retention as _ret
 from ..serve.schema import pool_rows, row_lanes
@@ -1058,10 +1058,10 @@ class LayeredEngine:
                 if _paged.supports(lanes, self.cdtype, page, hq, D) \
                         and lanes == kvh * D:
                     if window is None:
-                        ends, starts = _paged.walk_lengths(
+                        ends, starts = page_walk.walk_lengths(
                             pt, pos[:, 0], page, npages), None
                     else:
-                        ends, starts = _paged.walk_span(
+                        ends, starts = page_walk.walk_span(
                             pt, pos[:, 0], page, npages, window)
                     o = _paged.paged_attention(
                         q[:, 0], k[:, 0], v[:, 0], kpool, vpool, li, pt,
@@ -1173,7 +1173,7 @@ class LayeredEngine:
                                       npages):
             # the new token's key is in the pool already: the walk ends
             # AFTER its position
-            ends = _paged.walk_lengths(table, pos[:, 0] + 1, page, npages)
+            ends = page_walk.walk_lengths(table, pos[:, 0] + 1, page, npages)
             score, walk = _index.index_scores(
                 iq[:, 0], iw[:, 0], ikp, ii, table, ends,
                 lambda: view()[:, 0])
@@ -1250,7 +1250,7 @@ class LayeredEngine:
             wkv = self._wkv(lw, a)
             if C > 1:
                 qf = self._absorbed(a, q_nope, q_rope, wkv, lanes)
-                held = _paged.walk_lengths(
+                held = page_walk.walk_lengths(
                     table, jnp.full((B,), table.shape[1] * page, jnp.int32),
                     page, npages)
                 ctx, walk = _latent.latent_chunk_attention(
@@ -1261,7 +1261,7 @@ class LayeredEngine:
                                   wkv[..., a["nope"]:],
                                   preferred_element_type=jnp.float32), walk
             qf = self._absorbed(a, q_nope[:, 0], q_rope[:, 0], wkv, lanes)
-            ends = _paged.walk_lengths(table, pos[:, 0] + 1, page, npages)
+            ends = page_walk.walk_lengths(table, pos[:, 0] + 1, page, npages)
             ctx, walk = _latent.latent_paged_attention(
                 qf, lat, fi, table, ends, self._scale(a), a["kv_rank"],
                 lambda: dense(expand=False)[:, 0])

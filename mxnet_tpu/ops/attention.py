@@ -65,6 +65,18 @@ def _interpret() -> bool:
     return os.environ.get("MXNET_FLASH_INTERPRET", "") == "1"
 
 
+def on_tpu(kernel, fallback, *operands):
+    """``kernel(*operands)`` where the program lowers for a TPU,
+    ``fallback(*operands)`` on every other platform; with
+    ``MXNET_FLASH_INTERPRET=1``, ``kernel(*operands, interpret=True)``
+    wherever it is (CPU numerics).  How every Pallas kernel with an XLA
+    form beside it is chosen: ``kernel`` takes ``interpret=False`` by
+    default."""
+    if _interpret():
+        return kernel(*operands, interpret=True)
+    return lax.platform_dependent(*operands, tpu=kernel, default=fallback)
+
+
 def _pallas_backend_ok() -> bool:
     """Shared Pallas backend gate (flash, q8_matvec): interpret mode or a
     real TPU backend."""

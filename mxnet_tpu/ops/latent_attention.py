@@ -19,26 +19,18 @@ the pool IN PLACE, only as far as the slot's length.
   (``memory_space=pl.ANY``).  ``layer``, the flattened table, each slot's
   walk end and the groups' run flags ride in SMEM (scalar prefetch).
 - The walk's END is ``pos[b] + 1``: the new token's row is written before
-  the walk and a query sees its own position (``ops.paged_attention.
-  walk_lengths`` of ``pos + 1``: cut at the row's first sentinel, so a
-  retired slot walks nothing and reads 0).
-- A compute block is a GROUP of ``_ROWS // page`` pages, double-buffered:
-  while a group is contracted the next one — the same slot's, or the next
-  slot's first — is in flight.  A group is fetched as ``ops.index_scores``
-  fetches its own (``each_copy``): ONE copy where its pages have
-  consecutive ids, else a copy a run of ``_BLOCK`` rows, else a copy a page
-  (copies of 20 KB pages started 43 ns apart would not bound the walk, but
-  a document reserved whole lies in runs anyway).
+  the walk and a query sees its own position (``page_walk.walk_lengths`` of
+  ``pos + 1``); a slot that walks nothing reads 0.
+- The walk is ``ops.page_walk``'s: groups of ``_ROWS // page`` pages,
+  double-buffered across slots, fetched by ``run_copy`` with blocks of
+  ``_BLOCK`` rows (copies of 20 KB pages started 43 ns apart would not bound
+  the walk, but a document reserved whole lies in runs anyway).
 - Per group two MXU products: the scores ``qf (H, lanes) x rows^T``, then
   ``p (H, rows) x rows[:, :rank]``, operands in the pool's dtype, float32
-  accumulation; an online softmax in float32 over the groups.  The rows of
-  a slot's last group past its end are masked; what no copy ever filled is
-  zeros (the buffers are cleared once), so a masked row's weight is exactly
-  0 whatever it held.
+  accumulation; ``page_walk.online_softmax`` over the groups.  The rows of a
+  slot's last group past its end are masked.
 - Beside the context the kernel counts, per slot, the rows it walked and the
   copies it started: ``(B, 2)`` int32 out of SMEM.
-
-The grid runs the slots in turn (v5e has one TensorCore).
 
 Work at the ``pangu_ultra_moe_serve`` shapes (128 heads, rank 512 + rope 64
 in 640 lanes, bfloat16): a row's 1,152 useful bytes carry 128 x (576 + 512)
@@ -77,12 +69,11 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .attention import _interpret
-from .index_scores import each_copy, group_runs
+from . import page_walk
+from .attention import on_tpu
 
 __all__ = ["latent_paged_attention", "latent_chunk_attention", "supports"]
 
-_NEG_INF = -1e30
 _ROWS = 1024     # token rows a compute block (a group of pages) holds
 _BLOCK = 256     # rows of a group's blocks, each fetched as a run or by page
 # profiler_xla._KERNEL_REGIONS reads both kernels' device time under
@@ -101,19 +92,44 @@ _CHUNK_VMEM = 96 << 20
 
 
 def supports(lanes, rank, dtype, page, num_pages):
-    """Whether the kernel takes a latent pool of this static structure: rows
-    of whole 128-lane tiles whose first ``rank`` lanes (the context's) are
-    whole tiles too, pages of whole sublane tiles (16 rows of bfloat16, 8 of
-    float32) that divide a fetch block, and at least a compute block of
-    them (a run copy's stretch lies inside the pool)."""
-    dtype = jnp.dtype(dtype)
-    if dtype not in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32)):
-        return False
-    if lanes % 128 or rank % 128 or rank > lanes:
-        return False
-    sublanes = 8 * 4 // dtype.itemsize
-    return page % sublanes == 0 and _BLOCK % page == 0 \
-        and num_pages * page >= _ROWS
+    """Whether the kernel takes a latent pool of this static structure: one
+    ``page_walk.pool_fits`` whose first ``rank`` lanes (the context's) are
+    whole tiles too and whose pages divide a fetch block, and at least a
+    compute block of them (a run copy's stretch lies inside the pool)."""
+    return page_walk.pool_fits(lanes, dtype, page) and rank % 128 == 0 \
+        and rank <= lanes and _BLOCK % page == 0 and num_pages * page >= _ROWS
+
+
+def _open(q_ref, m_ref, l_ref, acc):
+    """A unit's queries, and its online softmax with nothing seen yet."""
+    q = q_ref[0]
+    m_ref[...] = jnp.full_like(m_ref, page_walk.NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc[...] = jnp.zeros_like(acc)
+    return q
+
+
+def _update(q, kbuf, buf, g, ends, m_ref, l_ref, acc, *, scale, rank):
+    """One group of rows into the online softmax of the queries ``q``: the
+    columns at and past ``ends`` (a position, or each query row's) masked,
+    none where ``ends`` is None.  A partly held group's stale rows lie past
+    the slot's end, a run's rows past the slot's length too."""
+    rows, lanes = kbuf.shape[1] * kbuf.shape[2], kbuf.shape[3]
+    keys = kbuf[buf].reshape(rows, lanes)
+    s = lax.dot_general(q, keys, (((1,), (1,)), ((), ())),
+                        precision=page_walk.precision(keys.dtype),
+                        preferred_element_type=jnp.float32) * scale
+    if ends is not None:
+        col = g * rows + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(col < ends, s, page_walk.NEG_INF)
+    page_walk.online_softmax(s, lambda: keys[:, :rank], keys.dtype, m_ref,
+                             l_ref, acc)
+
+
+def _readout(out_ref, acc, l_ref):
+    # a unit that walked nothing (an idle or retired slot) reads 0
+    l = l_ref[...]
+    out_ref[0] = (acc[...] / jnp.where(l > 0, l, 1.0)).astype(out_ref.dtype)
 
 
 def _kernel(layer_ref, pt_ref, end_ref, run_ref,        # SMEM (prefetch)
@@ -124,94 +140,31 @@ def _kernel(layer_ref, pt_ref, end_ref, run_ref,        # SMEM (prefetch)
     b = pl.program_id(0)
     nslots = pl.num_programs(0)
     rows = per * page
-    lanes = kbuf.shape[-1]
     end = end_ref[b]                    # the walk's end, a position
     ngroups = pl.cdiv(end, rows)
-    prec = lax.Precision.HIGHEST if kbuf.dtype == jnp.float32 \
-        else lax.Precision.DEFAULT
-    copies = functools.partial(
-        each_copy, pool_ref=pool_ref, kbuf=kbuf, sems=sems, pt_ref=pt_ref,
-        end_ref=end_ref, run_ref=run_ref, layer=layer_ref[0], page=page,
-        maxp=maxp, num_pages=num_pages, per=per, sub=sub, groups=groups)
+    copy = page_walk.run_copy(
+        pool_ref, kbuf, sems, pt_ref, end_ref, run_ref, layer_ref[0],
+        page=page, maxp=maxp, num_pages=num_pages, per=per, sub=sub,
+        groups=groups)
 
-    def start(slot_b, g, buf):
-        def counted(n):
-            cnt_ref[slot_b, 1] = cnt_ref[slot_b, 1] + n
-        copies(lambda c: c.start(), slot_b=slot_b, g=g, buf=buf,
-               counted=counted)
+    def tally():
+        cnt_ref[b, 0] = end
 
-    def wait(slot_b, g, buf):
-        copies(lambda c: c.wait(), slot_b=slot_b, g=g, buf=buf)
+    def setup():
+        q = _open(q_ref, m_ref, l_ref, acc)                 # (H, lanes)
+        return *page_walk.next_slot(
+            b, nslots, lambda s: pl.cdiv(end_ref[s], rows)), q
 
-    @pl.when(b == 0)
-    def _():
-        # what a copy never fills is then zeros, not whatever VMEM held: a
-        # masked row takes part in p . row with weight exactly 0
-        kbuf[...] = jnp.zeros_like(kbuf)
-        state[0] = 0        # groups walked so far: a group's buffer is
-        state[1] = 0        # its number's parity.  1: this slot's first
-                            # group was started by the slot before it
+    def group(g, buf, q):
+        _update(q, kbuf, buf, g, end, m_ref, l_ref, acc, scale=scale,
+                rank=rank)
 
-        def clear(s, carry):
-            cnt_ref[s, 1] = 0
-            return carry
-        lax.fori_loop(0, nslots, clear, 0)
-    first = state[0]
-    cnt_ref[b, 0] = end
-
-    @pl.when((ngroups > 0) & (state[1] == 0))
-    def _():
-        start(b, 0, first % 2)
-
-    q = q_ref[0]                                            # (H, lanes)
-    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-    l_ref[...] = jnp.zeros_like(l_ref)
-    acc[...] = jnp.zeros_like(acc)
-
-    nxt = jnp.minimum(b + 1, nslots - 1)
-    next_groups = jnp.where(b + 1 < nslots,
-                            pl.cdiv(end_ref[nxt], rows), 0)
-
-    def group(g, carry):
-        buf = (first + g) % 2
-
-        @pl.when(g + 1 < ngroups)
-        def _():
-            start(b, g + 1, 1 - buf)
-
-        @pl.when((g + 1 == ngroups) & (next_groups > 0))
-        def _():
-            start(nxt, 0, 1 - buf)
-
-        wait(b, g, buf)
-        keys = kbuf[buf].reshape(rows, lanes)
-        s = lax.dot_general(q, keys, (((1,), (1,)), ((), ())),
-                            precision=prec,
-                            preferred_element_type=jnp.float32) * scale
-        col = g * rows + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        # a partly held group's other rows are stale: masked, as are a
-        # run's rows past the slot's length
-        s = jnp.where(col < end, s, _NEG_INF)
-        m_old = m_ref[...]
-        m_new = jnp.maximum(m_old, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m_old - m_new)
-        p = jnp.exp(s - m_new)
-        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
-        acc[...] = alpha * acc[...] + lax.dot_general(
-            p.astype(keys.dtype), keys[:, :rank], (((1,), (0,)), ((), ())),
-            precision=prec, preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
-        return carry
-
-    lax.fori_loop(0, ngroups, group, 0)
-    state[0] = first + ngroups
-    state[1] = ((ngroups > 0) & (next_groups > 0)).astype(jnp.int32)
-    # a slot that walked nothing (idle, retired) reads 0
-    l = l_ref[...]
-    out_ref[0] = (acc[...] / jnp.where(l > 0, l, 1.0)).astype(out_ref.dtype)
+    page_walk.walk(state, copy, b, ngroups, setup, group, first_step=b == 0,
+                   nslots=nslots, zero=(kbuf,), counts=cnt_ref, tally=tally)
+    _readout(out_ref, acc, l_ref)
 
 
-def _kernel_call(q, pool, layer, pt, ends, scale, rank, interpret,
+def _kernel_call(q, pool, layer, pt, ends, scale, rank, interpret=False,
                  rows=None, runs=True):
     """``(context (B, H, rank) in the pool's dtype, counts (B, 2) int32)``.
     ``rows`` and ``runs`` are the benchmark's and the tests'
@@ -230,7 +183,7 @@ def _kernel_call(q, pool, layer, pt, ends, scale, rank, interpret,
     q = jnp.pad(q.astype(dtype), ((0, 0), (0, Hp - H),
                                   (0, lanes - q.shape[-1])))
     ends = ends.astype(jnp.int32)
-    flags = group_runs(pt, ends, page, per, sub, num_pages) if runs \
+    flags = page_walk.group_runs(pt, ends, page, per, sub, num_pages) if runs \
         else jnp.zeros((B, G), jnp.int32)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
@@ -286,12 +239,8 @@ def latent_paged_attention(q, pool, layer, pt, ends, scale, rank, fallback):
     def view(q, *_):
         return fallback(), jnp.zeros((q.shape[0], 2), jnp.int32)
 
-    kernel = functools.partial(_kernel_call, scale=scale, rank=rank)
-    if _interpret():
-        return kernel(q, pool, layer, pt, ends, interpret=True)
-    return lax.platform_dependent(
-        q, pool, layer, pt, ends,
-        tpu=functools.partial(kernel, interpret=False), default=view)
+    return on_tpu(functools.partial(_kernel_call, scale=scale, rank=rank),
+                  view, q, pool, layer, pt, ends)
 
 
 def _chunk_kernel(layer_ref, pt_ref, end_ref, run_ref, qend_ref, span_ref,
@@ -303,122 +252,55 @@ def _chunk_kernel(layer_ref, pt_ref, end_ref, run_ref, qend_ref, span_ref,
     b, t = pl.program_id(0), pl.program_id(1)
     nslots, ntiles = pl.num_programs(0), pl.num_programs(1)
     rows = per * page
-    lanes = kbuf.shape[-1]
     tq = acc.shape[0]
     tile = b * ntiles + t
     # the ends of the tile's first and last query: every row sees the
     # columns before ``lo``, none those from ``hi`` on
     lo, hi = span_ref[2 * tile], span_ref[2 * tile + 1]
     ngroups = pl.cdiv(hi, rows)
-    prec = lax.Precision.HIGHEST if kbuf.dtype == jnp.float32 \
-        else lax.Precision.DEFAULT
-    copies = functools.partial(
-        each_copy, pool_ref=pool_ref, kbuf=kbuf, sems=sems, pt_ref=pt_ref,
-        end_ref=end_ref, run_ref=run_ref, layer=layer_ref[0], page=page,
-        maxp=maxp, num_pages=num_pages, per=per, sub=sub, groups=groups)
+    copy = page_walk.run_copy(
+        pool_ref, kbuf, sems, pt_ref, end_ref, run_ref, layer_ref[0],
+        page=page, maxp=maxp, num_pages=num_pages, per=per, sub=sub,
+        groups=groups)
 
-    def start(slot_b, g, buf):
-        def counted(n):
-            cnt_ref[slot_b, 1] = cnt_ref[slot_b, 1] + n
-        copies(lambda c: c.start(), slot_b=slot_b, g=g, buf=buf,
-               counted=counted)
+    def tally():
+        # the slot's walk, counted once however many tiles re-read it
+        cnt_ref[b, 0] = end_ref[b]
 
-    def wait(slot_b, g, buf):
-        copies(lambda c: c.wait(), slot_b=slot_b, g=g, buf=buf)
+    def setup():
+        q = _open(q_ref, m_ref, l_ref, acc)                 # (TQ, lanes)
+        # the tile after this one: the slot's next, or the next slot's first
+        last = t + 1 == ntiles
+        nb = jnp.minimum(jnp.where(last, b + 1, b), nslots - 1)
+        nxt = nb * ntiles + jnp.where(last, 0, t + 1)
+        next_groups = jnp.where(last & (b + 1 == nslots), 0,
+                                pl.cdiv(span_ref[2 * nxt + 1], rows))
+        # each row's end: row r is query r // heads of the tile
+        r = lax.broadcasted_iota(jnp.int32, (tq, 1), 0)
 
-    @pl.when((b == 0) & (t == 0))
-    def _():
-        # a masked row's weight is exactly 0 against what no copy filled
-        kbuf[...] = jnp.zeros_like(kbuf)
-        state[0] = 0        # groups walked so far: a group's buffer is
-        state[1] = 0        # its number's parity.  1: this tile's first
-                            # group was started by the tile before it
+        def put(j, ends):
+            return jnp.where((r >= j * heads) & (r < (j + 1) * heads),
+                             qend_ref[tile * nq + j], ends)
+        rend = lax.fori_loop(0, nq, put, jnp.zeros((tq, 1), jnp.int32))
+        return nb, next_groups, q, rend
 
-        def clear(s, carry):
-            cnt_ref[s, 1] = 0
-            return carry
-        lax.fori_loop(0, nslots, clear, 0)
-    first = state[0]
-    # the slot's walk, counted once however many tiles re-read it
-    cnt_ref[b, 0] = end_ref[b]
-
-    @pl.when((ngroups > 0) & (state[1] == 0))
-    def _():
-        start(b, 0, first % 2)
-
-    q = q_ref[0]                                            # (TQ, lanes)
-    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-    l_ref[...] = jnp.zeros_like(l_ref)
-    acc[...] = jnp.zeros_like(acc)
-
-    # the tile after this one: the slot's next, or the next slot's first
-    last = t + 1 == ntiles
-    nb = jnp.minimum(jnp.where(last, b + 1, b), nslots - 1)
-    nxt = nb * ntiles + jnp.where(last, 0, t + 1)
-    next_groups = jnp.where(last & (b + 1 == nslots), 0,
-                            pl.cdiv(span_ref[2 * nxt + 1], rows))
-
-    # each row's end: row r is query r // heads of the tile
-    r = lax.broadcasted_iota(jnp.int32, (tq, 1), 0)
-
-    def put(j, ends):
-        return jnp.where((r >= j * heads) & (r < (j + 1) * heads),
-                         qend_ref[tile * nq + j], ends)
-    rend = lax.fori_loop(0, nq, put, jnp.zeros((tq, 1), jnp.int32))
-
-    def update(g, buf, masked):
-        keys = kbuf[buf].reshape(rows, lanes)
-        s = lax.dot_general(q, keys, (((1,), (1,)), ((), ())),
-                            precision=prec,
-                            preferred_element_type=jnp.float32) * scale
-        if masked:
-            # rows past a query's end, and a partly held group's stale
-            # rows past the slot's
-            col = g * rows + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(col < rend, s, _NEG_INF)
-        m_old = m_ref[...]
-        m_new = jnp.maximum(m_old, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m_old - m_new)
-        p = jnp.exp(s - m_new)
-        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
-        acc[...] = alpha * acc[...] + lax.dot_general(
-            p.astype(keys.dtype), keys[:, :rank], (((1,), (0,)), ((), ())),
-            precision=prec, preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
-
-    def group(g, carry):
-        buf = (first + g) % 2
-
-        @pl.when(g + 1 < ngroups)
-        def _():
-            start(b, g + 1, 1 - buf)
-
-        @pl.when((g + 1 == ngroups) & (next_groups > 0))
-        def _():
-            start(nb, 0, 1 - buf)
-
-        wait(b, g, buf)
+    def group(g, buf, q, rend):
+        update = functools.partial(_update, q, kbuf, buf, g, m_ref=m_ref,
+                                   l_ref=l_ref, acc=acc, scale=scale,
+                                   rank=rank)
+        # a group that every row of the tile sees whole takes no mask
         whole = (g + 1) * rows <= lo
+        pl.when(whole)(lambda: update(None))
+        pl.when(jnp.logical_not(whole))(lambda: update(rend))
 
-        @pl.when(whole)
-        def _():
-            update(g, buf, False)
-
-        @pl.when(jnp.logical_not(whole))
-        def _():
-            update(g, buf, True)
-        return carry
-
-    lax.fori_loop(0, ngroups, group, 0)
-    state[0] = first + ngroups
-    state[1] = ((ngroups > 0) & (next_groups > 0)).astype(jnp.int32)
-    # a tile that walked nothing (a retired slot) reads 0
-    l = l_ref[...]
-    out_ref[0] = (acc[...] / jnp.where(l > 0, l, 1.0)).astype(out_ref.dtype)
+    page_walk.walk(state, copy, b, ngroups, setup, group,
+                   first_step=(b == 0) & (t == 0), nslots=nslots,
+                   zero=(kbuf,), counts=cnt_ref, tally=tally)
+    _readout(out_ref, acc, l_ref)
 
 
-def _chunk_call(q, pool, layer, pt, ends, scale, rank, interpret, tile=None,
-                rows=None):
+def _chunk_call(q, pool, layer, pt, ends, scale, rank, interpret=False,
+                tile=None, rows=None):
     """``(context (B, C, H, rank) in the pool's dtype, counts (B, 2)
     int32)``.  ``tile`` and ``rows`` are the benchmark's and the tests'
     (``benchmark/latent_walk_bench.py``): another tile (query rows), another
@@ -446,7 +328,7 @@ def _chunk_call(q, pool, layer, pt, ends, scale, rank, interpret, tile=None,
     by_tile = qends.reshape(B, NT, nq)
     span = jnp.stack([by_tile.min(-1), by_tile.max(-1)], axis=-1)
     slot_end = jnp.max(ends, axis=1)
-    flags = group_runs(pt, slot_end, page, per, sub, num_pages)
+    flags = page_walk.group_runs(pt, slot_end, page, per, sub, num_pages)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6,
         grid=(B, NT),
@@ -502,9 +384,5 @@ def latent_chunk_attention(q, pool, layer, pt, ends, scale, rank, fallback):
     def view(q, *_):
         return fallback(), jnp.zeros((q.shape[0], 2), jnp.int32)
 
-    kernel = functools.partial(_chunk_call, scale=scale, rank=rank)
-    if _interpret():
-        return kernel(q, pool, layer, pt, ends, interpret=True)
-    return lax.platform_dependent(
-        q, pool, layer, pt, ends,
-        tpu=functools.partial(kernel, interpret=False), default=view)
+    return on_tpu(functools.partial(_chunk_call, scale=scale, rank=rank),
+                  view, q, pool, layer, pt, ends)

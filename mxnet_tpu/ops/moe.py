@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 
 from . import grouped_matmul
-from .attention import _interpret
+from .attention import on_tpu
 
 __all__ = ["route", "routed_experts", "swiglu"]
 
@@ -124,9 +124,5 @@ def _products(xs, w_gu, w_down, load, base):
     if grouped_matmul.plan(M, H, w_down.shape[1], xs.dtype) is None:
         return _ragged(xs, w_gu, w_down, load, base)
     base = jnp.asarray(base, jnp.int32)
-    if _interpret():
-        return grouped_matmul.grouped_swiglu(xs, w_gu, w_down, load, base,
-                                             interpret=True)
-    return jax.lax.platform_dependent(
-        xs, w_gu, w_down, load, base,
-        tpu=grouped_matmul.grouped_swiglu, default=_ragged)
+    return on_tpu(grouped_matmul.grouped_swiglu, _ragged, xs, w_gu, w_down,
+                  load, base)

@@ -9,41 +9,29 @@ contracts all ``T`` columns; this kernel walks each slot's row only as far
 as its length and never builds the view.
 
 A walk has an END and, for a layer that keeps a window, a START
-(``walk_span``): the table row is then a RING (logical page ``lp`` lies at
-entry ``lp % width``), the walk begins at the entry of the page that holds
-the first visible position ``start[b]`` and runs in ring order to the page
-of ``pos[b] - 1``, and the columns before ``start[b]`` in that first page
-are masked.  A layer without a window passes no start: that is position 0,
-entry 0, no wrap, and the kernel is what it was without one.
+(``page_walk.walk_span``): the table row is then a RING (logical page
+``lp`` lies at entry ``lp % width``), the walk begins at the entry of the
+page that holds the first visible position ``start[b]`` and runs in ring
+order to the page of ``pos[b] - 1``, and the columns before ``start[b]`` in
+that first page are masked.  A layer without a window passes no start: that
+is position 0, entry 0, no wrap, and the kernel is what it was without one.
 
 - The pools ``(NL, NPAGES, page, KV·D)`` stay in HBM, whole
   (``memory_space=pl.ANY``): no BlockSpec copy, no slice of the layer.
   ``layer``, the flattened table and each slot's walk end and start ride in
   SMEM (scalar prefetch).
-- A page is one contiguous ``(page, KV·D)`` block; it is fetched by an
-  async copy into VMEM, a GROUP of ``_ROWS // page`` pages (one compute
-  block of ``_ROWS`` rows) at a time, K and V alike, double-buffered: while
-  a group is contracted the next one — the same slot's, or the NEXT slot's
-  first — is in flight, so a slot of two or three groups does not pay a
-  fetch latency of its own.
+- The walk is ``ops.page_walk``'s: a GROUP of ``_ROWS // page`` pages (one
+  compute block of ``_ROWS`` rows) at a time, K and V alike, a copy a page
+  (``page_copy``), double-buffered across slots.
 - Scores for all heads of a row at once, as ``_flat_attention`` gets them:
   the queries arrive spread block-diagonally over the row's lanes
   (``_spread_queries``), one MXU contraction over the whole row, float32
-  accumulation; an online softmax in float32 over the groups; ``p`` cast to
-  the pool's dtype before ``p·V`` (as the view path does), accumulated in
-  float32.  Columns at and past the walk's end, and before its start, are
-  masked.
+  accumulation; ``page_walk.online_softmax`` over the groups, ``p`` cast to
+  the pool's dtype before ``p·V`` (as the view path does).  Columns at and
+  past the walk's end, and before its start, are masked.
 - The NEW token's own K and V are operands, not pool rows: they enter as the
   first key of the online softmax, so nothing is written to the pool here
   and the step's post-scan scatter stays as it is.
-- No page id reaches a copy unchecked: ``walk_lengths`` / ``walk_span`` cut
-  a slot's walk at the first sentinel entry on its way (a retired slot's row
-  is all sentinel: it walks nothing) and the kernel clamps what it reads
-  from the table — an out-of-range DMA takes the chip down, where a gather
-  only clamps.
-
-The grid runs the slots in turn (v5e has one TensorCore), so unequal
-lengths cost no balance.
 
 VMEM at GPT-2-large's shapes (page 16, KV·D = 1280, 20 heads, bfloat16;
 a group is 16 pages = 256 rows): K and V group buffers 2 x 2 x (256, 1280) x
@@ -64,11 +52,11 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .attention import _interpret
+from . import page_walk
+from .attention import on_tpu
 
-__all__ = ["paged_attention", "supports", "walk_lengths", "walk_span"]
+__all__ = ["paged_attention", "supports"]
 
-_NEG_INF = -1e30
 _ROWS = 256      # token rows a compute block (a group of pages) holds
 # profiler_xla._KERNEL_REGIONS knows both: the walk from position 0, and the
 # same kernel called with a start (a window layer's ring)
@@ -77,45 +65,11 @@ _NAME_WINDOW = "mx_paged_attention_window"
 
 
 def supports(lanes, dtype, page, num_heads, head_dim):
-    """Whether the kernel takes a pool of this static structure: rows of
-    ``lanes`` = whole 128-lane tiles holding whole heads, pages of whole
-    sublane tiles (16 rows of bfloat16, 8 of float32) that divide a compute
-    block, heads that group evenly over the K/V heads."""
-    dtype = jnp.dtype(dtype)
-    if dtype not in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32)):
-        return False
-    if lanes % 128 or lanes % head_dim or num_heads % (lanes // head_dim):
-        return False
-    sublanes = 8 * 4 // dtype.itemsize
-    return page % sublanes == 0 and _ROWS % page == 0
-
-
-def walk_lengths(pt, pos, page, num_pages):
-    """Per slot, how many cached tokens the kernel reads: ``pos[b]`` (the
-    tokens before the new one), cut at the row's first sentinel entry — a
-    retired slot walks nothing, whatever its stale ``pos`` says — and so
-    at the table's width.  ``(B,)`` int32; the same for every layer."""
-    owned = jnp.cumprod((pt < num_pages).astype(jnp.int32), axis=1)
-    return jnp.minimum(pos.astype(jnp.int32), owned.sum(axis=1) * page)
-
-
-def walk_span(pt, pos, page, num_pages, window):
-    """A window layer's walk over its RING ``pt`` ``(B, width)``: ``(ends,
-    starts)``, both ``(B,)`` int32 positions.  The new token at ``pos[b]``
-    sees the cached positions ``starts[b] = max(pos[b] - window + 1, 0)`` to
-    ``pos[b] - 1``; the walk runs from the ring entry of the start's page in
-    ring order and is cut at the first sentinel entry on its way, so a
-    retired slot (all sentinel) has ``ends[b] <= starts[b]`` and walks
-    nothing."""
-    pos = pos.astype(jnp.int32)
-    width = pt.shape[1]
-    starts = jnp.maximum(pos - (window - 1), 0)
-    first = starts // page
-    entries = (first[:, None] + jnp.arange(width, dtype=jnp.int32)[None]) \
-        % width
-    owned = jnp.cumprod((jnp.take_along_axis(pt, entries, axis=1)
-                         < num_pages).astype(jnp.int32), axis=1)
-    return jnp.minimum(pos, (first + owned.sum(axis=1)) * page), starts
+    """Whether the kernel takes a pool of this static structure: one
+    ``page_walk.pool_fits``, with rows of whole heads, pages that divide a
+    compute block, heads that group evenly over the K/V heads."""
+    return page_walk.pool_fits(lanes, dtype, page) and _ROWS % page == 0 \
+        and lanes % head_dim == 0 and num_heads % (lanes // head_dim) == 0
 
 
 def _spread_queries(q, kv, kvp, rows, dtype):
@@ -152,7 +106,6 @@ def _kernel(layer_ref, pt_ref, len_ref, start_ref,       # SMEM (prefetch)
             *, scale, page, maxp, num_pages, kv, kvp, groups, ring):
     b = pl.program_id(0)
     nslots = pl.num_programs(0)
-    per = _ROWS // page                 # pages a group
     lanes = kbuf.shape[-1]
     D = lanes // kv
     layer = layer_ref[0]
@@ -162,81 +115,26 @@ def _kernel(layer_ref, pt_ref, len_ref, start_ref,       # SMEM (prefetch)
     length = len_ref[b]                 # the walk's end, a position
     origin = first_page(b) * page       # position of the walk's column 0
     ngroups = pl.cdiv(length - origin, _ROWS)
-    prec = lax.Precision.HIGHEST if kbuf.dtype == jnp.float32 \
-        else lax.Precision.DEFAULT
+    prec = page_walk.precision(kbuf.dtype)
+    copy = page_walk.page_copy(
+        ((kp_ref, kbuf), (vp_ref, vbuf)), sems, pt_ref, len_ref, layer,
+        page=page, maxp=maxp, num_pages=num_pages, per=_ROWS // page,
+        starts=start_ref if ring else None)
 
-    def each_copy(slot_b, g, buf, act):
-        """``act`` on the K and the V copy of every page of group ``g`` of
-        slot ``slot_b`` into buffer ``buf`` (``start`` them, later ``wait``
-        for the same ones)."""
-        first = first_page(slot_b)
-        count = jnp.minimum(
-            pl.cdiv(len_ref[slot_b], page) - first - g * per, per)
+    def setup():
+        # the new token's own key opens the online softmax: its score is
+        # the running max, its weight 1, its V row the accumulator
+        qb = qb_ref[0]                                      # (rows, lanes)
+        kn = kn_ref[0].astype(jnp.float32)                  # (1, lanes)
+        m_ref[...] = jnp.sum(qb.astype(jnp.float32) * kn, axis=1,
+                             keepdims=True) * scale
+        l_ref[...] = jnp.ones_like(l_ref)
+        acc[...] = jnp.broadcast_to(vn_ref[0].astype(jnp.float32),
+                                    acc.shape)
+        return *page_walk.next_slot(b, nslots, lambda s: pl.cdiv(
+            len_ref[s] - first_page(s) * page, _ROWS)), qb
 
-        def body(j, carry):
-            entry = first + g * per + j
-            if ring:
-                entry = lax.rem(entry, maxp)
-            # checked against NPAGES though ``walk_lengths`` walks owned
-            # pages only: an id out of range must never reach a DMA
-            pid = jnp.clip(pt_ref[slot_b * maxp + entry], 0,
-                           num_pages - 1)
-            dst = pl.ds(pl.multiple_of(j * page, page), page)
-            for i, (pool, rows) in enumerate(((kp_ref, kbuf),
-                                              (vp_ref, vbuf))):
-                act(pltpu.make_async_copy(pool.at[layer, pid],
-                                          rows.at[buf, dst],
-                                          sems.at[i, buf]))
-            return carry
-        lax.fori_loop(0, count, body, 0)
-
-    def start(slot_b, g, buf):
-        each_copy(slot_b, g, buf, lambda c: c.start())
-
-    def wait(slot_b, g, buf):
-        each_copy(slot_b, g, buf, lambda c: c.wait())
-
-    @pl.when(b == 0)
-    def _():
-        # what a copy never fills is then zeros, not whatever VMEM held:
-        # a masked column's V takes part in p·V with weight exactly 0
-        kbuf[...] = jnp.zeros_like(kbuf)
-        vbuf[...] = jnp.zeros_like(vbuf)
-        state[0] = 0        # groups walked so far: a group's buffer is
-        state[1] = 0        # its number's parity.  1: this slot's first
-                            # group was started by the slot before it
-    first = state[0]
-
-    @pl.when((ngroups > 0) & (state[1] == 0))
-    def _():
-        start(b, 0, first % 2)
-
-    # the new token's own key opens the online softmax: its score is the
-    # running max, its weight 1, its V row the accumulator
-    qb = qb_ref[0]                                          # (rows, lanes)
-    kn = kn_ref[0].astype(jnp.float32)                      # (1, lanes)
-    m_ref[...] = jnp.sum(qb.astype(jnp.float32) * kn, axis=1,
-                         keepdims=True) * scale
-    l_ref[...] = jnp.ones_like(l_ref)
-    acc[...] = jnp.broadcast_to(vn_ref[0].astype(jnp.float32), acc.shape)
-
-    nxt = jnp.minimum(b + 1, nslots - 1)
-    next_groups = jnp.where(
-        b + 1 < nslots,
-        pl.cdiv(len_ref[nxt] - first_page(nxt) * page, _ROWS), 0)
-
-    def group(g, carry):
-        buf = (first + g) % 2
-
-        @pl.when(g + 1 < ngroups)
-        def _():
-            start(b, g + 1, 1 - buf)
-
-        @pl.when((g + 1 == ngroups) & (next_groups > 0))
-        def _():
-            start(nxt, 0, 1 - buf)
-
-        wait(b, g, buf)
+    def group(g, buf, qb):
         s = lax.dot_general(qb, kbuf[buf], (((1,), (1,)), ((), ())),
                             precision=prec,
                             preferred_element_type=jnp.float32) * scale
@@ -245,21 +143,12 @@ def _kernel(layer_ref, pt_ref, len_ref, start_ref,       # SMEM (prefetch)
         seen = col < length
         if ring:
             seen = seen & (col >= start_ref[b])
-        s = jnp.where(seen, s, _NEG_INF)
-        m_old = m_ref[...]
-        m_new = jnp.maximum(m_old, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m_old - m_new)
-        p = jnp.exp(s - m_new)
-        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
-        acc[...] = alpha * acc[...] + lax.dot_general(
-            p.astype(vbuf.dtype), vbuf[buf], (((1,), (0,)), ((), ())),
-            precision=prec, preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
-        return carry
+        page_walk.online_softmax(jnp.where(seen, s, page_walk.NEG_INF),
+                                 lambda: vbuf[buf], vbuf.dtype, m_ref, l_ref,
+                                 acc)
 
-    lax.fori_loop(0, ngroups, group, 0)
-    state[0] = first + ngroups
-    state[1] = ((ngroups > 0) & (next_groups > 0)).astype(jnp.int32)
+    page_walk.walk(state, copy, b, ngroups, setup, group, first_step=b == 0,
+                   nslots=nslots, zero=(kbuf, vbuf))
 
     # each head keeps its own D lanes of its row: the G heads of a K/V
     # head come out as G rows of the pool's lane layout
@@ -348,9 +237,5 @@ def paged_attention(q, k_new, v_new, kpool, vpool, layer, pt, lengths,
         return _kernel_call(q, k_new, v_new, kpool, vpool, layer, pt,
                             lengths, scale, interpret, *starts)
 
-    if _interpret():
-        return kernel(q, k_new, v_new, kpool, vpool, layer, pt, *span,
-                      interpret=True)
-    return lax.platform_dependent(
-        q, k_new, v_new, kpool, vpool, layer, pt, *span,
-        tpu=kernel, default=lambda *_: fallback())
+    return on_tpu(kernel, lambda *_: fallback(), q, k_new, v_new, kpool,
+                  vpool, layer, pt, *span)

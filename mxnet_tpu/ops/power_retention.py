@@ -60,7 +60,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .attention import _interpret
+from .attention import on_tpu
 
 __all__ = ["STATE_DTYPE", "expand", "expanded_rows", "exact_rows",
            "state_update", "chunk_scan", "supports", "prefill_kernels"]
@@ -263,7 +263,7 @@ def _routes(live):
     return slot, lead
 
 
-def _kernel_call(state, z, layer, live, q, k, v, log_a, interpret):
+def _kernel_call(state, z, layer, live, q, k, v, log_a, interpret=False):
     _, S, G, dv, E = state.shape
     d = k.shape[-1]
     hpg = q.shape[1] // G
@@ -351,14 +351,8 @@ def state_update(state, z, layer, q, k, v, log_a, live, eps):
     S, H, d = q.shape
     dv = v.shape[-1]
     args = (state, z, layer, live, q, k, v, log_a)
-    if not supports(d, dv):
-        num, den, state, z = _plain_call(*args)
-    elif _interpret():
-        num, den, state, z = _kernel_call(*args, interpret=True)
-    else:
-        num, den, state, z = lax.platform_dependent(
-            *args, tpu=functools.partial(_kernel_call, interpret=False),
-            default=_plain_call)
+    num, den, state, z = on_tpu(_kernel_call, _plain_call, *args) \
+        if supports(d, dv) else _plain_call(*args)
     y = num / (den + eps)[..., None]
     y = jnp.where(live[:, None, None, None], y, 0.0)
     return y.reshape(S, H, dv), state, z
@@ -528,15 +522,6 @@ def _write_call(x, c, v, E, interpret):
     )(xt, cp, vt)
 
 
-def _kernels_or(kernel, plain, *args):
-    """``kernel(*args, interpret)`` where prefill's kernels run (a TPU, or
-    ``MXNET_FLASH_INTERPRET=1``), else ``plain(*args)``."""
-    if _interpret():
-        return kernel(*args, True)
-    return lax.platform_dependent(
-        *args, tpu=functools.partial(kernel, interpret=False), default=plain)
-
-
 def chunk_scan(q, k, v, log_a, init, init_z, count, chunk, eps):
     """``T`` tokens a row from the state ``(init, init_z)``, in the ATTENTION
     form within the dispatch and the state form only at its two ends.  ``q``
@@ -615,7 +600,7 @@ def chunk_scan(q, k, v, log_a, init, init_z, count, chunk, eps):
                     jnp.einsum("btgjE,bgE->btgj", pq, init_z.astype(mdt),
                                preferred_element_type=f32))
 
-        def read_kernel(q_all, interpret):
+        def read_kernel(q_all, interpret=False):
             # z as a row below the state's, padded to a bf16 tile
             sa = jnp.concatenate(
                 [init.astype(mdt), jnp.pad(init_z[:, :, None].astype(mdt),
@@ -627,7 +612,7 @@ def chunk_scan(q, k, v, log_a, init, init_z, count, chunk, eps):
             return (jnp.transpose(out[:, :, :dv], (0, 4, 1, 3, 2)),
                     jnp.transpose(out[:, :, dv], (0, 3, 1, 2)))
 
-        nx, dx = _kernels_or(read_kernel, read_plain, qg) \
+        nx, dx = on_tpu(read_kernel, read_plain, qg) \
             if prefill_kernels(d) else read_plain(qg)
         return nx * dec[..., None], dx * dec
 
@@ -642,14 +627,14 @@ def chunk_scan(q, k, v, log_a, init, init_z, count, chunk, eps):
         return jnp.einsum("bsgE,bsgv->bgvE", pk, vv,
                           preferred_element_type=f32)
 
-    def write_kernel(kk, c, vv, interpret):
+    def write_kernel(kk, c, vv, interpret=False):
         flat = lambda x: jnp.moveaxis(x, 2, 1).reshape(
             (Bn * G, T) + x.shape[3:])
         out = _write_call(flat(kk), flat(c[..., None])[..., 0], flat(vv), E,
                           interpret)
         return out.reshape(Bn, G, dv, E)
 
-    added = _kernels_or(write_kernel, write_plain, k, to_end, vm) \
+    added = on_tpu(write_kernel, write_plain, k, to_end, vm) \
         if prefill_kernels(d) else write_plain(k, to_end, vm)
     gram = jnp.einsum("bsgi,bsgj->bgij", k.astype(f32) * to_end[..., None],
                       k.astype(f32), precision=_HIGHEST)

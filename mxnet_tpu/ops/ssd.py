@@ -43,7 +43,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .attention import _interpret
+from .attention import on_tpu
 
 __all__ = ["STATE_DTYPE", "heads_per_row", "pack", "unpack", "state_update",
            "chunk_scan", "conv_step", "conv_seq", "supports"]
@@ -132,7 +132,7 @@ def _kernel(layer_ref, live_ref, s_ref, da_ref, dtx_ref, b_ref, c_ref,
     lax.fori_loop(0, rows, row, 0)
 
 
-def _kernel_call(state, layer, live, da, dtx, bcol, ccol, interpret):
+def _kernel_call(state, layer, live, da, dtx, bcol, ccol, interpret=False):
     _, S, H2, N, L = state.shape
     hb = max(1, min(H2, _BLOCK_BYTES // (N * L * 4)))
     while H2 % hb:
@@ -185,14 +185,8 @@ def state_update(state, layer, x, dt, a, b, c, live):
     dtx = _rows(dt[..., None] * x.astype(f32), g)
     spread = lambda v: jnp.broadcast_to(v.astype(f32)[..., None], (S, N, L))
     args = (state, layer, live, da, dtx, spread(b), spread(c))
-    if _interpret():
-        state, y = _kernel_call(*args, interpret=True)
-    elif supports(state.shape):
-        state, y = lax.platform_dependent(
-            *args, tpu=functools.partial(_kernel_call, interpret=False),
-            default=_plain_call)
-    else:
-        state, y = _plain_call(*args)
+    state, y = on_tpu(_kernel_call, _plain_call, *args) \
+        if supports(state.shape) else _plain_call(*args)
     return _unrows(y, P), state
 
 

@@ -15,7 +15,7 @@ import pytest
 
 from mxnet_tpu.models import layered
 from mxnet_tpu.ops import index_scores as ix
-from mxnet_tpu.ops import paged_attention as pa
+from mxnet_tpu.ops import page_walk
 
 B, PAGE, MAXP, NL, J, DI = 4, 16, 20, 2, 4, 128
 ROWS = 128                      # a group of 8 pages: the table holds 2.5
@@ -96,7 +96,7 @@ def case(dtype):
     rows_of = rng.randn(B, MAXP, PAGE, 128).astype("float32")
 
     def kernel(pool, pt, pos):
-        ends = pa.walk_lengths(pt, pos + 1, PAGE, NPAGES)
+        ends = page_walk.walk_lengths(pt, pos + 1, PAGE, NPAGES)
         return ix._kernel_call(q, w, pool, jnp.int32(LAYER), pt, ends,
                                True, rows=ROWS, sub=BLOCK)
 
@@ -189,14 +189,14 @@ def test_every_table_read_is_clamped(case, dtype, bad):
     # with a bad id go page by page, the five others as one copy each
     assert onp.asarray(counts)[0].tolist() == [13, 9, MAXP]
     assert _copies(pt[0], 13) == 9
-    flags = onp.asarray(ix.group_runs(jnp.asarray(pt), ends, PAGE, PER,
-                                      SUB, NPAGES))
+    flags = onp.asarray(page_walk.group_runs(jnp.asarray(pt), ends, PAGE,
+                                             PER, SUB, NPAGES))
     # bit k: block k of the group is a run; 1 << 4: the whole group
     assert flags[0, :2].tolist() == [0b1101, 0b1110]
     # a run that would END outside the pool is none either: of the held
     # pages 93, 94, 95 the block 93, 94 is one, the block from 95 not
     last = jnp.asarray([[NPAGES - 3 + j for j in range(PER)]], jnp.int32)
-    assert onp.asarray(ix.group_runs(
+    assert onp.asarray(page_walk.group_runs(
         last, jnp.asarray([3 * PAGE]), PAGE, PER, SUB,
         NPAGES)).tolist() == [[0b0001]]
 

@@ -14,6 +14,7 @@ import pytest
 
 import mxnet_tpu as mx
 from mxnet_tpu.models import decoding
+from mxnet_tpu.ops import page_walk
 from mxnet_tpu.ops import paged_attention as pa
 
 B, PAGE, MAXP, NL, D = 4, 16, 20, 2, 64
@@ -71,7 +72,7 @@ def case(request, dtype):
             dtype).reshape(B, H * D)
 
     def kernel(pos):
-        lengths = pa.walk_lengths(pt, pos, PAGE, NPAGES)
+        lengths = page_walk.walk_lengths(pt, pos, PAGE, NPAGES)
         return pa._kernel_call(q, kn, vn, kp, vp, layer, pt, lengths,
                                SCALE, interpret=True)
 
@@ -129,8 +130,8 @@ def test_retired_slot_walks_nothing(case):
     _, kernel, _ = case
     pt = jnp.asarray(_tables(onp.random.RandomState(3)))
     pos = jnp.asarray([5, 37, 300, 77], jnp.int32)
-    assert onp.asarray(pa.walk_lengths(pt, pos, PAGE, NPAGES)).tolist() == \
-        [5, 37, 300, 0]
+    assert onp.asarray(page_walk.walk_lengths(
+        pt, pos, PAGE, NPAGES)).tolist() == [5, 37, 300, 0]
     assert onp.isfinite(onp.asarray(
         kernel(pos).astype(jnp.float32))[RETIRED]).all()
 
@@ -139,8 +140,8 @@ def test_walk_stops_at_the_first_sentinel_and_the_table_width():
     pt = jnp.asarray([[3, 4, NPAGES, 5], [0, 1, 2, 3], [NPAGES] * 4,
                       [7, NPAGES, NPAGES, NPAGES]], jnp.int32)
     pos = jnp.asarray([60, 999, 9, 16], jnp.int32)
-    assert onp.asarray(pa.walk_lengths(pt, pos, PAGE, NPAGES)).tolist() == \
-        [2 * PAGE, 4 * PAGE, 0, 16]
+    assert onp.asarray(page_walk.walk_lengths(
+        pt, pos, PAGE, NPAGES)).tolist() == [2 * PAGE, 4 * PAGE, 0, 16]
 
 
 @pytest.mark.parametrize("lanes, dtype, page, heads, ok", [

@@ -30,6 +30,7 @@ from mxnet_tpu import models, serve
 from mxnet_tpu.base import MXNetError
 from mxnet_tpu.models import decoding, layered, trinity
 from mxnet_tpu.ops import moe
+from mxnet_tpu.ops import page_walk
 from mxnet_tpu.ops import paged_attention as pa
 from mxnet_tpu.serve import schema
 
@@ -309,9 +310,9 @@ def test_kernel_walks_a_ring_from_its_start(dtype):
             table[b, lp % width] = free.pop()
     ends, starts = [], []
     for b in range(B):              # one window a slot: one call each
-        e, s = pa.walk_span(jnp.asarray(table[b:b + 1]),
-                            jnp.asarray(pos[b:b + 1]), page, npages,
-                            int(window[b]))
+        e, s = page_walk.walk_span(jnp.asarray(table[b:b + 1]),
+                                   jnp.asarray(pos[b:b + 1]), page, npages,
+                                   int(window[b]))
         ends.append(int(e[0]))
         starts.append(int(s[0]))
     assert ends[-1] <= starts[-1]           # the retired slot walks nothing
@@ -354,8 +355,9 @@ def test_walk_without_a_start_is_the_walk_from_zero():
     kn = jnp.asarray(rng.randn(3, KV * D), jnp.float32)
     vn = jnp.asarray(rng.randn(3, KV * D), jnp.float32)
     plain = pa._kernel_call(q, kn, vn, kp, vp, jnp.int32(0), pt,
-                            pa.walk_lengths(pt, pos, page, 40), 0.125, True)
-    ends, starts = pa.walk_span(pt, pos, page, 40, 10 ** 6)
+                            page_walk.walk_lengths(pt, pos, page, 40), 0.125,
+                            True)
+    ends, starts = page_walk.walk_span(pt, pos, page, 40, 10 ** 6)
     assert list(np.asarray(starts)) == [0, 0, 0]
     ring = pa._kernel_call(q, kn, vn, kp, vp, jnp.int32(0), pt, ends, 0.125,
                            True, starts)
